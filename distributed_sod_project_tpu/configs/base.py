@@ -119,7 +119,7 @@ class ModelConfig:
     #   fused — Pallas fused resample-merge (pallas/fused_resample.py):
     #           upsample + add/concat as ONE VMEM pass per image.
     #           Knob-gated pending a hardware A/B win (the pre-committed
-    #           non-XLA-default rule; legs in tools/tpu_agenda_r5.sh).
+    #           non-XLA-default rule; not measured on a chip).
     resample_impl: str = "fast"  # fast | xla | convt | fused
     # Conv-block execution strategy (minet / hdfnet / gatenet / u2net —
     # every ConvBNAct in the four decoder families AND their VGG/ResNet
@@ -135,7 +135,7 @@ class ModelConfig:
     #           budget) fall back per-site.  Composes with the serve
     #           precision arms (int8/fp8 weights dequantize in-kernel).
     #           Knob-gated pending a hardware A/B win (the pre-committed
-    #           non-XLA-default rule; legs in tools/tpu_agenda_r14.sh).
+    #           non-XLA-default rule; not measured on a chip).
     conv_impl: str = "xla"  # xla | fused
     pretrained: Optional[str] = None  # .npz from tools/port_torch_weights.py
     # Structural deep supervision for models where aux heads are

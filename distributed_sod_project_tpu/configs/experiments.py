@@ -124,9 +124,10 @@ def vit_sod_hires() -> ExperimentConfig:
     the N² scores fit in HBM, and the pre-committed decision rule says
     flash must measurably win to be a default (docs/PERFORMANCE.md).
     ``--set model.attn_impl=flash`` remains the documented memory
-    lever — at b16/N=4096 it runs where XLA OOMs — and the round-4
-    block sweep (tools/tpu_agenda_r4.sh leg 6) re-flips this default
-    if any block shape beats XLA at this config's operating point."""
+    lever — at b16/N=4096 it runs where XLA OOMs; a block-shape sweep
+    (tools/bench_flash.py; not measured on a chip) re-flips this
+    default if any block shape beats XLA at this config's operating
+    point."""
     return ExperimentConfig(
         name="vit_sod_hires",
         data=DataConfig(dataset="duts", image_size=(1024, 1024)),

@@ -120,11 +120,6 @@ def restore_for_eval(ckpt_dir: str, config_name: Optional[str] = None,
     from ..configs import apply_overrides, config_from_dict, get_config
     from ..models import build_model
     from ..train import build_optimizer, create_train_state
-    from ..utils.platform import maybe_enable_compilation_cache
-
-    # Before the first compile (create_train_state's model.init) so the
-    # persistent cache covers it too.
-    maybe_enable_compilation_cache()
 
     if config_name:
         cfg = get_config(config_name)

@@ -199,7 +199,7 @@ class ConvBNAct(nn.Module):
         fits = (self.strides == 1 and kh % 2 == 1 and kw % 2 == 1
                 and fc.fused_conv_available(
                     [tuple(p.shape) for p in parts], (kh, kw),
-                    self.dilation, self.features))
+                    self.dilation, self.features, dtype=cd))
         if not fits:
             # Out of envelope: trace-time note so a fused A/B leg knows
             # which sites opted out (fires once per compile, not per
@@ -471,8 +471,8 @@ def _upsample2_axis_convt(x, axis: int):
     interleave of ``_upsample_axis`` costing ~1.25 ms relayout copies
     per call at b64 (data-formatting = 10% of the step) — a conv's
     output needs no relayout.  Why it might lose: depthwise convs run
-    on the VPU with kernel overhead per channel.  Hardware A/B leg:
-    ``rsz_convt`` in tools/tpu_agenda_r4.sh.
+    on the VPU with kernel overhead per channel.  Not measured on a
+    chip.
     """
     import jax.lax as lax
 
@@ -505,8 +505,7 @@ def _resolve_resample_impl(impl: Optional[str]) -> str:
     ``model.resample_impl`` (threaded through the decoder modules as an
     explicit ``impl``) subsumes the ``DSOD_RESIZE_IMPL`` env knob: an
     explicit non-default impl always wins; at the default (``None`` /
-    ``"fast"``) a set env var still selects the arm, so the recorded
-    A/B legs (``rsz_convt`` etc. in tools/tpu_agenda_r4.sh) and the
+    ``"fast"``) a set env var still selects the arm, so the
     BASELINE.md measurement commands keep working unchanged.
     """
     from ..utils import envvars

@@ -39,26 +39,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax ≥ 0.6 renamed TPUCompilerParams → CompilerParams; take whichever
-# this jax ships (the utils/compat.py version-skew pattern — same
-# vmem_limit_bytes keyword either way).
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 # Beyond this many f32 elements for the padded x tile, fall back to the
 # XLA im2col path rather than risk VMEM pressure (≈8 MB at f32, and the
 # kernel maps add T·H·W on top).
 _MAX_TILE_ELEMS = 2 * 1024 * 1024
+_LANES = 128
 
-def _compiler_params() -> "_CompilerParams":
-    """Per-kernel scoped-VMEM ceiling, gated on the device generation.
-
-    The round-2 compile-failure history and the ADVICE-r3 v2/v3
-    small-VMEM denylist rule now live in the shared helper
-    (pallas/vmem_budget.py) so every kernel applies the same policy;
-    ``DSOD_DLF_VMEM_MB`` stays this kernel's escape hatch (0 =
-    compiler default).
-    """
+def _compiler_params() -> pltpu.CompilerParams:
+    """Scoped-VMEM ceiling via the shared rule
+    (pallas/vmem_budget.py); ``DSOD_DLF_VMEM_MB`` stays this kernel's
+    escape hatch (0 = compiler default)."""
     from .vmem_budget import scoped_vmem_params
 
     return scoped_vmem_params("DSOD_DLF_VMEM_MB")
@@ -182,10 +172,15 @@ def fused_dynamic_filter_available(shape, ksize: int,
     """True when one grid step's tiles fit the kernel's VMEM budget.
     Counts BOTH the padded x/cotangent tile (C channels) and the
     tap-major kernel-map tile (ksize² planes) — the backward loads the
-    padded kernel maps too, which dominate at low channel counts."""
+    padded kernel maps too, which dominate at low channel counts.
+    The padded width must also fit ONE 128-lane row of the tap-major
+    kernel maps: past it the v5e compiler refuses the backward's
+    lane-offset slice (``Unsupported reshape`` at 160+2r; 120+2r=128
+    compiles — tests/test_chip_compile.py)."""
     _, h, w, c = shape
     r = dilation * (ksize // 2)
-    return ((h + 2 * r) * (w + 2 * r) * (c + ksize * ksize)
+    return (w + 2 * r <= _LANES
+            and (h + 2 * r) * (w + 2 * r) * (c + ksize * ksize)
             <= _MAX_TILE_ELEMS)
 
 

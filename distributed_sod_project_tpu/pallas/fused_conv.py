@@ -68,10 +68,11 @@ never pays that write.
 Like the other kernels here: one image per grid step, f32-element VMEM
 budget checked by the CALLER (``layers.ConvBNAct``) via
 :func:`fused_conv_available` with per-site fallback, scoped-VMEM
-ceiling via the shared v2/v3 denylist rule (pallas/vmem_budget.py,
+ceiling via the shared rule (pallas/vmem_budget.py,
 ``DSOD_CONV_VMEM_MB`` override), ``interpret`` auto (interpret on CPU,
-Mosaic on TPU), exactness + the Mosaic lowering guarded in
-tests/test_pallas_conv.py via ``jax.export(platforms=['tpu'])``.
+Mosaic on TPU), exactness guarded in tests/test_pallas_conv.py and the
+v5e compiler's verdict at the flagship's shapes in
+tests/test_chip_compile.py.
 """
 
 from __future__ import annotations
@@ -111,9 +112,9 @@ def is_quantized_weight(w) -> bool:
 
 
 def _compiler_params():
-    """Scoped-VMEM ceiling via the shared v2/v3 small-VMEM denylist
-    rule (pallas/vmem_budget.py); ``DSOD_CONV_VMEM_MB`` overrides
-    either way (0 = compiler default)."""
+    """Scoped-VMEM ceiling via the shared rule
+    (pallas/vmem_budget.py); ``DSOD_CONV_VMEM_MB`` overrides either
+    way (0 = compiler default)."""
     from .vmem_budget import scoped_vmem_params
 
     return scoped_vmem_params("DSOD_CONV_VMEM_MB")
@@ -138,13 +139,19 @@ class _Spec(NamedTuple):
 
 def fused_conv_available(part_shapes: Sequence[Tuple[int, ...]],
                          kernel: Tuple[int, int], dilation: int,
-                         features: int) -> bool:
-    """True when one grid step's tiles fit the f32-element VMEM budget.
-    Callers fall back to the XLA path otherwise (same numerics, no
-    fusion).  Static shape constraints (stride 1, odd kernel) are the
-    caller's gate — this prices only the memory envelope."""
+                         features: int, dtype=jnp.float32) -> bool:
+    """True when one grid step's tiles fit the f32-element VMEM budget
+    and the v5e compiler takes the in-kernel im2col reshape.  Callers
+    fall back to the XLA path otherwise (same numerics, no fusion).
+    Static shape constraints (stride 1, odd kernel) are the caller's
+    gate."""
     kh, kw = kernel
     _, h, w, _ = part_shapes[0]
+    if jnp.dtype(dtype).itemsize < 4 and w % 2:
+        # (rows, w, taps) -> (rows*w, taps) on a packed (sub-32-bit)
+        # dtype with odd w: "unsupported shape cast" from the v5e
+        # compiler (the 5x5 stage of the 320 px flagship in bf16).
+        return False
     cin = sum(int(s[-1]) for s in part_shapes)
     ph, pw = dilation * (kh // 2), dilation * (kw // 2)
     taps = kh * kw * cin
@@ -298,8 +305,8 @@ def _call_fwd(parts, w, vecs: Dict[str, Any], spec: _Spec,
         out_specs=out_specs if save_preact else out_specs[0],
         out_shape=out_shape if save_preact else out_shape[0],
         cost_estimate=pl.CostEstimate(
-            flops=2.0 * b * h * wd * cout * taps, transcendentals=0,
-            bytes_accessed=float(
+            flops=2 * b * h * wd * cout * taps, transcendentals=0,
+            bytes_accessed=int(
                 sum(p.size * p.dtype.itemsize for p in parts)
                 + w.size * w.dtype.itemsize
                 + (2 if save_preact else 1) * b * h * wd * cout
@@ -323,9 +330,9 @@ def _call_dw(parts, g, spec: _Spec):
         out_shape=jax.ShapeDtypeStruct(
             (spec.kh, spec.kw, cin, cout), jnp.float32),
         cost_estimate=pl.CostEstimate(
-            flops=2.0 * b * h * wd * cout * spec.kh * spec.kw * cin,
+            flops=2 * b * h * wd * cout * spec.kh * spec.kw * cin,
             transcendentals=0,
-            bytes_accessed=float(
+            bytes_accessed=int(
                 sum(p.size * p.dtype.itemsize for p in parts)
                 + g.size * g.dtype.itemsize
                 + 4 * spec.kh * spec.kw * cin * cout)),

@@ -25,9 +25,9 @@ half-pixel centers, edge taps renormalised == index clamping)::
     out[2i]   = 0.25*x[i-1] + 0.75*x[i]     (x[-1] -> x[0])
     out[2i+1] = 0.75*x[i]   + 0.25*x[i+1]   (x[n]  -> x[n-1])
 
-applied separably H then W.  The in-kernel interleave uses the same
-concat-in-next-axis form the layout-stable XLA path uses (a VMEM
-shuffle here, never an HBM relayout).
+applied separably H then W.  The in-kernel interleave is four strided
+phase stores into a VMEM staging ref (``_up2_into``), never an HBM
+relayout.
 
 Backward is a closed form, not a recompute: the op is linear in both
 operands, so ``d_lateral`` is the cotangent (or its channel slab) and
@@ -43,9 +43,9 @@ as a second gather-form kernel with the axes applied in reverse order.
 
 Like the other kernels here: one image per grid step, a VMEM budget
 guard with fallback handled by the caller (``layers.resample_merge``),
-``interpret`` auto (interpret on CPU, Mosaic on TPU), parity + the
-Mosaic lowering guarded in tests/test_pallas_resample.py via
-``jax.export(platforms=['tpu'])``.
+``interpret`` auto (interpret on CPU, Mosaic on TPU), parity guarded
+in tests/test_pallas_resample.py and the v5e compiler's verdict at the
+flagship's shapes in tests/test_chip_compile.py.
 """
 
 from __future__ import annotations
@@ -58,27 +58,24 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax >= 0.6 renamed TPUCompilerParams -> CompilerParams (the
-# utils/compat.py version-skew posture, as in dynamic_filter.py).
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
-# f32-element budget for ONE grid step's tiles (padded coarse input +
-# lateral + merged output).  6M elems ~= 24 MB f32 against the 100 MB
-# scoped-VMEM ceiling — sized so EVERY flagship fine-decoder site fits,
-# including the largest, SIM-0's concat merge (80x80x32 up into
-# 160x160x64 -> 96ch out = 4.31M elems, which a 4M budget silently
-# excluded — exactly the 160-bucket stage lever #1 targets).  Oversize
-# maps (e.g. U²-Net's full-width 160->320 concat, 21M elems) fall back
-# to the XLA path via ``fused_resample_available``; v2/v3 (~16 MB/core)
-# would need DSOD_RESAMPLE_VMEM_MB=0 plus a smaller budget, but the
-# fused arm is a knob-gated experiment aimed at v4+/v5e.
-_MAX_TILE_ELEMS = 6 * 1024 * 1024
+# Element budget for ONE grid step's tiles AS VMEM HOLDS THEM (padded
+# coarse input + f32 staging ref + lateral + merged output; channels
+# round up to the 128 lanes, width to the 8 sublanes).  12M elems
+# against the 100 MB scoped-VMEM ceiling — sized so every flagship
+# fine-decoder site fits, including the largest, SIM-0's concat merge
+# (80x80x32 up into 160x160x64 -> 96ch out = 10.8M padded elems; the
+# v5e compiler accepts it in bf16 and f32 — tests/test_chip_compile.py).
+# What gives way to the XLA path via ``fused_resample_available``:
+# oversize maps (U²-Net's full-width 160->320 concat) and
+# narrow-channel maps whose lane padding dwarfs the data (the 1-channel
+# 160->320 saliency head: 128x waste, 13M elems for the staging ref
+# alone, 182 MB of VMEM asked of a 128 MB core).
+_MAX_TILE_ELEMS = 12 * 1024 * 1024
 
 
-def _compiler_params() -> "_CompilerParams":
-    """Scoped-VMEM ceiling via the shared v2/v3 small-VMEM denylist
-    rule (pallas/vmem_budget.py); ``DSOD_RESAMPLE_VMEM_MB`` overrides
+def _compiler_params() -> pltpu.CompilerParams:
+    """Scoped-VMEM ceiling via the shared rule
+    (pallas/vmem_budget.py); ``DSOD_RESAMPLE_VMEM_MB`` overrides
     either way (0 = compiler default)."""
     from .vmem_budget import scoped_vmem_params
 
@@ -96,16 +93,6 @@ def _img_spec(shape):
                         lambda i, _n=n: (i,) + (0,) * _n)
 
 
-def _ileave(e, o, axis):
-    """Interleave two equal blocks along ``axis``: out[2i]=e[i],
-    out[2i+1]=o[i].  Concat-in-next-axis + merge reshape — the same
-    row-major identity the layout-stable XLA interleave uses."""
-    t = jnp.concatenate([e, o], axis=axis + 1)
-    shape = list(e.shape)
-    shape[axis] *= 2
-    return t.reshape(tuple(shape))
-
-
 def _clamp_pad(x):
     """Edge-replicate pad by 1 in both spatial dims — VALUE-level, so
     the padded map lives only in VMEM.  (An earlier draft jnp.pad'ed
@@ -116,29 +103,40 @@ def _clamp_pad(x):
     return jnp.concatenate([x[:, 0:1], x, x[:, -1:]], axis=1)
 
 
-def _up2_vals(x):
-    """(h, w, C) f32 tile -> (2h, 2w, C) upsampled (clamped edges)."""
+def _up2_into(x, up_ref):
+    """(h, w, C) f32 tile -> the (2h, 2w, C) f32 VMEM ref ``up_ref``
+    (clamped edges), as four phase writes ``up[2i+a, 2j+b]``.
+
+    The interleave is a STRIDED STORE, not a value reshape: the v5e
+    compiler refuses the lane-changing shape cast an in-register
+    interleave needs (``(2h, w, 2C) -> (2h, 2w, C)``: "unsupported
+    shape cast"), and refuses strided stores of sub-32-bit data — hence
+    the f32 staging ref for bf16 outputs."""
     h, w = x.shape[0], x.shape[1]
     xp = _clamp_pad(x)                             # (h+2, w+2, C), VMEM
-    e = 0.25 * xp[0:h] + 0.75 * xp[1:h + 1]
-    o = 0.75 * xp[1:h + 1] + 0.25 * xp[2:h + 2]
-    y = _ileave(e, o, axis=0)                      # (2h, w+2, C)
-    ew = 0.25 * y[:, 0:w] + 0.75 * y[:, 1:w + 1]
-    ow = 0.75 * y[:, 1:w + 1] + 0.25 * y[:, 2:w + 2]
-    return _ileave(ew, ow, axis=1)                 # (2h, 2w, C)
+    rows = (0.25 * xp[0:h] + 0.75 * xp[1:h + 1],      # out rows 2i
+            0.75 * xp[1:h + 1] + 0.25 * xp[2:h + 2])  # out rows 2i+1
+    for a, y in enumerate(rows):
+        up_ref[pl.ds(a, h, stride=2), pl.ds(0, w, stride=2), :] = (
+            0.25 * y[:, 0:w] + 0.75 * y[:, 1:w + 1])
+        up_ref[pl.ds(a, h, stride=2), pl.ds(1, w, stride=2), :] = (
+            0.75 * y[:, 1:w + 1] + 0.25 * y[:, 2:w + 2])
 
 
-def _up_kernel(x_ref, o_ref):
-    o_ref[0] = _up2_vals(x_ref[0].astype(jnp.float32)).astype(o_ref.dtype)
+def _up_kernel(x_ref, o_ref, up_ref):
+    _up2_into(x_ref[0].astype(jnp.float32), up_ref)
+    o_ref[0] = up_ref[...].astype(o_ref.dtype)
 
 
-def _up_add_kernel(x_ref, lat_ref, o_ref):
-    up = _up2_vals(x_ref[0].astype(jnp.float32))
-    o_ref[0] = (up + lat_ref[0].astype(jnp.float32)).astype(o_ref.dtype)
+def _up_add_kernel(x_ref, lat_ref, o_ref, up_ref):
+    _up2_into(x_ref[0].astype(jnp.float32), up_ref)
+    o_ref[0] = (up_ref[...] + lat_ref[0].astype(jnp.float32)
+                ).astype(o_ref.dtype)
 
 
-def _up_cat_kernel(x_ref, lat_ref, o_ref, *, cx, x_first):
-    up = _up2_vals(x_ref[0].astype(jnp.float32)).astype(o_ref.dtype)
+def _up_cat_kernel(x_ref, lat_ref, o_ref, up_ref, *, cx, x_first):
+    _up2_into(x_ref[0].astype(jnp.float32), up_ref)
+    up = up_ref[...].astype(o_ref.dtype)
     lat = lat_ref[0].astype(o_ref.dtype)
     if x_first:
         o_ref[0, :, :, :cx] = up
@@ -149,22 +147,13 @@ def _up_cat_kernel(x_ref, lat_ref, o_ref, *, cx, x_first):
         o_ref[0, :, :, cl:] = up
 
 
-def _deint_T(g, axis):
-    """One axis of the transposed upsample: (…, 2n, …) -> (…, n, …).
-
-    Splits even/odd phases by the inverse of the interleave reshape,
-    then applies ``dx = 0.75*(ge+go) + 0.25*(go<<1 + ge>>1)`` with the
+def _lerp_T(ge, go, axis):
+    """One axis of the transposed upsample from its even/odd output
+    phases: ``dx = 0.75*(ge+go) + 0.25*(go<<1 + ge>>1)`` with the
     edge-clamp corrections folded into the shifted operands
     (``go[-1] -> ge[0]``, ``ge[n] -> go[n-1]`` — derivation in the
     module docstring)."""
-    n = g.shape[axis] // 2
-    shape = list(g.shape)
-    shape[axis] = n
-    shape[axis + 1] *= 2
-    t = g.reshape(tuple(shape))                    # inverse interleave
-    m = g.shape[axis + 1]
-    ge = lax.slice_in_dim(t, 0, m, axis=axis + 1)
-    go = lax.slice_in_dim(t, m, 2 * m, axis=axis + 1)
+    n = ge.shape[axis]
     if n == 1:  # both shifts degenerate to the other phase's only row
         return ge + go
     go_shift = jnp.concatenate(  # go[j-1], with go[-1] := ge[0]
@@ -176,10 +165,25 @@ def _deint_T(g, axis):
     return 0.75 * (ge + go) + 0.25 * (go_shift + ge_shift)
 
 
-def _upT_kernel(g_ref, dx_ref):
-    g = g_ref[0].astype(jnp.float32)
-    dx = _deint_T(_deint_T(g, axis=1), axis=0)  # reverse of fwd order
+def _upT_kernel(g_ref, dx_ref, g32_ref):
+    # Phase split by strided LOADS from an f32 staging copy (same two
+    # compiler limits as the forward's strided stores).
+    g32_ref[...] = g_ref[0].astype(jnp.float32)
+    h, w = dx_ref.shape[1], dx_ref.shape[2]
+
+    def phase(a, b):
+        return g32_ref[pl.ds(a, h, stride=2), pl.ds(b, w, stride=2), :]
+
+    # W first, then H: the reverse of the forward's order.
+    dx = _lerp_T(_lerp_T(phase(0, 0), phase(0, 1), axis=1),
+                 _lerp_T(phase(1, 0), phase(1, 1), axis=1), axis=0)
     dx_ref[0] = dx.astype(dx_ref.dtype)
+
+
+def _staging(h2, w2, c):
+    """The f32 (2h, 2w, C) VMEM staging ref the strided phase
+    stores/loads go through."""
+    return [pltpu.VMEM((h2, w2, c), jnp.float32)]
 
 
 def _call_up(x, interpret):
@@ -190,8 +194,9 @@ def _call_up(x, interpret):
         in_specs=[_img_spec(x.shape[1:])],
         out_specs=_img_spec((2 * h, 2 * w, c)),
         out_shape=jax.ShapeDtypeStruct((b, 2 * h, 2 * w, c), x.dtype),
+        scratch_shapes=_staging(2 * h, 2 * w, c),
         cost_estimate=pl.CostEstimate(
-            flops=16.0 * b * h * w * c, transcendentals=0,
+            flops=16 * b * h * w * c, transcendentals=0,
             bytes_accessed=(x.size + 4 * b * h * w * c) * 4),
         interpret=interpret,
         compiler_params=_compiler_params(),
@@ -212,8 +217,9 @@ def _call_merge(x, lat, mode, x_first, interpret):
         in_specs=[_img_spec(x.shape[1:]), _img_spec(lat.shape[1:])],
         out_specs=_img_spec((2 * h, 2 * w, c_out)),
         out_shape=jax.ShapeDtypeStruct((b, 2 * h, 2 * w, c_out), x.dtype),
+        scratch_shapes=_staging(2 * h, 2 * w, c),
         cost_estimate=pl.CostEstimate(
-            flops=(16.0 + 4.0) * b * h * w * c, transcendentals=0,
+            flops=(16 + 4) * b * h * w * c, transcendentals=0,
             bytes_accessed=(x.size + lat.size
                             + 4 * b * h * w * c_out) * 4),
         interpret=interpret,
@@ -229,6 +235,7 @@ def _call_upT(g, interpret):
         in_specs=[_img_spec(g.shape[1:])],
         out_specs=_img_spec((hh // 2, ww // 2, c)),
         out_shape=jax.ShapeDtypeStruct((b, hh // 2, ww // 2, c), g.dtype),
+        scratch_shapes=_staging(hh, ww, c),
         interpret=interpret,
         compiler_params=_compiler_params(),
     )(g)
@@ -290,16 +297,24 @@ def fused_resample_available(x_shape, out_hw, mode: str = "none",
                              lat_channels: int = 0) -> bool:
     """True when the fused kernel applies: the target is exactly a 2x
     upsample per axis AND one grid step's tiles (padded coarse input +
-    lateral + merged output, f32) fit the VMEM budget.  Callers fall
-    back to the XLA path otherwise (same numerics, no fusion)."""
+    f32 staging + lateral + merged output, at their VMEM footprint) fit
+    the budget.  Callers fall back to the XLA path otherwise (same
+    numerics, no fusion)."""
     b, h, w, c = x_shape
     if tuple(out_hw) != (2 * h, 2 * w):
         return False
-    elems = (h + 2) * (w + 2) * c
+    elems = _vmem_elems(h + 2, w + 2, c) + _vmem_elems(2 * h, 2 * w, c)
     if mode in ("add", "concat"):
-        elems += 4 * h * w * lat_channels
-    elems += 4 * h * w * (c + (lat_channels if mode == "concat" else 0))
+        elems += _vmem_elems(2 * h, 2 * w, lat_channels)
+    elems += _vmem_elems(
+        2 * h, 2 * w, c + (lat_channels if mode == "concat" else 0))
     return elems <= _MAX_TILE_ELEMS
+
+
+def _vmem_elems(h: int, w: int, c: int) -> int:
+    """Elements an (h, w, c) tile occupies in VMEM: the minor dim pads
+    to 128 lanes, the second-minor to 8 sublanes."""
+    return h * (-(-w // 8) * 8) * (-(-c // 128) * 128)
 
 
 def fused_upsample2(x: jnp.ndarray,

@@ -1,47 +1,46 @@
 """Shared scoped-VMEM compiler-params rule for the Pallas kernels.
 
-First real-v5e exposure (round 2, pallas/dynamic_filter.py): XLA's
-memory-space assignment can park a custom call's full output in VMEM
-and die against the default 16 MB scoped limit even when the per-grid-
-step windows are tiny.  v5e/v4 have 128 MB/core; raising the scoped
-ceiling to 100 MB compiles and runs.  ADVICE r3: gate the raise on a
-SMALL-VMEM **denylist** (v2/v3, ~16 MB/core — a limit past physical
-VMEM fails the compile there) rather than a big-VMEM allowlist, with a
-word-bounded regex so e.g. 'v23'/'TPU v4 lite' never mismatch; unknown
-and future generations default to the raised limit.  Each kernel keeps
-its own env-var escape hatch (0 = compiler default).
+XLA's memory-space assignment can park a custom call's full output in
+VMEM and die against the default 16 MB scoped limit even when the
+per-grid-step windows are tiny, and the whole-image blocks of the
+conv/resample kernels need tens of MB by design.  The kernels
+therefore raise the scoped ceiling to 100 MB where the chip has the
+VMEM for it (v5e: 128 MiB/core; the v5e compiler accepts the raise —
+tests/test_chip_compile.py).  Each kernel keeps its own env-var
+escape hatch (0 = compiler default).
 """
 
 from __future__ import annotations
 
-import re
-
 import jax
 from jax.experimental.pallas import tpu as pltpu
 
-# jax >= 0.6 renamed TPUCompilerParams -> CompilerParams; take
-# whichever this jax ships (the utils/compat.py version-skew posture —
-# same vmem_limit_bytes keyword either way).
-CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
+_RAISED_LIMIT = 100 * 1024 * 1024
 
 
-def scoped_vmem_params(env_var: str) -> "CompilerParams":
+def _device_kind() -> str | None:
+    """``device_kind`` of the TPU this process compiles for; ``None``
+    off-TPU, where the kernels run in interpret mode and the compiler
+    params are never read."""
+    dev = jax.devices()[0]
+    return dev.device_kind if dev.platform == "tpu" else None
+
+
+def scoped_vmem_params(env_var: str) -> pltpu.CompilerParams:
     """The per-kernel scoped-VMEM ceiling, overridable via ``env_var``
     (MB; 0 or negative = compiler default; must be a registered
-    program-affecting knob — utils/envvars.py)."""
+    program-affecting knob — utils/envvars.py).  An unknown TPU kind is
+    an error (utils/chips.py): a limit past physical VMEM fails the
+    compile with a far less helpful message."""
     from ..utils import envvars
+    from ..utils.chips import chip_peaks
 
     env = envvars.read(env_var)
     if env is not None:
         mb = int(env)
-        return (CompilerParams() if mb <= 0
-                else CompilerParams(vmem_limit_bytes=mb * 1024 * 1024))
-    try:
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:  # noqa: BLE001 — no backend: assume modern
-        kind = ""
-    # "tpu v2" / "tpu v3" (word-bounded so "v23"/"v32" never match).
-    if re.search(r"\bv[23]\b", kind) is not None:
-        return CompilerParams()
-    return CompilerParams(vmem_limit_bytes=100 * 1024 * 1024)
+        return (pltpu.CompilerParams() if mb <= 0
+                else pltpu.CompilerParams(vmem_limit_bytes=mb * 1024 * 1024))
+    kind = _device_kind()
+    if kind is None or chip_peaks(kind).vmem_bytes <= _RAISED_LIMIT:
+        return pltpu.CompilerParams()
+    return pltpu.CompilerParams(vmem_limit_bytes=_RAISED_LIMIT)

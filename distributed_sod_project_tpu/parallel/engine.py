@@ -62,7 +62,6 @@ from ..train.step import (_loss_kwargs, apply_update, chunk_batch_spec,
                           chunked_step_fn, maybe_health_metrics,
                           maybe_remat, notfinite_count, rescale_batch,
                           resolve_remat_policy)
-from ..utils.compat import shard_map
 from . import rules as rules_mod
 from .mesh import (batch_sharding, batch_spec, hier_data_groups,
                    replicated_sharding)
@@ -333,7 +332,7 @@ def make_unified_train_step(
     base = P("data") if preset == "dp" else P("data", "seq")
     batch_in = base if body is inner_fn else chunk_batch_spec(base)
     if ef:
-        sharded = shard_map(
+        sharded = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=((P(), P("data")), batch_in),
@@ -357,7 +356,7 @@ def make_unified_train_step(
             (state.replace(comm_residual=None), state.comm_residual),
             batch)
         return step
-    sharded = shard_map(
+    sharded = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(), batch_in),
@@ -394,8 +393,7 @@ def comm_plan(state, mesh: Mesh, *, preset: str, zero: int = 0,
     reduced last) can overlap remaining backward compute, so
     ``overlap_frac = 1 - last_bucket_bytes / total``; a monolithic
     reduce (or the GSPMD presets, whose schedule the partitioner owns)
-    reports 0.  The measured number stays a TPU-window item
-    (tools/tpu_agenda_r18.sh).
+    reports 0.  Not measured on a chip.
     """
     leaves = jax.tree_util.tree_leaves(state.params)
     shapes = [(g.shape, g.dtype) for g in leaves]

@@ -64,11 +64,6 @@ def make_mesh(
     gradient psum rides the remaining links.
     """
     devices = list(devices if devices is not None else jax.devices())
-    # Backend is resolved by now — safe point to turn on the persistent
-    # compilation cache for accelerator runs (no-op on CPU).
-    from ..utils.platform import maybe_enable_compilation_cache
-
-    maybe_enable_compilation_cache()
     data = getattr(mesh_cfg, "data", -1) if mesh_cfg is not None else -1
     model = getattr(mesh_cfg, "model", 1) if mesh_cfg is not None else 1
     seq = getattr(mesh_cfg, "seq", 1) if mesh_cfg is not None else 1

@@ -30,7 +30,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-from ..utils.compat import axis_size, shard_map
 
 
 def resolve_attn_fn(attn_impl: str, causal: bool = False):
@@ -99,7 +98,7 @@ def ring_attention(
     resolve_attn_fn(attn_impl, causal=causal)  # one shared validation
     if attn_impl == "flash":
         return _ring_flash(q, k, v, axis_name)
-    n_blocks = axis_size(axis_name)
+    n_blocks = lax.axis_size(axis_name)
     my_idx = lax.axis_index(axis_name)
     scale = 1.0 / (q.shape[-1] ** 0.5)
     qf = q.astype(jnp.float32)
@@ -159,7 +158,7 @@ def _ring_flash(q, k, v, axis_name: str) -> jnp.ndarray:
     into VMEM.  Exact vs ``full_attention`` (tests)."""
     from ..pallas.flash_attention import flash_attention_with_lse
 
-    n_blocks = axis_size(axis_name)
+    n_blocks = lax.axis_size(axis_name)
     perm = [(i, (i + 1) % n_blocks) for i in range(n_blocks)]
     b, h, n_local, d = q.shape
 
@@ -205,7 +204,7 @@ def full_attention(q, k, v, causal: bool = False) -> jnp.ndarray:
 
 def make_ring_attention_fn(mesh, causal: bool = False,
                            attn_impl: str = "xla"):
-    """jit(shard_map(...)) wrapper: global [B,H,N,D] arrays sharded on
+    """jit(jax.shard_map(...)) wrapper: global [B,H,N,D] arrays sharded on
     N over the mesh's ``seq`` axis; drop-in replacement for
     ``full_attention`` at pod scale."""
     from jax.sharding import PartitionSpec as P
@@ -216,6 +215,6 @@ def make_ring_attention_fn(mesh, causal: bool = False,
         return ring_attention(q, k, v, axis_name="seq", causal=causal,
                               attn_impl=attn_impl)
 
-    sharded = shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+    sharded = jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
                             out_specs=spec, check_vma=False)
     return jax.jit(sharded)

@@ -46,7 +46,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..losses.ssim import _C1, _C2, _blur, gaussian_window
 from .ring_attention import ring_attention
-from ..utils.compat import axis_size, shard_map
 
 
 def sp_batch_sharding(mesh: Mesh) -> NamedSharding:
@@ -69,7 +68,7 @@ def _sp_hybrid_loss(logits, mask, *, bce_w, iou_w, cel_w,
     # Global per-image sums: this device's rows + everyone else's.
     bce_i, inter_i, psum_i, tsum_i = lax.psum(
         (bce_i, inter_i, psum_i, tsum_i), axis)
-    n_pix_total = x.shape[1] * axis_size(axis)
+    n_pix_total = x.shape[1] * lax.axis_size(axis)
 
     comps: Dict[str, jnp.ndarray] = {}
     total = jnp.float32(0.0)
@@ -95,7 +94,7 @@ def _exchange_row_halo(x, halo: int, axis: str):
     with no neighbor on a side receive ppermute's zero fill — identical
     to the SAME zero padding the single-device blur sees at the global
     image edge, so no special-casing of edge devices is needed."""
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     top = lax.ppermute(x[:, -halo:], axis,
                        [(i, i + 1) for i in range(n - 1)])
     bot = lax.ppermute(x[:, :halo], axis,
@@ -132,7 +131,7 @@ def _sp_ssim_loss(logits, mask, *, axis="seq", window_size=11, sigma=1.5):
     den = (mu_aa + mu_bb + _C1) * ((e_aa - mu_aa) + (e_bb - mu_bb) + _C2)
     local_sum = jnp.sum(num / den)
     global_sum = lax.psum(local_sum, axis)
-    n_global = (num.size) * axis_size(axis)  # uniform row blocks
+    n_global = (num.size) * lax.axis_size(axis)  # uniform row blocks
     return 1.0 - global_sum / n_global
 
 
@@ -160,7 +159,7 @@ def _sp_apply(model, variables, image, *, train: bool, rngs=None,
         raise ValueError(f"mesh.sp_strategy must be 'ring' or "
                          f"'ulysses', got {sp_strategy!r}")
     local_rows = image.shape[1] // model.patch
-    seq = axis_size("seq")
+    seq = lax.axis_size("seq")
     row_off = lax.axis_index("seq") * local_rows
     full_grid = (local_rows * seq, image.shape[2] // model.patch)
     return model.apply(
@@ -202,7 +201,7 @@ def make_sp_eval_step(model, mesh: Mesh,
                          sp_strategy=sp_strategy)
         return jax.nn.sigmoid(outs[0][..., 0].astype(jnp.float32))
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         eval_fn,
         mesh=mesh,
         in_specs=(P(), P("data", "seq")),

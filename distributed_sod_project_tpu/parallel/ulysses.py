@@ -24,11 +24,11 @@ round-off (tests/test_ulysses.py).
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
 from .ring_attention import resolve_attn_fn
-from ..utils.compat import axis_size, shard_map
 
 
 def ulysses_attention(
@@ -46,7 +46,7 @@ def ulysses_attention(
     ``ring_attention`` layout); returns the same shape/dtype.
     Requires ``H % axis_size == 0``.
     """
-    s = axis_size(axis_name)
+    s = lax.axis_size(axis_name)
     h = q.shape[1]
     if h % s:
         raise ValueError(
@@ -70,7 +70,7 @@ def ulysses_attention(
 
 def make_ulysses_attention_fn(mesh, causal: bool = False,
                               attn_impl: str = "xla"):
-    """jit(shard_map(...)) wrapper mirroring
+    """jit(jax.shard_map(...)) wrapper mirroring
     ``ring_attention.make_ring_attention_fn``."""
     import jax
     from jax.sharding import PartitionSpec as P
@@ -81,6 +81,6 @@ def make_ulysses_attention_fn(mesh, causal: bool = False,
         return ulysses_attention(q, k, v, axis_name="seq", causal=causal,
                                  attn_impl=attn_impl)
 
-    sharded = shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+    sharded = jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
                             out_specs=spec, check_vma=False)
     return jax.jit(sharded)

@@ -8,7 +8,7 @@ in-process recovery is possible (the thread is stuck in C++), so the
 contract is: detect the stall from a side thread, dump live stack
 traces + the last known metrics for post-mortem, and exit the process
 with a DISTINCT code (:data:`WATCHDOG_EXIT_CODE`) so the supervising
-layer (tools/tpu_watch.sh, a k8s restart policy, or
+layer (a k8s restart policy, or
 resilience/supervisor.py run under a process manager) can tell "step
 deadline exceeded" from a crash and re-fire cleanly — the next run
 ``--resume``'s from the last valid checkpoint.

@@ -283,6 +283,8 @@ class InferenceEngine:
         # different compiled program).
         self.programs: Dict[Tuple[str, int, int, str, str, str],
                             object] = {}
+        # Keys the jit fallback has compiled on the request path.
+        self._request_compiled: set = set()
 
         self.batcher = DynamicBatcher(
             self.batch_buckets, sc.max_wait_ms / 1000.0,
@@ -868,7 +870,17 @@ class InferenceEngine:
                  tta: bool):
         key = (self.cfg.model.name, res, bb, self.cfg.model.resample_impl,
                self._conv_impl, arm)
-        call = self.programs.get(key, self._fwds[arm])
+        call = self.programs.get(key)
+        if call is None:
+            # Not AOT-warmed (a bucket added after start): the jit
+            # fallback compiles ON THE REQUEST PATH the first time a
+            # key is used, and only then.  Counted once per key, so "no
+            # request ever pays a compile" is a number on /metrics and
+            # not only a design intent.  (One dispatch thread: no lock.)
+            if key not in self._request_compiled:
+                self._request_compiled.add(key)
+                self.stats.inc("request_compiles")
+            call = self._fwds[arm]
 
         def fn(b):
             return call(variables, b)

@@ -10,14 +10,14 @@ Two disciplines, because they answer different questions:
   (the generator slows down with the server — coordinated omission).
 - **open** loop — requests fired on a fixed schedule at ``rps``
   regardless of completions, the arrival process real traffic has.
-  Measures SLO behavior: p99 and shed rate at an offered rate, which is
-  what the throughput-vs-p99 curve in tools/tpu_agenda_r7.sh sweeps.
+  Measures SLO behavior: p99 and shed rate at an offered rate (the
+  throughput-vs-p99 curve; not measured on a chip).
 
 Either discipline can offer **mixed traffic** against a fleet router
 (``mix=``: weighted per-model/per-tenant request mix via X-Model /
 X-Tenant headers), with per-SERVED-model p50/p95/p99 broken out in the
 summary next to the per-arm breakdown — the fleet's mixed-model curve
-(tools/tpu_agenda_r9.sh) is one command.
+(not measured on a chip) is one command.
 
 **Duplicate traffic** (``zipf=(s, catalog)``): instead of cycling a
 small body pool, each request draws its payload from a ``catalog`` of

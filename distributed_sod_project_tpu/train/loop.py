@@ -140,6 +140,20 @@ def fit(
     # same images.  Pure DP reduces to (process_index, process_count).
     shard_id, num_shards = host_batch_shard(mesh)
     dataset = resolve_dataset(cfg.data)
+    # Name the input path that actually runs: a missing
+    # native/build/libdsod_host.so (git-ignored, built by `make -C
+    # native`) silently means PIL decode, and two machines would
+    # otherwise feed the same step from different loaders unnoticed.
+    from ..data import native as native_decode
+    from ..data.synthetic import SyntheticSOD
+
+    log.info("host loader: backend=%s dataset=%s decode=%s workers=%d",
+             cfg.data.backend, type(dataset).__name__,
+             "none (generated in numpy)"
+             if isinstance(dataset, SyntheticSOD)
+             else "native libdsod_host.so" if native_decode.available()
+             else "PIL (native/build/libdsod_host.so not built)",
+             cfg.data.num_workers)
     # Corrupt-sample degradation: bounded skip-budget with
     # deterministic substitution instead of an epoch-killing exception
     # (host/grain backends fetch through the wrapper; tfdata enforces

@@ -55,20 +55,56 @@ def create_train_state(rng, model, tx, sample_batch,
     depth = sample_batch.get("depth")
     if depth is not None:
         depth = jnp.asarray(depth)
-    variables = model.init(rng, image, depth, train=False)
+    # ONE compiled program, not an eager init: un-jitted, flax's init
+    # dispatches (and on the chip compiles) every primitive on its own —
+    # 165 s before the trainer's first log line for minet_r50_dp on a
+    # v5e, ~110 s of a server's start (chip_smoke.py, PR 23).
+    variables = jax.jit(
+        lambda r, i, d: model.init(r, i, d, train=False))(rng, image, depth)
     if pretrained:
         from ..models.pretrained import load_pretrained
 
         variables = load_pretrained(variables, pretrained)
     params = variables["params"]
     batch_stats = variables.get("batch_stats", {})
+    opt_state, ema_params = jax.jit(lambda p: (
+        tx.init(p),
+        jax.tree_util.tree_map(jnp.copy, p) if ema else None))(params)
     return TrainState(
         step=jnp.zeros((), jnp.int32),
         params=params,
         batch_stats=batch_stats,
-        opt_state=tx.init(params),
-        ema_params=jax.tree_util.tree_map(jnp.copy, params) if ema else None,
+        opt_state=opt_state,
+        ema_params=ema_params,
     )
+
+
+def random_init_setup(cfg, batch_size: int, hw: int,
+                      total_steps: int = 1000):
+    """The measurement recipe bench.py and chip_smoke.py share: the
+    config's model and optimizer, one host batch of seeded noise at
+    ``batch_size`` x ``hw`` x ``hw`` (depth where the config uses it)
+    and the TrainState initialised from it.  Returns ``(model, tx,
+    schedule, host_batch, state)``; the caller picks the mesh and the
+    step."""
+    import numpy as np
+
+    from ..models import build_model
+    from .optim import build_optimizer
+
+    model = build_model(cfg.model)
+    tx, sched = build_optimizer(cfg.optim, total_steps)
+    rng = np.random.RandomState(0)
+    host_batch = {
+        "image": rng.randn(batch_size, hw, hw, 3).astype(np.float32),
+        "mask": (rng.rand(batch_size, hw, hw, 1) > 0.5
+                 ).astype(np.float32),
+    }
+    if cfg.data.use_depth:
+        host_batch["depth"] = rng.randn(batch_size, hw, hw, 1
+                                        ).astype(np.float32)
+    state = create_train_state(jax.random.key(0), model, tx, host_batch)
+    return model, tx, sched, host_batch, state
 
 
 def param_count(state: TrainState) -> int:

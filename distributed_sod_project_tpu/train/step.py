@@ -21,7 +21,6 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .state import TrainState
-from ..utils.compat import shard_map
 
 
 def resolve_remat_policy(name: str):
@@ -193,7 +192,7 @@ def make_eval_step(model, mesh: Mesh) -> Callable:
         )
         return jax.nn.sigmoid(outs[0][..., 0].astype(jnp.float32))
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         eval_fn,
         mesh=mesh,
         in_specs=(P(), P("data")),

@@ -26,9 +26,12 @@ into live utilization:
 
 Off by default (``serve.capacity_ledger`` / ``capacity_ledger``):
 nothing records, nothing renders, /metrics is byte-identical.  The
-peak numbers default to the same v5e constants as tools/roofline.py —
-on other hardware override at construction (MFU is then reported
-against the configured peak, like every MFU number in this repo).
+peaks are the published ones of the chip the process RUNS ON
+(utils/chips.py, keyed by ``device_kind``): an unknown TPU kind is an
+error at construction, and on the CPU there is no peak — the static
+cost and measured time still render, MFU / roofline utilization and
+the comm time estimates read 0 rather than a share of some chip's
+peak.
 """
 
 from __future__ import annotations
@@ -36,15 +39,8 @@ from __future__ import annotations
 import threading
 from typing import Callable, Dict, Optional
 
+from .chips import DCN_BW, ChipPeaks, local_chip_peaks
 from .logging import get_logger
-
-# v5e per-chip peaks — the SAME constants tools/roofline.py predicts
-# against, so live MFU and the offline roofline share a denominator.
-PEAK_FLOPS = 197e12  # dense bf16 MACs*2
-HBM_BW = 819e9       # bytes/s
-ICI_BW = 2e11        # bytes/s — v5e 1,600 Gbps aggregate ICI per chip
-DCN_BW = 12.5e9      # bytes/s — ~100 Gbps per-host DCN NIC (the
-                     # inter-host hop hierarchical collectives price)
 
 
 def ring_wire_bytes(payload_bytes: float, axis_size: int) -> float:
@@ -68,12 +64,14 @@ def collective_wire_bytes(c: Dict) -> float:
     return 2.0 * (n - 1) / n * payload
 
 
-def collective_link_bw(c: Dict) -> float:
-    """The link bandwidth a collective's wire bytes traverse:
-    ``level='dcn'`` (the inter-host hop of ``mesh.data_hosts>1``
-    plans) prices against ``DCN_BW``, everything else against
-    ``ICI_BW``.  Plans from before the level field default to ici."""
-    return DCN_BW if c.get("level", "ici") == "dcn" else ICI_BW
+def collective_est_ms(c: Dict, ici_bw: Optional[float]) -> float:
+    """Wire bytes over the link they traverse, in ms: ``level='dcn'``
+    (the inter-host hop of ``mesh.data_hosts>1`` plans) prices against
+    ``DCN_BW``, everything else against the chip's ICI (plans from
+    before the level field default to ici) — 0 where the chip, and so
+    its ICI, is unknown (CPU)."""
+    bw = DCN_BW if c.get("level", "ici") == "dcn" else ici_bw
+    return collective_wire_bytes(c) / bw * 1e3 if bw else 0.0
 
 
 def program_cost(compiled) -> Dict[str, float]:
@@ -130,14 +128,11 @@ class CapacityLedger:
     live utilization gauges.  Thread-safe; renders through the standard
     ``prom_families(labels)`` provider contract."""
 
-    def __init__(self, *, peak_flops: float = PEAK_FLOPS,
-                 hbm_bw: float = HBM_BW,
+    def __init__(self, *, peaks: Optional[ChipPeaks] = None,
                  share_fn: Optional[Callable[[], Dict[str, float]]] = None,
                  device_memory: bool = True):
-        if peak_flops <= 0 or hbm_bw <= 0:
-            raise ValueError("peak_flops/hbm_bw must be > 0")
-        self.peak_flops = float(peak_flops)
-        self.hbm_bw = float(hbm_bw)
+        # None = the chip this process runs on (None again on the CPU).
+        self.peaks = peaks if peaks is not None else local_chip_peaks()
         self._share_fn = share_fn
         self._device_memory = device_memory
         self._lock = threading.Lock()
@@ -191,7 +186,7 @@ class CapacityLedger:
         ZeRO HBM saving).  Rendered as the ``dsod_capacity_comm_*``
         families (DCN-level legs as ``dsod_capacity_comm_dcn_*``);
         wire bytes and estimated milliseconds are derived here against
-        ``ICI_BW``/``DCN_BW`` so the constants live in ONE place."""
+        the chip's ICI / ``DCN_BW`` (utils/chips.py)."""
         if not isinstance(plan, dict) or "collectives" not in plan:
             raise ValueError("record_comm wants a comm_plan dict "
                              "(missing 'collectives')")
@@ -213,22 +208,19 @@ class CapacityLedger:
 
     # -- derived -------------------------------------------------------
 
-    @staticmethod
-    def _util(p: Dict[str, float], peak_flops: float, hbm_bw: float
-              ) -> Dict[str, float]:
+    def _util(self, p: Dict[str, float]) -> Dict[str, float]:
         ms = p.get("ewma_ms")
-        if not ms:
+        if not ms or self.peaks is None:
             return {"mfu": 0.0, "roofline": 0.0}
         s = ms / 1000.0
-        mfu = p["flops"] / s / peak_flops if p["flops"] else 0.0
-        bwu = p["bytes"] / s / hbm_bw if p["bytes"] else 0.0
+        mfu = p["flops"] / s / self.peaks.flops_bf16
+        bwu = p["bytes"] / s / self.peaks.hbm_bw
         return {"mfu": mfu, "roofline": max(mfu, bwu)}
 
     def mfu(self, key: str) -> float:
         with self._lock:
             p = self._programs.get(key)
-            return self._util(p, self.peak_flops, self.hbm_bw)["mfu"] \
-                if p else 0.0
+            return self._util(p)["mfu"] if p else 0.0
 
     def snapshot(self) -> Dict:
         """The /stats capacity block."""
@@ -237,7 +229,7 @@ class CapacityLedger:
                         sorted(self._programs.items())}
         out = {}
         for k, p in programs.items():
-            u = self._util(p, self.peak_flops, self.hbm_bw)
+            u = self._util(p)
             out[k] = {
                 "flops": p["flops"],
                 "bytes": p["bytes"],
@@ -248,7 +240,8 @@ class CapacityLedger:
                 "roofline_util": round(u["roofline"], 6),
             }
         snap = {"programs": out,
-                "peak_flops": self.peak_flops, "hbm_bw": self.hbm_bw}
+                "peak_flops": self.peaks and self.peaks.flops_bf16,
+                "hbm_bw": self.peaks and self.peaks.hbm_bw}
         with self._lock:
             comm = {k: dict(p) for k, p in sorted(self._comm.items())}
         if comm:
@@ -257,9 +250,9 @@ class CapacityLedger:
                     wire = collective_wire_bytes(c)
                     c["wire_bytes"] = int(wire)
                     c["est_ms"] = round(
-                        wire / collective_link_bw(c) * 1e3, 6)
+                        collective_est_ms(c, self._ici_bw()), 6)
             snap["comm"] = comm
-            snap["ici_bw"] = ICI_BW
+            snap["ici_bw"] = self._ici_bw()
             snap["dcn_bw"] = DCN_BW
         if self._share_fn is not None:
             try:
@@ -269,6 +262,9 @@ class CapacityLedger:
             except Exception:  # noqa: BLE001 — telemetry must not throw
                 pass
         return snap
+
+    def _ici_bw(self) -> Optional[float]:
+        return self.peaks.ici_bw if self.peaks is not None else None
 
     # -- exposition ----------------------------------------------------
 
@@ -288,7 +284,7 @@ class CapacityLedger:
 
         flops, bts, peak, ms, mfu, roof = [], [], [], [], [], []
         for k, p in rows:
-            u = self._util(p, self.peak_flops, self.hbm_bw)
+            u = self._util(p)
             flops.append('dsod_capacity_program_flops{%s} %g'
                          % (plbl(k), p["flops"]))
             bts.append('dsod_capacity_program_hbm_bytes{%s} %g'
@@ -324,7 +320,7 @@ class CapacityLedger:
                       f'axis="{c.get("axis", "")}"')
                 payload = float(c.get("bytes", 0))
                 wire = collective_wire_bytes(c)
-                est = wire / collective_link_bw(c) * 1e3
+                est = collective_est_ms(c, self._ici_bw())
                 if c.get("level", "ici") == "dcn":
                     # The slow hop gets its own families so a dashboard
                     # can alarm on DCN pressure without parsing labels.

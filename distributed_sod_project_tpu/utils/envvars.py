@@ -92,11 +92,6 @@ _ENTRIES = (
            "Path override for bench.py's baseline file (default: "
            "bench_baseline.json next to bench.py).",
            "bench.py"),
-    EnvVar("DSOD_BENCH_HISTORY", None, False,
-           "Path override for the append-only bench history JSONL "
-           "(empty string disables; default: "
-           "tools/bench_history.jsonl).",
-           "bench.py"),
     EnvVar("DSOD_BISECT_EXPORT", None, False,
            "'1' makes tools/bisect_swin_eval.py stage scripts "
            "jax.export for TPU instead of executing (read inside the "

@@ -411,7 +411,8 @@ class ServeStats:
     """
 
     COUNTERS = ("submitted", "served", "shed", "expired", "errors",
-                "batches", "reloads", "degraded_entered", "degraded_exited")
+                "batches", "reloads", "degraded_entered", "degraded_exited",
+                "request_compiles")
 
     def __init__(self):
         self._lock = threading.Lock()

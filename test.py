@@ -53,11 +53,14 @@ def parse_args(argv=None):
 def main(argv=None):
     args = parse_args(argv)
 
-    from distributed_sod_project_tpu.utils.platform import select_platform
+    from distributed_sod_project_tpu.utils.platform import (
+        enable_compilation_cache, select_platform)
 
     select_platform(args.device)
 
     import jax
+
+    enable_compilation_cache()
 
     from distributed_sod_project_tpu.data import resolve_dataset
     from distributed_sod_project_tpu.eval import evaluate

@@ -94,8 +94,43 @@ def test_record_jit_requires_lower():
 # ---------------------------------------------------- utilization
 
 
+def _peaks(flops=1e9, hbm_bw=1e9):
+    from distributed_sod_project_tpu.utils.chips import ChipPeaks
+
+    return ChipPeaks(flops_bf16=flops, hbm_bw=hbm_bw, hbm_bytes=1e9,
+                     ici_bw=1e9, vmem_bytes=1 << 20, source="test")
+
+
+def test_no_mfu_on_cpu_and_unknown_chip_is_an_error():
+    """The ledger divides by the peaks of the chip it RUNS ON
+    (utils/chips.py): on the CPU there is none and MFU reads 0 — never
+    a share of a v5e's peak — and a device kind without a row in the
+    table is an error wherever a share of peak would be printed."""
+    from distributed_sod_project_tpu.utils import chips
+
+    cap = CapacityLedger()  # this process: the CPU
+    assert cap.peaks is None
+
+    class Stub:
+        def cost_analysis(self):
+            return {"flops": 5e8, "bytes accessed": 1e9}
+
+        def memory_analysis(self):
+            return None
+
+    cap.record("p", Stub())
+    cap.observe("p", 1000.0)
+    snap = cap.snapshot()
+    assert snap["programs"]["p"]["mfu"] == 0.0
+    assert snap["programs"]["p"]["flops"] == 5e8  # static cost still there
+    assert snap["peak_flops"] is None
+    assert chips.chip_peaks("TPU v5 lite").flops_bf16 == 197e12
+    with pytest.raises(chips.UnknownChipError, match="TPU v9 ultra"):
+        chips.chip_peaks("TPU v9 ultra")
+
+
 def test_mfu_and_roofline_math():
-    cap = CapacityLedger(peak_flops=1e9, hbm_bw=1e9)
+    cap = CapacityLedger(peaks=_peaks())
 
     class Stub:
         def cost_analysis(self):

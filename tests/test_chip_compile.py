@@ -1,0 +1,250 @@
+"""Real-compiler guard: every Pallas kernel entry point, compiled for a
+DESCRIBED (not attached) TPU v5e at the shapes its configs give at
+320x320 (flash: vit_sod_hires' 1024 px / N=4096).
+
+Interpret mode and ``jax.export`` lowering (the per-kernel
+``*_lowers_for_real_tpu`` tests) stop before Mosaic's layout inference
+and the VMEM allocator; this file goes through them, which is where
+the chip's compiler refused the pre-PR-23 resample kernel ("unsupported
+shape cast"), bf16 odd-width conv tiles and over-wide dynamic-filter
+maps.  Nothing runs — a pass here is a compile, not a chip run.
+
+All of these tests live in ONE file on purpose: only one process may
+hold the TPU library, pytest-xdist (``--dist loadfile``) gives a file
+to one worker, and the topology is described inside a fixture — never
+at import — so every worker collects the same tests.  The no-chip CLI
+tests at the bottom are here for the same reason: ``--device tpu``
+makes each child try the TPU library, and in another file (another
+worker) such a child could hold the library's lock at the moment this
+file's fixture asks for it, turning every compile test into a skip.
+"""
+
+import collections
+import glob
+import importlib
+import json
+import os
+import subprocess
+import sys
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+_P = "distributed_sod_project_tpu.pallas."
+fc, fr, dfm, fl, fs, fa, vb = (
+    importlib.import_module(_P + m)
+    for m in ("fused_conv", "fused_resample", "dynamic_filter",
+              "fused_loss", "fused_ssim", "flash_attention",
+              "vmem_budget"))
+
+_S = collections.namedtuple("_S", "shape dtype")  # an argument's spec
+B = 2  # the kernels grid over images; the tile is what the compiler prices
+BF, F32 = jnp.bfloat16, jnp.float32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """Compile-for-v5e context: a one-chip sharding for the argument
+    shapes, the scoped-VMEM rule steered to the described chip's kind
+    (``jax.devices()`` still says cpu here), conftest's float32
+    matmul-precision default lifted (the chip runs the kernels' bf16
+    dots at the default precision; forced fp32 on bf16 operands is a
+    "Bad lhs type" the program never asks for), and the persistent
+    compile cache off — an entry compiled for a described chip cannot
+    be read back without one and every later compile would warn."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    mp = pytest.MonkeyPatch()
+    mp.setattr(vb, "_device_kind", lambda: topo.devices[0].device_kind)
+    was = {k: getattr(jax.config, k) for k in (
+        "jax_enable_compilation_cache", "jax_default_matmul_precision")}
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_default_matmul_precision", None)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    for k, v in was.items():
+        jax.config.update(k, v)
+    compilation_cache.reset_cache()
+    mp.undo()
+
+
+def _conv(parts, w, mode="none", relu=False, grad=False):
+    cout = w.shape[-1]
+    vecs = ({k: _S((cout,), F32) for k in ("mean", "mul", "bias")}
+            if mode == "bn" else {})
+
+    def f(parts, w, vecs):
+        y = fc.fused_conv(parts, w, vecs, kernel=w.shape[:2], mode=mode,
+                          relu=relu, interpret=False)
+        return y.astype(F32).sum()
+
+    return (jax.grad(f, argnums=(0, 1)) if grad else f), (parts, w, vecs)
+
+
+def _resample_vjp(f, out, *args):
+    # The op is linear: its grad never reads the primals, so a
+    # ``jax.grad`` of it prunes every argument and the lowering loses
+    # the described device.  Feed the cotangent as an input instead.
+    return (lambda g, *a: jax.vjp(f, *a)[1](g)), (out,) + args
+
+
+def _dlf(h, dilation, grad):
+    def f(x, k):
+        return dfm.fused_dynamic_filter(x, k, 3, dilation,
+                                        interpret=False)
+
+    args = (_S((B, h, h, 64), BF), _S((B, h, h, 9), BF))
+    if grad:
+        return jax.grad(lambda x, k: f(x, k).astype(F32).sum(),
+                        argnums=(0, 1)), args
+    return f, args
+
+
+_up = partial(fr.fused_upsample2, interpret=False)
+_add = partial(fr.fused_upsample2_merge, mode="add", interpret=False)
+_cat = partial(fr.fused_upsample2_merge, mode="concat", x_first=False,
+               interpret=False)
+_IMG = _S((B, 320, 320, 1), F32)
+_QKV = _S((1, 6, 4096, 64), BF)
+_flash = partial(fa.flash_attention, interpret=False)
+
+# name -> (fn, pytree of argument specs, custom calls expected)
+CASES = {
+    # u2net_ds / basnet_ds / gatenet_vgg16: loss.fused_kernel=True
+    "fused_loss.sums@320": (
+        partial(fl.pixel_region_sums, interpret=False), (_IMG, _IMG), 1),
+    "fused_ssim.fwd@320": (
+        lambda a, b: fs._run(fs._fwd_kernel, a, b, [(1, 128)],
+                             fs._taps(11, 1.5), interpret=False),
+        (_IMG, _IMG), 1),
+    "fused_ssim.bwd@320": (
+        lambda a, b: fs._run(fs._bwd_kernel, a, b, [(320, 320)] * 2,
+                             fs._taps(11, 1.5), interpret=False),
+        (_IMG, _IMG), 1),
+    # hdfnet_rgbd, model.dlf_impl=pallas: 80/40/20 px maps x 64ch,
+    # dilations 1/2/4.
+    "dynamic_filter.fwd@80d1": _dlf(80, 1, False) + (1,),
+    "dynamic_filter.dx+dk@80d4": _dlf(80, 4, True) + (2,),
+    # minet_r50_dp, model.resample_impl=fused: AIM/SIM merges.
+    "fused_resample.up@80x64": (_up, (_S((B, 80, 80, 64), BF),), 1),
+    "fused_resample.add@80x64": (
+        _add, (_S((B, 80, 80, 64), BF), _S((B, 160, 160, 64), BF)), 1),
+    "fused_resample.concat@80x32+64": (
+        _cat, (_S((B, 80, 80, 32), BF), _S((B, 160, 160, 64), BF)), 1),
+    "fused_resample.concat@80x32+64,f32": (
+        _cat, (_S((B, 80, 80, 32), F32), _S((B, 160, 160, 64), F32)), 1),
+    "fused_resample.transposed@160x64": _resample_vjp(
+        _up, _S((B, 160, 160, 64), BF), _S((B, 80, 80, 64), BF)) + (1,),
+    "fused_resample.transposed@10x64": _resample_vjp(
+        _up, _S((B, 10, 10, 64), BF), _S((B, 5, 5, 64), BF)) + (1,),
+    # minet_r50_dp, model.conv_impl=fused: decoder ConvBNAct sites.
+    "fused_conv.fwd_bn_relu@80x64": _conv(
+        (_S((B, 80, 80, 64), BF),), _S((3, 3, 64, 64), BF), "bn", True)
+    + (1,),
+    "fused_conv.dx+dw@80x64": _conv(
+        (_S((B, 80, 80, 64), BF),), _S((3, 3, 64, 64), BF), grad=True)
+    + (2,),
+    "fused_conv.dx+dw@80x(64,64,64)": _conv(
+        (_S((B, 80, 80, 64), BF),) * 3, _S((3, 3, 192, 64), BF), grad=True)
+    + (2,),
+    "fused_conv.dx+dw@40x512,1x1": _conv(
+        (_S((B, 40, 40, 512), BF),), _S((1, 1, 512, 128), BF), grad=True)
+    + (2,),
+    "fused_conv.dx+dw@160x128": _conv(  # the budget's upper edge
+        (_S((B, 160, 160, 128), BF),), _S((3, 3, 128, 128), BF), grad=True)
+    + (2,),
+    # vit_sod_hires, model.attn_impl=flash: 1024 px -> N=4096.
+    "flash_attention.fwd@4096": (_flash, (_QKV,) * 3, 1),
+    "flash_attention.bwd@4096": (
+        jax.grad(lambda q, k, v: _flash(q, k, v).astype(F32).sum(),
+                 argnums=(0, 1, 2)), (_QKV,) * 3, 3),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_v5e(chip, name):
+    fn, shapes, n_calls = CASES[name]
+    args = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip),
+        shapes, is_leaf=lambda s: isinstance(s, _S))
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= n_calls
+
+
+def test_availability_rules_match_the_compiler():
+    """What the v5e compiler refused at real widths gives way to the
+    XLA path through the ``*_available`` rules (each probed once by
+    hand, PR 23 — CHANGES.md): lane-padded 1-channel resample maps,
+    bf16 conv tiles of odd width, dynamic-filter maps whose padded
+    width passes one 128-lane row."""
+    assert not fr.fused_resample_available((B, 160, 160, 1), (320, 320))
+    assert fr.fused_resample_available((B, 80, 80, 32), (160, 160),
+                                       "concat", 64)
+    shape = [(B, 5, 5, 32)]
+    assert not fc.fused_conv_available(shape, (3, 3), 1, 32, dtype=BF)
+    assert fc.fused_conv_available(shape, (3, 3), 1, 32, dtype=F32)
+    assert fc.fused_conv_available([(B, 10, 10, 64)], (3, 3), 1, 64,
+                                   dtype=BF)
+    assert dfm.fused_dynamic_filter_available((B, 120, 120, 64), 3, 4)
+    assert not dfm.fused_dynamic_filter_available((B, 160, 160, 64), 3, 4)
+
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_NO_CHIP_CLIS = {
+    "train.py": ["train.py", "--config", "minet_r50_dp", "--device", "tpu",
+                 "--max-steps", "1"],
+    "test.py": ["test.py", "--ckpt-dir", "/nonexistent", "--device", "tpu"],
+    "tools/serve.py": ["tools/serve.py", "--config", "minet_r50_dp",
+                       "--init-random", "--device", "tpu", "--port", "0"],
+    "bench.py": ["bench.py", "--device", "tpu", "--steps", "1"],
+    "chip_smoke.py": ["chip_smoke.py"],
+}
+
+
+def _tpu_device_nodes():
+    """Device nodes a TPU host exposes (v4/v5e: /dev/accel*, newer:
+    numbered /dev/vfio groups) — looked for without touching JAX."""
+    return glob.glob("/dev/accel*") + glob.glob("/dev/vfio/[0-9]*")
+
+
+@pytest.mark.parametrize("cli", sorted(_NO_CHIP_CLIS))
+def test_device_tpu_without_a_chip_exits_nonzero(cli):
+    """No chip here: every entry point asked for the TPU exits non-zero
+    with a message naming the backend found, and none prints a rate or
+    an ok result.  (A child of the worker that holds the TPU library
+    fails on its lock instead of on "no device found" — the same
+    NoAcceleratorError either way.)  On a host that HAS a chip these
+    children would take it and run for real — against the
+    one-process-per-chip rule, and for minutes — so the case is
+    skipped there; ``chip_smoke.py`` is that host's check."""
+    nodes = _tpu_device_nodes()
+    if nodes:
+        pytest.skip(f"this host has a TPU ({nodes[0]}): the no-chip "
+                    "exits cannot be shown here")
+    p = subprocess.run([sys.executable] + _NO_CHIP_CLIS[cli], cwd=_REPO,
+                       capture_output=True, text=True, timeout=300)
+    out = p.stdout + p.stderr
+    assert p.returncode != 0, out[-2000:]
+    assert "--device tpu: JAX" in out and "cpu" in out, out[-2000:]
+    assert "imgs/s" not in out and '"value"' not in out
+    assert '"ok": true' not in out
+    if cli == "chip_smoke.py":
+        last = json.loads(p.stdout.strip().splitlines()[-1])
+        assert last["ok"] is False and last["device"] is None

@@ -48,6 +48,42 @@ def _rand(*shape, seed=0):
         np.random.RandomState(seed).randn(*shape).astype(np.float32))
 
 
+_EPS32 = float(np.finfo(np.float32).eps)
+
+
+def _same(got, ref, ulps: float = 8.0) -> bool:
+    """The fused arm against the XLA arm in f32: equal to ``ulps`` units
+    of f32 round-off at the array's own scale (max |ref|, at least 1).
+
+    These assertions were BITWISE until PR 23.  The in-kernel im2col
+    dot contracts the same flattened (u, v, cin) index in the same
+    order as XLA:CPU's conv, and on a one-core host the two come out
+    bit-equal; that is a property of the host's XLA:CPU kernels, not
+    of the Pallas kernel.  On the 8-core host the suite runs on now,
+    XLA:CPU blocks the conv and the dot differently and the sums
+    re-associate: 17 of these cases differ at the parent commit too,
+    by at most 4.9e-7 of the array's scale (7.3e-4 at |values| ~ 2057,
+    9.5e-6 at ~ 26), and under ``--xla_cpu_multi_thread_eigen=false``
+    3 still differ, one of which is bit-equal with threads.  8 ulps of
+    scale (9.5e-7) is twice what was seen, 10x under what a wrong tap
+    or a dropped term would give, and ~4000x under bf16 round-off."""
+    got = np.asarray(got, np.float64)
+    ref = np.asarray(ref, np.float64)
+    if not ref.size:
+        return got.shape == ref.shape
+    scale = max(1.0, float(np.abs(ref).max()))
+    return bool(np.abs(got - ref).max() <= ulps * _EPS32 * scale)
+
+
+def _grads_same(got, ref) -> bool:
+    """Gradients of the two arms (longer sums, a sin() on top).  The
+    limit was 2e-5 ABSOLUTE until PR 23, which the same re-association
+    breaks on this host at the parent commit (3.9e-5 at |values| ~ 12,
+    1.7e-4 at ~ 170: at most 3.6e-6 of the array's scale).  Held to
+    1e-5 of scale, never looser than the old 2e-5 below |values| = 2."""
+    return _same(got, ref, ulps=1e-5 / _EPS32)
+
+
 def _conv_ref(x, w, dilation=1):
     kh, kw = w.shape[0], w.shape[1]
     pad = [(dilation * (kh // 2),) * 2, (dilation * (kw // 2),) * 2]
@@ -74,7 +110,7 @@ def test_fused_conv_matches_xla_bitwise_f32(h, w, dilation, k):
     ref = jax.jit(lambda a, b: _conv_ref(a, b, dilation))(x, wk)
     got = jax.jit(lambda a, b: fc.fused_conv(
         (a,), b, kernel=(k, k), dilation=dilation))(x, wk)
-    assert jnp.array_equal(got, ref), float(jnp.abs(got - ref).max())
+    assert _same(got, ref), float(jnp.abs(got - ref).max())
 
 
 @pytest.mark.parametrize("h,w", [(1, 2), (2, 2), (2, 4)])
@@ -113,7 +149,7 @@ def test_fused_conv_concat_and_bn_relu_bitwise_f32():
                              kernel=(3, 3), mode="bn", relu=True)
 
     r, g = ref(x1, x2, wk), got(x1, x2, wk)
-    assert jnp.array_equal(r, g), float(jnp.abs(r - g).max())
+    assert _same(r, g), float(jnp.abs(r - g).max())
 
 
 @pytest.mark.parametrize("mode", ["none", "bias", "bn"])
@@ -147,7 +183,7 @@ def test_fused_conv_vjp_matches_autodiff(mode):
         lambda *a: jnp.sum(jnp.sin(fused_path(*a))), (0, 1, 2, 3)))
     for r, g in zip(jax.tree_util.tree_leaves(loss_r(*args)),
                     jax.tree_util.tree_leaves(loss_g(*args))):
-        assert float(jnp.abs(r - g).max()) <= 2e-5
+        assert _grads_same(g, r), float(jnp.abs(r - g).max())
 
 
 def test_fused_conv_vjp_cotangent_dtypes_match_primals():
@@ -237,11 +273,11 @@ def test_convbnact_fused_matches_xla_bitwise(use_bn, act, dilation,
             v, x, train=True, mutable=["batch_stats"]))(v, x)
         for a, b in zip(jax.tree_util.tree_leaves(sx),
                         jax.tree_util.tree_leaves(sf)):
-            assert jnp.array_equal(a, b)  # identical stat updates
+            assert _same(a, b)  # the same stat updates
     else:
         yx = jax.jit(lambda v, x: mx.apply(v, x, train=False))(v, x)
         yf = jax.jit(lambda v, x: mf.apply(v, x, train=False))(v, x)
-    assert jnp.array_equal(yx, yf), float(jnp.abs(yx - yf).max())
+    assert _same(yx, yf), float(jnp.abs(yx - yf).max())
 
 
 def test_convbnact_list_input_is_concat_on_both_arms():
@@ -255,7 +291,7 @@ def test_convbnact_list_input_is_concat_on_both_arms():
     yf = jax.jit(lambda v: mf.apply(v, [a, b], train=False))(v)
     ycat = jax.jit(lambda v: mx.apply(
         v, jnp.concatenate([a, b], -1), train=False))(v)
-    assert jnp.array_equal(yx, yf)
+    assert _same(yx, yf)
     assert jnp.array_equal(yx, ycat)
 
 
@@ -287,7 +323,7 @@ def test_convbnact_grads_match_xla_arm():
         jnp.sin(mf.apply(v, x, train=False))), (0, 1)))(v, x)
     for a, b in zip(jax.tree_util.tree_leaves(gx),
                     jax.tree_util.tree_leaves(gf)):
-        assert float(jnp.abs(a - b).max()) <= 2e-5
+        assert _grads_same(b, a), float(jnp.abs(a - b).max())
 
 
 class _TwoSite(nn.Module):
@@ -340,28 +376,29 @@ def test_vmem_budget_falls_back_per_site_not_globally(monkeypatch,
             logger="distributed_sod_project_tpu.models.layers"):
         yf = mf.apply(v, x, train=False)
     yx = mx.apply(v, x, train=False)
-    assert jnp.array_equal(yx, yf)
+    assert _same(yx, yf)
     assert len(calls) == 1 and calls[0][-1] == 8  # only narrow fused
     assert any("fused conv out of envelope" in r.message
                for r in caplog.records)
 
 
-def test_conv_compiler_params_vmem_gate_denylist(monkeypatch):
-    """Same v2/v3 small-VMEM denylist rule as fused_resample (ADVICE
-    r3), with DSOD_CONV_VMEM_MB as the escape hatch."""
-
-    class _Dev:
-        def __init__(self, kind):
-            self.device_kind = kind
+def test_conv_compiler_params_follow_the_shared_vmem_rule(monkeypatch):
+    """pallas/vmem_budget.py: the raised 100 MB scoped-VMEM ceiling on a
+    chip the table knows to have the VMEM for it (v5e), the compiler
+    default off-TPU (interpret mode never reads it), an ERROR for a TPU
+    kind utils/chips.py has no row for, and DSOD_CONV_VMEM_MB as the
+    escape hatch."""
+    from distributed_sod_project_tpu.pallas import vmem_budget as vb
+    from distributed_sod_project_tpu.utils.chips import UnknownChipError
 
     monkeypatch.delenv("DSOD_CONV_VMEM_MB", raising=False)
-    for kind, want in {"TPU v2": None, "TPU v3": None,
-                       "TPU v4": 100 << 20, "TPU v5 lite": 100 << 20,
-                       "unknown-future-chip": 100 << 20}.items():
-        monkeypatch.setattr(fc.jax, "devices",
-                            lambda kind=kind: [_Dev(kind)])
+    for kind, want in {"TPU v5 lite": 100 << 20, None: None}.items():
+        monkeypatch.setattr(vb, "_device_kind", lambda kind=kind: kind)
         got = getattr(fc._compiler_params(), "vmem_limit_bytes", None)
         assert got == want, (kind, got, want)
+    monkeypatch.setattr(vb, "_device_kind", lambda: "TPU v9 ultra")
+    with pytest.raises(UnknownChipError):
+        fc._compiler_params()
     monkeypatch.setenv("DSOD_CONV_VMEM_MB", "8")
     assert fc._compiler_params().vmem_limit_bytes == 8 << 20
     monkeypatch.setenv("DSOD_CONV_VMEM_MB", "0")

@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from distributed_sod_project_tpu.models.hdfnet import dynamic_local_filter
+from distributed_sod_project_tpu.pallas import dynamic_filter as df
 from distributed_sod_project_tpu.pallas.dynamic_filter import (
     fused_dynamic_filter, fused_dynamic_filter_available)
 
@@ -139,40 +140,24 @@ def test_dynfilter_lowers_for_real_tpu():
     assert "tpu_custom_call" in exp.mlir_module()
 
 
-def test_compiler_params_vmem_gate_denylist(monkeypatch):
-    """ADVICE r3: the scoped-VMEM raise is gated on a v2/v3 SMALL-VMEM
-    denylist (word-bounded regex), not a substring allowlist — v4 and
-    unknown/future generations get the raised limit, 'lite' never
-    matches against unrelated device kinds, and DSOD_DLF_VMEM_MB stays
-    the escape hatch."""
-    from distributed_sod_project_tpu.pallas import dynamic_filter as df
-
-    class _Dev:
-        def __init__(self, kind):
-            self.device_kind = kind
+def test_dlf_compiler_params_follow_the_shared_vmem_rule(monkeypatch):
+    """pallas/vmem_budget.py: the raised 100 MB scoped-VMEM ceiling on a
+    chip the table knows to have the VMEM for it (v5e), the compiler
+    default off-TPU (interpret mode never reads it), an ERROR for a TPU
+    kind utils/chips.py has no row for, and DSOD_DLF_VMEM_MB as the
+    escape hatch."""
+    from distributed_sod_project_tpu.pallas import vmem_budget as vb
+    from distributed_sod_project_tpu.utils.chips import UnknownChipError
 
     monkeypatch.delenv("DSOD_DLF_VMEM_MB", raising=False)
-    cases = {
-        "TPU v2": None,            # small VMEM: compiler default
-        "TPU v3": None,
-        "TPU v4": 100 << 20,       # the allowlist-era omission
-        "TPU v4 lite": 100 << 20,  # 'lite' substring must not matter
-        "TPU v5 lite": 100 << 20,
-        "TPU v5p": 100 << 20,
-        "TPU v6e": 100 << 20,
-        "TPU v23x": 100 << 20,     # word boundary: not v2/v3
-        "unknown-future-chip": 100 << 20,
-    }
-    for kind, want in cases.items():
-        monkeypatch.setattr(df.jax, "devices",
-                            lambda kind=kind: [_Dev(kind)])
+    for kind, want in {"TPU v5 lite": 100 << 20, None: None}.items():
+        monkeypatch.setattr(vb, "_device_kind", lambda kind=kind: kind)
         got = getattr(df._compiler_params(), "vmem_limit_bytes", None)
-        assert got == want, f"{kind}: {got} != {want}"
-
-    # Escape hatch overrides the device gate in both directions.
-    monkeypatch.setattr(df.jax, "devices", lambda: [_Dev("TPU v2")])
-    monkeypatch.setenv("DSOD_DLF_VMEM_MB", "64")
-    assert df._compiler_params().vmem_limit_bytes == 64 << 20
+        assert got == want, (kind, got, want)
+    monkeypatch.setattr(vb, "_device_kind", lambda: "TPU v9 ultra")
+    with pytest.raises(UnknownChipError):
+        df._compiler_params()
+    monkeypatch.setenv("DSOD_DLF_VMEM_MB", "8")
+    assert df._compiler_params().vmem_limit_bytes == 8 << 20
     monkeypatch.setenv("DSOD_DLF_VMEM_MB", "0")
-    assert getattr(df._compiler_params(), "vmem_limit_bytes",
-                   None) is None
+    assert getattr(df._compiler_params(), "vmem_limit_bytes", None) is None

@@ -113,14 +113,17 @@ def test_resample_merge_falls_back_out_of_envelope(monkeypatch):
 def test_vmem_budget_covers_flagship_fine_sites():
     """The budget must admit EVERY flagship fine-decoder site — the
     roofline lever-#1 targets — including the largest one, SIM-0's
-    concat merge (80x80x32 -> into 160x160x64, 96ch out = 4.31M
-    elems), which a 4M budget silently excluded.  U²-Net's full-width
-    160->320 concat (21M elems) stays out by design."""
+    concat merge (80x80x32 -> into 160x160x64, 96ch out: 10.8M
+    elements as VMEM holds them, lanes padded to 128; the v5e compiler
+    accepts it — tests/test_chip_compile.py).  What stays out, by
+    design: U²-Net's full-width 160->320 concat, and the 1-channel
+    160->320 saliency head, whose lane padding (128x) asks 182 MB of a
+    128 MB core."""
     assert fr.fused_resample_available((64, 80, 80, 32), (160, 160),
                                        "concat", 64)
     assert fr.fused_resample_available((64, 80, 80, 64), (160, 160),
                                        "add", 64)
-    assert fr.fused_resample_available((64, 160, 160, 1), (320, 320))
+    assert not fr.fused_resample_available((64, 160, 160, 1), (320, 320))
     assert not fr.fused_resample_available((16, 160, 160, 64),
                                            (320, 320), "concat", 64)
 
@@ -298,22 +301,23 @@ def test_fused_resample_lowers_for_real_tpu():
         assert "tpu_custom_call" in exp.mlir_module()
 
 
-def test_resample_compiler_params_vmem_gate_denylist(monkeypatch):
-    """Same v2/v3 small-VMEM denylist rule as dynamic_filter (ADVICE
-    r3), with DSOD_RESAMPLE_VMEM_MB as the escape hatch."""
-
-    class _Dev:
-        def __init__(self, kind):
-            self.device_kind = kind
+def test_resample_compiler_params_follow_the_shared_vmem_rule(monkeypatch):
+    """pallas/vmem_budget.py: the raised 100 MB scoped-VMEM ceiling on a
+    chip the table knows to have the VMEM for it (v5e), the compiler
+    default off-TPU (interpret mode never reads it), an ERROR for a TPU
+    kind utils/chips.py has no row for, and DSOD_RESAMPLE_VMEM_MB as the
+    escape hatch."""
+    from distributed_sod_project_tpu.pallas import vmem_budget as vb
+    from distributed_sod_project_tpu.utils.chips import UnknownChipError
 
     monkeypatch.delenv("DSOD_RESAMPLE_VMEM_MB", raising=False)
-    for kind, want in {"TPU v2": None, "TPU v3": None,
-                       "TPU v4": 100 << 20, "TPU v5 lite": 100 << 20,
-                       "unknown-future-chip": 100 << 20}.items():
-        monkeypatch.setattr(fr.jax, "devices",
-                            lambda kind=kind: [_Dev(kind)])
+    for kind, want in {"TPU v5 lite": 100 << 20, None: None}.items():
+        monkeypatch.setattr(vb, "_device_kind", lambda kind=kind: kind)
         got = getattr(fr._compiler_params(), "vmem_limit_bytes", None)
         assert got == want, (kind, got, want)
+    monkeypatch.setattr(vb, "_device_kind", lambda: "TPU v9 ultra")
+    with pytest.raises(UnknownChipError):
+        fr._compiler_params()
     monkeypatch.setenv("DSOD_RESAMPLE_VMEM_MB", "8")
     assert fr._compiler_params().vmem_limit_bytes == 8 << 20
     monkeypatch.setenv("DSOD_RESAMPLE_VMEM_MB", "0")

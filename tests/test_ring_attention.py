@@ -95,7 +95,6 @@ def test_ring_attention_bf16_inputs(eight_devices):
 def test_ring_attention_grads_finite(eight_devices):
     from distributed_sod_project_tpu.parallel.ring_attention import (
         ring_attention)
-    from distributed_sod_project_tpu.utils.compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = make_mesh(MeshConfig(data=1, model=1, seq=8), eight_devices)
@@ -108,7 +107,7 @@ def test_ring_attention_grads_finite(eight_devices):
 
     # Grad through shard_map: psum of local losses.
     def global_loss(q, k, v):
-        f = shard_map(
+        f = jax.shard_map(
             lambda a, b, c: jax.lax.psum(loss(a, b, c), "seq"),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=P(),
             check_vma=False)

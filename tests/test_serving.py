@@ -280,6 +280,24 @@ def test_engine_warms_every_bucket_program_and_reuses_them(tiny):
         eng.stop()
 
 
+def test_request_compiles_counts_one_per_unwarmed_program(tiny):
+    """``dsod_serve_request_compiles_total``: 0 while every dispatch
+    finds its AOT-warmed program; a program missing from the cache is
+    compiled by the jit fallback on its FIRST request only, and counted
+    once — not once per dispatch."""
+    eng = _engine(tiny, batch_buckets=(1,), resolution_buckets=(16,))
+    eng.start()
+    try:
+        eng.predict(_img(0, 16, 16), timeout=30)
+        assert eng.stats.snapshot()["request_compiles"] == 0
+        eng.programs.clear()  # as a bucket added after start would be
+        for seed in range(3):
+            eng.predict(_img(seed, 16, 16), timeout=30)
+        assert eng.stats.snapshot()["request_compiles"] == 1
+    finally:
+        eng.stop()
+
+
 def test_engine_expired_requests_shed_before_forward(tiny):
     eng = _engine(tiny, max_wait_ms=60.0, batch_buckets=(4,))
     forwards = []

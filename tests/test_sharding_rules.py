@@ -205,7 +205,6 @@ def test_grad_buckets_partition_invariants():
 
 
 def test_bucketed_pmean_bitwise_lax_pmean(eight_devices):
-    from distributed_sod_project_tpu.utils.compat import shard_map
 
     mesh = make_mesh(MeshConfig(), eight_devices)
     tree = {"a": np.linspace(-3, 3, 8 * 64, dtype=np.float32
@@ -220,7 +219,7 @@ def test_bucketed_pmean_bitwise_lax_pmean(eight_devices):
     def bucketed(t):
         return bucketed_pmean(t, "data", 64)
 
-    run = lambda f: jax.device_get(jax.jit(shard_map(  # noqa: E731
+    run = lambda f: jax.device_get(jax.jit(jax.shard_map(  # noqa: E731
         f, mesh=mesh, in_specs=(P("data"),), out_specs=P("data"),
         check_vma=False))(sharded))
     a, b = run(ref), run(bucketed)
@@ -593,7 +592,6 @@ def test_hier_psum_bitwise_flat_on_integer_wire(eight_devices):
     integer wire), allclose on arbitrary floats.  2 hosts × 2 chips on
     a 4-device CPU mesh; odd leaf sizes exercise the chip-pad path."""
     from distributed_sod_project_tpu.parallel.mesh import hier_data_groups
-    from distributed_sod_project_tpu.utils.compat import shard_map
 
     mesh = make_mesh(MeshConfig(data=4), eight_devices[:4])
     hier = hier_data_groups(mesh, 2)
@@ -608,7 +606,7 @@ def test_hier_psum_bitwise_flat_on_integer_wire(eight_devices):
                    for k, v in tree.items()}
         f = lambda t: bucketed_pmean(  # noqa: E731
             t, "data", 256, hierarchy=hierarchy)
-        return jax.device_get(jax.jit(shard_map(
+        return jax.device_get(jax.jit(jax.shard_map(
             f, mesh=mesh, in_specs=(P("data"),), out_specs=P("data"),
             check_vma=False))(sharded))
 

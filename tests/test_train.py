@@ -158,6 +158,24 @@ def test_dp_equivalence_1_vs_8_devices(eight_devices):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=chex_tol)
 
 
+def test_first_step_metrics_do_not_depend_on_lr(eight_devices):
+    """Step 1's loss and gradient norm are those of the INITIAL
+    parameters: the learning rate enters only the update that follows.
+    chip_smoke.py --four-chips leans on this — it compares step 1 of
+    two meshes under a turned-down lr and reads the result as the
+    config's own-lr step 1."""
+    mesh = make_mesh(MeshConfig(), eight_devices)
+    b = global_batch_array(_batch(8, seed=3), mesh)
+    first = {}
+    for lr in (0.1, 1e-5):
+        _, state, step = _setup(mesh, lr=lr)
+        new_state, m = step(state, b)
+        first[lr] = (float(m["total"]), float(m["grad_norm"]),
+                     jax.tree_util.tree_leaves(new_state.params)[0])
+    assert first[0.1][:2] == first[1e-5][:2]
+    assert not np.array_equal(first[0.1][2], first[1e-5][2])
+
+
 def test_overfit_smoke(eight_devices):
     """20 steps on one fixed batch must cut the loss (SURVEY.md §4
     integration prescription)."""

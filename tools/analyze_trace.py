@@ -6,10 +6,10 @@
 sandbox has no browser — this tool extracts the numbers that matter
 straight from xprof's converters (installed with jax's profiler deps):
 
-    python tools/analyze_trace.py tpu_results/trace
-    python tools/analyze_trace.py tpu_results/trace --tool hlo_stats --top 25
-    python tools/analyze_trace.py tpu_results/trace --list-tools
-    python tools/analyze_trace.py tpu_results/trace --dump-json out/
+    python tools/analyze_trace.py <profile-dir>
+    python tools/analyze_trace.py <profile-dir> --tool hlo_stats --top 25
+    python tools/analyze_trace.py <profile-dir> --list-tools
+    python tools/analyze_trace.py <profile-dir> --dump-json out/
 
 Default output: the overview page's step-time / FLOPS utilisation
 summary plus the top-N HLO ops by self time (the "attack list" for

@@ -128,7 +128,7 @@ TERMINAL_BOOKING_CALLS = {"inc_submitted", "inc_shed", "inc_response",
 
 # Functions that open a traced scope when a function object is passed
 # to them (matched on the callee's terminal name: jax.jit, pl.jit,
-# lax.scan, compat shard_map, pl.pallas_call all resolve).
+# lax.scan, jax.shard_map, pl.pallas_call all resolve).
 TRACE_ENTRY_NAMES = {"jit", "shard_map", "scan", "pallas_call"}
 
 _ENVVARS_FILE = f"{PKG}/utils/envvars.py"

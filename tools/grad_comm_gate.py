@@ -7,7 +7,7 @@ before the ``psum`` (half the bytes); ``int8_ef`` quantizes to int8
 against a global scale with a persistent error-feedback residual
 (``state.comm_residual``) carrying each replica's rounding error into
 the next step (quarter the achievable bytes).  The step-time win is a
-TPU-window measurement (``tools/tpu_agenda_r18.sh``); the QUALITY cost
+chip measurement (not measured on a chip); the QUALITY cost
 is not — wire rounding is a pure function of the
 model/data/optimizer, measurable on CPU at t1 time.  This tool trains
 the same model from the same init on the same deterministic synthetic

@@ -46,7 +46,7 @@ appear only after XLA:TPU's layout assignment, which CPU cannot run —
 but every one of them is *caused by* a reshape/transpose pattern that
 is already visible (and countable) before optimization.  Fewer
 formatting ops in ≈ fewer relayout copies out; the exact ms stays a
-TPU-window measurement (tools/tpu_agenda_r5.sh leg ``ilv_stack``).
+chip measurement (not measured on a chip).
 
 Usage:
     python tools/hlo_guard.py                      # print delta line

@@ -4,7 +4,7 @@
 
 The serve engine can run every request through a bf16 / int8 / fp8
 weight view of the f32 checkpoint (`serve/precision.py`).  Throughput
-is a TPU-window measurement (`tools/tpu_agenda_r8.sh`), but QUALITY is
+is a chip measurement (not measured on a chip), but QUALITY is
 not: the arms' metric deltas vs f32 are a pure function of the weights
 and the eval set, measurable on CPU at t1 time.  This tool scores each
 arm against the f32 arm on a fixed eval set with the in-tree

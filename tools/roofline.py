@@ -23,7 +23,7 @@ Cross-checks:
 
 Usage:
     python tools/roofline.py                       # predictions
-    python tools/roofline.py --trace tpu_results/trace --batch 64 --remat
+    python tools/roofline.py --trace <profile-dir> --batch 64 --remat
     python tools/roofline.py --xla-check
 
 Modeling assumptions (documented so disagreement is informative):
@@ -58,17 +58,22 @@ import re
 import sys
 from dataclasses import dataclass, field
 
-# v5e per-chip numbers (same sources as bench.py's MFU self-report).
-PEAK_FLOPS = 197e12  # dense bf16 MACs*2
-HBM_BW = 819e9       # bytes/s
-ICI_BW = 2e11        # bytes/s — v5e 1,600 Gbps aggregate ICI per chip
-#                      (same constant as utils/capacity.py's live side)
-DCN_BW = 12.5e9      # bytes/s — ~100 Gbps per-host DCN NIC, the
-#                      inter-host leg of a multi-pod mesh (same
-#                      constant as utils/capacity.py's live side);
-#                      16x slower than ICI, which is WHY the
-#                      hierarchical reduction moves only 1/chips of
-#                      the bytes across it
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from distributed_sod_project_tpu.utils.chips import (  # noqa: E402
+    DCN_BW, chip_peaks)
+
+# This is an OFFLINE model of one named chip, whatever machine prints
+# it: the v5e the flagship was sized for.  Its peaks are the table's
+# (utils/chips.py) — the same rows the live ledger and bench.py divide
+# by when they run on that chip.  DCN is 16x slower than ICI, which is
+# WHY the hierarchical reduction moves only 1/chips of the bytes
+# across it.
+MODELED_CHIP = "TPU v5 lite"
+_PEAKS = chip_peaks(MODELED_CHIP)
+PEAK_FLOPS = _PEAKS.flops_bf16
+HBM_BW = _PEAKS.hbm_bw
+ICI_BW = _PEAKS.ici_bw
 
 A = 2  # activation bytes (bf16)
 P = 4  # param / stat / f32 bytes
@@ -378,7 +383,7 @@ def fmt_fused_ledger(b: int, hw: int = 320) -> str:
     """Per-site HBM ledger for the fused-resample arm
     (``model.resample_impl=fused``): what each decoder upsample/merge
     stage saves per step vs the fast XLA path, and the falsifiable
-    total the tools/tpu_agenda_r5.sh A/B legs are queued against.
+    total a chip A/B has to meet (not measured on a chip).
 
     Conservative by construction: only sites the base ledger already
     prices are counted (SIM's concat-merge upsample is idealized away
@@ -412,8 +417,8 @@ def fmt_fused_ledger(b: int, hw: int = 320) -> str:
 def fmt_fused_conv_ledger(b: int, hw: int = 320) -> str:
     """Per-site HBM ledger for the fused conv-stage arm
     (``model.conv_impl=fused``): what each decoder ConvBNAct saves per
-    step vs the XLA arm, and the falsifiable total the
-    tools/tpu_agenda_r14.sh A/B legs are queued against.
+    step vs the XLA arm, and the falsifiable total a chip A/B has to
+    meet (not measured on a chip).
 
     Assumptions on record (the ledger's honesty contract): the XLA arm
     is charged one extra read+write of each decoder conv's OUTPUT map
@@ -487,8 +492,7 @@ def fmt_comm_ledger(b: int, n_dp: int = 8, bucket_mb: float = 25.0,
     format's information content, the transport honesty note below
     keeps the gap visible.  The live twin of this table is the
     ``dsod_capacity_comm_*`` surface (utils/capacity.py::record_comm);
-    the measured numbers stay tools/tpu_agenda_r18.sh predictions
-    until a TPU window lands them.
+    the numbers are predictions, not measured on a chip.
     """
     sys.path.insert(0, os.path.join(os.path.dirname(
         os.path.abspath(__file__)), ".."))

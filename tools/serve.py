@@ -31,6 +31,7 @@ every in-process member after its own overrides).
 from __future__ import annotations
 
 import argparse
+import json
 import os
 import sys
 
@@ -86,13 +87,22 @@ def main(argv=None) -> int:
             "need --fleet-config, --ckpt-dir, or --init-random with "
             "--config")
 
-    from distributed_sod_project_tpu.utils.platform import select_platform
+    from distributed_sod_project_tpu.utils.platform import (
+        describe_device, enable_compilation_cache, select_platform)
 
     select_platform(args.device)
 
-    if args.fleet_config:
-        import json
+    def device_ready() -> None:
+        """Before the first in-process engine: place the compile cache
+        (a restart then reuses the AOT-warmed programs) and name the
+        device that serves.  A router fronting only remote replicas
+        never calls this and never touches a backend — a chip belongs
+        to one process, and it is the replica's."""
+        cache_dir = enable_compilation_cache()
+        print(json.dumps({"device": describe_device(),
+                          "compile_cache_dir": cache_dir}), flush=True)
 
+    if args.fleet_config:
         from distributed_sod_project_tpu.configs import \
             fleet_config_from_dict
         from distributed_sod_project_tpu.serve.fleet import Fleet
@@ -101,6 +111,8 @@ def main(argv=None) -> int:
 
         with open(args.fleet_config) as f:
             fc = fleet_config_from_dict(json.load(f))
+        if any(not (m.url or m.urls) for m in fc.models):
+            device_ready()
         fleet = Fleet.from_config(fc, extra_overrides=args.overrides)
         host = args.host if args.host is not None else fc.host
         port = args.port if args.port is not None else fc.port
@@ -110,6 +122,7 @@ def main(argv=None) -> int:
     from distributed_sod_project_tpu.serve.engine import InferenceEngine
     from distributed_sod_project_tpu.serve.server import serve_forever
 
+    device_ready()
     if args.ckpt_dir:
         engine = InferenceEngine.from_checkpoint(
             args.ckpt_dir, config_name=args.config,

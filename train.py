@@ -14,7 +14,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
-import os
+import json
 import sys
 
 
@@ -81,16 +81,25 @@ def main(argv=None):
     if not args.config:
         raise SystemExit("--config is required (see --list-configs)")
 
-    from distributed_sod_project_tpu.utils.platform import select_platform
+    from distributed_sod_project_tpu.utils.platform import (
+        CompileStats, describe_device, enable_compilation_cache,
+        pin_platform, verify_platform)
 
-    select_platform(args.device)
+    pin_platform(args.device)
 
     import jax
 
     if args.debug_nans:
         jax.config.update("jax_debug_nans", True)
+    # Order matters: initialize() refuses to run once a backend is up,
+    # and verify_platform() brings one up.
     if args.distributed:
         jax.distributed.initialize()
+    verify_platform(args.device)
+    compiles = CompileStats()
+    cache_dir = enable_compilation_cache()
+    # The imgs/s that fit() logs are rates on THIS device.
+    print(json.dumps({"device": describe_device()}), flush=True)
 
     from distributed_sod_project_tpu.configs import apply_overrides, get_config
     from distributed_sod_project_tpu.train.loop import fit
@@ -114,6 +123,8 @@ def main(argv=None):
                   max_steps=args.max_steps, profile_dir=args.profile_dir,
                   telemetry_port=args.telemetry_port,
                   telemetry_port_file=args.telemetry_port_file)
+    print(json.dumps({"compile": dict(compiles.as_dict(),
+                                      cache_dir=cache_dir)}), flush=True)
     print({k: round(v, 4) if isinstance(v, float) else v
            for k, v in metrics.items()})
     return 0
