@@ -33,12 +33,21 @@ from __future__ import annotations
 
 import collections
 import concurrent.futures as cf
+import contextlib
 import queue
 import threading
-import time
 from typing import Dict, Iterator, Optional
 
 import numpy as np
+
+
+def _timed(stats, key: str, **attrs):
+    """``stats.timed(key)`` — counter and ``dsod.data.*`` span from one
+    timed region (utils/observability.PipelineStats) — or nothing
+    where the caller passed no stats."""
+    if stats is None:
+        return contextlib.nullcontext()
+    return stats.timed(key, **attrs)
 
 # A yielded batch stays valid for this many further yields in ring mode
 # (the consumer typically holds the current batch while requesting the
@@ -90,12 +99,8 @@ class BatchRing:
             return self._free.get_nowait()
         except queue.Empty:
             pass
-        t0 = time.perf_counter()
-        slot = self._free.get()
-        if self._stats is not None:
-            self._stats.add("data_ring_wait_ms",
-                            (time.perf_counter() - t0) * 1000.0)
-        return slot
+        with _timed(self._stats, "data_ring_wait_ms"):
+            return self._free.get()
 
     def release(self, slot: Dict[str, np.ndarray]) -> None:
         self._free.put(slot)
@@ -332,14 +337,15 @@ class HostDataLoader:
         else:
             buf = {k: np.empty(shape, dtype)
                    for k, (shape, dtype) in self._spec.items()}
-        self._decode_into(buf, idxs)
-        return augment_batch(
-            buf, idxs, aug_seed, hflip=self.hflip,
-            rotate_degrees=self.rotate_degrees,
-            color_jitter=self.color_jitter,
-            norm_mean=getattr(self.dataset, "mean", None),
-            norm_std=getattr(self.dataset, "std", None),
-            reuse_buffers=self._ring is not None)
+        with _timed(self.stats, "data_build_ms", batch=step):
+            self._decode_into(buf, idxs)
+            return augment_batch(
+                buf, idxs, aug_seed, hflip=self.hflip,
+                rotate_degrees=self.rotate_degrees,
+                color_jitter=self.color_jitter,
+                norm_mean=getattr(self.dataset, "mean", None),
+                norm_std=getattr(self.dataset, "std", None),
+                reuse_buffers=self._ring is not None)
 
     def _build_native(self, idxs, native_batch, aug_seed: int):
         """C++ data plane: whole-batch decode (+hflip) without the GIL,
@@ -436,7 +442,8 @@ class HostDataLoader:
                 lo = (start * self.global_batch_size
                       + self.shard_id * self.local_batch_size)
                 idxs = order[lo:lo + self.local_batch_size]
-                batch = self._build_native(idxs, native_batch, aug_seed)
+                with _timed(self.stats, "data_build_ms", batch=start):
+                    batch = self._build_native(idxs, native_batch, aug_seed)
                 if batch is None:
                     break  # Python pipeline takes over from `start`
                 if self.stats is not None:
@@ -486,11 +493,8 @@ class HostDataLoader:
                 nxt += 1
             while inflight:
                 fut = inflight.popleft()
-                t0 = time.perf_counter()
-                batch = fut.result()
-                if self.stats is not None:
-                    self.stats.add("data_build_wait_ms",
-                                   (time.perf_counter() - t0) * 1000.0)
+                with _timed(self.stats, "data_build_wait_ms"):
+                    batch = fut.result()
                 if nxt < steps:
                     inflight.append(pool.submit(self._build, nxt,
                                                 order, aug_seed))
@@ -549,10 +553,8 @@ def chunk_batches(iterator, steps_per_dispatch: int, stats=None):
     bufs: list = [None, None]
     flip = 0
     filled = 0
-    t_asm = 0.0
     for batch in iterator:
         if filled == 0:
-            t_asm = 0.0
             buf = bufs[flip]
             stale = (buf is None or set(buf) != set(batch) or any(
                 buf[key].shape[1:] != np.asarray(v).shape
@@ -563,14 +565,12 @@ def chunk_batches(iterator, steps_per_dispatch: int, stats=None):
                     key: np.empty((k,) + np.asarray(v).shape,
                                   np.asarray(v).dtype)
                     for key, v in batch.items()}
-        t0 = time.perf_counter()
-        for key, v in batch.items():
-            bufs[flip][key][filled] = v
-        t_asm += time.perf_counter() - t0
+        with _timed(stats, "data_chunk_assemble_ms", batch=filled):
+            for key, v in batch.items():
+                bufs[flip][key][filled] = v
         filled += 1
         if filled == k:
             if stats is not None:
-                stats.add("data_chunk_assemble_ms", t_asm * 1000.0)
                 stats.add("data_chunks", 1.0)
             out = bufs[flip]
             flip ^= 1
@@ -675,36 +675,31 @@ def prefetch_to_device(iterator, size: int = 2, sharding=None, mesh=None,
                              for k, v in batch.items()}
                 if stop.is_set():
                     return
-                t0 = time.perf_counter()
-                if mesh is not None:
-                    from ..parallel.mesh import global_batch_array
+                with _timed(stats, "data_h2d_ms"):
+                    if mesh is not None:
+                        from ..parallel.mesh import global_batch_array
 
-                    batch = global_batch_array(batch, mesh, spec=spec)
-                elif sharding is not None:
-                    batch = jax.device_put(batch, sharding)
-                else:
-                    batch = jax.device_put(batch)
-                if not on_cpu:
-                    # H2D transfers are ASYNC: the host buffers (ring
-                    # slots, rotating cast buffers) must stay immutable
-                    # until the copy lands.  Waiting here, on the H2D
-                    # thread, bounds in-flight reuse without stalling
-                    # the consumer — the device batch had to finish
-                    # transferring before a step could read it anyway.
-                    jax.block_until_ready(batch)
-                if stats is not None:
-                    stats.add("data_h2d_ms",
-                              (time.perf_counter() - t0) * 1000.0)
-                t0 = time.perf_counter()
-                while not stop.is_set():
-                    try:
-                        q.put(batch, timeout=0.1)
-                        break
-                    except queue.Full:
-                        continue
-                if stats is not None:
-                    stats.add("data_prefetch_full_ms",
-                              (time.perf_counter() - t0) * 1000.0)
+                        batch = global_batch_array(batch, mesh, spec=spec)
+                    elif sharding is not None:
+                        batch = jax.device_put(batch, sharding)
+                    else:
+                        batch = jax.device_put(batch)
+                    if not on_cpu:
+                        # H2D transfers are ASYNC: the host buffers
+                        # (ring slots, rotating cast buffers) must stay
+                        # immutable until the copy lands.  Waiting
+                        # here, on the H2D thread, bounds in-flight
+                        # reuse without stalling the consumer — the
+                        # device batch had to finish transferring
+                        # before a step could read it anyway.
+                        jax.block_until_ready(batch)
+                with _timed(stats, "data_prefetch_full_ms"):
+                    while not stop.is_set():
+                        try:
+                            q.put(batch, timeout=0.1)
+                            break
+                        except queue.Full:
+                            continue
                 if stop.is_set():
                     return
             q.put(_END)
@@ -722,11 +717,8 @@ def prefetch_to_device(iterator, size: int = 2, sharding=None, mesh=None,
         while True:
             if stats is not None:
                 stats.observe_depth(q.qsize(), size)
-            t0 = time.perf_counter()
-            item = q.get()
-            if stats is not None:
-                stats.add("data_starved_ms",
-                          (time.perf_counter() - t0) * 1000.0)
+            with _timed(stats, "data_starved_ms"):
+                item = q.get()
             if item is _END:
                 break
             if isinstance(item, BaseException):

@@ -24,6 +24,7 @@ from __future__ import annotations
 
 from typing import Any, List, Optional
 
+import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
@@ -93,42 +94,47 @@ class BASNet(nn.Module):
 
         # --- predict-module encoder ---------------------------------
         # Stem at full resolution (3×3/1 — BASNet keeps stage 1 unpooled).
-        x = ConvBNAct(64, (3, 3), **kw)(x, train)
-        feats = []
-        stage_blocks = [(3, 64, 1), (4, 128, 2), (6, 256, 2), (3, 512, 2)]
-        for n, width, first_stride in stage_blocks:
-            for i in range(n):
-                x = BasicBlock(width, strides=first_stride if i == 0 else 1,
-                               **kw)(x, train)
-            feats.append(x)  # strides 1, 2, 4, 8
-        for _ in range(2):  # extra stages → strides 16, 32
-            x = max_pool(x)
+        with jax.named_scope("dsod.encoder"):
+            x = ConvBNAct(64, (3, 3), **kw)(x, train)
+            feats = []
+            stage_blocks = [(3, 64, 1), (4, 128, 2), (6, 256, 2), (3, 512, 2)]
+            for n, width, first_stride in stage_blocks:
+                for i in range(n):
+                    x = BasicBlock(width,
+                                   strides=first_stride if i == 0 else 1,
+                                   **kw)(x, train)
+                feats.append(x)  # strides 1, 2, 4, 8
+            for _ in range(2):  # extra stages → strides 16, 32
+                x = max_pool(x)
+                for _ in range(3):
+                    x = BasicBlock(512, **kw)(x, train)
+                feats.append(x)
+
+        with jax.named_scope("dsod.decoder"):
+            # Bridge: dilated 512 convs at the coarsest resolution.
+            b = x
             for _ in range(3):
-                x = BasicBlock(512, **kw)(x, train)
-            feats.append(x)
+                b = ConvBNAct(512, (3, 3), dilation=2, **kw)(b, train)
 
-        # Bridge: dilated 512 convs at the coarsest resolution.
-        b = x
-        for _ in range(3):
-            b = ConvBNAct(512, (3, 3), dilation=2, **kw)(b, train)
-
-        # --- decoder with side heads --------------------------------
-        widths = [512, 512, 512, 256, 128, 64]
-        d = b
-        stages = [b]
-        for width, skip in zip(widths, reversed(feats)):
-            d = _DecoderStage(width, **kw)(d, skip, train)
-            stages.append(d)
+            # --- decoder with side heads ----------------------------
+            widths = [512, 512, 512, 256, 128, 64]
+            d = b
+            stages = [b]
+            for width, skip in zip(widths, reversed(feats)):
+                d = _DecoderStage(width, **kw)(d, skip, train)
+                stages.append(d)
 
         hw = image.shape[1:3]
         side_logits = []
-        for s in reversed(stages):  # finest decoder stage first, bridge last
-            l = nn.Conv(1, (3, 3), padding="SAME", dtype=self.dtype,
-                        param_dtype=self.param_dtype)(s)
-            side_logits.append(resize_to(l, hw).astype(jnp.float32))
+        with jax.named_scope("dsod.heads"):
+            for s in reversed(stages):  # finest decoder stage first, bridge last
+                l = nn.Conv(1, (3, 3), padding="SAME", dtype=self.dtype,
+                            param_dtype=self.param_dtype)(s)
+                side_logits.append(resize_to(l, hw).astype(jnp.float32))
 
-        refined = RefineModule(axis_name=self.axis_name,
-                               bn_momentum=self.bn_momentum, dtype=self.dtype,
-                               param_dtype=self.param_dtype)(
-            side_logits[0], train)
+            refined = RefineModule(axis_name=self.axis_name,
+                                   bn_momentum=self.bn_momentum,
+                                   dtype=self.dtype,
+                                   param_dtype=self.param_dtype)(
+                side_logits[0], train)
         return [refined] + side_logits

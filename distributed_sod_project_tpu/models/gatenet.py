@@ -35,6 +35,7 @@ from __future__ import annotations
 
 from typing import Any, List, Optional
 
+import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
@@ -120,35 +121,43 @@ class GateNet(nn.Module):
         bkw = dict(axis_name=self.axis_name, bn_momentum=self.bn_momentum,
                    conv_impl=self.conv_impl,
                    dtype=self.dtype, param_dtype=self.param_dtype)
-        if self.backbone == "vgg16":
-            feats = VGG16(use_bn=self.backbone_bn, **bkw)(x, train=train)
-        elif self.backbone == "resnet50":
-            feats = ResNet50(**bkw)(x, train=train)
-        else:
-            raise ValueError(f"GateNet: unknown backbone {self.backbone!r}")
+        with jax.named_scope("dsod.encoder"):
+            if self.backbone == "vgg16":
+                feats = VGG16(use_bn=self.backbone_bn, **bkw)(x, train=train)
+            elif self.backbone == "resnet50":
+                feats = ResNet50(**bkw)(x, train=train)
+            else:
+                raise ValueError(
+                    f"GateNet: unknown backbone {self.backbone!r}")
 
         kw = dict(axis_name=self.axis_name, bn_momentum=self.bn_momentum,
                   conv_impl=self.conv_impl,
                   dtype=self.dtype, param_dtype=self.param_dtype)
-        # Per-level transfer convs to the decoder width.
-        trans = [ConvBNAct(self.width, (3, 3), **kw)(f, train=train)
-                 for f in feats]
-
-        d = DilatedPyramidBridge(self.width, **kw)(trans[-1], train=train)
         logits: List[jnp.ndarray] = []
 
         def side_logit(feat):
-            l = nn.Conv(1, (3, 3), padding="SAME", dtype=self.dtype,
-                        param_dtype=self.param_dtype)(feat)
-            return resize_to(l, image.shape[1:3],
-                             impl=self.resample_impl).astype(jnp.float32)
+            with jax.named_scope("dsod.heads"):
+                l = nn.Conv(1, (3, 3), padding="SAME", dtype=self.dtype,
+                            param_dtype=self.param_dtype)(feat)
+                return resize_to(l, image.shape[1:3],
+                                 impl=self.resample_impl).astype(jnp.float32)
 
+        # The side heads interleave with the decoder stages (module
+        # order fixes the parameter names), so the decoder scope opens
+        # per stage and the two stay siblings.
+        with jax.named_scope("dsod.decoder"):
+            # Per-level transfer convs to the decoder width.
+            trans = [ConvBNAct(self.width, (3, 3), **kw)(f, train=train)
+                     for f in feats]
+            d = DilatedPyramidBridge(self.width, **kw)(trans[-1],
+                                                       train=train)
         logits.append(side_logit(d))  # coarsest
         for i in range(len(trans) - 2, -1, -1):
-            up = upsample_like(d, trans[i], impl=self.resample_impl)
-            gated = GateUnit(**kw)(trans[i], up, train=train)
-            d = ConvBNAct(self.width, (3, 3), **kw)([gated, up],
-                                                    train=train)
+            with jax.named_scope("dsod.decoder"):
+                up = upsample_like(d, trans[i], impl=self.resample_impl)
+                gated = GateUnit(**kw)(trans[i], up, train=train)
+                d = ConvBNAct(self.width, (3, 3), **kw)([gated, up],
+                                                        train=train)
             logits.append(side_logit(d))
 
         # Zoo contract: element 0 is the primary (finest) prediction.

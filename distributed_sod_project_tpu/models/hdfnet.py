@@ -155,53 +155,58 @@ class HDFNet(nn.Module):
         if d.shape[-1] == 1:
             d = jnp.repeat(d, 3, axis=-1)
 
-        rgb_feats = self._backbone("rgb")(x, train=train)
-        dep_feats = self._backbone("depth")(d, train=train)
+        with jax.named_scope("dsod.encoder"):
+            rgb_feats = self._backbone("rgb")(x, train=train)
+            dep_feats = self._backbone("depth")(d, train=train)
 
         kw = dict(axis_name=self.axis_name, bn_momentum=self.bn_momentum,
                   conv_impl=self.conv_impl,
                   dtype=self.dtype, param_dtype=self.param_dtype)
 
-        # Fuse the three deepest levels with dynamic filtering; the depth
-        # stream is the kernel-generating guide (hierarchical: each level
-        # gets its own DDPM).
-        filtered = []
-        for lvl in (2, 3, 4):
-            # The two streams convolve as their channel concat inside
-            # DDPM's entry conv — the ConvBNAct seam fuses it away on
-            # the fused arm.
-            fused = [rgb_feats[lvl], dep_feats[lvl]]
-            guide = ConvBNAct(self.width, (3, 3), **kw)(dep_feats[lvl], train)
-            filtered.append(DDPM(self.width, axis_name=self.axis_name,
-                                 bn_momentum=self.bn_momentum,
-                                 dlf_impl=self.dlf_impl,
-                                 conv_impl=self.conv_impl,
-                                 dtype=self.dtype,
-                                 param_dtype=self.param_dtype)(
-                fused, guide, train))
+        with jax.named_scope("dsod.decoder"):
+            # Fuse the three deepest levels with dynamic filtering; the
+            # depth stream is the kernel-generating guide (hierarchical:
+            # each level gets its own DDPM).
+            filtered = []
+            for lvl in (2, 3, 4):
+                # The two streams convolve as their channel concat inside
+                # DDPM's entry conv — the ConvBNAct seam fuses it away on
+                # the fused arm.
+                fused = [rgb_feats[lvl], dep_feats[lvl]]
+                guide = ConvBNAct(self.width, (3, 3), **kw)(dep_feats[lvl],
+                                                            train)
+                filtered.append(DDPM(self.width, axis_name=self.axis_name,
+                                     bn_momentum=self.bn_momentum,
+                                     dlf_impl=self.dlf_impl,
+                                     conv_impl=self.conv_impl,
+                                     dtype=self.dtype,
+                                     param_dtype=self.param_dtype)(
+                    fused, guide, train))
 
-        # Top-down decoder: deepest filtered level down to the finest two
-        # RGB levels (compressed to `width`).
-        dec = filtered[-1]
-        sides = []  # supervised decoder states, coarse → fine
-        for skip in (filtered[1], filtered[0]):
-            dec = resample_merge(dec, skip, mode="add",
-                                 impl=self.resample_impl)
-            dec = ConvBNAct(self.width, (3, 3), **kw)(dec, train)
-            sides.append(dec)
-        for lvl in (1, 0):
-            skip = ConvBNAct(self.width, (3, 3), **kw)(rgb_feats[lvl], train)
-            dec = resample_merge(dec, skip, mode="add",
-                                 impl=self.resample_impl)
-            dec = ConvBNAct(self.width, (3, 3), **kw)(dec, train)
+            # Top-down decoder: deepest filtered level down to the finest
+            # two RGB levels (compressed to `width`).
+            dec = filtered[-1]
+            sides = []  # supervised decoder states, coarse → fine
+            for skip in (filtered[1], filtered[0]):
+                dec = resample_merge(dec, skip, mode="add",
+                                     impl=self.resample_impl)
+                dec = ConvBNAct(self.width, (3, 3), **kw)(dec, train)
+                sides.append(dec)
+            for lvl in (1, 0):
+                skip = ConvBNAct(self.width, (3, 3), **kw)(rgb_feats[lvl],
+                                                           train)
+                dec = resample_merge(dec, skip, mode="add",
+                                     impl=self.resample_impl)
+                dec = ConvBNAct(self.width, (3, 3), **kw)(dec, train)
 
         hw = image.shape[1:3]
         logits = []
         # Primary head on the finest decoder state + one deep-supervision
         # head per intermediate decoder level.
-        for s in (dec, sides[1], sides[0]):
-            l = nn.Conv(1, (3, 3), padding="SAME", dtype=self.dtype,
-                        param_dtype=self.param_dtype)(s)
-            logits.append(resize_to(l, hw, impl=self.resample_impl)
-                          .astype(jnp.float32))
+        with jax.named_scope("dsod.heads"):
+            for s in (dec, sides[1], sides[0]):
+                l = nn.Conv(1, (3, 3), padding="SAME", dtype=self.dtype,
+                            param_dtype=self.param_dtype)(s)
+                logits.append(resize_to(l, hw, impl=self.resample_impl)
+                              .astype(jnp.float32))
         return logits

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 from typing import Any, Callable, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
@@ -533,6 +534,7 @@ def _fast_bilinear_axis(x, axis: int, out_n: int, impl: str = "fast"):
     return None
 
 
+@jax.named_scope("dsod.resample")
 def resize_to(x, hw: Tuple[int, int], method: str = "bilinear",
               impl: Optional[str] = None):
     """Static-shape spatial resize (the upsample path of every decoder).
@@ -557,8 +559,6 @@ def resize_to(x, hw: Tuple[int, int], method: str = "bilinear",
     kernel lerps in f32 in-kernel, so under bf16 compute it is the
     MORE precise arm, not a bit-equal one).
     """
-    import jax
-
     impl = _resolve_resample_impl(impl)
     if method == "bilinear" and impl != "xla":
         if impl == "fused":
@@ -583,6 +583,7 @@ def upsample_like(x, ref, method: str = "bilinear",
                      impl=impl)
 
 
+@jax.named_scope("dsod.resample")
 def resample_merge(x, lateral, mode: str = "add", x_first: bool = True,
                    impl: Optional[str] = None):
     """The decoder-stage idiom: upsample ``x`` to ``lateral``'s spatial

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 from typing import Any, List, Optional
 
+import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
@@ -109,36 +110,41 @@ class MINet(nn.Module):
         bkw = dict(axis_name=self.axis_name, bn_momentum=self.bn_momentum,
                    conv_impl=self.conv_impl,
                    dtype=self.dtype, param_dtype=self.param_dtype)
-        if self.backbone == "vgg16":
-            feats = VGG16(use_bn=self.backbone_bn, **bkw)(x, train=train)
-        elif self.backbone == "resnet50":
-            feats = ResNet50(**bkw)(x, train=train)
-        else:
-            raise ValueError(f"MINet: unknown backbone {self.backbone!r}")
+        with jax.named_scope("dsod.encoder"):
+            if self.backbone == "vgg16":
+                feats = VGG16(use_bn=self.backbone_bn, **bkw)(x, train=train)
+            elif self.backbone == "resnet50":
+                feats = ResNet50(**bkw)(x, train=train)
+            else:
+                raise ValueError(
+                    f"MINet: unknown backbone {self.backbone!r}")
 
         kw = dict(axis_name=self.axis_name, conv_impl=self.conv_impl,
                   dtype=self.dtype, param_dtype=self.param_dtype)
         rkw = dict(resample_impl=self.resample_impl, **kw)
 
-        # AIM per level.
-        agg = []
-        for i, f in enumerate(feats):
-            below = feats[i - 1] if i > 0 else None
-            above = feats[i + 1] if i < len(feats) - 1 else None
-            agg.append(AIM(self.width, **rkw)(below, f, above, train=train))
+        with jax.named_scope("dsod.decoder"):
+            # AIM per level.
+            agg = []
+            for i, f in enumerate(feats):
+                below = feats[i - 1] if i > 0 else None
+                above = feats[i + 1] if i < len(feats) - 1 else None
+                agg.append(AIM(self.width, **rkw)(below, f, above,
+                                                  train=train))
 
-        # Top-down decoder with SIM refinement.
-        d = agg[-1]
-        d = SIM(self.width, **rkw)(d, train=train)
-        for i in range(len(agg) - 2, -1, -1):
-            d = resample_merge(d, agg[i], mode="add",
-                               impl=self.resample_impl)
+            # Top-down decoder with SIM refinement.
+            d = agg[-1]
             d = SIM(self.width, **rkw)(d, train=train)
+            for i in range(len(agg) - 2, -1, -1):
+                d = resample_merge(d, agg[i], mode="add",
+                                   impl=self.resample_impl)
+                d = SIM(self.width, **rkw)(d, train=train)
 
         # Head → full-resolution single-channel logit.
-        h = ConvBNAct(32, (3, 3), **kw)(d, train=train)
-        logit = nn.Conv(1, (3, 3), padding="SAME", dtype=self.dtype,
-                        param_dtype=self.param_dtype)(h)
-        logit = resize_to(logit, image.shape[1:3],
-                          impl=self.resample_impl).astype(jnp.float32)
+        with jax.named_scope("dsod.heads"):
+            h = ConvBNAct(32, (3, 3), **kw)(d, train=train)
+            logit = nn.Conv(1, (3, 3), padding="SAME", dtype=self.dtype,
+                            param_dtype=self.param_dtype)(h)
+            logit = resize_to(logit, image.shape[1:3],
+                              impl=self.resample_impl).astype(jnp.float32)
         return [logit]

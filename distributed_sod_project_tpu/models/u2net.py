@@ -21,6 +21,7 @@ from __future__ import annotations
 
 from typing import Any, List, Optional
 
+import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
@@ -129,39 +130,43 @@ class U2Net(nn.Module):
             dec_spec = [(4, 128, 256), (5, 64, 128), (6, 32, 64), (7, 16, 64)]
 
         # Encoder: 4 RSU stages + 2 dilated stages, pooling between all 6.
-        feats = []
-        h = x
-        for lv, mid, out in enc_spec:
-            h = RSU(lv, mid, out, **rkw)(h, train)
+        with jax.named_scope("dsod.encoder"):
+            feats = []
+            h = x
+            for lv, mid, out in enc_spec:
+                h = RSU(lv, mid, out, **rkw)(h, train)
+                feats.append(h)
+                h = max_pool(h)
+            h = RSU4F(f_mid, f_out, **kw)(h, train)
             feats.append(h)
             h = max_pool(h)
-        h = RSU4F(f_mid, f_out, **kw)(h, train)
-        feats.append(h)
-        h = max_pool(h)
-        h = RSU4F(f_mid, f_out, **kw)(h, train)  # En_6 (bottleneck)
+            h = RSU4F(f_mid, f_out, **kw)(h, train)  # En_6 (bottleneck)
 
         # Decoder: RSU4F then the mirrored RSU stack on concat skips.
-        sides = [h]  # bottleneck side output source
-        d = RSU4F(f_mid, f_out, **kw)(
-            resample_merge(h, feats[4], mode="concat",
-                           impl=self.resample_impl), train)
-        sides.append(d)
-        for (lv, mid, out), skip in zip(dec_spec, feats[3::-1]):
-            d = RSU(lv, mid, out, **rkw)(
-                resample_merge(d, skip, mode="concat",
+        with jax.named_scope("dsod.decoder"):
+            sides = [h]  # bottleneck side output source
+            d = RSU4F(f_mid, f_out, **kw)(
+                resample_merge(h, feats[4], mode="concat",
                                impl=self.resample_impl), train)
             sides.append(d)
+            for (lv, mid, out), skip in zip(dec_spec, feats[3::-1]):
+                d = RSU(lv, mid, out, **rkw)(
+                    resample_merge(d, skip, mode="concat",
+                                   impl=self.resample_impl), train)
+                sides.append(d)
 
         # Side heads: 3x3 conv → 1ch logit, upsampled to input resolution.
         hw = image.shape[1:3]
         logits = []
-        for s in reversed(sides):  # finest (d1) first
-            l = nn.Conv(1, (3, 3), padding="SAME", dtype=self.dtype,
-                        param_dtype=self.param_dtype)(s)
-            logits.append(resize_to(l, hw, impl=self.resample_impl)
-                          .astype(jnp.float32))
-        # Fused head over all 6 side logits.
-        fused = nn.Conv(1, (1, 1), dtype=self.dtype,
-                        param_dtype=self.param_dtype)(
-            jnp.concatenate([l.astype(self.dtype) for l in logits], axis=-1))
+        with jax.named_scope("dsod.heads"):
+            for s in reversed(sides):  # finest (d1) first
+                l = nn.Conv(1, (3, 3), padding="SAME", dtype=self.dtype,
+                            param_dtype=self.param_dtype)(s)
+                logits.append(resize_to(l, hw, impl=self.resample_impl)
+                              .astype(jnp.float32))
+            # Fused head over all 6 side logits.
+            fused = nn.Conv(1, (1, 1), dtype=self.dtype,
+                            param_dtype=self.param_dtype)(
+                jnp.concatenate([l.astype(self.dtype) for l in logits],
+                                axis=-1))
         return [fused.astype(jnp.float32)] + logits

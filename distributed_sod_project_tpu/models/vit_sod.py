@@ -27,6 +27,7 @@ from __future__ import annotations
 
 from typing import Any, Callable, List, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from flax import linen as nn
@@ -116,24 +117,28 @@ class ViTSOD(nn.Module):
         rows, cols = hh // p, ww // p
         grid = tuple(full_grid) if full_grid is not None else (rows, cols)
 
-        # Disjoint-tile patchify: kernel == stride == patch.
-        x = nn.Conv(self.dim, (p, p), strides=(p, p), dtype=self.dtype,
-                    param_dtype=self.param_dtype, name="patch_embed")(x)
-        x = x.reshape(b, rows * cols, self.dim)
+        # Disjoint-tile patchify: kernel == stride == patch.  (No
+        # ``dsod.decoder`` here: the heads read the encoder's tokens.)
+        with jax.named_scope("dsod.encoder"):
+            x = nn.Conv(self.dim, (p, p), strides=(p, p), dtype=self.dtype,
+                        param_dtype=self.param_dtype, name="patch_embed")(x)
+            x = x.reshape(b, rows * cols, self.dim)
 
-        pos = self.param(
-            "pos_embed",
-            nn.initializers.truncated_normal(0.02),
-            (grid[0] * grid[1], self.dim), self.param_dtype)
-        # This call's token window of the full positional table: row
-        # offset may be a traced per-device index (SP), so slice
-        # dynamically; cols always span the full width.
-        start = jnp.asarray(pos_row_offset, jnp.int32) * grid[1]
-        from jax import lax
+            pos = self.param(
+                "pos_embed",
+                nn.initializers.truncated_normal(0.02),
+                (grid[0] * grid[1], self.dim), self.param_dtype)
+            # This call's token window of the full positional table: row
+            # offset may be a traced per-device index (SP), so slice
+            # dynamically; cols always span the full width.
+            start = jnp.asarray(pos_row_offset, jnp.int32) * grid[1]
+            from jax import lax
 
-        pos_win = lax.dynamic_slice_in_dim(pos, start, rows * cols, axis=0)
-        x = x + pos_win[None].astype(self.dtype)
+            pos_win = lax.dynamic_slice_in_dim(pos, start, rows * cols,
+                                               axis=0)
+            x = x + pos_win[None].astype(self.dtype)
 
+        @jax.named_scope("dsod.heads")
         def unpatchify_head(tokens, name):
             """Per-token D -> p*p logits, tiled back to pixels — the
             only head shape that keeps the model halo-free for SP."""
@@ -148,10 +153,11 @@ class ViTSOD(nn.Module):
 
         aux = None
         for i in range(self.depth):
-            x = _Block(dim=self.dim, heads=self.heads,
-                       mlp_ratio=self.mlp_ratio, dtype=self.dtype,
-                       param_dtype=self.param_dtype, name=f"block{i}")(
-                           x, attn_fn, train=train)
+            with jax.named_scope("dsod.encoder"):
+                x = _Block(dim=self.dim, heads=self.heads,
+                           mlp_ratio=self.mlp_ratio, dtype=self.dtype,
+                           param_dtype=self.param_dtype, name=f"block{i}")(
+                               x, attn_fn, train=train)
             if self.deep_supervision and i == self.depth // 2 - 1:
                 aux = unpatchify_head(x, "aux_head")
 
@@ -159,7 +165,6 @@ class ViTSOD(nn.Module):
         if aux is not None:
             logits.append(aux)
         return logits
-
 
 PRESETS = {
     # name: (dim, depth, heads).  "small"/"base" match the public

@@ -104,6 +104,7 @@ def _img_spec(shape3):
                         lambda i, _n=n: (i,) + (0,) * _n)
 
 
+@jax.named_scope("dsod.kernel.dynamic_filter")
 def _call_filter(x, kt, ksize, dilation, interpret):
     b, h, w, c = x.shape
     r = dilation * (ksize // 2)
@@ -132,6 +133,7 @@ def _dlf_fwd(x, kt, ksize, dilation, interpret):
     return _call_filter(x, kt, ksize, dilation, interpret), (x, kt)
 
 
+@jax.named_scope("dsod.kernel.dynamic_filter")
 def _dlf_bwd(ksize, dilation, interpret, res, g):
     x, kt = res
     b, h, w, c = x.shape

@@ -209,6 +209,7 @@ def _specs(bq, bkv, d, *, kv_resident: bool):
     return qs, kv, row
 
 
+@jax.named_scope("dsod.kernel.flash_attention")
 def _fwd_call(q, k, v, cfg):
     bq, bkv, interpret, n = cfg
     bh, np_, d = q.shape
@@ -231,6 +232,7 @@ def _fwd_call(q, k, v, cfg):
     )(q, k, v)
 
 
+@jax.named_scope("dsod.kernel.flash_attention")
 def _bwd_call(q, k, v, out, lse_row, do, cfg, dlse=None):
     bq, bkv, interpret, n = cfg
     bh, np_, d = q.shape

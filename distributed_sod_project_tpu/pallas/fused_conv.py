@@ -283,6 +283,7 @@ def _vec2d(v):
     return jnp.asarray(v).reshape(1, -1)
 
 
+@jax.named_scope("dsod.kernel.fused_conv")
 def _call_fwd(parts, w, vecs: Dict[str, Any], spec: _Spec,
               save_preact: bool = False):
     b, h, wd, _ = parts[0].shape
@@ -317,6 +318,7 @@ def _call_fwd(parts, w, vecs: Dict[str, Any], spec: _Spec,
     return out
 
 
+@jax.named_scope("dsod.kernel.fused_conv")
 def _call_dw(parts, g, spec: _Spec):
     b, h, wd, cout = g.shape
     cd = parts[0].dtype

@@ -64,6 +64,7 @@ def fused_loss_available(shape) -> bool:
     return n % _LANES == 0 and jax.default_backend() in ("cpu", "tpu")
 
 
+@jax.named_scope("dsod.kernel.fused_loss")
 def pixel_region_sums(logits: jnp.ndarray, targets: jnp.ndarray,
                       interpret: bool | None = None,
                       ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray,

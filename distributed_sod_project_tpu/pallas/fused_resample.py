@@ -186,6 +186,7 @@ def _staging(h2, w2, c):
     return [pltpu.VMEM((h2, w2, c), jnp.float32)]
 
 
+@jax.named_scope("dsod.kernel.fused_resample")
 def _call_up(x, interpret):
     b, h, w, c = x.shape
     return pl.pallas_call(
@@ -203,6 +204,7 @@ def _call_up(x, interpret):
     )(x)
 
 
+@jax.named_scope("dsod.kernel.fused_resample")
 def _call_merge(x, lat, mode, x_first, interpret):
     b, h, w, c = x.shape
     cl = lat.shape[-1]
@@ -227,6 +229,7 @@ def _call_merge(x, lat, mode, x_first, interpret):
     )(x, lat)
 
 
+@jax.named_scope("dsod.kernel.fused_resample")
 def _call_upT(g, interpret):
     b, hh, ww, c = g.shape
     return pl.pallas_call(

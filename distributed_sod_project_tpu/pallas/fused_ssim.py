@@ -147,6 +147,7 @@ def fused_ssim_mean(a, b, window: int = 11, sigma: float = 1.5):
     return val
 
 
+@jax.named_scope("dsod.kernel.fused_ssim")
 def _run(kernel, a, b, out_shapes, taps, interpret=None):
     from jax.experimental import pallas as pl
 

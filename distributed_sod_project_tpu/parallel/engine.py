@@ -209,20 +209,22 @@ def make_unified_train_step(
                     outs = outs[:1]  # primary head only
                 total = jnp.float32(0.0)
                 comps: Dict[str, jnp.ndarray] = {}
-                for level in outs:
-                    t, c = _sp_hybrid_loss(
-                        level, mask, bce_w=loss_cfg.bce,
-                        iou_w=loss_cfg.iou, cel_w=loss_cfg.cel)
-                    if getattr(loss_cfg, "ssim", 0.0):
-                        c["ssim"] = _sp_ssim_loss(
-                            level, mask,
-                            window_size=getattr(loss_cfg, "ssim_window",
-                                                11))
-                        t = t + loss_cfg.ssim * c["ssim"]
-                    total = total + t
-                    for k, v in c.items():
-                        if k != "total":
-                            comps[k] = comps.get(k, jnp.float32(0.0)) + v
+                with jax.named_scope("dsod.loss"):
+                    for level in outs:
+                        t, c = _sp_hybrid_loss(
+                            level, mask, bce_w=loss_cfg.bce,
+                            iou_w=loss_cfg.iou, cel_w=loss_cfg.cel)
+                        if getattr(loss_cfg, "ssim", 0.0):
+                            c["ssim"] = _sp_ssim_loss(
+                                level, mask,
+                                window_size=getattr(loss_cfg,
+                                                    "ssim_window", 11))
+                            t = t + loss_cfg.ssim * c["ssim"]
+                        total = total + t
+                        for k, v in c.items():
+                            if k != "total":
+                                comps[k] = (comps.get(k, jnp.float32(0.0))
+                                            + v)
                 comps["total"] = total
                 return total, comps
 
@@ -242,8 +244,9 @@ def make_unified_train_step(
                                  batch["image"], batch.get("depth"))
             if not loss_cfg.deep_supervision:
                 outs = outs[:1]  # primary head only, uniform across steps
-            total, comps = deep_supervision_loss(outs, batch["mask"],
-                                                 **lkw)
+            with jax.named_scope("dsod.loss"):
+                total, comps = deep_supervision_loss(outs, batch["mask"],
+                                                     **lkw)
             return total, (comps, mut.get("batch_stats",
                                           state.batch_stats))
 
@@ -251,6 +254,7 @@ def make_unified_train_step(
             state.params)
         return grads, comps, new_stats
 
+    @jax.named_scope("dsod.update")
     def _reduce(grads, comps, residual=None):
         """Per-preset gradient/metric reduction — the comm seam.
         Returns ``(grads, comps, new_residual)``; the residual is only
@@ -279,6 +283,7 @@ def make_unified_train_step(
             grads = lax.with_sharding_constraint(grads, grad_constraint)
         return grads, comps, residual
 
+    @jax.named_scope("dsod.update")
     def _finish(state, grads, comps, new_stats):
         """Optimizer/EMA/metric tail — identical on every preset."""
         new_state = apply_update(state, grads, new_stats, tx,

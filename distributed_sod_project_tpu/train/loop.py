@@ -166,19 +166,22 @@ def fit(
         data_guard = GuardedDataset(dataset, cfg.data.skip_budget,
                                     fault_plan=plan)
         dataset = data_guard
-    # Host-data-plane telemetry: every blocking point in the loader /
-    # prefetch stages reports here; the per-interval deltas ride the
-    # metric stream (data_starved_ms is the input-bound signal).
-    from ..utils.observability import PipelineStats
-
-    data_stats = PipelineStats()
-    # Chunk tracing (utils/tracing.py; docs/OBSERVABILITY.md): sampled
-    # chunks record data_wait/dispatch/flush (+ckpt/eval, + synthetic
-    # build/ring-wait/h2d children from the data-plane counters)
-    # correlated to step numbers.  sample=0 (default): no clock reads.
-    from ..utils.tracing import Tracer, mint_trace_id
+    # Spans (utils/tracing.py::span; docs/OBSERVABILITY.md): the loop
+    # and the data plane name what they do under ``dsod.train.*`` /
+    # ``dsod.data.*`` as profiler annotations — in the .xplane.pb of
+    # any profiler session, on the device ops' clock, a flag check
+    # otherwise — and sampled chunks (cfg.trace_sample) keep the same
+    # intervals in the /debug/traces ring, correlated to step numbers.
+    # sample=0 (default): no clock reads, no Tracer calls.
+    from ..utils.tracing import Tracer, mint_trace_id, span
 
     tracer = Tracer(sample=cfg.trace_sample)
+    # Host-data-plane telemetry: every blocking point in the loader /
+    # prefetch stages reports here; the per-interval deltas ride the
+    # metric stream (data_starved_ms: the loop's wait for a batch).
+    from ..utils.observability import PipelineStats
+
+    data_stats = PipelineStats(keep_spans=tracer.enabled)
     loader = make_loader(
         dataset, cfg.data,
         global_batch_size=cfg.global_batch_size,
@@ -267,8 +270,9 @@ def fit(
 
         def _train_shares():
             # Host-vs-device attribution for the train loop: the
-            # starved counter is exactly "device idle waiting on the
-            # host data plane" — the futile-to-scale share.
+            # starved counter (the loop's wait for a batch) is an
+            # upper bound on "device idle waiting on the host data
+            # plane" — the futile-to-scale share.
             wall_ms = max((time.monotonic() - t_run0) * 1000.0, 1e-9)
             starved = data_stats.snapshot().get("data_starved_ms", 0.0)
             host = min(starved / wall_ms, 1.0)
@@ -680,12 +684,12 @@ def fit(
                 "training has diverged; no bad update was "
                 "applied, restart from the last checkpoint "
                 "with a lower lr / higher loss scale")
-        host["imgs_per_sec"] = timer.images_per_sec(
-            cfg.global_batch_size)
+        host["imgs_per_sec"] = timer.fetched(at_step,
+                                             cfg.global_batch_size)
         host["epoch"] = at_epoch
         # Data-plane health for this logging interval:
-        # data_starved_ms > 0 means the device waited on
-        # the host pipeline (docs/PERFORMANCE.md).
+        # data_starved_ms > 0 means the loop waited on the
+        # host pipeline (docs/PERFORMANCE.md).
         host.update(data_stats.delta())
         if cfg.data.skip_budget > 0:
             # Corrupt samples tolerated so far (dataguard
@@ -717,21 +721,16 @@ def fit(
         return bool(cfg.checkpoint_every_steps
                     and at_step % cfg.checkpoint_every_steps == 0)
 
-    def _run_state_events(at_step, trace=None):
+    def _run_state_events(at_step, root=None):
         """Eval/checkpoint at a boundary — these read the CURRENT state,
         so under chunking they may only run while ``state`` still is the
         state at ``at_step`` (before the next chunk's donated dispatch
-        replaces it).  ``trace`` (the boundary chunk's open trace dict)
-        gets an eval/ckpt span per event."""
+        replaces it).  ``root`` (the boundary chunk's root span when it
+        is sampled) gets an eval/ckpt span per event."""
         nonlocal eval_metrics, last_eval_step, last_saved
         if _eval_due(at_step):
-            t_e0 = time.monotonic() if trace else 0.0
-            eval_metrics = eval_fn(state)
-            if trace:
-                tracer.record(trace["root"].trace_id, "eval", t_e0,
-                              time.monotonic(),
-                              parent_id=trace["root"].span_id,
-                              attrs={"step": at_step})
+            with span("dsod.train.eval", root, step=at_step):
+                eval_metrics = eval_fn(state)
             last_eval_step = at_step
             if recorder is not None:
                 recorder.event("eval", step=at_step,
@@ -757,13 +756,8 @@ def fit(
                 last_eval_step = at_step
             # state passed as-is: orbax's async save does the D2H
             # copy behind the next train steps (no device_get stall).
-            t_c0 = time.monotonic() if trace else 0.0
-            mgr.save(at_step, state, metrics=eval_metrics or None)
-            if trace:
-                tracer.record(trace["root"].trace_id, "ckpt", t_c0,
-                              time.monotonic(),
-                              parent_id=trace["root"].span_id,
-                              attrs={"step": at_step})
+            with span("dsod.train.ckpt", root, step=at_step):
+                mgr.save(at_step, state, metrics=eval_metrics or None)
             if recorder is not None:
                 recorder.event("checkpoint", step=at_step)
             last_saved = at_step
@@ -781,43 +775,31 @@ def fit(
     # otherwise idle the device once per chunk).  Boundaries that need
     # the post-chunk STATE (eval/checkpoint) flush synchronously before
     # the next dispatch instead — donation replaces the state.
-    pending = None  # (end_step, metrics_device, epoch, chunk_trace)
+    pending = None  # (end_step, metrics_device, epoch, root span | None)
 
-    def _finish_chunk_trace(trace, at_step):
-        """Close a sampled chunk's trace: synthesize the data-plane
-        children (build/ring-wait/h2d durations accumulated by the
-        pipeline THREADS during this chunk, placed at the root's start
-        and tagged synthetic — durations are measured, placement is
-        not), then end the root."""
-        if not trace:
+    def _finish_chunk_trace(root, at_step):
+        """Close a chunk: the data plane's spans kept since the last
+        chunk closed (``PipelineStats.timed`` regions of the pipeline
+        THREADS, at the time they ran) join a sampled chunk's trace,
+        then its root ends.  An unsampled chunk drops them."""
+        kept = data_stats.drain_spans()
+        if root is None:
             return
-        root = trace["root"]
-        snap = data_stats.snapshot()
-        for key, name in (("data_build_wait_ms", "build_wait"),
-                          ("data_ring_wait_ms", "ring_wait"),
-                          ("data_h2d_ms", "h2d")):
-            dur_ms = snap.get(key, 0.0) - trace["snap"].get(key, 0.0)
-            if dur_ms > 0:
-                tracer.record(root.trace_id, name, root.t0,
-                              root.t0 + dur_ms / 1000.0,
-                              parent_id=root.span_id,
-                              attrs={"synthetic": True})
+        for name, t0, t1, attrs in kept:
+            tracer.record(root.trace_id, name, t0, t1,
+                          parent_id=root.span_id, attrs=attrs)
         root.end(key=("train",), step=at_step)
 
     def _flush_chunk(with_state: bool):
         nonlocal pending, stop
-        at_step, metrics_dev, at_epoch, trace = pending
+        at_step, metrics_dev, at_epoch, root = pending
         pending = None
         # The fetch cannot return before chunk `at_step` completed, so
         # it doubles as the completed-work signal — the timer/watchdog
         # beat is fed by finished device work, not by dispatch
         # (utils/timing.py).
-        t_f0 = time.monotonic() if trace else 0.0
-        metrics_host = jax.device_get(metrics_dev)
-        if trace:
-            tracer.record(trace["root"].trace_id, "flush", t_f0,
-                          time.monotonic(),
-                          parent_id=trace["root"].span_id)
+        with span("dsod.train.flush", root):
+            metrics_host = jax.device_get(metrics_dev)
         timer.tick(steps=k)
         _observe_capacity_slo(at_step - k)
         # Health observes EVERY fetched chunk (a mid-interval NaN must
@@ -827,14 +809,15 @@ def fit(
             hooks["on_chunk_metrics"](at_step, metrics_host)
         stop = _poll_stop(guard, at_step, sync_every) or stop
         if at_step % cfg.log_every_steps == 0 or at_step == total_steps:
-            _process_log(at_step, metrics_host, at_epoch)
+            with span("dsod.train.log", root, step=at_step):
+                _process_log(at_step, metrics_host, at_epoch)
         if with_state:
-            _run_state_events(at_step, trace=trace)
-        _finish_chunk_trace(trace, at_step)
+            _run_state_events(at_step, root)
+        _finish_chunk_trace(root, at_step)
 
-    # End-of-previous-chunk timestamp: the gap to the next body entry
-    # is the chunk's data_wait span (blocked on the prefetch queue).
-    # Only maintained while tracing is on — sample=0 reads no clocks.
+    # End-of-previous-chunk timestamp: a sampled chunk's root span
+    # starts there, so it covers the wait for its batch.  Only
+    # maintained while tracing is on — sample=0 reads no clocks.
     t_prev_end = None
     try:
       with PreemptionGuard() as guard:
@@ -869,87 +852,78 @@ def fit(
             for batch in it:
                 if step >= total_steps or stop:
                     break
-                if pending is not None and _state_event_at(pending[0]):
-                    # Chunk n's eval/checkpoint must observe the state
-                    # AT its boundary — flush before chunk n+1's
-                    # donated dispatch replaces it.
-                    _flush_chunk(with_state=True)
-                    if stop:
-                        break
-                # Chunk trace: root spans the data wait + dispatch (+
-                # flush/ckpt/eval recorded where they happen); None
-                # unless this chunk is sampled.
-                chunk_tr = None
-                if tracer.enabled:
-                    t_now = time.monotonic()
-                    root = tracer.begin(
-                        "chunk", mint_trace_id(),
-                        t0=t_prev_end if t_prev_end is not None else t_now,
-                        root=True,
-                        attrs={"step_first": step + 1, "step_last": step + k,
-                               "epoch": epoch})
-                    if root is not None:
-                        chunk_tr = {"root": root,
-                                    "snap": data_stats.snapshot()}
-                        if t_prev_end is not None:
-                            tracer.record(root.trace_id, "data_wait",
-                                          t_prev_end, t_now,
-                                          parent_id=root.span_id)
-                train_step = train_step_at(step)
-                _maybe_record_capacity(step, train_step, state, batch)
-                if plan is not None:
-                    batch = plan.maybe_poison_batch(step + 1, batch)
-                t_d0 = time.monotonic() if chunk_tr else 0.0
-                if step == profile_at:
-                    with profile_window(profile_dir):
-                        state, metrics = train_step(state, batch)
-                        jax.block_until_ready(metrics["total"])
-                else:
-                    state, metrics = train_step(state, batch)
-                if chunk_tr:
+                with span("dsod.train.step", step_num=step + 1, steps=k,
+                          epoch=epoch):
+                    if pending is not None and _state_event_at(pending[0]):
+                        # Chunk n's eval/checkpoint must observe the
+                        # state AT its boundary — flush before chunk
+                        # n+1's donated dispatch replaces it.
+                        _flush_chunk(with_state=True)
+                        if stop:
+                            break
+                    # Chunk trace: the root spans the wait for the batch
+                    # + dispatch (+ flush/log/ckpt/eval and the data
+                    # plane's spans, recorded where they happen); None
+                    # unless this chunk is sampled.
+                    root = None
+                    if tracer.enabled:
+                        t_now = time.monotonic()
+                        root = tracer.begin(
+                            "chunk", mint_trace_id(),
+                            t0=t_prev_end if t_prev_end is not None
+                            else t_now, root=True,
+                            attrs={"step_first": step + 1,
+                                   "step_last": step + k, "epoch": epoch})
+                    train_step = train_step_at(step)
+                    _maybe_record_capacity(step, train_step, state, batch)
+                    if plan is not None:
+                        batch = plan.maybe_poison_batch(step + 1, batch)
                     # Host-side dispatch time (the device runs async;
                     # completed-work time shows up in the flush span).
-                    tracer.record(chunk_tr["root"].trace_id, "dispatch",
-                                  t_d0, time.monotonic(),
-                                  parent_id=chunk_tr["root"].span_id)
-                step += k
-                if k > 1:
-                    # Lagged flush: observe chunk n only after chunk
-                    # n+1 is in flight, so the device never sits idle
-                    # across the host's fetch + bookkeeping + dispatch
-                    # gap (see _flush_chunk).
-                    if pending is not None:
-                        _flush_chunk(with_state=False)
-                    pending = (step, metrics, epoch, chunk_tr)
+                    with span("dsod.train.dispatch", root):
+                        if step == profile_at:
+                            with profile_window(profile_dir):
+                                state, metrics = train_step(state, batch)
+                                jax.block_until_ready(metrics["total"])
+                        else:
+                            state, metrics = train_step(state, batch)
+                    step += k
+                    if k > 1:
+                        # Lagged flush: observe chunk n only after chunk
+                        # n+1 is in flight, so the device never sits
+                        # idle across the host's fetch + bookkeeping +
+                        # dispatch gap (see _flush_chunk).
+                        if pending is not None:
+                            _flush_chunk(with_state=False)
+                        pending = (step, metrics, epoch, root)
+                        if tracer.enabled:
+                            t_prev_end = time.monotonic()
+                        continue
+                    # ---- k == 1: the historical per-step path.
+                    if plan is not None:
+                        # Stall BEFORE the heartbeat: to the watchdog
+                        # this step is still in flight, like a wedged
+                        # dispatch.
+                        plan.maybe_stall(step)
+                    timer.tick()
+                    _observe_capacity_slo(step - 1)
+                    if plan is not None:
+                        plan.maybe_sigterm(step)
+                    stop = _poll_stop(guard, step, sync_every)
+                    if step % cfg.log_every_steps == 0 or step == total_steps:
+                        # ONE batched device_get for the whole metric
+                        # dict — not a blocking float(v) per scalar
+                        # (each paid a full host↔device round trip on
+                        # remote transports).
+                        with span("dsod.train.flush", root):
+                            metrics_host = jax.device_get(metrics)
+                        _observe_health(metrics_host)
+                        with span("dsod.train.log", root, step=step):
+                            _process_log(step, metrics_host, epoch)
+                    _run_state_events(step, root)
+                    _finish_chunk_trace(root, step)
                     if tracer.enabled:
                         t_prev_end = time.monotonic()
-                    continue
-                # ---- k == 1: the historical per-step path, unchanged.
-                if plan is not None:
-                    # Stall BEFORE the heartbeat: to the watchdog this
-                    # step is still in flight, like a wedged dispatch.
-                    plan.maybe_stall(step)
-                timer.tick()
-                _observe_capacity_slo(step - 1)
-                if plan is not None:
-                    plan.maybe_sigterm(step)
-                stop = _poll_stop(guard, step, sync_every)
-                if step % cfg.log_every_steps == 0 or step == total_steps:
-                    # ONE batched device_get for the whole metric dict —
-                    # not a blocking float(v) per scalar (each paid a
-                    # full host↔device round trip on remote transports).
-                    t_f0 = time.monotonic() if chunk_tr else 0.0
-                    metrics_host = jax.device_get(metrics)
-                    if chunk_tr:
-                        tracer.record(chunk_tr["root"].trace_id, "flush",
-                                      t_f0, time.monotonic(),
-                                      parent_id=chunk_tr["root"].span_id)
-                    _observe_health(metrics_host)
-                    _process_log(step, metrics_host, epoch)
-                _run_state_events(step, trace=chunk_tr)
-                _finish_chunk_trace(chunk_tr, step)
-                if tracer.enabled:
-                    t_prev_end = time.monotonic()
             if step >= total_steps or stop:
                 break
         if pending is not None:

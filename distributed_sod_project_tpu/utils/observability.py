@@ -16,12 +16,15 @@ and nothing for preemption beyond --resume restarts.  TPU-native forms:
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import signal
 import threading
+import time
 from typing import Dict, Optional
 
 from .logging import get_logger, is_primary_process
+from .tracing import span
 
 
 class PipelineStats:
@@ -31,17 +34,28 @@ class PipelineStats:
     reports here, so "the step is input-bound" is a measured number
     instead of a guess.  Counters (cumulative):
 
-    - ``data_starved_ms``   — consumer blocked on an empty prefetch
-      queue: device idle waiting for data.  THE input-bound signal.
+    - ``data_starved_ms``   — the CONSUMER (the train loop's thread)
+      blocked on an empty prefetch queue.  A host wait, not device
+      idle: the device may still hold queued steps.  How much of it
+      the device felt is read where both share a clock — the
+      ``dsod.data.starved`` span of a profiler trace against the
+      device ops (``data_starved_exposed_ms_per_step``, PERF.md).
     - ``data_h2d_ms``       — time inside device_put / global array
       assembly on the H2D thread.
     - ``data_prefetch_full_ms`` — H2D thread blocked on a full queue
       (healthy: the step, not the input, is the bottleneck).
+    - ``data_build_ms``     — build workers decoding + augmenting one
+      batch each (summed over workers, so it can exceed wall time).
     - ``data_build_wait_ms`` — loader blocked waiting for a batch
       build worker (decode+augment stage is the bottleneck).
     - ``data_ring_wait_ms`` — builders blocked waiting for a free
       batch buffer (consumer holding the ring; raise ring_buffers).
     - ``data_batches``      — batches produced.
+
+    Each ``data_*_ms`` counter is fed by :meth:`timed` and by nothing
+    else: one timed region adds the counter AND emits the span
+    ``dsod.data.<key without data_/_ms>`` on the profiler's clock, so
+    count and span are the same measurement.
 
     Queue depth is tracked as a running (sum, count) pair and reported
     as ``data_queue_depth_avg`` / ``data_queue_size``.
@@ -50,15 +64,42 @@ class PipelineStats:
     ``delta()`` call — the train loop calls it once per logging
     interval and hands the result to :class:`MetricWriter`, so the
     TensorBoard curves are per-interval, not monotone totals.
+
+    ``keep_spans``: also keep each timed region as ``(name, t0, t1,
+    attrs)`` on ``time.monotonic`` (the :class:`Tracer` ring's clock)
+    until :meth:`drain_spans` — the train loop moves them into the
+    sampled chunk's trace.  Bounded; off by default.
     """
 
-    def __init__(self):
+    def __init__(self, keep_spans: bool = False):
         self._lock = threading.Lock()
         self._counts: Dict[str, float] = {}
         self._last: Dict[str, float] = {}
         self._depth_sum = 0.0
         self._depth_n = 0
         self._depth_size = 0
+        self._spans = collections.deque(maxlen=1024) if keep_spans else None
+
+    @contextlib.contextmanager
+    def timed(self, key: str, **attrs):
+        """THE timing seam of the data plane: time the body once, add
+        it to counter ``key`` (``data_<what>_ms``) and emit the span
+        ``dsod.data.<what>`` with ``attrs``."""
+        name = "dsod.data." + key[len("data_"):-len("_ms")]
+        t0 = time.monotonic()
+        with span(name, **attrs):
+            yield
+        t1 = time.monotonic()
+        self.add(key, (t1 - t0) * 1000.0)
+        if self._spans is not None:
+            self._spans.append((name, t0, t1, attrs))
+
+    def drain_spans(self) -> list:
+        """The timed regions kept since the last drain (``keep_spans``)."""
+        out = []
+        while self._spans:
+            out.append(self._spans.popleft())
+        return out
 
     def add(self, key: str, value: float) -> None:
         with self._lock:
@@ -98,7 +139,8 @@ class PipelineStats:
     # that happens to be zero this run must not read as "vanished" to
     # tools/metrics_lint.py.
     CANONICAL = ("data_starved_ms", "data_h2d_ms", "data_prefetch_full_ms",
-                 "data_build_wait_ms", "data_ring_wait_ms", "data_batches",
+                 "data_build_ms", "data_build_wait_ms",
+                 "data_ring_wait_ms", "data_batches",
                  "data_chunk_assemble_ms", "data_chunks",
                  "data_partial_chunks_dropped")
 

@@ -23,7 +23,10 @@ class StepTimer:
     per-dispatch tick: there the log-cadence metric fetch bounds host
     run-ahead, so the window mean still converges to the completion
     rate (documented dispatch-rate semantics, preserved so recorded
-    baselines replay identically).
+    baselines replay identically).  The LOGGED ``imgs_per_sec`` does
+    not read that window: :meth:`fetched` rates the steps between two
+    host fetches of the metrics over the time between them, which is
+    completed work on either path.
 
     ``on_tick`` (optional) is invoked once per ``tick()`` — the train
     loop feeds the step watchdog's heartbeat through it
@@ -38,6 +41,20 @@ class StepTimer:
         self._times: deque = deque(maxlen=window)
         self._last = None
         self._count = 0
+        self._fetch = None  # (time, step) of the last metric fetch
+
+    def fetched(self, step: int, batch_size: int) -> float:
+        """Images per second over the interval since the previous call:
+        the loop calls this right after a host fetch of step ``step``'s
+        metrics, which cannot return before that step completed, so
+        both ends of the interval are completed work (the clock the
+        benchmark's ``on_metrics`` ticks read).  The first call has no
+        interval and falls back to the windowed mean."""
+        now = time.perf_counter()
+        prev, self._fetch = self._fetch, (now, step)
+        if prev is None or step <= prev[1] or now <= prev[0]:
+            return self.images_per_sec(batch_size)
+        return (step - prev[1]) * batch_size / (now - prev[0])
 
     def tick(self, steps: int = 1) -> None:
         """Record that ``steps`` more train steps completed since the

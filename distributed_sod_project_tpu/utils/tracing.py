@@ -47,7 +47,7 @@ from typing import Dict, List, Optional, Tuple
 
 __all__ = [
     "Span", "Tracer", "format_timing", "mint_trace_id", "parse_timing",
-    "trace_sampled",
+    "span", "trace_sampled",
 ]
 
 _SAMPLE_MOD = 1 << 24
@@ -315,6 +315,53 @@ class Tracer:
     def completed_total(self) -> int:
         with self._lock:
             return self._completed
+
+
+# -- dsod.* spans on the profiler's clock -------------------------------
+
+_profiler = None  # jax.profiler, imported on first use: this module
+# stays importable (and the load generator stays light) without JAX.
+
+
+class span:
+    """One host interval under one ``dsod.*`` name, for both readers.
+
+    Opens a ``jax.profiler.TraceAnnotation`` (``StepTraceAnnotation``
+    when ``step_num`` is given), so the interval lands in whatever
+    ``.xplane.pb`` a profiler session is writing — on the clock of the
+    device ops — and costs a flag check when none is.  With ``root``,
+    the root :class:`Span` of a SAMPLED chunk, the same interval is
+    also recorded into that chunk's trace in the ring (``/debug/traces``)
+    under the same name; without it no clock is read.
+    """
+
+    __slots__ = ("_ann", "_root", "_name", "_attrs", "_t0")
+
+    def __init__(self, name: str, root: Optional[Span] = None, *,
+                 step_num: Optional[int] = None, **attrs):
+        global _profiler
+        if _profiler is None:
+            import jax.profiler as _profiler
+        self._ann = (_profiler.TraceAnnotation(name, **attrs)
+                     if step_num is None else
+                     _profiler.StepTraceAnnotation(name, step_num=step_num,
+                                                   **attrs))
+        self._root, self._name, self._attrs = root, name, attrs
+
+    def __enter__(self) -> "span":
+        if self._root is not None:
+            self._t0 = self._root._tracer._clock()
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self._ann.__exit__(*exc)
+        root = self._root
+        if root is not None:
+            root._tracer.record(
+                root.trace_id, self._name, self._t0, root._tracer._clock(),
+                parent_id=root.span_id, attrs=self._attrs)
+        return False
 
 
 # -- X-Timing header ---------------------------------------------------

@@ -750,3 +750,34 @@ def test_step_timer_credits_chunk_steps(monkeypatch):
     clock["t"] += 0.4
     t.tick(steps=1)
     assert t.mean_step_time == pytest.approx((0.1 + 0.4) / 2)
+
+
+def test_logged_rate_is_completed_steps_between_metric_fetches(monkeypatch):
+    """The logged imgs_per_sec: steps between two host fetches of the
+    metrics over the time between them (completed work on both ends),
+    whatever the k=1 loop's per-dispatch ticks say in between."""
+    from distributed_sod_project_tpu.utils import timing
+
+    clock = {"t": 50.0}
+    monkeypatch.setattr(timing.time, "perf_counter",
+                        lambda: clock["t"])
+    beats = []
+    t = timing.StepTimer(window=8, warmup=0, on_tick=lambda: beats.append(1))
+    # k=1 with run-ahead: five dispatches tick within 10 ms, then the
+    # fetch at the log boundary blocks until the device has done them.
+    for _ in range(5):
+        clock["t"] += 0.002
+        t.tick()
+    clock["t"] += 1.24
+    first = t.fetched(5, 16)  # no interval yet: the windowed mean
+    assert first == pytest.approx(t.images_per_sec(16))
+    for _ in range(5):
+        clock["t"] += 0.002
+        t.tick()
+    assert t.images_per_sec(16) > 1.5 * 64  # the window is led by dispatches
+    clock["t"] += 1.24
+    assert t.fetched(10, 16) == pytest.approx(5 * 16 / 1.25)
+    assert len(beats) == 10  # the watchdog's cadence is the ticks', untouched
+    # A fetch that reports no progress has no rate of its own.
+    clock["t"] += 1.0
+    assert t.fetched(10, 16) == pytest.approx(t.images_per_sec(16))

@@ -491,39 +491,49 @@ def test_export_model_roundtrip_and_tpu_lowering(tmp_path, eight_devices):
     assert info["platform"] == "tpu" and info["bytes"] > 0
 
 
-@pytest.mark.slow
 def test_analyze_trace_summarises_profile(tmp_path, capsys):
-    # End-to-end: capture a tiny real profiler trace, then assert the
-    # analyzer extracts an overview and a sorted HLO table from it (the
-    # MFU-push workflow of BASELINE.md round 2).
+    # End-to-end: capture a tiny real profiler trace with the program's
+    # own names in it, then read it back through the one reduction the
+    # benchmark's per-layer metrics use (benchmark/harness/trace.py +
+    # spans.py; tools/analyze_trace.py is their CLI).
     import jax
     import jax.numpy as jnp
 
     import analyze_trace
+    from distributed_sod_project_tpu.utils.tracing import span
 
-    f = jax.jit(lambda x: jnp.tanh(x @ x.T).sum())
-    x = jnp.ones((512, 512))
+    @jax.jit
+    def f(x):
+        with jax.named_scope("dsod.loss"):
+            return jnp.tanh(x @ x.T).sum()
+
+    x = jnp.ones((256, 256))
     f(x).block_until_ready()
     trace_dir = str(tmp_path / "trace")
     jax.profiler.start_trace(trace_dir)
-    for _ in range(8):
-        f(x).block_until_ready()
+    for i in range(4):
+        with span("dsod.train.step", step_num=i + 1, steps=1, epoch=0):
+            with span("dsod.train.dispatch"):
+                y = f(x)
+            with span("dsod.train.flush"):
+                y.block_until_ready()
     jax.profiler.stop_trace()
 
-    assert analyze_trace.main([trace_dir]) == 0
+    assert analyze_trace.main([trace_dir, "--top", "3"]) == 0
     out = capsys.readouterr().out
-    # XLA:CPU traces carry no per-HLO device plane (device-op tables
-    # populate only for real accelerator traces — the v5e run in
-    # BASELINE.md), so this asserts the plumbing: overview renders and
-    # the HLO section is either a table or the explicit empty notice.
-    assert "== overview ==" in out
-    assert "HLO ops by self time" in out
-    assert ("Occurrences" in out or "hlo_stats empty" in out
-            or "hlo_stats unavailable" in out)
-    # --list-tools enumerates converters for the same trace.
-    assert analyze_trace.main([trace_dir, "--list-tools"]) == 0
-    out = capsys.readouterr().out
-    assert "overview_page" in out and "hlo_stats" in out
+    # XLA:CPU traces carry no TPU device plane (the op and stage tables
+    # populate only for a trace from the chip — PERF.md section 5), so
+    # this asserts the plumbing: the file is found, the device section
+    # says what it lacks, and the host spans are totalled by thread.
+    assert "xplane: " in out and ".xplane.pb" in out
+    assert "no operation on a TPU plane" in out
+    rows = {ln.split()[3]: int(ln.split()[5])
+            for ln in out.splitlines() if ln.startswith("spans: host ")}
+    assert rows == {"dsod.train.step": 4, "dsod.train.dispatch": 4,
+                    "dsod.train.flush": 4}
+    # It imports no xprof converter: the reduction needs only JAX.
+    with open(analyze_trace.__file__) as fh:
+        assert "xprof" not in fh.read()
     # Missing dir is a clean rc=1, not a traceback.
     assert analyze_trace.main([str(tmp_path / "nope")]) == 1
 
@@ -547,8 +557,8 @@ def test_roofline_ledger_and_buckets(capsys):
     invariants that need no hardware — FLOP linearity in batch,
     HBM-bound totals at the flagship's intensity, remat adding
     forward recompute, capacity estimates that retro-predict the
-    round-2 b256 death, and the HLO shape-bucket parser the trace
-    reconciliation stands on."""
+    round-2 b256 death.  (The measured side reads a trace by the
+    step's ``dsod.<stage>`` scopes: tools/analyze_trace.py.)"""
     import roofline
 
     rows32, f32_, b32_, t32 = roofline.predict(32)
@@ -567,17 +577,6 @@ def test_roofline_ledger_and_buckets(capsys):
     # Capacity: monotone in batch; b256 no-remat must exceed v5e HBM.
     caps = [roofline.act_capacity_gb(b) for b in (64, 128, 256)]
     assert caps[0] < caps[1] < caps[2] and caps[2] > 16.0
-
-    # Bucket parser: tuple results, operand fallback (dw fusions),
-    # and non-spatial ops.
-    known = {320, 160, 80, 40, 20, 10}
-    assert roofline._bucket_of(
-        "%fusion.13 = (f32[64]{0}, bf16[64,160,160,64]{3,0}) "
-        "fusion(bf16[64,80,80,64]{0})", known) == 160
-    assert roofline._bucket_of(
-        "%dw = f32[3,3,96,64]{2,3} fusion(bf16[64,80,80,96]{3})",
-        known) == 80
-    assert roofline._bucket_of("%p = f32[64]{0} parameter()", known) == 0
 
     # CLI prints the prediction tables.
     assert roofline.main(["--batch", "64", "--remat"]) == 0

@@ -405,6 +405,20 @@ def test_metrics_byte_identical_with_tracing_off(tiny):
             live = r.read().decode()
         assert live == eng.stats.render_prometheus()
         assert "trace" not in live
+        # The trainer's half of the same promise: with no sampled chunk
+        # (trace_sample=0 gives none) and no profiler session, the span
+        # helper the loop calls reads no clock and touches no Tracer.
+        from distributed_sod_project_tpu.utils import tracing
+
+        reads = []
+        real = tracing.time.monotonic
+        tracing.time.monotonic = lambda: reads.append(1) or real()
+        try:
+            with tracing.span("dsod.train.dispatch", None):
+                pass
+        finally:
+            tracing.time.monotonic = real
+        assert reads == []
         # The registry render path is the identity for one provider.
         reg = TelemetryRegistry().register("serve",
                                            eng.stats.prom_families)
@@ -868,10 +882,22 @@ def test_trainer_sidecar_live_fit_endpoints_and_chunk_traces(tmp_path):
     assert done, snap
     t = done[-1]
     names = {s["name"] for s in t["spans"]}
-    assert "chunk" in names and "dispatch" in names
+    assert "chunk" in names and "dsod.train.dispatch" in names
     root = [s for s in t["spans"] if s["name"] == "chunk"][0]
     assert root["attrs"]["step_last"] - root["attrs"]["step_first"] == 1
     assert t["key"] == "train"
+    # The data plane's spans are REAL intervals (PipelineStats.timed
+    # regions of the pipeline threads, moved into the chunk that was
+    # open when they ran) — none fabricated at the root's start.
+    spans = [s for tr in done for s in tr["spans"]]
+    assert {"dsod.data.h2d", "dsod.data.build", "dsod.data.starved",
+            "dsod.train.flush"} <= {s["name"] for s in spans}
+    assert not [s for s in spans if s.get("attrs", {}).get("synthetic")]
+    h2d = [s for s in spans if s["name"] == "dsod.data.h2d"]
+    assert any(s["rel_ms"] > 0 for s in h2d)  # placed where they ran
+    assert all(s["parent"] == [r for r in tr["spans"]
+                               if r["name"] == "chunk"][0]["span"]
+               for tr in done for s in tr["spans"] if s["name"] != "chunk")
     code, prof = got["/debug/profile?seconds=0.2"]
     assert code == 200
     assert os.path.isdir(json.loads(prof)["logdir"])

@@ -9,11 +9,11 @@ This derives, from closed forms (no device needed):
   - a per-resolution-bucket roofline time  t >= max(F/peak, B/bw)
     on v5e (197 TFLOP/s dense bf16, 819 GB/s HBM),
   - predicted step time / throughput / MFU at b32/b64/b128,
-    remat on/off, plain vs s2d stem, fast vs xla resize,
-  - and (with ``--trace DIR``) the measured per-bucket table from a
-    captured profile, aggregated by the spatial resolution parsed out
-    of each HLO op's result shapes — so prediction and measurement
-    meet on the same axis without any fusion-name mapping.
+    remat on/off, plain vs s2d stem, fast vs xla resize.
+
+The measured side is ``tools/analyze_trace.py <profile-dir>``: the step
+names its stages (``dsod.encoder`` ... ``dsod.update``), so a trace is
+read by stage, not by the spatial size in an op's result shape.
 
 Cross-checks:
   - ``--xla-check`` jits the REAL train step on CPU at b4 and compares
@@ -23,7 +23,6 @@ Cross-checks:
 
 Usage:
     python tools/roofline.py                       # predictions
-    python tools/roofline.py --trace <profile-dir> --batch 64 --remat
     python tools/roofline.py --xla-check
 
 Modeling assumptions (documented so disagreement is informative):
@@ -51,10 +50,8 @@ custom kernel (HBM-bound, far from roofline) before any is written.
 from __future__ import annotations
 
 import argparse
-import json
 import math
 import os
-import re
 import sys
 from dataclasses import dataclass, field
 
@@ -601,99 +598,6 @@ def fmt_comm_ledger(b: int, n_dp: int = 8, bucket_mb: float = 25.0,
     return "\n".join(out)
 
 
-# ---------------------------------------------------------------------
-# measured side: bucket a captured trace by result-shape resolution
-# ---------------------------------------------------------------------
-
-_SHAPE = re.compile(r"\[(\d+(?:,\d+)*)\]")
-
-
-def _scan_square(text: str, known: set) -> int:
-    best = 0
-    for m in _SHAPE.finditer(text):
-        dims = [int(d) for d in m.group(1).split(",")]
-        if len(dims) >= 3:
-            for a, c in zip(dims[1:-1], dims[2:]):
-                if a == c and a in known and a > best:
-                    best = a
-    return best
-
-
-def _bucket_of(expr: str, known: set) -> int:
-    """Spatial bucket of an HLO op: the largest known square spatial
-    dim among its RESULT shapes — falling back to the whole expression
-    (operands included) for ops whose results carry no spatial square,
-    e.g. weight-grad fusions producing f32[3,3,Cin,Cout]."""
-    rhs = expr.split("=", 1)[1].strip() if "=" in expr else expr
-    if rhs.startswith("("):  # tuple result: take the balanced parens
-        depth = 0
-        head = rhs
-        for i, ch in enumerate(rhs):
-            if ch == "(":
-                depth += 1
-            elif ch == ")":
-                depth -= 1
-                if depth == 0:
-                    head = rhs[:i + 1]
-                    break
-    else:
-        head = rhs.split("(", 1)[0]
-    return _scan_square(head, known) or _scan_square(expr, known)
-
-
-def measured_table(trace_dir: str, top_unmatched: int = 5):
-    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    from analyze_trace import convert, find_xspaces
-
-    xs = find_xspaces(trace_dir)
-    if not xs:
-        raise SystemExit(f"no xplane.pb under {trace_dir}")
-    data = convert(xs, "hlo_stats")
-    table = json.loads(data[data.index("{"):]) if isinstance(data, str) else data
-    cols = [c.get("id") for c in table["cols"]]
-    i_expr = cols.index("hlo_op_expression")
-    i_self = cols.index("total_self_time")
-    i_occ = cols.index("occurrences")
-    i_bound = cols.index("bound_by")
-    i_cat = cols.index("category")
-    known = {320, 160, 80, 40, 20, 10}
-    buckets: dict = {}
-    cats: dict = {}
-    unmatched: list = []
-    total_us = 0.0
-    for r in table["rows"]:
-        vals = [c.get("v") if isinstance(c, dict) else c for c in r["c"]]
-        occ = float(vals[i_occ] or 1)
-        us = float(vals[i_self] or 0.0) / max(occ, 1)  # per-step us
-        total_us += us
-        cat = str(vals[i_cat] or "?")
-        cats[cat] = cats.get(cat, 0.0) + us
-        res = _bucket_of(str(vals[i_expr]), known)
-        b = buckets.setdefault(res, [0.0, {}])
-        b[0] += us
-        bound = str(vals[i_bound] or "?")
-        b[1][bound] = b[1].get(bound, 0.0) + us
-        if res == 0 and us > 0:
-            unmatched.append((us, str(vals[i_expr])[:90]))
-    out = ["| res | measured ms/step | share | top bound-by |",
-           "|---|---|---|---|"]
-    for res in sorted(buckets, reverse=True):
-        us, bounds = buckets[res]
-        top = max(bounds.items(), key=lambda kv: kv[1])[0] if bounds else "?"
-        out.append(f"| {res or 'other'} | {us / 1e3:.2f} | "
-                   f"{us / total_us:.0%} | {top} |")
-    out.append(f"| **total (self-time)** | **{total_us / 1e3:.2f}** | | |")
-    out.append("")
-    out.append("| category | ms/step | share |")
-    out.append("|---|---|---|")
-    for cat, us in sorted(cats.items(), key=lambda kv: -kv[1]):
-        out.append(f"| {cat} | {us / 1e3:.2f} | {us / total_us:.0%} |")
-    unmatched.sort(reverse=True)
-    for us, e in unmatched[:top_unmatched]:
-        out.append(f"  unbucketed {us / 1e3:.3f} ms: {e}")
-    return "\n".join(out)
-
-
 def xla_check(b: int = 4, hw: int = 64):
     """Compare the ledger against XLA's cost model on the REAL step —
     and cross-check the LIVE capacity ledger (utils/capacity.py) on the
@@ -784,7 +688,6 @@ def main(argv=None) -> int:
                         "kernel, model.conv_impl=fused; also prints "
                         "the per-decoder-site bytes-saved ledger and "
                         "asserts FLOPs invariance vs the xla arm)")
-    p.add_argument("--trace", help="profile dir to reconcile against")
     p.add_argument("--xla-check", action="store_true")
     p.add_argument("--comm", action="store_true",
                    help="print the gradient-communication ledger "
@@ -832,9 +735,6 @@ def main(argv=None) -> int:
         if args.conv == "fused":
             print(fmt_fused_conv_ledger(b))
             print()
-    if args.trace:
-        print(f"## measured ({args.trace})")
-        print(measured_table(args.trace))
     return 0
 
 
