@@ -16,8 +16,10 @@ coarse predict-module output, then the deeper side outputs — all at
 input resolution so ``deep_supervision_loss`` consumes them uniformly.
 
 TPU notes: the encoder is pure 3×3 convs (MXU-friendly); the refinement
-residual is elementwise and fuses into the surrounding graph; all
-resizes are static-shape ``jax.image.resize``.
+residual is elementwise and fuses into the surrounding graph; every
+upsample + skip concat goes through ``layers.resample_merge`` and every
+side logit through ``layers.resize_to``, which pick a one-pass route
+from the shape (Pallas kernel / lane-dense matmuls).
 """
 
 from __future__ import annotations
@@ -29,7 +31,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from .backbones.resnet import BasicBlock
-from .layers import ConvBNAct, max_pool, resize_to, upsample_like
+from .layers import ConvBNAct, max_pool, resample_merge, resize_to
 
 
 class _DecoderStage(nn.Module):
@@ -45,7 +47,7 @@ class _DecoderStage(nn.Module):
     def __call__(self, d, skip, train: bool = False):
         kw = dict(axis_name=self.axis_name, bn_momentum=self.bn_momentum,
                   dtype=self.dtype, param_dtype=self.param_dtype)
-        x = jnp.concatenate([upsample_like(d, skip), skip], axis=-1)
+        x = resample_merge(d, skip, mode="concat")
         for _ in range(3):
             x = ConvBNAct(self.width, (3, 3), **kw)(x, train)
         return x
@@ -73,7 +75,7 @@ class RefineModule(nn.Module):
         x = ConvBNAct(self.width, (3, 3), **kw)(x, train)
         for skip in reversed(skips):
             x = ConvBNAct(self.width, (3, 3), **kw)(
-                jnp.concatenate([upsample_like(x, skip), skip], axis=-1), train)
+                resample_merge(x, skip, mode="concat"), train)
         res = nn.Conv(1, (3, 3), padding="SAME", dtype=self.dtype,
                       param_dtype=self.param_dtype)(x)
         return logit + res.astype(jnp.float32)

@@ -15,10 +15,14 @@ TPU-first conventions used throughout the zoo:
 
 from __future__ import annotations
 
+import collections
+import contextlib
+import logging
 from typing import Any, Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import linen as nn
 
 Dtype = Any
@@ -381,8 +385,11 @@ def _upsample_axis(x, axis: int, s: int):
     phase constants baked in at trace time.  Pure slice/lerp/interleave
     — a single VPU pass, where the generic resize lowers to per-axis
     ``dot_general``s whose operand layouts cost two relayout copies per
-    call (measured 15% of the MINet-R50 train step on v5e;
-    docs/PERFORMANCE.md).
+    call (15% of the MINet-R50 train step on another tree, in July;
+    on THIS tree's one measured cell the order is the reverse — the
+    generic path 13.6 ms, this one 40.3 ms of BASNet's step, PERF.md
+    section 6, PR 26 — which is why it is the fallback now and no
+    longer what BASNet runs).
 
     The interleave is LAYOUT-STABLE (round 5): the phases concatenate
     along the NEXT axis and one reshape merges the pair — by row-major
@@ -390,8 +397,9 @@ def _upsample_axis(x, axis: int, s: int):
     this produces exactly the same elements as the historical
     ``stack(axis+1) + reshape`` form, but without inserting size-1 axes
     XLA:TPU answers with dim-shuffled relayout copies (~1.25 ms per
-    call on ``bf16[64,160,64,160]`` in the round-2 v5e trace, ~10% of
-    the flagship step in data-formatting total).  Bit-identical either
+    call on ``bf16[64,160,64,160]`` in a July trace of another tree,
+    ~10% of the flagship step in data-formatting total; not
+    re-measured here).  Bit-identical either
     way; ``DSOD_RESIZE_INTERLEAVE=stack`` keeps the old form as the A/B
     arm ``tools/hlo_guard.py`` diffs against.
     """
@@ -498,6 +506,43 @@ def _upsample2_axis_convt(x, axis: int):
 
 
 RESAMPLE_IMPLS = ("fast", "xla", "convt", "fused")
+# What a resample site can come out as.  With no arm named (``impl``
+# None, ``DSOD_RESIZE_IMPL`` unset) the route follows from the shape:
+# an exact-2x map of 8+ channels goes through the row-banded Pallas
+# kernel, a 1-channel map through two lane-dense matmuls, anything
+# else through the slice/lerp path.
+RESAMPLE_ROUTES = ("kernel", "lane_dense", "fallback")
+_ROUTES: "collections.Counter[str]" = collections.Counter()
+_log = logging.getLogger(__name__)
+
+
+@contextlib.contextmanager
+def resample_routes(log_as: Optional[str] = None):
+    """Tally the route every resample site traced inside the block
+    took (sites that resize nothing are no sites).  Yields a dict
+    filled on exit; with ``log_as`` the tally is one line of the
+    program's log — the train engine wraps the step's trace in it, so
+    the line appears once per compile."""
+    before = _ROUTES.copy()
+    counts: dict = {}
+    try:
+        yield counts
+    finally:
+        counts.update({k: _ROUTES[k] - before[k] for k in RESAMPLE_ROUTES})
+        if log_as:
+            from ..utils.logging import get_logger
+
+            get_logger().info("resample routes (%s): %s", log_as, " ".join(
+                f"{k}={counts[k]}" for k in RESAMPLE_ROUTES))
+
+
+def _count_route(route: str, x_shape, hw):
+    _ROUTES[route] += 1
+    if route == "fallback":
+        # Trace-time note (once per compile, not per step): which
+        # sites took neither the kernel nor the lane-dense form.
+        _log.debug("resample outside the kernel's envelope at %s -> %s: "
+                   "slice/lerp path", tuple(x_shape), tuple(hw))
 
 
 def _resolve_resample_impl(impl: Optional[str]) -> str:
@@ -507,16 +552,48 @@ def _resolve_resample_impl(impl: Optional[str]) -> str:
     explicit ``impl``) subsumes the ``DSOD_RESIZE_IMPL`` env knob: an
     explicit non-default impl always wins; at the default (``None`` /
     ``"fast"``) a set env var still selects the arm, so the
-    BASELINE.md measurement commands keep working unchanged.
+    BASELINE.md measurement commands keep working unchanged.  With
+    neither, ``None`` resolves to ``"auto"`` (the route follows from
+    the shape) and an explicit ``"fast"`` stays the slice/lerp arm.
     """
     from ..utils import envvars
 
     if impl in (None, "fast"):
-        impl = envvars.read("DSOD_RESIZE_IMPL") or "fast"
-    if impl not in RESAMPLE_IMPLS:
+        impl = (envvars.read("DSOD_RESIZE_IMPL")
+                or ("auto" if impl is None else "fast"))
+    if impl != "auto" and impl not in RESAMPLE_IMPLS:
         raise ValueError(
             f"resample impl must be one of {RESAMPLE_IMPLS}, got {impl!r}")
     return impl
+
+
+def _interp_matrix(n: int, out_n: int) -> np.ndarray:
+    """The (out_n, n) bilinear interpolation matrix of an upsample by a
+    whole factor: half-pixel centres, the tap past an edge clamped onto
+    it — row for row what ``jax.image.resize`` computes."""
+    src = (np.arange(out_n) + 0.5) * n / out_n - 0.5
+    lo = np.floor(src)
+    f = (src - lo).astype(np.float32)
+    a = np.zeros((out_n, n), np.float32)
+    rows = np.arange(out_n)
+    np.add.at(a, (rows, np.clip(lo, 0, n - 1).astype(int)), 1 - f)
+    np.add.at(a, (rows, np.clip(lo + 1, 0, n - 1).astype(int)), f)
+    return a
+
+
+def _resize_lane_dense(x, hw: Tuple[int, int]):
+    """Whole-factor bilinear upsample of a 1-CHANNEL map with W on the
+    lanes: ``A_h @ x @ A_w^T`` per image, in float32 (the matrices are
+    constants; autodiff gives their transposes).  As NHWC such a map
+    uses one lane of 128 in every op that touches it."""
+    import jax.lax as lax
+
+    hi = lax.Precision.HIGHEST
+    y = jnp.einsum("Hh,bhw->bHw", _interp_matrix(x.shape[1], hw[0]),
+                   x[..., 0].astype(jnp.float32), precision=hi)
+    y = jnp.einsum("bHw,Ww->bHW", y, _interp_matrix(x.shape[2], hw[1]),
+                   precision=hi)
+    return y.astype(x.dtype)[..., None]
 
 
 def _fast_bilinear_axis(x, axis: int, out_n: int, impl: str = "fast"):
@@ -540,38 +617,57 @@ def resize_to(x, hw: Tuple[int, int], method: str = "bilinear",
     """Static-shape spatial resize (the upsample path of every decoder).
 
     Bilinear integer-factor resizes — every resize the zoo performs —
-    take the fused slice/lerp path above; anything else falls back to
-    ``jax.image.resize`` (same numerics either way, asserted in
-    tests/test_models.py).  ``impl`` (default: ``DSOD_RESIZE_IMPL``,
-    else ``fast``) selects the execution strategy:
+    never reach ``jax.image.resize`` unless asked to (same numerics
+    either way, asserted in tests/test_models.py).  With no ``impl``
+    and no ``DSOD_RESIZE_IMPL`` the route follows from the shape, one
+    pass over HBM in a layout that fills the lanes:
+
+    - exact 2x, 8+ channels, a row band that fits VMEM — the Pallas
+      kernel (``pallas/fused_resample.py``);
+    - 1 channel, a whole factor per axis — two constant interpolation
+      matrices with W on the lanes (``_resize_lane_dense``);
+    - anything else — the slice/lerp path.
+
+    A named ``impl`` pins one arm:
 
     - ``fast``  — slice/lerp with the layout-stable interleave;
     - ``xla``   — force the generic ``jax.image.resize`` everywhere
       (the measurement/debug escape hatch behind the BASELINE.md
-      numbers);
+      numbers, and the arm the tests compare against);
     - ``convt`` — 2x upsamples as depthwise fractionally-strided convs;
-    - ``fused`` — exact-2x upsamples as one Pallas VMEM pass
-      (``pallas/fused_resample.py``) where the shape/VMEM budget
-      allows, the ``fast`` path otherwise.
+    - ``fused`` — the Pallas kernel where its rule admits the site,
+      the ``fast`` path otherwise.
 
     Every arm computes the same bilinear resample; ``fast``/``convt``
-    match bitwise, ``xla``/``fused`` to dtype round-off (the fused
-    kernel lerps in f32 in-kernel, so under bf16 compute it is the
-    MORE precise arm, not a bit-equal one).
+    match bitwise, the others to dtype round-off (the kernel and the
+    lane-dense form lerp in f32, so under bf16 compute they are the
+    MORE precise arms, not bit-equal ones).
     """
     impl = _resolve_resample_impl(impl)
+    hw = tuple(hw)
     if method == "bilinear" and impl != "xla":
-        if impl == "fused":
+        if hw == x.shape[1:3]:
+            return x  # resizes nothing: no site
+        if impl in ("auto", "fused"):
             from ..pallas.fused_resample import (fused_resample_available,
                                                  fused_upsample2)
 
             if fused_resample_available(x.shape, hw):
+                _count_route("kernel", x.shape, hw)
                 return fused_upsample2(x)
-        h = _fast_bilinear_axis(x, 1, hw[0], impl)
+        if (impl == "auto" and x.shape[3] == 1
+                and hw[0] % x.shape[1] == 0 and hw[1] % x.shape[2] == 0):
+            _count_route("lane_dense", x.shape, hw)
+            return _resize_lane_dense(x, hw)
+        _count_route("fallback", x.shape, hw)
+        arm = impl if impl == "convt" else "fast"
+        h = _fast_bilinear_axis(x, 1, hw[0], arm)
         if h is not None:
-            w = _fast_bilinear_axis(h, 2, hw[1], impl)
+            w = _fast_bilinear_axis(h, 2, hw[1], arm)
             if w is not None:
                 return w
+    elif hw != x.shape[1:3]:
+        _count_route("fallback", x.shape, hw)
     out = jax.image.resize(x, (x.shape[0], hw[0], hw[1], x.shape[3]), method=method)
     return out.astype(x.dtype)
 
@@ -593,18 +689,28 @@ def resample_merge(x, lateral, mode: str = "add", x_first: bool = True,
 
     All four decoder users (MINet AIM/SIM, HDFNet, GateNet via its
     bare-upsample form, U²-Net) route their merges here so the
-    ``model.resample_impl`` knob selects one strategy zoo-wide.  With
-    ``impl='fused'`` and an exact-2x, VMEM-sized resample the whole
-    chain runs as ONE Pallas pass (the fine map is read from HBM once
-    — roofline lever #1, docs/PERFORMANCE.md); any other impl, or an
-    out-of-envelope shape, takes the plain resize + merge.  Every arm
+    ``model.resample_impl`` knob selects one strategy zoo-wide; BASNet's
+    decoder stages and refine module come here with no ``impl``.  With
+    ``impl='fused'`` (or no arm named and an add merge) and an exact-2x
+    resample within the kernel's rule the whole chain runs as ONE
+    Pallas pass (the kernel writes the merge; ``up`` is never in HBM —
+    roofline lever #1, docs/PERFORMANCE.md); any other impl, or an
+    out-of-envelope shape, takes ``resize_to`` + the plain merge (with
+    no arm named ``resize_to`` still picks the kernel for the upsample
+    alone).  Every arm
     computes the same resample (≤1e-5 in f32, asserted in
     tests/test_pallas_resample.py); under bf16 compute the fused arm
     lerps in f32 in-kernel where the fast arm lerps in bf16, so the
     arms agree to bf16 round-off (~1e-3), not bitwise.
     """
     impl = _resolve_resample_impl(impl)
-    if impl == "fused":
+    # With no arm named a CONCAT is left to XLA: it reads the two maps
+    # straight into the conv that follows (the concat never exists in
+    # HBM), where a kernel-written concat is a second copy of the
+    # lateral that the backward keeps (+0.95 GiB on BASNet's step
+    # compiled for a v5e — PERF.md, PR 26); the upsample alone still
+    # takes the kernel, inside resize_to.
+    if impl == "fused" or (impl == "auto" and mode == "add"):
         from ..pallas.fused_resample import (fused_resample_available,
                                              fused_upsample2_merge)
 
@@ -613,19 +719,14 @@ def resample_merge(x, lateral, mode: str = "add", x_first: bool = True,
                 and (mode != "add" or lateral.shape[-1] == x.shape[-1])
                 and fused_resample_available(
                     x.shape, lateral.shape[1:3], mode, lateral.shape[-1])):
+            _count_route("kernel", x.shape, lateral.shape[1:3])
             return fused_upsample2_merge(x, lateral, mode=mode,
                                          x_first=x_first)
-        # Out of envelope: trace-time note so a fused A/B leg knows
-        # which sites opted out (fires once per compile, not per step),
-        # then keep the EXPLICIT 'fused' selection and let resize_to
-        # degrade it to the fast path itself — rewriting to 'fast'
-        # would re-enter env resolution and let a stray
-        # DSOD_RESIZE_IMPL hijack a site the user pinned to fused.
-        import logging
-
-        logging.getLogger(__name__).debug(
-            "fused resample out of envelope at %s -> %s (%s): fast path",
-            x.shape, lateral.shape, mode)
+        # Out of the merge kernel's envelope: hand the RESOLVED arm on
+        # and let resize_to pick and count the route itself (it notes a
+        # fallback at trace time) — rewriting 'fused' to 'fast', or
+        # 'auto' back to None, would re-enter env resolution and let a
+        # stray DSOD_RESIZE_IMPL hijack the site.
     up = resize_to(x, (lateral.shape[1], lateral.shape[2]), impl=impl)
     if mode == "add":
         return up + lateral

@@ -44,3 +44,16 @@ def scoped_vmem_params(env_var: str) -> pltpu.CompilerParams:
     if kind is None or chip_peaks(kind).vmem_bytes <= _RAISED_LIMIT:
         return pltpu.CompilerParams()
     return pltpu.CompilerParams(vmem_limit_bytes=_RAISED_LIMIT)
+
+
+def rows_per_band(n_rows: int, *, per_row: int, fixed: int,
+                  budget: int) -> int:
+    """Rows one grid step of a row-banded kernel takes: the most that
+    fit ``budget`` (``per_row`` a row plus ``fixed`` a band, in
+    whatever unit the caller counts), evened out so the bands differ by
+    at most one row's worth of rounding; 0 when not one row fits."""
+    fit = (budget - fixed) // per_row
+    if fit < 1:
+        return 0
+    n_bands = -(-n_rows // fit)
+    return -(-n_rows // n_bands)

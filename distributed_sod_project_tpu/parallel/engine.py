@@ -57,6 +57,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..losses import deep_supervision_loss
+from ..models.layers import resample_routes
 from ..train.state import TrainState
 from ..train.step import (_loss_kwargs, apply_update, chunk_batch_spec,
                           chunked_step_fn, maybe_health_metrics,
@@ -254,6 +255,13 @@ def make_unified_train_step(
             state.params)
         return grads, comps, new_stats
 
+    def _forward_loss_counted(state, batch, rng):
+        # Runs at trace time only: one "resample routes" log line per
+        # compile, saying how many resample sites of the step took the
+        # Pallas kernel / the lane-dense form / the slice-lerp path.
+        with resample_routes(log_as=f"train step, {preset}"):
+            return _forward_loss(state, batch, rng)
+
     @jax.named_scope("dsod.update")
     def _reduce(grads, comps, residual=None):
         """Per-preset gradient/metric reduction — the comm seam.
@@ -303,7 +311,7 @@ def make_unified_train_step(
         if preset != "sp":
             batch = rescale_batch(batch, scale_hw)
         rng = _rng(state.step)
-        grads, comps, new_stats = _forward_loss(state, batch, rng)
+        grads, comps, new_stats = _forward_loss_counted(state, batch, rng)
         grads, comps, _ = _reduce(grads, comps)
         return _finish(state, grads, comps, new_stats)
 
@@ -313,7 +321,7 @@ def make_unified_train_step(
         state, residual = carry
         batch = rescale_batch(batch, scale_hw)
         rng = _rng(state.step)
-        grads, comps, new_stats = _forward_loss(state, batch, rng)
+        grads, comps, new_stats = _forward_loss_counted(state, batch, rng)
         grads, comps, new_res = _reduce(grads, comps, residual[0])
         new_state, metrics = _finish(state, grads, comps, new_stats)
         return (new_state, new_res[None]), metrics

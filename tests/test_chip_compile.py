@@ -42,6 +42,7 @@ fc, fr, dfm, fl, fs, fa, vb = (
 
 _S = collections.namedtuple("_S", "shape dtype")  # an argument's spec
 B = 2  # the kernels grid over images; the tile is what the compiler prices
+RB = 16  # ... but the resample kernel keeps the batch on the sublanes
 BF, F32 = jnp.bfloat16, jnp.float32
 
 
@@ -105,6 +106,17 @@ def _resample_vjp(f, out, *args):
     return (lambda g, *a: jax.vjp(f, *a)[1](g)), (out,) + args
 
 
+def _basnet_site(h, cx, cl):
+    """One of BASNet's 2x upsample + skip concat sites, bf16: the
+    row-banded forward kernel, and its VJP (XLA's transposed resize of
+    the upsampled operand's slab of the cotangent: no custom call)."""
+    x, lat = _S((RB, h, h, cx), BF), _S((RB, 2 * h, 2 * h, cl), BF)
+    name = f"fused_resample.concat@{h}x{cx}+{cl}"
+    return {name: (_cat_up, (x, lat), 1),
+            name + ",transposed": _resample_vjp(
+                _cat_up, _S((RB, 2 * h, 2 * h, cx + cl), BF), x, lat) + (0,)}
+
+
 def _dlf(h, dilation, grad):
     def f(x, k):
         return dfm.fused_dynamic_filter(x, k, 3, dilation,
@@ -121,6 +133,8 @@ _up = partial(fr.fused_upsample2, interpret=False)
 _add = partial(fr.fused_upsample2_merge, mode="add", interpret=False)
 _cat = partial(fr.fused_upsample2_merge, mode="concat", x_first=False,
                interpret=False)
+_cat_up = partial(fr.fused_upsample2_merge, mode="concat", x_first=True,
+                  interpret=False)
 _IMG = _S((B, 320, 320, 1), F32)
 _QKV = _S((1, 6, 4096, 64), BF)
 _flash = partial(fa.flash_attention, interpret=False)
@@ -143,17 +157,22 @@ CASES = {
     "dynamic_filter.fwd@80d1": _dlf(80, 1, False) + (1,),
     "dynamic_filter.dx+dk@80d4": _dlf(80, 4, True) + (2,),
     # minet_r50_dp, model.resample_impl=fused: AIM/SIM merges.
-    "fused_resample.up@80x64": (_up, (_S((B, 80, 80, 64), BF),), 1),
+    "fused_resample.up@80x64": (_up, (_S((RB, 80, 80, 64), BF),), 1),
     "fused_resample.add@80x64": (
-        _add, (_S((B, 80, 80, 64), BF), _S((B, 160, 160, 64), BF)), 1),
+        _add, (_S((RB, 80, 80, 64), BF), _S((RB, 160, 160, 64), BF)), 1),
     "fused_resample.concat@80x32+64": (
-        _cat, (_S((B, 80, 80, 32), BF), _S((B, 160, 160, 64), BF)), 1),
+        _cat, (_S((RB, 80, 80, 32), BF), _S((RB, 160, 160, 64), BF)), 1),
     "fused_resample.concat@80x32+64,f32": (
-        _cat, (_S((B, 80, 80, 32), F32), _S((B, 160, 160, 64), F32)), 1),
+        _cat, (_S((RB, 80, 80, 32), F32), _S((RB, 160, 160, 64), F32)), 1),
     "fused_resample.transposed@160x64": _resample_vjp(
-        _up, _S((B, 160, 160, 64), BF), _S((B, 80, 80, 64), BF)) + (1,),
+        _up, _S((RB, 160, 160, 64), BF), _S((RB, 80, 80, 64), BF)) + (0,),
     "fused_resample.transposed@10x64": _resample_vjp(
-        _up, _S((B, 10, 10, 64), BF), _S((B, 5, 5, 64), BF)) + (1,),
+        _up, _S((RB, 10, 10, 64), BF), _S((RB, 5, 5, 64), BF)) + (0,),
+    # basnet_ds, no arm named: the 320 px decoder stage (bands of 32
+    # output rows), the refine module's last level, a wide coarse
+    # stage, the coarsest (one band, width no multiple of 8 sublanes).
+    **_basnet_site(160, 128, 64), **_basnet_site(160, 64, 64),
+    **_basnet_site(80, 256, 128), **_basnet_site(10, 512, 512),
     # minet_r50_dp, model.conv_impl=fused: decoder ConvBNAct sites.
     "fused_conv.fwd_bn_relu@80x64": _conv(
         (_S((B, 80, 80, 64), BF),), _S((3, 3, 64, 64), BF), "bn", True)
@@ -191,11 +210,14 @@ def test_kernel_compiles_for_v5e(chip, name):
 def test_availability_rules_match_the_compiler():
     """What the v5e compiler refused at real widths gives way to the
     XLA path through the ``*_available`` rules (each probed once by
-    hand, PR 23 — CHANGES.md): lane-padded 1-channel resample maps,
+    hand, PR 23 — CHANGES.md): lane-padded 1-channel resample maps
+    (and, the batch sitting on the sublanes, batches under 8),
     bf16 conv tiles of odd width, dynamic-filter maps whose padded
     width passes one 128-lane row."""
-    assert not fr.fused_resample_available((B, 160, 160, 1), (320, 320))
-    assert fr.fused_resample_available((B, 80, 80, 32), (160, 160),
+    assert not fr.fused_resample_available((RB, 160, 160, 1), (320, 320))
+    assert not fr.fused_resample_available((B, 80, 80, 32), (160, 160),
+                                           "concat", 64)  # 2 images
+    assert fr.fused_resample_available((RB, 80, 80, 32), (160, 160),
                                        "concat", 64)
     shape = [(B, 5, 5, 32)]
     assert not fc.fused_conv_available(shape, (3, 3), 1, 32, dtype=BF)

@@ -95,15 +95,16 @@ def test_fused_bare_upsample_matches_gatenet_path(h, w):
 def test_resample_merge_falls_back_out_of_envelope(monkeypatch):
     """Oversize tiles and non-2x targets must take the plain path —
     same numerics, no kernel."""
-    x = _rand(1, 4, 4, 8, seed=4)
-    lat = _rand(1, 8, 8, 8, seed=5)
+    x = _rand(8, 4, 4, 8, seed=4)
+    lat = _rand(8, 8, 8, 8, seed=5)
     ref = resample_merge(x, lat, mode="add", impl="fast")
+    assert fr.fused_resample_available(x.shape, (8, 8), "add", 8)
     # Budget of zero elements: nothing fits, everything falls back.
     monkeypatch.setattr(fr, "_MAX_TILE_ELEMS", 0)
     got = resample_merge(x, lat, mode="add", impl="fused")
     assert float(jnp.abs(got - ref).max()) == 0.0
     # Non-2x target (4x upsample): available() is False regardless.
-    assert not fr.fused_resample_available((1, 4, 4, 8), (16, 16),
+    assert not fr.fused_resample_available((8, 4, 4, 8), (16, 16),
                                            "add", 8)
     big = resize_to(x, (16, 16), impl="fused")
     assert float(jnp.abs(big - resize_to(x, (16, 16), impl="fast")
@@ -111,21 +112,27 @@ def test_resample_merge_falls_back_out_of_envelope(monkeypatch):
 
 
 def test_vmem_budget_covers_flagship_fine_sites():
-    """The budget must admit EVERY flagship fine-decoder site — the
-    roofline lever-#1 targets — including the largest one, SIM-0's
-    concat merge (80x80x32 -> into 160x160x64, 96ch out: 10.8M
-    elements as VMEM holds them, lanes padded to 128; the v5e compiler
-    accepts it — tests/test_chip_compile.py).  What stays out, by
-    design: U²-Net's full-width 160->320 concat, and the 1-channel
-    160->320 saliency head, whose lane padding (128x) asks 182 MB of a
-    128 MB core."""
+    """The rule must admit EVERY flagship fine-decoder site — the
+    roofline lever-#1 targets — and, now that VMEM need follows the
+    row band of one batch block and not the map or the batch, BASNet's
+    160->320 sites (a coarse row or two a step).  What stays out, by
+    design: the 1-channel 160->320 saliency head, whose lane padding is
+    128x the data (``layers.resize_to`` gives it the lane-dense form
+    instead), and batches under 8, which would pad the sublanes the
+    same way."""
     assert fr.fused_resample_available((64, 80, 80, 32), (160, 160),
                                        "concat", 64)
     assert fr.fused_resample_available((64, 80, 80, 64), (160, 160),
                                        "add", 64)
     assert not fr.fused_resample_available((64, 160, 160, 1), (320, 320))
-    assert not fr.fused_resample_available((16, 160, 160, 64),
-                                           (320, 320), "concat", 64)
+    assert not fr.fused_resample_available((16, 160, 160, 4), (320, 320))
+    assert not fr.fused_resample_available((4, 80, 80, 64), (160, 160))
+    assert fr.fused_resample_available((16, 160, 160, 64),
+                                       (320, 320), "concat", 64)
+    assert fr._band_rows((16, 160, 160, 128)) == 2
+    assert fr._band_rows((16, 160, 160, 128), "concat", 64) == 1
+    assert fr._band_rows((64, 160, 160, 128)) == 2  # 16 images a step
+    assert fr._band_rows((16, 10, 10, 512)) == 10
 
 
 def test_fused_merge_validates_shapes():
@@ -162,7 +169,7 @@ def test_resample_impl_subsumes_env(monkeypatch):
         _resolve_resample_impl
 
     monkeypatch.delenv("DSOD_RESIZE_IMPL", raising=False)
-    assert _resolve_resample_impl(None) == "fast"
+    assert _resolve_resample_impl(None) == "auto"  # route by shape
     assert _resolve_resample_impl("fast") == "fast"
     assert _resolve_resample_impl("convt") == "convt"
     monkeypatch.setenv("DSOD_RESIZE_IMPL", "xla")
@@ -233,8 +240,10 @@ def test_train_metrics_invariant_across_resample_impls():
                                                    create_train_state)
 
     rng = np.random.RandomState(0)
-    batch = {"image": rng.randn(8, 16, 16, 3).astype(np.float32),
-             "mask": (rng.rand(8, 16, 16, 1) > 0.5).astype(np.float32)}
+    # 8 images a device: the kernel keeps the batch on the sublanes and
+    # gives way below 8.
+    batch = {"image": rng.randn(16, 16, 16, 3).astype(np.float32),
+             "mask": (rng.rand(16, 16, 16, 1) > 0.5).astype(np.float32)}
     mesh = make_mesh(MeshConfig(data=-1), jax.devices()[:2])
     metrics = {}
     for impl in ("fast", "xla", "convt", "fused"):
@@ -284,18 +293,16 @@ def test_zoo_forward_invariant_across_resample_impls(cfg_name, model_name):
 def test_fused_resample_lowers_for_real_tpu():
     """interpret=False + export for platform='tpu' runs the Mosaic
     pipeline end-to-end (no chip needed) — all three forward kernels
-    and the transposed-resample backward."""
+    (the backward is XLA's: ``fr._upT``)."""
     from jax import export
 
     x = jnp.zeros((1, 16, 16, 8), jnp.float32)
     lat = jnp.zeros((1, 32, 32, 8), jnp.float32)
-    g = jnp.zeros((1, 32, 32, 8), jnp.float32)
-    for fn, args in [
-        (lambda a: fr._call_up(a, False), (x,)),
-        (lambda a, b: fr._call_merge(a, b, "add", True, False), (x, lat)),
-        (lambda a, b: fr._call_merge(a, b, "concat", False, False),
+    for fn, args in [  # two bands of 8 coarse rows each
+        (lambda a: fr._call_up(a, None, "none", True, 8, False), (x,)),
+        (lambda a, b: fr._call_up(a, b, "add", True, 8, False), (x, lat)),
+        (lambda a, b: fr._call_up(a, b, "concat", False, 8, False),
          (x, lat)),
-        (lambda c: fr._call_upT(c, False), (g,)),
     ]:
         exp = export.export(jax.jit(fn), platforms=["tpu"])(*args)
         assert "tpu_custom_call" in exp.mlir_module()
@@ -322,3 +329,244 @@ def test_resample_compiler_params_follow_the_shared_vmem_rule(monkeypatch):
     assert fr._compiler_params().vmem_limit_bytes == 8 << 20
     monkeypatch.setenv("DSOD_RESAMPLE_VMEM_MB", "0")
     assert getattr(fr._compiler_params(), "vmem_limit_bytes", None) is None
+
+# -- the row-banded grid -------------------------------------------------
+
+def _bf16_close(got, ref32, at_scale=False):
+    """``got`` (bf16 or f32) against a float32 reference computed from
+    the same (upcast) inputs: float32 round-off for f32, ONE bf16
+    rounding of the f32 result for bf16 (half an ulp is 2**-9
+    relative; 2**-8 leaves room for a tie that f32 association flips).
+    ``at_scale``: a few bf16 roundings at the ARRAY's scale (2**-7 of
+    its largest value) — what XLA's own bf16 resize transpose, the
+    backward of every arm, is held to: it rounds between its two axes,
+    so an output that cancels to near zero keeps its addends' error."""
+    got32 = np.asarray(got, np.float32)
+    ref32 = np.asarray(ref32, np.float32)
+    if got.dtype == jnp.float32:
+        tol = 1e-5 + 1e-6 * np.abs(ref32)
+    elif at_scale:
+        tol = 2.0 ** -7 * np.abs(ref32).max()
+    else:
+        tol = 2.0 ** -8 * np.abs(ref32) + 1e-6
+    return bool(np.all(np.abs(got32 - ref32) <= tol))
+
+
+# (coarse height, coarse rows per band): one band, two bands, a last
+# band shorter than the rest — at every h of ISSUE 26's list.
+_BANDS = [(1, 1), (2, 2), (2, 1), (5, 5), (5, 2), (10, 10), (10, 5),
+          (10, 4), (10, 3)]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("mode,x_first", [
+    ("concat", True), ("concat", False), ("add", True), ("none", True)],
+    ids=["cat_up_first", "cat_lat_first", "add", "bare"])
+@pytest.mark.parametrize("h,r", _BANDS, ids=[f"h{h}r{r}" for h, r in _BANDS])
+def test_banded_kernel_matches_xla_arm_fwd_and_vjp(monkeypatch, h, r, mode,
+                                                   x_first, dtype):
+    """The kernel over a grid of (batch block, band of ``r`` coarse rows
+    + one halo row each side) against the ``xla`` arm
+    (``jax.image.resize`` + the plain merge) in float32 on the same
+    inputs, forward and VJP (the op is linear: one random cotangent;
+    the backward is XLA's transposed resize in the cotangent's
+    dtype)."""
+    w, cx = 6, 16
+    cl = {"concat": 24, "add": cx, "none": 0}[mode]
+    monkeypatch.setattr(fr, "_band_rows", lambda *a, **k: r)
+    x = _rand(2, h, w, cx, seed=11).astype(dtype)
+    lat = _rand(2, 2 * h, 2 * w, max(cl, 1), seed=12).astype(dtype)
+
+    def xla_arm(a, b):
+        up = resize_to(a, (2 * h, 2 * w), impl="xla")
+        if mode == "none":
+            return up
+        if mode == "add":
+            return up + b
+        return jnp.concatenate([up, b] if x_first else [b, up], axis=-1)
+
+    def kernel(a, b):
+        if mode == "none":
+            return fr.fused_upsample2(a)
+        return fr.fused_upsample2_merge(a, b, mode=mode, x_first=x_first)
+
+    ref, ref_vjp = jax.vjp(xla_arm, x.astype(jnp.float32),
+                           lat.astype(jnp.float32))
+    got, got_vjp = jax.vjp(kernel, x, lat)
+    assert got.shape == ref.shape and got.dtype == dtype
+    assert _bf16_close(got, ref)
+    g = _rand(*ref.shape, seed=13).astype(dtype)
+    for got_d, ref_d in zip(got_vjp(g), ref_vjp(g.astype(jnp.float32))):
+        assert got_d.dtype == dtype
+        assert _bf16_close(got_d, ref_d, at_scale=True)
+
+
+def test_band_rule_follows_the_budget(monkeypatch):
+    """Band height is the shape's and the budget's, evened out over
+    the bands; with no room for one row the site is refused."""
+    from distributed_sod_project_tpu.pallas.vmem_budget import rows_per_band
+
+    assert rows_per_band(160, per_row=10, fixed=5, budget=705) == 54
+    assert rows_per_band(10, per_row=10, fixed=0, budget=1000) == 10
+    assert rows_per_band(10, per_row=10, fixed=5, budget=14) == 0
+    shape = (2, 10, 6, 16)
+    assert fr._band_rows(shape) == 10
+    tile = fr._vmem_elems(2, 16)  # one (images, channels) tile: 8 x 128
+    assert tile == 8 * 128
+    halo, row = 2 * 6 * tile, 6 * tile + 2 * 12 * 2 * tile  # x; f32 + out
+    monkeypatch.setattr(fr, "_MAX_TILE_ELEMS", halo + 4 * row)
+    assert fr._band_rows(shape) == 4  # 4 fit -> 3 bands -> 4, 4, 2
+    x = _rand(*shape, seed=14)
+    assert float(jnp.abs(fr.fused_upsample2(x) - resize_to(
+        x, (20, 12), impl="xla")).max()) <= 1e-5
+    monkeypatch.setattr(fr, "_MAX_TILE_ELEMS", halo + row - 1)
+    assert fr._band_rows(shape) == 0
+    assert not fr.fused_resample_available((8,) + shape[1:], (20, 12))
+    assert fr._batch_block(64) == 16 and fr._batch_block(24) == 8
+    assert fr._batch_block(6) == 6
+
+
+# -- the lane-dense form of 1-channel maps -------------------------------
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("factor", [2, 4, 8, 16, 32])
+def test_lane_dense_logit_resize_matches_jax_image_resize(factor, dtype):
+    """``resize_to`` of a (B, h, w, 1) map with no arm named: two
+    constant interpolation matrices, W on the lanes — against
+    ``jax.image.resize`` in float32 on the same inputs, forward and
+    VJP.  The two axes need not share a factor."""
+    from distributed_sod_project_tpu.models import layers
+
+    h, w = 64 // factor, 96 // factor
+    x = _rand(2, h, w, 1, seed=factor).astype(dtype)
+    with layers.resample_routes() as routes:
+        got, got_vjp = jax.vjp(lambda a: resize_to(a, (64, 96)), x)
+        mixed = resize_to(x, (h, 96))  # one axis stays
+    assert routes == {"kernel": 0, "lane_dense": 2, "fallback": 0}
+    ref, ref_vjp = jax.vjp(
+        lambda a: jax.image.resize(a, (2, 64, 96, 1), "bilinear"),
+        x.astype(jnp.float32))
+    assert got.shape == (2, 64, 96, 1) and got.dtype == dtype
+    assert _bf16_close(got, ref)
+    g = _rand(2, 64, 96, 1, seed=99).astype(dtype)
+    (got_d,), (ref_d,) = got_vjp(g), ref_vjp(g.astype(jnp.float32))
+    assert got_d.dtype == dtype and _bf16_close(got_d, ref_d)
+    assert _bf16_close(mixed, jax.image.resize(
+        x.astype(jnp.float32), (2, h, 96, 1), "bilinear"))
+
+
+def test_route_follows_the_shape_and_named_arms_pin(monkeypatch):
+    """No arm named: kernel / lane-dense / slice-lerp by what the shape
+    shows; a named arm (argument or DSOD_RESIZE_IMPL) keeps its path,
+    and a site that resizes nothing is no site."""
+    from distributed_sod_project_tpu.models import layers
+
+    monkeypatch.delenv("DSOD_RESIZE_IMPL", raising=False)
+    wide, one, few = (_rand(8, 4, 4, c, seed=15) for c in (16, 1, 4))
+    with layers.resample_routes() as routes:
+        resize_to(wide, (8, 8))            # exact 2x, 16 ch: kernel
+        resize_to(wide, (16, 16))          # 4x: slice/lerp
+        resize_to(few, (8, 8))             # 4 ch < 8: slice/lerp
+        resize_to(wide[:4], (8, 8))        # 4 images < 8: slice/lerp
+        resize_to(one, (16, 8))            # 1 ch: lane-dense
+        resize_to(one, (2, 2))             # a downsample: slice/lerp
+        resize_to(wide, (4, 4))            # nothing to do
+        # a concat is XLA's (it reads it into the next conv); the
+        # upsample under it still takes the kernel; an add is fused.
+        resample_merge(wide, _rand(8, 8, 8, 8), mode="concat")
+        resample_merge(wide, _rand(8, 8, 8, 16), mode="add")
+        resample_merge(wide, _rand(8, 4, 4, 8), mode="concat")  # no site
+    assert routes == {"kernel": 3, "lane_dense": 1, "fallback": 4}
+    with layers.resample_routes() as routes:
+        resize_to(wide, (8, 8), impl="fast")
+        resize_to(one, (8, 8), impl="fast")
+        monkeypatch.setenv("DSOD_RESIZE_IMPL", "xla")
+        resize_to(wide, (8, 8))
+        resize_to(one, (8, 8))
+    assert routes == {"kernel": 0, "lane_dense": 0, "fallback": 4}
+
+
+# -- BASNet through the seam ----------------------------------------------
+
+@pytest.fixture(scope="module")
+def basnet64():
+    from distributed_sod_project_tpu.configs import get_config
+    from distributed_sod_project_tpu.models.registry import build_model
+
+    mc = dataclasses.replace(get_config("basnet_ds").model, sync_bn=False,
+                             compute_dtype="float32")
+    model = build_model(mc)
+    img = _rand(8, 64, 64, 3, seed=16)  # 8: the kernel's least batch
+    init = jax.jit(lambda k, i: model.init(k, i, train=False))
+    return model, img, init(jax.random.key(0), img[:1])
+
+
+def test_basnet_route_counter_reads_9_6_0(basnet64, monkeypatch, caplog):
+    """BASNet's nine 2x upsample+concat sites (5 decoder, 4 refine) take
+    the kernel, six of its seven side logits the lane-dense form (the
+    seventh is already full size), nothing falls back — at 64 px as at
+    320 — and the tally is one log line."""
+    from distributed_sod_project_tpu.models import layers
+
+    monkeypatch.delenv("DSOD_RESIZE_IMPL", raising=False)
+    model, img, variables = basnet64
+    from distributed_sod_project_tpu.utils.logging import get_logger
+
+    get_logger().addHandler(caplog.handler)  # "dsod" does not propagate
+    try:
+        with layers.resample_routes(log_as="test") as routes:
+            jax.eval_shape(lambda v, i: model.apply(v, i, train=False),
+                           variables, img)
+            assert routes == {}  # filled on exit
+    finally:
+        get_logger().removeHandler(caplog.handler)
+    assert routes == {"kernel": 9, "lane_dense": 6, "fallback": 0}
+    lines = [r.getMessage() for r in caplog.records
+             if "resample routes" in r.getMessage()]
+    assert lines == [
+        "resample routes (test): kernel=9 lane_dense=6 fallback=0"]
+    big = jax.ShapeDtypeStruct((16, 320, 320, 3), jnp.float32)
+    with layers.resample_routes() as routes:
+        jax.eval_shape(lambda v, i: model.apply(v, i, train=False),
+                       variables, big)
+    assert routes == {"kernel": 9, "lane_dense": 6, "fallback": 0}
+
+
+def test_basnet_default_route_matches_xla_arm(basnet64, monkeypatch):
+    """BASNet at 64 px, float32 compute: the 8 outputs and the
+    parameter gradient of a scalar readout with the default route
+    (kernel + lane-dense) against ``DSOD_RESIZE_IMPL=xla``.  Both arms
+    are the same bilinear resample in float32, so they differ by
+    float32 round-off carried through ~60 conv layers: outputs within
+    2e-4 of the largest output, every leaf's gradient within 1e-3 of
+    its norm.  (Under bf16 compute the arms differ by one bf16
+    rounding per resample, ~4e-3 relative: the kernel lerps in f32
+    where the slice/lerp path lerps in bf16 — asserted per site in
+    ``test_banded_kernel_matches_xla_arm_fwd_and_vjp``.)"""
+    model, img, variables = basnet64
+
+    def run():
+        def readout(params):
+            outs = model.apply({**variables, "params": params}, img,
+                               train=False)
+            return sum(jnp.mean(jnp.tanh(o)) for o in outs), outs
+
+        (_, outs), grads = jax.jit(
+            jax.value_and_grad(readout, has_aux=True))(variables["params"])
+        return outs, grads
+
+    monkeypatch.delenv("DSOD_RESIZE_IMPL", raising=False)
+    outs, grads = run()
+    monkeypatch.setenv("DSOD_RESIZE_IMPL", "xla")
+    ref_outs, ref_grads = run()
+    scale = max(float(jnp.abs(o).max()) for o in ref_outs)
+    for o, ref in zip(outs, ref_outs):
+        assert float(jnp.abs(o - ref).max()) <= 2e-4 * scale
+    gaps = jax.tree_util.tree_map(
+        lambda g, ref: float(jnp.linalg.norm(g - ref)
+                             / (jnp.linalg.norm(ref) + 1e-30)),
+        grads, ref_grads)
+    worst = max(jax.tree_util.tree_leaves(gaps))
+    assert worst <= 1e-3, worst

@@ -602,7 +602,13 @@ def test_retries_share_one_trace_with_attempt_spans():
         # Both replicas saw the SAME forwarded X-Request-ID.
         assert r0.calls[0]["X-Request-ID"] == rid
         assert r1.calls[0]["X-Request-ID"] == rid
+        # The root span closes AFTER the response flushes (the handler
+        # thread books its terminals then): an immediate read races it.
+        deadline = time.monotonic() + 5.0
         t = fleet.tracer.get_trace(rid)
+        while not (t and t["done"]) and time.monotonic() < deadline:
+            time.sleep(0.01)
+            t = fleet.tracer.get_trace(rid)
         assert t is not None and t["done"]
         attempts = sorted((s for s in t["spans"]
                            if s["name"] == "attempt"),
