@@ -77,6 +77,43 @@ class DataConfig:
     # exhaustion raises.  0 = fail on the first corrupt sample.
     # See resilience/dataguard.py and docs/RESILIENCE.md.
     skip_budget: int = 0
+    # dataset="packed_tokens" (data/tokens.py; the token model's
+    # source): sequences of seq_len ids cut from packed documents of
+    # log-normal length joined by an end-of-document id, the ids
+    # Zipf-distributed over the first `vocab` of the vocabulary;
+    # synthetic_size is the number of sequences.
+    seq_len: int = 8192
+    vocab: int = 16384
+
+
+@dataclasses.dataclass(frozen=True)
+class LMConfig:
+    """Shape of the token model (model.name=lfm2, models/lfm2.py).  The
+    defaults are LFM2-8B-A1B's published widths (LiquidAI, config.json)
+    and the share one chip holds in `lfm2_8b_a1b_ep4`: the layers kept,
+    `experts_held` of `experts` from `first_expert` on, `vocab` rows of
+    the 65,536."""
+
+    vocab: int = 16384
+    hidden: int = 2048
+    layer_types: Tuple[str, ...] = (  # conv | attention, per layer kept
+        "conv", "attention", "conv", "conv", "conv")
+    ffn_types: Tuple[str, ...] = (  # dense | moe, per layer kept
+        "dense", "moe", "moe", "moe", "moe")
+    heads: int = 32
+    kv_heads: int = 8
+    head_dim: int = 64
+    dense_width: int = 7168
+    expert_width: int = 1792
+    experts: int = 32  # the router's width
+    experts_held: int = 8
+    first_expert: int = 0
+    top_k: int = 4
+    conv_kernel: int = 3
+    norm_eps: float = 1e-5
+    rope_theta: float = 1e6
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -138,6 +175,9 @@ class ModelConfig:
     #           non-XLA-default rule; not measured on a chip).
     conv_impl: str = "xla"  # xla | fused
     pretrained: Optional[str] = None  # .npz from tools/port_torch_weights.py
+    # The token model's shape (model.name=lfm2 only; remat is per layer
+    # there).
+    lm: LMConfig = dataclasses.field(default_factory=LMConfig)
     # Structural deep supervision for models where aux heads are
     # optional add-ons (vit_sod's mid-depth head).  U²-Net/BASNet side
     # outputs are integral to their architectures and ignore this.

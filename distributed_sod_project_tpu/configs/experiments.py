@@ -10,6 +10,7 @@
 from .base import (
     DataConfig,
     ExperimentConfig,
+    LMConfig,
     LossConfig,
     MeshConfig,
     ModelConfig,
@@ -177,4 +178,36 @@ def vit_sod_sp() -> ExperimentConfig:
                           warmup_steps=500),
         global_batch_size=16,
         mesh=MeshConfig(data=-1, model=1, seq=1),
+    )
+
+
+@register_config("lfm2_8b_a1b_ep4")
+def lfm2_8b_a1b_ep4() -> ExperimentConfig:
+    """The zoo's token model: LFM2-8B-A1B (LiquidAI, ``lfm2_moe``) at
+    its published widths, ONE chip's share of a 4-way expert-parallel
+    deployment — experts 0-7 of 32 in every expert layer (the router
+    stays 32 wide, top-4), vocabulary rows 0-16,383 of 65,536, and of
+    the 24 layers the leading dense one plus one period of the pattern
+    that follows (attention, conv, conv, conv); the layers left out lie
+    on further hosts as pipeline stages.  Trains on packed synthetic
+    documents, 4 sequences of 8,192 tokens a step (each held expert then
+    sees the 4,096 tokens a step it sees in the deployment), AdamW,
+    per-layer remat.  ``model.lm.*`` / ``data.seq_len`` shrink it for a
+    CPU drive (tests/test_lfm2.py)."""
+    return ExperimentConfig(
+        name="lfm2_8b_a1b_ep4",
+        data=DataConfig(dataset="packed_tokens", hflip=False,
+                        synthetic_size=4096, seq_len=8192, vocab=16384),
+        model=ModelConfig(name="lfm2", backbone="none", sync_bn=False,
+                          remat=True, lm=LMConfig()),
+        loss=LossConfig(),
+        # The warm-up matters beyond habit: this chip's partial sum lets
+        # the loss fall by routing AWAY from the held experts (random at
+        # first, so noise), and at the full rate Adam does that within
+        # ten steps (PERF.md, Findings PR 28).
+        optim=OptimConfig(optimizer="adamw", lr=3e-4, weight_decay=0.1,
+                          schedule="poly", warmup_steps=2000),
+        global_batch_size=4,
+        num_epochs=100,
+        mesh=MeshConfig(data=1, model=1, seq=1),
     )

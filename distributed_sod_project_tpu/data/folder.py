@@ -159,6 +159,11 @@ def resolve_dataset(cfg) -> object:
     An existing ``root`` always wins — a user passing ``--data-root``
     to a config whose default dataset is synthetic means the files,
     not the fallback."""
+    if cfg.dataset == "packed_tokens":
+        from .tokens import PackedTokens
+
+        return PackedTokens(size=cfg.synthetic_size, seq_len=cfg.seq_len,
+                            vocab=cfg.vocab)
     if cfg.root is None or not os.path.isdir(cfg.root):
         if cfg.dataset != "synthetic":
             from ..utils.logging import get_logger

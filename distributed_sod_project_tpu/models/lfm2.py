@@ -1,0 +1,461 @@
+"""LFM2-MoE token model (LiquidAI ``lfm2_moe``): gated short-convolution
+and causal grouped-query attention layers over bias-routed sparse
+experts — one chip's share of an expert-parallel deployment.
+
+The zoo's first token model.  ``kind = "tokens"`` is what
+``parallel/engine.py`` and ``train/loop.py`` route on: the batch is
+``tokens`` / ``targets``, the call is ``model.apply(variables, tokens,
+train=...) -> (hidden [B, N, D] after the final norm, counters)``, and
+the loss is ``losses/token_ce.py`` over the tied embedding.
+
+Pre-norm residual blocks, ``h += op(RMSNorm(h))``, ``h += ffn(RMSNorm(h))``:
+
+- *conv*: ``[B, C, x] = h W_in``; ``y = C * causal_depthwise_conv1d(B * x)``
+  (kernel ``conv_kernel``, no bias); ``out = y W_out``;
+- *attention*: grouped-query heads, RMSNorm on each head's q and k,
+  rotary embedding, causal softmax through the Pallas flash kernel
+  (``pallas/flash_attention.py::flash_attention_causal``);
+- dense ffn: SwiGLU; expert ffn (:class:`ExpertLayer`): the router
+  scores ALL ``experts``, picks ``top_k`` by ``sigmoid + expert_bias``
+  (the bias only selects and is a buffer, not a parameter), and this
+  chip computes the part of the sum that ITS experts give —
+  ``first_expert .. first_expert + experts_held``.  What the absent
+  experts would add is left out; on one chip the layer runs without its
+  exchange.
+
+Compute is ``dtype`` (bf16) with float32 parameters; the router, every
+norm's statistics, the rotary angles and the softmax are float32.
+Each block is rematerialised when ``remat`` is on.
+
+Device scopes (PERF.md section 3): the whole stack is ``dsod.encoder``;
+inside it ``dsod.shortconv``, ``dsod.attn``, ``dsod.densemlp``,
+``dsod.moe.route`` (router, top-k, sort, gather into expert order),
+``dsod.moe.experts`` (the grouped products), ``dsod.moe.combine``; the
+final norm is ``dsod.heads``.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Any
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ..pallas.flash_attention import flash_attention_causal
+from ..pallas.grouped_matmul import TILE_M, grouped_matmul
+
+
+class RMSNorm(nn.Module):
+    eps: float = 1e-5
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           jnp.float32)
+        x32 = x.astype(jnp.float32)
+        y = x32 * lax.rsqrt(jnp.mean(jnp.square(x32), -1, keepdims=True)
+                            + self.eps)
+        return (y * scale).astype(self.dtype)
+
+
+def _dense(features, name, dtype, param_dtype):
+    return nn.Dense(features, use_bias=False, dtype=dtype,
+                    param_dtype=param_dtype, name=name,
+                    kernel_init=nn.initializers.lecun_normal())
+
+
+class SwiGLU(nn.Module):
+    width: int
+    dtype: jnp.dtype = jnp.bfloat16
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        g = _dense(self.width, "gate", self.dtype, self.param_dtype)(x)
+        u = _dense(self.width, "up", self.dtype, self.param_dtype)(x)
+        return _dense(x.shape[-1], "down", self.dtype, self.param_dtype)(
+            nn.silu(g) * u)
+
+
+class ShortConv(nn.Module):
+    """The gated short convolution.  ``conv/kernel`` is [L, D]; tap j
+    multiplies the input L-1-j positions back."""
+    kernel: int = 3
+    dtype: jnp.dtype = jnp.bfloat16
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        d = x.shape[-1]
+        bcx = _dense(3 * d, "in_proj", self.dtype, self.param_dtype)(x)
+        b, c, v = (t.astype(jnp.float32) for t in jnp.split(bcx, 3, -1))
+        k = self.param("kernel", nn.initializers.lecun_normal(),
+                       (self.kernel, d), self.param_dtype)
+        u = b * v
+        n = u.shape[1]
+        up = jnp.pad(u, ((0, 0), (self.kernel - 1, 0), (0, 0)))
+        y = sum(up[:, j:j + n] * k[j] for j in range(self.kernel))
+        return _dense(d, "out_proj", self.dtype, self.param_dtype)(
+            (c * y).astype(self.dtype))
+
+
+def rope(x, theta: float):
+    """x: [B, N, H, d] float32; rotate-half form, positions 0..N-1."""
+    n, d = x.shape[1], x.shape[-1]
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = jnp.arange(n, dtype=jnp.float32)[:, None] * inv[None]
+    ang = jnp.concatenate([ang, ang], -1)[None, :, None, :]
+    x1, x2 = x[..., :d // 2], x[..., d // 2:]
+    return x * jnp.cos(ang) + jnp.concatenate([-x2, x1], -1) * jnp.sin(ang)
+
+
+class Attention(nn.Module):
+    heads: int
+    kv_heads: int
+    head_dim: int
+    rope_theta: float = 1e6
+    eps: float = 1e-5
+    dtype: jnp.dtype = jnp.bfloat16
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        b, n, d = x.shape
+        hq, hkv, hd = self.heads, self.kv_heads, self.head_dim
+
+        def heads(name, h):
+            return _dense(h * hd, name, self.dtype, self.param_dtype)(
+                x).reshape(b, n, h, hd)
+
+        def normed(t, name):  # per-head RMSNorm, then the rotation
+            t = RMSNorm(self.eps, jnp.float32, name=name)(t)
+            return rope(t, self.rope_theta).astype(
+                self.dtype).transpose(0, 2, 1, 3)
+
+        q = normed(heads("q_proj", hq), "q_norm")
+        k = normed(heads("k_proj", hkv), "k_norm")
+        v = heads("v_proj", hkv).transpose(0, 2, 1, 3)
+        o = flash_attention_causal(q, k, v)
+        o = o.transpose(0, 2, 1, 3).reshape(b, n, hq * hd)
+        return _dense(d, "o_proj", self.dtype, self.param_dtype)(o)
+
+
+# -- the expert layer --------------------------------------------------------
+
+def worst_case_tiles(pairs: int, experts_held: int, tile_m: int) -> int:
+    """Row tiles that hold every pair whatever the imbalance: all
+    ``pairs`` may be held, and each expert's group is padded to whole
+    tiles, at least one."""
+    return -(-pairs // tile_m) + experts_held
+
+
+def held_key(idx, first_expert: int, experts_held: int):
+    """Per (token, choice) pair, flat: the chosen expert's index among
+    the held ones, ``experts_held`` where it is not held."""
+    local = idx.reshape(-1) - first_expert
+    return jnp.where((local >= 0) & (local < experts_held), local,
+                     experts_held).astype(jnp.int32)
+
+
+def tiles_needed(idx, first_expert: int, experts_held: int, tile_m: int):
+    """Row tiles the held experts' groups take, each at least one."""
+    counts = jnp.sum(held_key(idx, first_expert, experts_held)[None, :]
+                     == jnp.arange(experts_held)[:, None], axis=1)
+    return jnp.sum(jnp.maximum(-(-counts // tile_m), 1))
+
+
+def plan_dispatch(idx, first_expert: int, experts_held: int, tile_m: int,
+                  n_tiles: int):
+    """Where each (token, choice) pair routed to a held expert goes in
+    the expert-ordered buffer, from a SORT of the pairs by expert.
+
+    idx: [T, K] int32, the chosen experts over all of them.  The buffer
+    has ``n_tiles * tile_m`` rows, each expert's group padded to whole
+    row tiles, at least one: :func:`worst_case_tiles` holds every held
+    pair whatever the imbalance; a caller that passes fewer checks
+    :func:`tiles_needed` first.  Returns
+
+    - ``row_of_pair`` [T, K]: the pair's row, ``rows`` where the expert
+      is not held (reads back as zero);
+    - ``pair_of_row`` [rows]: the flat pair index, -1 on padding rows;
+    - ``tile_expert`` [n_tiles], ``n_used`` [1]: the grouped product's
+      per-tile expert map and tile count;
+    - ``counts`` [experts_held]: pairs per held expert;
+    - ``dropped``: held pairs that found no row (0 by construction;
+      counted, not assumed).
+    """
+    t, k = idx.shape
+    rows = n_tiles * tile_m
+    key = held_key(idx, first_expert, experts_held)
+    held = key < experts_held
+    held_ids = jnp.arange(experts_held, dtype=jnp.int32)
+
+    def lookup(table, e):  # table[e] over a handful of experts, no gather
+        return jnp.sum(jnp.where(e[..., None] == held_ids, table, 0), -1)
+
+    # The sort: pairs by expert, ties in pair order.  ``order[i]`` is the
+    # pair in sorted slot i; a pair's slot inside its expert's group is
+    # its rank, a running count of the same key.
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    same = (key[None, :] == held_ids[:, None]).astype(jnp.int32)
+    running = jnp.cumsum(same, axis=1)                # [E_held, T*K]
+    counts = running[:, -1]
+    rank = jnp.sum(running * same, axis=0) - 1
+    tiles = jnp.maximum(-(-counts // tile_m), 1)
+    tile_end = jnp.cumsum(tiles)
+    row0 = (tile_end - tiles) * tile_m                # first row per expert
+    slot0 = jnp.cumsum(counts) - counts               # first sorted slot
+    row_of_pair = jnp.where(held, lookup(row0, key) + rank, rows)
+    # Row r of expert e holds sorted slot slot0[e] + (r - row0[e]), if
+    # the group is that long: a gather of ``order``, not a scatter.
+    tile_expert = jnp.minimum(
+        jnp.sum(jnp.arange(n_tiles)[:, None] >= tile_end[None, :], axis=1),
+        experts_held - 1).astype(jnp.int32)
+    e_row = jnp.repeat(tile_expert, tile_m)
+    within = jnp.arange(rows, dtype=jnp.int32) - lookup(row0, e_row)
+    filled = within < lookup(counts, e_row)
+    slot = jnp.where(filled, lookup(slot0, e_row) + within, 0)
+    pair_of_row = jnp.where(filled, order[slot], -1)
+    dropped = jnp.sum(held & (row_of_pair >= rows))
+    return (row_of_pair.reshape(t, k), pair_of_row, tile_expert,
+            tile_end[-1:].astype(jnp.int32), counts, dropped)
+
+
+def _rows(x, index):
+    """x[index] with zeros where ``index`` is out of range."""
+    return jnp.take(x, index, axis=0, mode="fill", fill_value=0)
+
+
+@jax.custom_vjp
+def dispatch(x, row_of_pair, pair_of_row):
+    """[T, D] -> the expert-ordered buffer [rows, D] (padding rows zero).
+    Both directions are gathers: a buffer row has one pair, so the
+    backward sums a token's K rows instead of scattering."""
+    k = row_of_pair.shape[1]
+    return _rows(x, jnp.where(pair_of_row >= 0, pair_of_row // k,
+                              x.shape[0]))
+
+
+def _dispatch_fwd(x, row_of_pair, pair_of_row):
+    return dispatch(x, row_of_pair, pair_of_row), row_of_pair
+
+
+def _dispatch_bwd(row_of_pair, g):
+    dx = jnp.sum(_rows(g, row_of_pair).astype(jnp.float32), axis=1)
+    return dx.astype(g.dtype), None, None
+
+
+dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def combine(y, w, row_of_pair, pair_of_row):
+    """out[t] = sum_k w[t, k] * y[row_of_pair[t, k]] in float32 (a pair
+    whose expert is not held reads a zero row)."""
+    return jnp.einsum("tk,tkd->td", w, _rows(y, row_of_pair).astype(
+        jnp.float32))
+
+
+def _combine_fwd(y, w, row_of_pair, pair_of_row):
+    return (combine(y, w, row_of_pair, pair_of_row),
+            (y, w, row_of_pair, pair_of_row))
+
+
+def _combine_bwd(res, g):
+    y, w, row_of_pair, pair_of_row = res
+    k = w.shape[1]
+    tok = jnp.where(pair_of_row >= 0, pair_of_row // k, g.shape[0])
+    w_row = _rows(w.reshape(-1), pair_of_row)      # -1 -> fill 0
+    dy = (_rows(g, tok) * w_row[:, None]).astype(y.dtype)
+    dw = jnp.einsum("td,tkd->tk", g,
+                    _rows(y, row_of_pair).astype(jnp.float32))
+    return dy, dw, None, None
+
+
+combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _tile_m(pairs: int, experts_held: int) -> int:
+    """Row-tile height: ``TILE_M``, or at a tiny test size the largest
+    power of two (>= 8) under an expert's balanced share of the pairs."""
+    share = max(pairs // experts_held, 8)
+    return min(TILE_M, 1 << (share.bit_length() - 1))
+
+
+class ExpertLayer(nn.Module):
+    """Bias-routed sparse experts, the share of one chip.
+
+    Told which experts it holds (``first_expert``, ``experts_held`` of
+    ``experts``); routes over all of them; computes its own experts'
+    part of ``sum_e w_e SwiGLU_e(h)`` with no pair dropped.  Returns
+    ``(out, counters)``: ``pairs_here`` (pairs routed to held experts),
+    ``load_max_over_mean`` (over the held experts) and ``dropped``.
+    """
+    experts: int
+    experts_held: int
+    first_expert: int
+    top_k: int
+    width: int
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    dtype: jnp.dtype = jnp.bfloat16
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        b, n, d = x.shape
+        e, f = self.experts_held, self.width
+        xt = x.reshape(b * n, d)
+        init = nn.initializers.variance_scaling(
+            1.0, "fan_in", "normal", in_axis=-2, out_axis=-1, batch_axis=0)
+        w_gate = self.param("gate", init, (e, d, f), self.param_dtype)
+        w_up = self.param("up", init, (e, d, f), self.param_dtype)
+        w_down = self.param("down", init, (e, f, d), self.param_dtype)
+        bias = self.variable("batch_stats", "expert_bias", jnp.zeros,
+                             (self.experts,), jnp.float32).value
+        tile_m = _tile_m(b * n * self.top_k, e)
+        worst = worst_case_tiles(b * n * self.top_k, e, tile_m)
+        # The usual buffer: 1.5 x the balanced share of the pairs.  The
+        # worst case is four times the balanced share, and everything
+        # around the grouped products (gathers, SwiGLU, cotangents) costs
+        # by the buffer's rows, not by the rows used.
+        usual = -(-(b * n * self.top_k * 3 * e) // (2 * self.experts * tile_m)
+                  ) + e
+
+        with jax.named_scope("dsod.moe.route"):
+            logits = nn.Dense(
+                self.experts, use_bias=False, dtype=jnp.float32,
+                param_dtype=self.param_dtype, name="router",
+                precision=lax.Precision.HIGHEST)(xt.astype(jnp.float32))
+            s = jax.nn.sigmoid(logits)
+            _, idx = lax.top_k(s + lax.stop_gradient(bias), self.top_k)
+            idx = idx.astype(jnp.int32)
+            w = jnp.take_along_axis(s, idx, -1)
+            if self.norm_topk_prob:
+                w = w / (jnp.sum(w, -1, keepdims=True) + 1e-6)
+            w = w * self.routed_scaling_factor
+
+        def experts(n_tiles, xt, w, idx, w_gate, w_up, w_down):
+            """This chip's part of the sum through a buffer of
+            ``n_tiles`` row tiles -> (out [T, D] f32, counts, dropped)."""
+            with jax.named_scope("dsod.moe.route"):
+                (row_of_pair, pair_of_row, tile_expert, n_used, counts,
+                 dropped) = plan_dispatch(idx, self.first_expert, e, tile_m,
+                                          n_tiles)
+                xs = dispatch(xt, row_of_pair, pair_of_row)
+            if n_tiles < worst:
+                # The usual buffer is multiplied WHOLE, its empty tiles
+                # (zero rows) too: a capacity factor of 1.5, the price of
+                # static shapes.  Skipping them (as the worst-case buffer
+                # must, at four times the balanced share) would make the
+                # step's time follow the routing of whatever weights it
+                # is given: +-0.3 % across seeds at random weights
+                # (PERF.md section 6, PR 28), for ~6 % of the step.
+                n_used = jnp.full((1,), n_tiles, jnp.int32)
+            with jax.named_scope("dsod.moe.experts"):
+                gmm = lambda a, wt: grouped_matmul(  # noqa: E731
+                    a, wt, tile_expert, n_used, tile_m=tile_m)
+                h = nn.silu(gmm(xs, w_gate)) * gmm(xs, w_up)
+                ys = gmm(h, w_down)
+            with jax.named_scope("dsod.moe.combine"):
+                out = combine(ys, w, row_of_pair, pair_of_row)
+            return out, counts, dropped
+
+        args = (xt, w, idx, w_gate, w_up, w_down)
+        if usual >= worst:  # a tiny size: one buffer
+            out, counts, dropped = experts(worst, *args)
+        else:
+            # No pair is dropped whatever the imbalance: routing that
+            # does not fit the usual buffer takes the worst-case one.
+            fits = tiles_needed(idx, self.first_expert, e, tile_m) <= usual
+            # Each branch keeps its inputs alone for the backward and
+            # recomputes inside it: a cond under autodiff otherwise holds
+            # BOTH branches' residuals (zeros for the one not taken), the
+            # worst-case buffers among them (+3.8 GiB compiled for a v5e).
+            out, counts, dropped = lax.cond(
+                fits, jax.checkpoint(functools.partial(experts, usual)),
+                jax.checkpoint(functools.partial(experts, worst)), *args)
+        pairs = jnp.sum(counts).astype(jnp.float32)
+        counters = {
+            "pairs_here": pairs,
+            "load_max_over_mean": jnp.max(counts) * e / jnp.maximum(pairs, 1),
+            "dropped": dropped.astype(jnp.float32)}
+        return out.astype(self.dtype).reshape(b, n, d), counters
+
+
+class Block(nn.Module):
+    op: str           # conv | attention
+    ffn: str          # dense | moe
+    cfg: Any          # configs.base.LMConfig
+    dtype: jnp.dtype = jnp.bfloat16
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, h):
+        c = self.cfg
+        kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
+        y = RMSNorm(c.norm_eps, self.dtype, name="op_norm")(h)
+        if self.op == "conv":
+            with jax.named_scope("dsod.shortconv"):
+                h = h + ShortConv(c.conv_kernel, name="conv", **kw)(y)
+        else:
+            with jax.named_scope("dsod.attn"):
+                h = h + Attention(c.heads, c.kv_heads, c.head_dim,
+                                  c.rope_theta, c.norm_eps, name="attn",
+                                  **kw)(y)
+        y = RMSNorm(c.norm_eps, self.dtype, name="ffn_norm")(h)
+        if self.ffn == "dense":
+            with jax.named_scope("dsod.densemlp"):
+                return h + SwiGLU(c.dense_width, name="mlp", **kw)(y), None
+        out, counters = ExpertLayer(
+            c.experts, c.experts_held, c.first_expert, c.top_k,
+            c.expert_width, c.norm_topk_prob, c.routed_scaling_factor,
+            name="moe", **kw)(y)
+        return h + out, counters
+
+
+class LFM2(nn.Module):
+    """``cfg`` is the frozen ``configs.base.LMConfig`` (``model.lm``):
+    the published widths, the layers kept and the chip's share."""
+    cfg: Any
+    remat: bool = True
+    dtype: jnp.dtype = jnp.bfloat16
+    param_dtype: jnp.dtype = jnp.float32
+
+    kind = "tokens"  # what engine.py / loop.py route on
+
+    @nn.compact
+    def __call__(self, tokens, *, train: bool = False):
+        del train  # no dropout, no batch statistics
+        c = self.cfg
+        block = nn.remat(Block) if self.remat else Block
+        per_layer = []
+        with jax.named_scope("dsod.encoder"):
+            h = nn.Embed(c.vocab, c.hidden, dtype=self.dtype,
+                         param_dtype=self.param_dtype, name="embed")(tokens)
+            for i, (op, ffn) in enumerate(zip(c.layer_types, c.ffn_types)):
+                h, counters = block(op, ffn, c, self.dtype, self.param_dtype,
+                                    name=f"layer_{i}")(h)
+                if counters is not None:
+                    per_layer.append(counters)
+        with jax.named_scope("dsod.heads"):
+            h = RMSNorm(c.norm_eps, self.dtype, name="final_norm")(h)
+        return h, moe_counters(per_layer, tokens.size * c.top_k)
+
+
+def moe_counters(per_layer, pairs_total: int):
+    """The trainer's three counters from the expert layers' own:
+    ``moe_pairs_here_share`` (mean over layers of held pairs / all
+    pairs), ``moe_load_max_over_mean`` (worst layer),
+    ``moe_dropped_pairs`` (sum)."""
+    if not per_layer:
+        return {}
+    stack = {k: jnp.stack([c[k] for c in per_layer]) for k in per_layer[0]}
+    return {
+        "moe_pairs_here_share": jnp.mean(stack["pairs_here"]) / pairs_total,
+        "moe_load_max_over_mean": jnp.max(stack["load_max_over_mean"]),
+        "moe_dropped_pairs": jnp.sum(stack["dropped"])}

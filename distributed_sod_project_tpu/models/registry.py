@@ -181,3 +181,13 @@ def _build_hdfnet(cfg, *, dtype, param_dtype, axis_name):
         dtype=dtype,
         param_dtype=param_dtype,
     )
+
+
+@register_model("lfm2")
+def _build_lfm2(cfg, *, dtype, param_dtype, axis_name):
+    """The token model (``kind = "tokens"``): its shape is ``cfg.lm``,
+    ``cfg.remat`` rematerialises each layer."""
+    from .lfm2 import LFM2
+
+    return LFM2(cfg=cfg.lm, remat=cfg.remat, dtype=dtype,
+                param_dtype=param_dtype)

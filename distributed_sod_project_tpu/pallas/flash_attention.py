@@ -383,3 +383,272 @@ def flash_attention_with_lse(q, k, v, *, block_q: int | None = None,
     out, lse = _flash_lse(qf, kf, vf, cfg)
     return (out[:, :n].reshape(b, h, n, d),
             lse[:, :n].reshape(b, h, n))
+
+
+# ---------------------------------------------------------------------------
+# causal, grouped KV heads (the token model's attention, models/lfm2.py)
+# ---------------------------------------------------------------------------
+#
+# Same online-softmax tiling as above with three differences.  (1) One
+# square block size, so a tile pair is either wholly below the diagonal
+# (no mask), on it (a local row >= col mask), or above it: those are
+# SKIPPED — no compute, and the index maps clamp to the last needed
+# block so no DMA either.  (2) Hq query heads share Hkv key/value heads:
+# the kv index map divides the folded head index by the group size, and
+# the dk/dv kernel keeps one kv block resident while the q blocks of all
+# G heads of its group stream past.  (3) delta = sum(out * do) is
+# reduced in-kernel from the streamed out/do tiles instead of being
+# broadcast to a lane-replicated HBM array; the matmul operands stay in
+# the input dtype (bf16 on the chip) with float32 accumulation.
+# Zero-padded rows past N need no key mask of their own: a valid query
+# row never sees a padded (later) key, and padded query rows carry zero
+# cotangents.
+
+_CAUSAL_BLOCK = 512
+
+
+def _causal_p(q_ref, k_ref, lse_or_none, *, scale, masked):
+    """One tile pair: the masked, scaled scores (forward: no lse yet),
+    or ``p = exp(scores - lse)`` once the row's lse is known."""
+    s = lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32) * scale
+    if masked:
+        row = lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        col = lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(col <= row, s, _MASK_VALUE)
+    if lse_or_none is None:
+        return s
+    return jnp.exp(s - _widen(lse_or_none, s.shape[1]))
+
+
+def _c_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s,
+                  *, scale: float):
+    i, j = pl.program_id(1), pl.program_id(2)
+    d = acc_s.shape[1]
+
+    @pl.when(j == 0)
+    def _():
+        m_s[...] = jnp.full(m_s.shape, _MASK_VALUE, jnp.float32)
+        l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
+        acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
+
+    def visit(masked):
+        s = _causal_p(q_ref, k_ref, None, scale=scale, masked=masked)
+        m_prev = m_s[...]
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])
+        p = jnp.exp(s - _widen(m_next, s.shape[1]))
+        corr = jnp.exp(m_prev - m_next)
+        l_s[...] = l_s[...] * corr + jnp.sum(p, axis=1)[:, None]
+        m_s[...] = m_next
+        pv = lax.dot_general(p.astype(v_ref.dtype), v_ref[0],
+                             (((1,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+        acc_s[...] = acc_s[...] * _widen(corr, d) + pv
+
+    pl.when(j < i)(lambda: visit(False))
+    pl.when(j == i)(lambda: visit(True))
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        o_ref[0] = (acc_s[...] / _widen(l_s[...], d)).astype(o_ref.dtype)
+        lse_ref[0] = m_s[...] + jnp.log(l_s[...])
+
+
+def _causal_ds(p, q_side, v_ref, *, scale):
+    """p * (dp - delta) * scale from the streamed do/out tiles."""
+    do_ref, out_ref = q_side
+    dp = lax.dot_general(do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
+                         preferred_element_type=jnp.float32)
+    delta = jnp.sum(out_ref[0].astype(jnp.float32)
+                    * do_ref[0].astype(jnp.float32), axis=1, keepdims=True)
+    return p * (dp - delta) * scale
+
+
+def _c_dq_kernel(q_ref, k_ref, v_ref, do_ref, out_ref, lse_ref, dq_ref,
+                 dq_s, *, scale: float):
+    i, j = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(j == 0)
+    def _():
+        dq_s[...] = jnp.zeros(dq_s.shape, jnp.float32)
+
+    def visit(masked):
+        p = _causal_p(q_ref, k_ref, lse_ref[0], scale=scale, masked=masked)
+        ds = _causal_ds(p, (do_ref, out_ref), v_ref, scale=scale)
+        dq_s[...] += lax.dot_general(ds.astype(k_ref.dtype), k_ref[0],
+                                     (((1,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+
+    pl.when(j < i)(lambda: visit(False))
+    pl.when(j == i)(lambda: visit(True))
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        dq_ref[0] = dq_s[...].astype(dq_ref.dtype)
+
+
+def _c_dkv_kernel(k_ref, v_ref, q_ref, do_ref, out_ref, lse_ref,
+                  dk_ref, dv_ref, dk_s, dv_s, *, scale: float, nq: int):
+    i, t = pl.program_id(1), pl.program_id(2)   # kv block; (head, q block)
+    j = t % nq
+
+    @pl.when(t == 0)
+    def _():
+        dk_s[...] = jnp.zeros(dk_s.shape, jnp.float32)
+        dv_s[...] = jnp.zeros(dv_s.shape, jnp.float32)
+
+    def visit(masked):
+        p = _causal_p(q_ref, k_ref, lse_ref[0], scale=scale, masked=masked)
+        dv_s[...] += lax.dot_general(p.astype(do_ref.dtype), do_ref[0],
+                                     (((0,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+        ds = _causal_ds(p, (do_ref, out_ref), v_ref, scale=scale)
+        dk_s[...] += lax.dot_general(ds.astype(q_ref.dtype), q_ref[0],
+                                     (((0,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+
+    pl.when(j > i)(lambda: visit(False))
+    pl.when(j == i)(lambda: visit(True))
+
+    @pl.when(t == pl.num_programs(2) - 1)
+    def _():
+        dk_ref[0] = dk_s[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
+
+
+@jax.named_scope("dsod.kernel.flash_attention_causal")
+def _c_fwd_call(q, k, v, cfg):
+    blk, group, interpret = cfg
+    bh, np_, d = q.shape
+    nb = np_ // blk
+    qs = pl.BlockSpec((1, blk, d), lambda b, i, j: (b, i, 0))
+    kvs = pl.BlockSpec((1, blk, d),
+                       lambda b, i, j: (b // group, jnp.minimum(j, i), 0))
+    row = pl.BlockSpec((1, blk, _LANES), lambda b, i, j: (b, i, 0))
+    return pl.pallas_call(
+        partial(_c_fwd_kernel, scale=1.0 / d**0.5),
+        grid=(bh, nb, nb),
+        in_specs=[qs, kvs, kvs],
+        out_specs=[qs, row],
+        out_shape=[jax.ShapeDtypeStruct((bh, np_, d), q.dtype),
+                   jax.ShapeDtypeStruct((bh, np_, _LANES), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((blk, _LANES), jnp.float32),
+                        pltpu.VMEM((blk, _LANES), jnp.float32),
+                        pltpu.VMEM((blk, d), jnp.float32)],
+        cost_estimate=pl.CostEstimate(
+            flops=2 * bh * np_ * np_ * d,
+            transcendentals=bh * np_ * np_ // 2,
+            bytes_accessed=2 * (q.size + k.size) * q.dtype.itemsize),
+        interpret=interpret,
+    )(q, k, v)
+
+
+@jax.named_scope("dsod.kernel.flash_attention_causal_dq")
+def _c_dq_call(q, k, v, out, lse, do, cfg):
+    blk, group, interpret = cfg
+    bh, np_, d = q.shape
+    nb = np_ // blk
+    qs = pl.BlockSpec((1, blk, d), lambda b, i, j: (b, i, 0))
+    kvs = pl.BlockSpec((1, blk, d),
+                       lambda b, i, j: (b // group, jnp.minimum(j, i), 0))
+    row = pl.BlockSpec((1, blk, _LANES), lambda b, i, j: (b, i, 0))
+    return pl.pallas_call(
+        partial(_c_dq_kernel, scale=1.0 / d**0.5),
+        grid=(bh, nb, nb),
+        in_specs=[qs, kvs, kvs, qs, qs, row],
+        out_specs=qs,
+        out_shape=jax.ShapeDtypeStruct((bh, np_, d), q.dtype),
+        scratch_shapes=[pltpu.VMEM((blk, d), jnp.float32)],
+        cost_estimate=pl.CostEstimate(
+            flops=3 * bh * np_ * np_ * d,
+            transcendentals=bh * np_ * np_ // 2,
+            bytes_accessed=4 * q.size * q.dtype.itemsize),
+        interpret=interpret,
+    )(q, k, v, do, out, lse)
+
+
+@jax.named_scope("dsod.kernel.flash_attention_causal_dkv")
+def _c_dkv_call(q, k, v, out, lse, do, cfg):
+    """kv block resident; the q blocks of the group's G heads stream
+    past, t = head-in-group * nb + q block, blocks above the diagonal
+    clamped to the first needed one (and skipped)."""
+    blk, group, interpret = cfg
+    bh, np_, d = q.shape
+    nb = np_ // blk
+    q_ix = lambda b, i, t: (b * group + t // nb,  # noqa: E731
+                            jnp.maximum(t % nb, i), 0)
+    qs = pl.BlockSpec((1, blk, d), q_ix)
+    row = pl.BlockSpec((1, blk, _LANES), q_ix)
+    kvs = pl.BlockSpec((1, blk, d), lambda b, i, t: (b, i, 0))
+    return pl.pallas_call(
+        partial(_c_dkv_kernel, scale=1.0 / d**0.5, nq=nb),
+        grid=(k.shape[0], nb, group * nb),
+        in_specs=[kvs, kvs, qs, qs, qs, row],
+        out_specs=[kvs, kvs],
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((blk, d), jnp.float32),
+                        pltpu.VMEM((blk, d), jnp.float32)],
+        cost_estimate=pl.CostEstimate(
+            flops=5 * bh * np_ * np_ * d,
+            transcendentals=bh * np_ * np_ // 2,
+            bytes_accessed=4 * q.size * q.dtype.itemsize),
+        interpret=interpret,
+    )(k, v, q, do, out, lse)
+
+
+def _c_bwd_call(q, k, v, out, lse_row, do, cfg):
+    # One scope per pallas_call and nothing else under it: the trace
+    # reader counts a kernel's calls by its scope.
+    lse = jnp.broadcast_to(lse_row[..., None], q.shape[:2] + (_LANES,))
+    dq = _c_dq_call(q, k, v, out, lse, do, cfg)
+    dk, dv = _c_dkv_call(q, k, v, out, lse, do, cfg)
+    return dq, dk, dv
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _flash_causal(q, k, v, cfg):
+    return _c_fwd_call(q, k, v, cfg)[0]
+
+
+def _flash_causal_fwd(q, k, v, cfg):
+    out, lse = _c_fwd_call(q, k, v, cfg)
+    return out, (q, k, v, out, lse[:, :, 0])
+
+
+def _flash_causal_bwd(cfg, res, g):
+    return _c_bwd_call(*res, g, cfg)
+
+
+_flash_causal.defvjp(_flash_causal_fwd, _flash_causal_bwd)
+
+
+def flash_attention_causal(q, k, v, *, block: int | None = None,
+                           interpret: bool | None = None) -> jnp.ndarray:
+    """Causal attention with grouped KV heads.
+
+    q: [B, Hq, N, D]; k, v: [B, Hkv, N, D] with Hkv dividing Hq (query
+    head h reads kv head h // (Hq / Hkv)); any N (zero-padded to the
+    block), D <= 128 or a multiple of 128.  Tiles above the diagonal are
+    skipped.  Differentiable via the Pallas backward kernels.
+    """
+    if k.shape != v.shape or q.ndim != 4 or k.ndim != 4:
+        raise ValueError(f"bad q/k/v shapes {q.shape} {k.shape} {v.shape}")
+    b, hq, n, d = q.shape
+    hkv = k.shape[1]
+    if (b, n, d) != (k.shape[0], k.shape[2], k.shape[3]) or hq % hkv:
+        raise ValueError(f"q {q.shape} and k/v {k.shape} do not pair into "
+                         "groups of query heads over shared kv heads")
+    if d > _LANES and d % _LANES:
+        raise ValueError(f"head dim {d} unsupported")
+    if block is None:
+        block = min(_CAUSAL_BLOCK, -(-n // _LANES) * _LANES)
+    if block % _LANES:
+        raise ValueError("block must be a multiple of 128")
+    np_ = -(-n // block) * block
+    interpret = (jax.default_backend() == "cpu" if interpret is None
+                 else interpret)
+    fold = lambda t: _pad_n(t.reshape(-1, n, d), np_)  # noqa: E731
+    out = _flash_causal(fold(q), fold(k), fold(v),
+                        (block, hq // hkv, interpret))
+    return out[:, :n].reshape(b, hq, n, d)

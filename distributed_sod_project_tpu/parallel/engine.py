@@ -326,7 +326,49 @@ def make_unified_train_step(
         new_state, metrics = _finish(state, grads, comps, new_stats)
         return (new_state, new_res[None]), metrics
 
-    inner_fn = step_fn_ef if ef else step_fn
+    # The token model (models/lfm2.py; ``kind`` is a class attribute) is
+    # the third forward+loss path beside ``sp`` and the image branch of
+    # ``_forward_loss``, chosen by the model at trace time: the batch is
+    # tokens/targets, the forward returns the final hidden states and
+    # the expert layers' counters, the loss is the chunked cross-entropy
+    # over the tied embedding; everything after the gradients is the
+    # shared tail.  It is defined HERE, below every closure an image
+    # model's trace passes through, and not as an ``if`` inside
+    # ``_forward_loss``: the Pallas kernels' serialized bodies carry the
+    # line numbers of the frames above them, those bytes are part of the
+    # compile-cache key, and a line inserted above would make every
+    # image config's step a cache miss (and, on the chip, a different
+    # compile: PERF.md section 6, PRs 27-28).
+    tokens = getattr(model, "kind", "image") == "tokens"
+    if tokens and (preset != "dp" or ef):
+        raise ValueError(
+            "the token model trains under the dp preset without "
+            f"error-feedback compression, got preset={preset!r} "
+            f"grad_compression={grad_compression!r}")
+
+    def _forward_loss_tokens(state, batch):
+        from ..losses.token_ce import tied_cross_entropy
+
+        def loss_fn(params):
+            hidden, counters = model.apply(
+                {"params": params, "batch_stats": state.batch_stats},
+                batch["tokens"], train=True)
+            total = tied_cross_entropy(
+                hidden, params["embed"]["embedding"], batch["targets"])
+            return total, dict(counters, total=total)
+
+        grads, comps = jax.grad(loss_fn, has_aux=True)(state.params)
+        return grads, comps, state.batch_stats
+
+    def step_fn_tokens(state: TrainState, batch):
+        # No image to rescale, no dropout draw, no resample site to
+        # count; the same reduce/finish.
+        grads, comps, new_stats = _forward_loss_tokens(state, batch)
+        grads, comps, _ = _reduce(grads, comps)
+        return _finish(state, grads, comps, new_stats)
+
+    inner_fn = (step_fn_tokens if tokens
+                else step_fn_ef if ef else step_fn)
     body = chunked_step_fn(inner_fn, steps_per_dispatch,
                            always_scan=_always_scan)
     donated = (0,) if donate else ()

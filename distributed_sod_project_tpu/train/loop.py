@@ -222,9 +222,9 @@ def fit(
     tx, schedule = build_optimizer(cfg.optim, total_steps)
 
     sample = next(iter(loader))
-    from ..utils.checks import validate_batch
+    from ..utils.checks import validate_first_batch  # by the model's kind
 
-    validate_batch(sample, cfg.data.image_size, use_depth=cfg.data.use_depth)
+    validate_first_batch(sample, cfg, model)
     state = create_train_state(jax.random.key(cfg.seed), model, tx, sample,
                                pretrained=cfg.model.pretrained,
                                ema=cfg.optim.ema_decay > 0)

@@ -51,6 +51,13 @@ def create_train_state(rng, model, tx, sample_batch,
     and wrap them with the optimizer's initial state.  ``pretrained``
     merges a ported ImageNet backbone (.npz) over the fresh init.
     ``ema=True`` seeds the EMA tree as a copy of the initial params."""
+    if getattr(model, "kind", "image") == "tokens":
+        # Parameter shapes do not depend on the sequence length: a short
+        # stretch of one sequence keeps the init program small.
+        tokens = jnp.asarray(sample_batch["tokens"])[:1, :128]
+        variables = jax.jit(
+            lambda r, t: model.init(r, t, train=False))(rng, tokens)
+        return _wrap_state(variables, tx, ema)
     image = jnp.asarray(sample_batch["image"])
     depth = sample_batch.get("depth")
     if depth is not None:
@@ -65,6 +72,10 @@ def create_train_state(rng, model, tx, sample_batch,
         from ..models.pretrained import load_pretrained
 
         variables = load_pretrained(variables, pretrained)
+    return _wrap_state(variables, tx, ema)
+
+
+def _wrap_state(variables, tx, ema: bool) -> TrainState:
     params = variables["params"]
     batch_stats = variables.get("batch_stats", {})
     opt_state, ema_params = jax.jit(lambda p: (

@@ -90,3 +90,35 @@ def periodic_validate(batches: Iterable[Dict], every: int,
         if i % every == 0:
             check_finite_batch(batch, batch_index=i)
         yield batch
+
+
+def validate_first_batch(batch: Dict, cfg, model) -> None:
+    """``fit()``'s one check of its first batch: a token model's batch
+    against ``data.seq_len`` and the vocabulary rows the model holds, an
+    image model's against ``data.image_size`` (and depth)."""
+    if getattr(model, "kind", "image") == "tokens":
+        validate_token_batch(batch, cfg.data.seq_len, cfg.model.lm.vocab)
+    else:
+        validate_batch(batch, cfg.data.image_size,
+                       use_depth=cfg.data.use_depth)
+
+
+def validate_token_batch(batch: Dict, seq_len: int, vocab: int) -> None:
+    """The token model's first-batch check: ``tokens`` / ``targets`` are
+    [B, seq_len] integers inside the vocabulary slice, and the targets
+    are the tokens shifted by one."""
+    for k in ("tokens", "targets"):
+        v = batch.get(k)
+        if v is None:
+            raise ValueError(f"batch is missing {k!r}")
+        v = np.asarray(v)
+        if v.ndim != 2 or v.shape[1] != int(seq_len) \
+                or not np.issubdtype(v.dtype, np.integer):
+            raise ValueError(f"{k} is {v.dtype}{v.shape}, not integers "
+                             f"[B, {seq_len}]")
+        if v.min() < 0 or v.max() >= vocab:
+            raise ValueError(f"{k} ids span [{v.min()}, {v.max()}], outside "
+                             f"the {vocab} rows of the vocabulary held")
+    if not np.array_equal(np.asarray(batch["tokens"])[:, 1:],
+                          np.asarray(batch["targets"])[:, :-1]):
+        raise ValueError("targets are not the tokens shifted by one")

@@ -85,6 +85,10 @@ def test_zoo_sweep_covers_every_registered_config():
         # (flash attention, hires memory posture) are A/B'd by
         # tools/bench_flash.py.
         "vit_sod_hires",
+        # The token model: bench.py feeds image batches; its step is
+        # timed through fit() by the benchmark's own cell
+        # (lfm2_8b_a1b_ep4.train_s8k_b4).
+        "lfm2_8b_a1b_ep4",
     }
     missing = set(list_configs()) - set(bench_zoo.ZOO) - excluded
     assert not missing, (

@@ -34,11 +34,11 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 _P = "distributed_sod_project_tpu.pallas."
-fc, fr, dfm, fl, fs, fa, vb = (
+fc, fr, dfm, fl, fs, fa, vb, gm = (
     importlib.import_module(_P + m)
     for m in ("fused_conv", "fused_resample", "dynamic_filter",
               "fused_loss", "fused_ssim", "flash_attention",
-              "vmem_budget"))
+              "vmem_budget", "grouped_matmul"))
 
 _S = collections.namedtuple("_S", "shape dtype")  # an argument's spec
 B = 2  # the kernels grid over images; the tile is what the compiler prices
@@ -138,6 +138,14 @@ _cat_up = partial(fr.fused_upsample2_merge, mode="concat", x_first=True,
 _IMG = _S((B, 320, 320, 1), F32)
 _QKV = _S((1, 6, 4096, 64), BF)
 _flash = partial(fa.flash_attention, interpret=False)
+_causal = partial(fa.flash_attention_causal, interpret=False)
+_CAUSAL_QKV = (_S((1, 32, 8192, 64), BF),) + (_S((1, 8, 8192, 64), BF),) * 2
+_gmm = partial(gm.grouped_matmul, interpret=False)
+
+
+def _gmm_args(a, b, tiles=24, experts=8):
+    return (_S((tiles * 512, a), BF), _S((experts, a, b), F32),
+            _S((tiles,), jnp.int32), _S((1,), jnp.int32))
 
 # name -> (fn, pytree of argument specs, custom calls expected)
 CASES = {
@@ -194,6 +202,21 @@ CASES = {
     "flash_attention.bwd@4096": (
         jax.grad(lambda q, k, v: _flash(q, k, v).astype(F32).sum(),
                  argnums=(0, 1, 2)), (_QKV,) * 3, 3),
+    # lfm2_8b_a1b_ep4: one 8,192-token sequence of 32 query / 8 KV
+    # heads of 64 (the cell runs four), causal, forward and backward.
+    "flash_attention_causal.fwd@8192": (_causal, _CAUSAL_QKV, 1),
+    "flash_attention_causal.bwd@8192": (
+        jax.grad(lambda q, k, v: _causal(q, k, v).astype(F32).sum(),
+                 argnums=(0, 1, 2)), _CAUSAL_QKV, 3),
+    # ... and its grouped expert products at the published widths:
+    # 8 experts of 2048 -> 1792 -> 2048 over 16 + 8 row tiles of 512.
+    "grouped_matmul.fwd@2048x1792": (_gmm, _gmm_args(2048, 1792), 1),
+    "grouped_matmul.dx+dw@2048x1792": (
+        jax.grad(lambda x, w, te, nu: _gmm(x, w, te, nu).astype(F32).sum(),
+                 argnums=(0, 1)), _gmm_args(2048, 1792), 2),
+    "grouped_matmul.dx+dw@1792x2048": (
+        jax.grad(lambda x, w, te, nu: _gmm(x, w, te, nu).astype(F32).sum(),
+                 argnums=(0, 1)), _gmm_args(1792, 2048), 2),
 }
 
 

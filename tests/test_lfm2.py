@@ -1,0 +1,366 @@
+"""The token model (models/lfm2.py, config ``lfm2_8b_a1b_ep4``) against
+its plain reference (benchmark/reference/lfm2.py) on the CPU at tiny
+widths, float32, seeded weights (benchmark/harness/weights_lm.py):
+
+- each layer kind, the whole model's loss and every gradient leaf;
+- the four shares' expert outputs add up to the uncut reference layer;
+- no pair dropped when every token picks held experts only, zero
+  contribution when none does; the grouped product against a loop;
+- the causal grouped-KV flash kernel (interpret mode), also at a length
+  that is no multiple of the block;
+- the chunked loss equals the unchunked one;
+- every ``dsod.moe.*`` / ``dsod.attn`` / ``dsod.shortconv`` scope in the
+  lowered step and none of them in BASNet's;
+- the packed-token dataset, and three steps of ``fit()``.
+"""
+
+import dataclasses
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.harness.weights_lm import variables_builder
+from benchmark.reference import lfm2 as ref
+from distributed_sod_project_tpu.configs import apply_overrides, get_config
+from distributed_sod_project_tpu.losses.token_ce import tied_cross_entropy
+from distributed_sod_project_tpu.models import build_model
+from distributed_sod_project_tpu.models import lfm2 as lm
+from distributed_sod_project_tpu.pallas.flash_attention import \
+    flash_attention_causal
+from distributed_sod_project_tpu.pallas.grouped_matmul import grouped_matmul
+
+TINY = ["model.lm.vocab=512", "model.lm.hidden=64", "model.lm.heads=4",
+        "model.lm.kv_heads=2", "model.lm.head_dim=16",
+        "model.lm.dense_width=96", "model.lm.expert_width=48",
+        "model.lm.experts=8", "model.lm.experts_held=2",
+        "model.lm.top_k=2", "data.seq_len=160", "data.vocab=512",
+        "data.synthetic_size=32", "global_batch_size=2",
+        "model.compute_dtype=float32"]
+B, N = 2, 160  # 160: one whole 128-row block and a part of one
+
+
+def _cfg(*more):
+    return apply_overrides(get_config("lfm2_8b_a1b_ep4"),
+                           TINY + list(more))
+
+
+@pytest.fixture(scope="module")
+def setup():
+    cfg = _cfg()
+    model = build_model(cfg.model)
+    tokens = jax.random.randint(jax.random.key(0), (B, N), 0, 512)
+    shapes = jax.eval_shape(lambda r, t: model.init(r, t),
+                            jax.random.key(1), tokens)
+    variables = variables_builder(shapes, {})(7)
+    return cfg, model, variables, tokens, dataclasses.asdict(cfg.model.lm)
+
+
+def _close(a, b, tol=2e-5):
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=tol,
+                               atol=tol * float(np.max(np.abs(b)) + 1e-12))
+
+
+# -- layer by layer ----------------------------------------------------------
+
+def _x(seed=3):
+    return jax.random.normal(jax.random.key(seed), (B, N, 64))
+
+
+def _per_seq(fn, x):
+    return jnp.stack([fn(x[i]) for i in range(x.shape[0])])
+
+
+@pytest.mark.parametrize("kind", ["shortconv", "attention", "densemlp",
+                                  "experts"])
+def test_layer_matches_reference(setup, kind):
+    cfg, _, v, _, m = setup
+    c, x = cfg.model.lm, _x()
+    f32 = dict(dtype=jnp.float32)
+    if kind == "shortconv":
+        p = v["params"]["layer_0"]["conv"]
+        got = lm.ShortConv(c.conv_kernel, **f32).apply({"params": p}, x)
+        want = _per_seq(lambda s: ref.short_conv(s, p), x)
+    elif kind == "attention":
+        p = v["params"]["layer_1"]["attn"]
+        got = lm.Attention(c.heads, c.kv_heads, c.head_dim, c.rope_theta,
+                           c.norm_eps, **f32).apply({"params": p}, x)
+        want = _per_seq(lambda s: ref.attention(s, p, m), x)
+    elif kind == "densemlp":
+        p = v["params"]["layer_0"]["mlp"]
+        got = lm.SwiGLU(c.dense_width, **f32).apply({"params": p}, x)
+        want = _per_seq(lambda s: ref.swiglu(s, p), x)
+    else:
+        p = v["params"]["layer_1"]["moe"]
+        b = v["batch_stats"]["layer_1"]["moe"]
+        got, counters = _experts(c).apply(
+            {"params": p, "batch_stats": b}, x)
+        want = _per_seq(lambda s: ref.moe(s, p, b["expert_bias"], m), x)
+        assert float(counters["dropped"]) == 0.0
+        assert 0 < float(counters["pairs_here"]) < B * N * c.top_k
+    _close(got, want)
+
+
+def _experts(c, **kw):
+    args = dict(experts=c.experts, experts_held=c.experts_held,
+                first_expert=c.first_expert, top_k=c.top_k,
+                width=c.expert_width, dtype=jnp.float32)
+    return lm.ExpertLayer(**dict(args, **kw))
+
+
+def test_model_loss_and_every_gradient_match_reference(setup):
+    _, model, v, tokens, m = setup
+    targets = jnp.roll(tokens, -1, 1)
+
+    def prog(p):
+        h, _ = model.apply({"params": p, "batch_stats": v["batch_stats"]},
+                           tokens, train=True)
+        return tied_cross_entropy(h, p["embed"]["embedding"], targets,
+                                  chunk=64)
+
+    def plain(p):
+        return ref.batch_loss({"params": p, "batch_stats": v["batch_stats"]},
+                              tokens, targets, m)
+
+    lp, gp = jax.jit(jax.value_and_grad(prog))(v["params"])
+    lr, gr = jax.jit(jax.value_and_grad(plain))(v["params"])
+    assert abs(float(lp) - float(lr)) < 1e-5 * float(lr)
+    flat = jax.tree_util.tree_flatten_with_path(gp)[0]
+    for (path, a), b in zip(flat, jax.tree_util.tree_leaves(gr)):
+        assert float(jnp.max(jnp.abs(a - b))) \
+            <= 2e-4 * float(jnp.max(jnp.abs(b))), jax.tree_util.keystr(path)
+        assert float(jnp.max(jnp.abs(b))) > 0, jax.tree_util.keystr(path)
+
+
+# -- the chip's share --------------------------------------------------------
+
+def _whole_layer(seed=11, experts=8, d=64, f=48):
+    """An uncut expert layer: all ``experts`` held."""
+    ks = jax.random.split(jax.random.key(seed), 5)
+    p = {"router": {"kernel": jax.random.normal(ks[0], (d, experts)) / 8},
+         "gate": jax.random.normal(ks[1], (experts, d, f)) / 8,
+         "up": jax.random.normal(ks[2], (experts, d, f)) / 8,
+         "down": jax.random.normal(ks[3], (experts, f, d)) / 7}
+    return p, jax.random.normal(ks[4], (experts,)) * 0.01
+
+
+def test_four_shares_add_up_to_the_uncut_reference_layer(setup):
+    cfg, _, _, _, m = setup
+    c, x = cfg.model.lm, _x(5)
+    p, bias = _whole_layer()
+    whole = _per_seq(lambda s: ref.moe(s, p, bias, m), x)
+    total, pairs = jnp.zeros_like(x), 0.0
+    for share in range(4):
+        lo = share * 2
+        mine = dict(p, **{k: p[k][lo:lo + 2] for k in ("gate", "up", "down")})
+        out, counters = _experts(c, first_expert=lo).apply(
+            {"params": mine, "batch_stats": {"expert_bias": bias}}, x)
+        assert float(counters["dropped"]) == 0.0
+        # the reference, given the same share, gives the same part
+        _close(out, _per_seq(lambda s: ref.moe(s, mine, bias, m,
+                                               first_expert=lo), x))
+        total, pairs = total + out, pairs + float(counters["pairs_here"])
+    _close(total, whole)
+    assert pairs == B * N * c.top_k  # every pair computed on some chip
+
+
+@pytest.mark.parametrize("held", ["every_pair", "no_pair"])
+def test_no_pair_dropped_whatever_the_imbalance(setup, held):
+    """A bias that sends every token to the two held experts fills the
+    buffer's worst case with no pair dropped; one that sends every
+    token elsewhere gives exactly zero."""
+    cfg, _, _, _, m = setup
+    c, x = cfg.model.lm, _x(6)
+    p, _ = _whole_layer()
+    mine = dict(p, **{k: p[k][:2] for k in ("gate", "up", "down")})
+    bias = jnp.where(jnp.arange(8) < 2, 10.0, 0.0) * (
+        1.0 if held == "every_pair" else -1.0)
+    out, counters = _experts(c).apply(
+        {"params": mine, "batch_stats": {"expert_bias": bias}}, x)
+    assert float(counters["dropped"]) == 0.0
+    if held == "every_pair":
+        assert float(counters["pairs_here"]) == B * N * c.top_k
+        _close(out, _per_seq(lambda s: ref.moe(s, mine, bias, m), x))
+    else:
+        assert float(counters["pairs_here"]) == 0.0
+        assert float(jnp.max(jnp.abs(out))) == 0.0
+
+
+@pytest.mark.parametrize("counts,a,b", [
+    ((40, 0, 100, 7), 24, 40), ((0, 0, 0, 64), 24, 40),
+    ((16, 16, 16, 16), 24, 40),
+    # widths whose widest tile does not divide them (640 = 5 x 128, as
+    # 1792 = 14 x 128 at the published width): every block of dw written
+    ((20, 3), 640, 128), ((5, 30), 128, 640)])
+def test_grouped_matmul_matches_a_loop_over_experts(counts, a, b):
+    """Forward, dx and dw, with empty experts and ragged groups."""
+    tile = 16
+    e = len(counts)
+    tiles = [max(-(-n // tile), 1) for n in counts]
+    rows = (sum(tiles) + 2) * tile  # two unused tiles at the end
+    x = np.zeros((rows, a), np.float32)
+    expert_of_row = np.full(rows, -1)
+    r0 = 0
+    rng = np.random.RandomState(0)
+    for i, (n, t) in enumerate(zip(counts, tiles)):
+        x[r0:r0 + n] = rng.randn(n, a)
+        expert_of_row[r0:r0 + n] = i
+        r0 += t * tile
+    te = np.repeat(np.arange(e), tiles).tolist()
+    te = np.asarray(te + [te[-1]] * 2, np.int32)
+    nu = np.asarray([sum(tiles)], np.int32)
+    w = jnp.asarray(rng.randn(e, a, b), jnp.float32)
+    g = jnp.asarray(rng.randn(rows, b), jnp.float32)
+
+    def loop(x, w):
+        onehot = (expert_of_row[:, None] == np.arange(e)[None]).astype(
+            np.float32)
+        return jnp.einsum("re,ra,eab->rb", onehot, x, w)
+
+    def kernel(x, w):
+        return grouped_matmul(x, w, jnp.asarray(te), jnp.asarray(nu),
+                              tile_m=tile)
+
+    x = jnp.asarray(x)
+    _close(kernel(x, w), loop(x, w))
+    for i in (0, 1):
+        got = jax.grad(lambda *a: jnp.sum(kernel(*a) * g), i)(x, w)
+        want = jax.grad(lambda *a: jnp.sum(loop(*a) * g), i)(x, w)
+        valid = (expert_of_row >= 0)[:, None] if i == 0 else 1.0
+        _close(got * valid, want)
+
+
+# -- the kernels and the loss ------------------------------------------------
+
+def _plain_causal(q, k, v):
+    g = q.shape[1] // k.shape[1]
+    k, v = (jnp.repeat(t, g, axis=1) for t in (k, v))
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    n = q.shape[2]
+    s = jnp.where(jnp.tril(jnp.ones((n, n), bool)), s, -jnp.inf)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
+
+
+@pytest.mark.parametrize("n,block", [(256, 128), (160, 128), (72, None),
+                                     (384, 128)])
+def test_flash_causal_grouped_kv_matches_plain_attention(n, block):
+    ks = jax.random.split(jax.random.key(n), 4)
+    q = jax.random.normal(ks[0], (2, 4, n, 16))
+    k = jax.random.normal(ks[1], (2, 2, n, 16))
+    v = jax.random.normal(ks[2], (2, 2, n, 16))
+    g = jax.random.normal(ks[3], (2, 4, n, 16))
+    flash = lambda *a: flash_attention_causal(*a, block=block)  # noqa: E731
+    _close(flash(q, k, v), _plain_causal(q, k, v))
+    got = jax.grad(lambda *a: jnp.sum(flash(*a) * g), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(_plain_causal(*a) * g),
+                    (0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        _close(a, b, 1e-4)
+
+
+@pytest.mark.parametrize("chunk", [64, 100, 4096])
+def test_chunked_loss_equals_the_unchunked_one(chunk):
+    ks = jax.random.split(jax.random.key(2), 3)
+    h = jax.random.normal(ks[0], (2, 160, 32))
+    e = jax.random.normal(ks[1], (96, 32))
+    t = jax.random.randint(ks[2], (2, 160), 0, 96)
+
+    def plain(h, e):
+        z = h.reshape(-1, 32) @ e.T
+        return jnp.mean(jax.nn.logsumexp(z, -1) - jnp.take_along_axis(
+            z, t.reshape(-1, 1), -1)[:, 0])
+
+    chunked = lambda h, e: tied_cross_entropy(h, e, t, chunk=chunk)  # noqa: E731
+    lp, gp = jax.value_and_grad(plain, (0, 1))(h, e)
+    lc, gc = jax.value_and_grad(chunked, (0, 1))(h, e)
+    assert abs(float(lp) - float(lc)) < 1e-5
+    for a, b in zip(gc, gp):
+        _close(a, b, 1e-4)
+
+
+# -- scopes ------------------------------------------------------------------
+
+SCOPES = ("dsod.moe.route", "dsod.moe.experts", "dsod.moe.combine",
+          "dsod.attn", "dsod.shortconv", "dsod.densemlp",
+          "dsod.kernel.grouped_matmul", "dsod.kernel.grouped_matmul_dw",
+          "dsod.kernel.flash_attention_causal",
+          "dsod.kernel.flash_attention_causal_dq",
+          "dsod.kernel.flash_attention_causal_dkv")
+_STAGE = re.compile(r"dsod\.(encoder|decoder|heads|loss|update)\b")
+
+
+def _scope_paths(name):
+    from test_profiler_names import _lowered_step_text
+
+    return re.findall(r'^#loc\d+ = loc\("([^"]*)"',
+                      _lowered_step_text(name), re.M)
+
+
+@pytest.fixture(scope="module")
+def lowered_paths():
+    return _scope_paths("lfm2_8b_a1b_ep4")
+
+
+@pytest.mark.parametrize("scope", SCOPES)
+def test_lowered_step_names_the_token_models_scopes(lowered_paths, scope):
+    under = [p for p in lowered_paths if re.search(re.escape(scope) + r"\b", p)]
+    assert under, scope
+    # inside the encoder stage and no other (a jitted helper called
+    # under jax.checkpoint lowers to a function of its own, whose ops
+    # carry the path from the checkpoint down: no stage, never another)
+    stages = [set(_STAGE.findall(p)) for p in under]
+    assert {"encoder"} in stages and all(s <= {"encoder"} for s in stages)
+
+
+def test_image_models_step_names_none_of_them():
+    paths = _scope_paths("basnet_ds")
+    assert not [p for p in paths if re.search(
+        r"dsod\.(moe|attn|shortconv|densemlp)\b"
+        r"|dsod\.kernel\.(grouped_matmul|flash_attention_causal)", p)]
+
+
+# -- data and the loop -------------------------------------------------------
+
+def test_packed_tokens_are_deterministic_zipf_documents():
+    from distributed_sod_project_tpu.data.tokens import EOD, PackedTokens
+    from distributed_sod_project_tpu.utils.checks import validate_token_batch
+
+    ds = PackedTokens(size=8, seq_len=4096, vocab=1024)
+    a, b = ds[5], ds[5]
+    assert np.array_equal(a["tokens"], b["tokens"])
+    assert a["tokens"].dtype == np.int32 and a["tokens"].shape == (4096,)
+    assert np.array_equal(a["tokens"][1:], a["targets"][:-1])
+    assert not np.array_equal(a["tokens"], ds[6]["tokens"])
+    ids = np.concatenate([ds[i]["tokens"] for i in range(8)])
+    assert 0 <= ids.min() and ids.max() < 1024
+    ends = int((ids == EOD).sum())  # mean length 512 * exp(0.72) ~ 1050
+    assert 8 <= ends <= 120
+    counts = np.bincount(ids, minlength=1024)[1:]
+    assert counts[:8].sum() > counts[512:].sum()  # Zipf: few ids, most mass
+    batch = {k: np.stack([ds[i][k] for i in range(2)]) for k in a}
+    validate_token_batch(batch, 4096, 1024)
+    with pytest.raises(ValueError, match="outside"):
+        validate_token_batch(batch, 4096, 512)
+    with pytest.raises(ValueError, match="shifted"):
+        validate_token_batch(dict(batch, targets=batch["tokens"]), 4096, 1024)
+
+
+def test_three_steps_of_fit_at_tiny_size(tmp_path):
+    from distributed_sod_project_tpu.train.loop import fit
+
+    cfg = _cfg("log_every_steps=1", "data.num_workers=2", "tensorboard=false",
+               "checkpoint_every_steps=100").replace(
+                   checkpoint_dir=str(tmp_path / "ck"))
+    seen = []
+    out = fit(cfg, max_steps=3,
+              hooks={"on_metrics": lambda step, host: seen.append(host)})
+    assert out["final_step"] == 3 and len(seen) == 3
+    for host in seen:
+        assert np.isfinite(host["total"]) and host["grad_norm"] > 0
+        assert host["moe_dropped_pairs"] == 0.0
+        assert 0.1 < host["moe_pairs_here_share"] < 0.45
+        assert host["moe_load_max_over_mean"] >= 1.0
+        assert "data_starved_ms" in host
+    assert abs(seen[0]["total"] - np.log(512)) < 1.5

@@ -124,6 +124,12 @@ def test_fit_under_profiler_names_loop_and_data_plane(tmp_path, monkeypatch):
         >= set(range(4))
 
 
+_TOKEN_MODELS = {"lfm2_8b_a1b_ep4"}  # a token batch, no convolution
+_TINY_LM = ["model.lm.vocab=512", "model.lm.hidden=64", "model.lm.heads=4",
+            "model.lm.kv_heads=2", "model.lm.head_dim=16",
+            "model.lm.dense_width=96", "model.lm.expert_width=48"]
+
+
 def _lowered_step_text(name: str, size: int = 64) -> str:
     """``lower().as_text(debug_info=True)`` of the config's train step
     on abstract state at a tiny size: traced, never compiled."""
@@ -136,7 +142,8 @@ def _lowered_step_text(name: str, size: int = 64) -> str:
 
     cfg = apply_overrides(get_config(name), [
         "global_batch_size=2", f"data.image_size={size},{size}",
-        "mesh.data=1", "mesh.model=1", "mesh.seq=1"])
+        "mesh.data=1", "mesh.model=1", "mesh.seq=1"] + (
+            _TINY_LM if name in _TOKEN_MODELS else []))
     mesh = make_mesh(cfg.mesh, jax.devices()[:1])
     model = build_model(cfg.model)
     tx, sched = build_optimizer(cfg.optim, 100)
@@ -144,6 +151,9 @@ def _lowered_step_text(name: str, size: int = 64) -> str:
              "mask": jnp.zeros((2, size, size, 1))}
     if cfg.data.use_depth:
         batch["depth"] = jnp.zeros((2, size, size, 1))
+    if getattr(model, "kind", "image") == "tokens":
+        batch = {k: jnp.zeros((2, 256), jnp.int32)
+                 for k in ("tokens", "targets")}
     state = jax.eval_shape(
         lambda: create_train_state(jax.random.key(0), model, tx, batch))
     step = make_unified_train_step(model, cfg.loss, tx, mesh, preset="dp",
@@ -157,11 +167,19 @@ def test_lowered_step_carries_stage_scopes(name):
     locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
     stages_of = {k: set(_STAGE.findall(v)) for k, v in locs.items()}
     seen = set().union(*stages_of.values())
-    token_model = get_config(name).model.name == "vit_sod"
+    token_model = (get_config(name).model.name == "vit_sod"
+                   or name in _TOKEN_MODELS)
     want = set(STAGES) - ({"decoder"} if token_model else set())
     assert seen == want
     # Sibling scopes: no op path names two different stages.
     assert [locs[k] for k, v in stages_of.items() if len(v) > 1] == []
+    if name in _TOKEN_MODELS:
+        # No convolution to place; every matrix product belongs to a stage.
+        dots = [ln for ln in text.splitlines()
+                if "stablehlo.dot_general" in ln]
+        assert dots and not [ln[-160:] for ln in dots if not stages_of.get(
+            re.search(r"loc\((#loc\d+)\)\s*$", ln).group(1))]
+        return
     # Every convolution, forward and backward, belongs to a stage.
     convs = [ln for ln in text.splitlines() if "stablehlo.convolution" in ln]
     assert convs

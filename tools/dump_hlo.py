@@ -27,6 +27,12 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
+_TINY_LM = ["model.lm.vocab=512", "model.lm.hidden=64", "model.lm.heads=4",
+            "model.lm.kv_heads=2", "model.lm.head_dim=16",
+            "model.lm.dense_width=96", "model.lm.expert_width=48",
+            "data.vocab=512"]
+
+
 def dump(config_name: str, out_dir: str, n_devices: int = 8,
          batch_per_device: int = 1, image_size: int = 64,
          compile_cost: bool = True, overrides=(),
@@ -71,11 +77,15 @@ def dump(config_name: str, out_dir: str, n_devices: int = 8,
         build_optimizer, create_train_state)
 
     cfg = get_config(config_name)
+    shrink = [f"data.image_size={image_size},{image_size}"]
+    if cfg.model.name == "lfm2":
+        # The token model's shrink: tiny widths, --image-size tokens a
+        # sequence (the structure of the program is what is diffed).
+        shrink = [f"data.seq_len={image_size}"] + _TINY_LM
     cfg = apply_overrides(cfg, [
         f"global_batch_size={batch_per_device * n_devices}",
-        f"data.image_size={image_size},{image_size}",
         "mesh.data=-1", "mesh.model=1", "mesh.seq=1",
-    ] + list(overrides))
+    ] + shrink + list(overrides))
     mesh = make_mesh(cfg.mesh, jax.devices()[:n_devices])
     model = build_model(cfg.model)
     tx, sched = build_optimizer(cfg.optim, 100)
@@ -88,6 +98,10 @@ def dump(config_name: str, out_dir: str, n_devices: int = 8,
     }
     if cfg.data.use_depth:
         batch["depth"] = rng.randn(b, hw, hw, 1).astype(np.float32)
+    if getattr(model, "kind", "image") == "tokens":
+        # The token model: --image-size is the sequence length here.
+        batch = {k: rng.randint(0, cfg.model.lm.vocab, (b, hw)).astype(
+            np.int32) for k in ("tokens", "targets")}
     state = create_train_state(jax.random.key(0), model, tx, batch)
     dbatch = jax.device_put(batch, batch_sharding(mesh))
 
