@@ -46,6 +46,7 @@ from jax import lax
 
 from ..pallas.flash_attention import flash_attention_causal
 from ..pallas.grouped_matmul import TILE_M, grouped_matmul
+from ..pallas.moe_unpermute import moe_unpermute, unpermute_steps
 
 
 class RMSNorm(nn.Module):
@@ -230,50 +231,68 @@ def _rows(x, index):
     return jnp.take(x, index, axis=0, mode="fill", fill_value=0)
 
 
-@jax.custom_vjp
-def dispatch(x, row_of_pair, pair_of_row):
-    """[T, D] -> the expert-ordered buffer [rows, D] (padding rows zero).
-    Both directions are gathers: a buffer row has one pair, so the
-    backward sums a token's K rows instead of scattering."""
-    k = row_of_pair.shape[1]
-    return _rows(x, jnp.where(pair_of_row >= 0, pair_of_row // k,
-                              x.shape[0]))
+def _token_of_row(pair_of_row, top_k: int, tokens: int):
+    """The token each buffer row holds, ``tokens`` (out of range: reads
+    back as zero) on padding rows."""
+    return jnp.where(pair_of_row >= 0, pair_of_row // top_k, tokens)
 
 
-def _dispatch_fwd(x, row_of_pair, pair_of_row):
-    return dispatch(x, row_of_pair, pair_of_row), row_of_pair
+# Which pass is which.  INTO the buffer a row has one source, so both
+# ``dispatch`` forward and ``combine`` backward are XLA row gathers of
+# the buffer's size (``_rows(., token of row)``).  OUT of the buffer a
+# token sums its K rows, of which this chip holds about a quarter:
+# ``combine`` forward and ``dispatch`` backward are the Pallas kernel
+# (``pallas/moe_unpermute.py``), which reads the held rows alone.
+# Nothing in either direction is sized [T, K, D].
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def dispatch(x, row_of_pair, pair_of_row, steps, tile_m):
+    """[T, D] -> the expert-ordered buffer [rows, D] (padding rows
+    zero): an XLA gather of ``rows`` rows.  The backward sums a token's
+    held rows in the un-permute kernel (a buffer row has one pair, so
+    nothing is scattered)."""
+    return _rows(x, _token_of_row(pair_of_row, row_of_pair.shape[1],
+                                  x.shape[0]))
 
 
-def _dispatch_bwd(row_of_pair, g):
-    dx = jnp.sum(_rows(g, row_of_pair).astype(jnp.float32), axis=1)
-    return dx.astype(g.dtype), None, None
+def _dispatch_fwd(x, row_of_pair, pair_of_row, steps, tile_m):
+    return (dispatch(x, row_of_pair, pair_of_row, steps, tile_m),
+            (row_of_pair, steps))
+
+
+def _dispatch_bwd(tile_m, res, g):
+    row_of_pair, steps = res
+    dx = moe_unpermute(g, jnp.ones(row_of_pair.shape, jnp.float32),
+                       row_of_pair, steps, tile_m=tile_m)
+    return dx.astype(g.dtype), None, None, None
 
 
 dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-@jax.custom_vjp
-def combine(y, w, row_of_pair, pair_of_row):
-    """out[t] = sum_k w[t, k] * y[row_of_pair[t, k]] in float32 (a pair
-    whose expert is not held reads a zero row)."""
-    return jnp.einsum("tk,tkd->td", w, _rows(y, row_of_pair).astype(
-        jnp.float32))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def combine(y, w, row_of_pair, pair_of_row, steps, tile_m):
+    """out[t] = sum_k w[t, k] * y[row_of_pair[t, k]] in float32, by the
+    un-permute kernel (a pair whose expert is not held adds nothing and
+    is never read).  The backward is sized by the buffer: one XLA gather
+    of ``g``'s rows gives ``dy``, and ``dw`` is a per-ROW dot product
+    ``<g[token of row], y[row]>`` read back through a gather of scalars.
+    """
+    return moe_unpermute(y, w, row_of_pair, steps, tile_m=tile_m)
 
 
-def _combine_fwd(y, w, row_of_pair, pair_of_row):
-    return (combine(y, w, row_of_pair, pair_of_row),
+def _combine_fwd(y, w, row_of_pair, pair_of_row, steps, tile_m):
+    return (combine(y, w, row_of_pair, pair_of_row, steps, tile_m),
             (y, w, row_of_pair, pair_of_row))
 
 
-def _combine_bwd(res, g):
+def _combine_bwd(tile_m, res, g):
     y, w, row_of_pair, pair_of_row = res
-    k = w.shape[1]
-    tok = jnp.where(pair_of_row >= 0, pair_of_row // k, g.shape[0])
+    gt = _rows(g, _token_of_row(pair_of_row, w.shape[1], g.shape[0]))
     w_row = _rows(w.reshape(-1), pair_of_row)      # -1 -> fill 0
-    dy = (_rows(g, tok) * w_row[:, None]).astype(y.dtype)
-    dw = jnp.einsum("td,tkd->tk", g,
-                    _rows(y, row_of_pair).astype(jnp.float32))
-    return dy, dw, None, None
+    dy = (gt * w_row[:, None]).astype(y.dtype)
+    dw_row = jnp.sum(gt * y.astype(jnp.float32), axis=1)
+    return dy, _rows(dw_row, row_of_pair), None, None, None
 
 
 combine.defvjp(_combine_fwd, _combine_bwd)
@@ -346,7 +365,9 @@ class ExpertLayer(nn.Module):
                 (row_of_pair, pair_of_row, tile_expert, n_used, counts,
                  dropped) = plan_dispatch(idx, self.first_expert, e, tile_m,
                                           n_tiles)
-                xs = dispatch(xt, row_of_pair, pair_of_row)
+                steps = unpermute_steps(pair_of_row, self.top_k, b * n,
+                                        tile_m, e)
+                xs = dispatch(xt, row_of_pair, pair_of_row, steps, tile_m)
             if n_tiles < worst:
                 # The usual buffer is multiplied WHOLE, its empty tiles
                 # (zero rows) too: a capacity factor of 1.5, the price of
@@ -362,7 +383,8 @@ class ExpertLayer(nn.Module):
                 h = nn.silu(gmm(xs, w_gate)) * gmm(xs, w_up)
                 ys = gmm(h, w_down)
             with jax.named_scope("dsod.moe.combine"):
-                out = combine(ys, w, row_of_pair, pair_of_row)
+                out = combine(ys, w, row_of_pair, pair_of_row, steps,
+                              tile_m)
             return out, counts, dropped
 
         args = (xt, w, idx, w_gate, w_up, w_down)

@@ -24,8 +24,15 @@ The MXU sees bf16 operands with float32 accumulation; ``w`` may stay
 float32 outside (it is cast once per call) and ``dw`` comes back in
 float32 straight from the accumulators.
 
-The FLOPs and bytes these calls need are counted by the benchmark
-(``benchmark/harness/flops_lm.py``), not here.
+What surrounds these calls (``models/lfm2.py``): rows go INTO the
+buffer by XLA row gathers of the buffer's size (``dispatch`` forward,
+``combine`` backward) and come OUT of it, summed over a token's K
+choices, through ``pallas/moe_unpermute.py`` (``combine`` forward,
+``dispatch`` backward).  Neither is fused into the kernels here: the
+benchmark prices each of these calls as the plain product of its
+shapes (``benchmark/harness/flops_lm.py`` counts the FLOPs and bytes,
+not this file), and a gather or a combine inside them would change
+what that price means.
 """
 
 from __future__ import annotations

@@ -34,11 +34,11 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 _P = "distributed_sod_project_tpu.pallas."
-fc, fr, dfm, fl, fs, fa, vb, gm = (
+fc, fr, dfm, fl, fs, fa, vb, gm, mu = (
     importlib.import_module(_P + m)
     for m in ("fused_conv", "fused_resample", "dynamic_filter",
               "fused_loss", "fused_ssim", "flash_attention",
-              "vmem_budget", "grouped_matmul"))
+              "vmem_budget", "grouped_matmul", "moe_unpermute"))
 
 _S = collections.namedtuple("_S", "shape dtype")  # an argument's spec
 B = 2  # the kernels grid over images; the tile is what the compiler prices
@@ -147,6 +147,19 @@ def _gmm_args(a, b, tiles=24, experts=8):
     return (_S((tiles * 512, a), BF), _S((experts, a, b), F32),
             _S((tiles,), jnp.int32), _S((1,), jnp.int32))
 
+
+def _unpermute(rows, tokens=32768, top_k=4, d=2048):
+    """The expert layer's way back to token order at the cell's shapes:
+    the step list from ``pair_of_row`` and the kernel over it."""
+    def f(y, w, row_of_pair, pair_of_row):
+        steps = mu.unpermute_steps(pair_of_row, top_k, tokens, 512, 8)
+        return mu.moe_unpermute(y, w, row_of_pair, steps, tile_m=512,
+                                interpret=False)
+
+    return f, (_S((rows, d), BF), _S((tokens, top_k), F32),
+               _S((tokens, top_k), jnp.int32), _S((rows,), jnp.int32)), 1
+
+
 # name -> (fn, pytree of argument specs, custom calls expected)
 CASES = {
     # u2net_ds / basnet_ds / gatenet_vgg16: loss.fused_kernel=True
@@ -217,6 +230,10 @@ CASES = {
     "grouped_matmul.dx+dw@1792x2048": (
         jax.grad(lambda x, w, te, nu: _gmm(x, w, te, nu).astype(F32).sum(),
                  argnums=(0, 1)), _gmm_args(1792, 2048), 2),
+    # ... and the un-permute-and-sum out of the usual buffer (1.5 x the
+    # balanced share: 104 row tiles) and the worst-case one (264).
+    "moe_unpermute@53248": _unpermute(53248),
+    "moe_unpermute@135168": _unpermute(135168),
 }
 
 

@@ -6,6 +6,9 @@ widths, float32, seeded weights (benchmark/harness/weights_lm.py):
 - the four shares' expert outputs add up to the uncut reference layer;
 - no pair dropped when every token picks held experts only, zero
   contribution when none does; the grouped product against a loop;
+- the un-permute kernel (``combine`` / ``dispatch`` and their VJPs)
+  against ``jnp.take`` + ``einsum``, and no [T, K, D] array anywhere in
+  the expert layer's forward or backward;
 - the causal grouped-KV flash kernel (interpret mode), also at a length
   that is no multiple of the block;
 - the chunked loss equals the unchunked one;
@@ -31,6 +34,7 @@ from distributed_sod_project_tpu.models import lfm2 as lm
 from distributed_sod_project_tpu.pallas.flash_attention import \
     flash_attention_causal
 from distributed_sod_project_tpu.pallas.grouped_matmul import grouped_matmul
+from distributed_sod_project_tpu.pallas.moe_unpermute import unpermute_steps
 
 TINY = ["model.lm.vocab=512", "model.lm.hidden=64", "model.lm.heads=4",
         "model.lm.kv_heads=2", "model.lm.head_dim=16",
@@ -232,6 +236,135 @@ def test_grouped_matmul_matches_a_loop_over_experts(counts, a, b):
         _close(got * valid, want)
 
 
+def _routing(case, t, k, experts, held, rng):
+    """[t, k] distinct experts per token, held experts 0..held-1."""
+    idx = np.stack([rng.permutation(experts)[:k] for _ in range(t)])
+    if case == "no_pair_and_every_pair":
+        idx[0] = np.arange(held, held + k)       # token 0: none held
+        idx[1] = np.arange(k)                    # token 1: all K held
+        idx[t // 2:t // 2 + 70] = np.arange(held, held + k)  # a whole tile
+    elif case == "empty_expert":
+        idx[idx == 1] = experts - 1              # expert 1: padding only
+    elif case == "long_group":                   # most pairs to expert 0
+        idx[:, 0], idx[:, 1:] = 0, 1 + np.stack(
+            [rng.permutation(experts - 1)[:k - 1] for _ in range(t)])
+    return jnp.asarray(idx, jnp.int32)
+
+
+@pytest.mark.parametrize("case,t,k,held,tile_m,buffer,d", [
+    # a token with no held pair, one with all K held, a token tile with
+    # no held pair at all (it still writes its zeros)
+    ("no_pair_and_every_pair", 320, 2, 2, 16, "usual", 64),
+    # a group that is padding only, and padding rows in every other
+    ("empty_expert", 192, 2, 3, 32, "usual", 64),
+    # one expert's group over several row tiles and several chunks;
+    # three token tiles of 64 cut its run twice
+    ("long_group", 192, 2, 2, 16, "worst", 64),
+    # 128-row chunks, two to a row tile; five token tiles
+    ("random", 320, 4, 4, 256, "worst", 128),
+    # the worst-case buffer: more rows than pairs
+    ("random", 192, 2, 2, 8, "worst", 64),
+    # a width whose widest column tile does not divide it
+    ("random", 64, 2, 2, 16, "usual", 640)])
+def test_unpermute_kernel_matches_take_and_einsum(case, t, k, held, tile_m,
+                                                  buffer, d):
+    """``combine`` (value, dy, dw) and ``dispatch``'s backward (the
+    ``w = 1`` use) against ``jnp.take(mode="fill")`` + ``einsum``."""
+    experts = 8
+    rng = np.random.RandomState(t + d)
+    idx = _routing(case, t, k, experts, held, rng)
+    worst = lm.worst_case_tiles(t * k, held, tile_m)
+    needed = int(lm.tiles_needed(idx, 0, held, tile_m))
+    n_tiles = worst if buffer == "worst" else needed + 1
+    row_of_pair, pair_of_row, _, _, _, dropped = lm.plan_dispatch(
+        idx, 0, held, tile_m, n_tiles)
+    rows = n_tiles * tile_m
+    steps = unpermute_steps(pair_of_row, k, t, tile_m, held)
+    assert int(dropped) == 0
+    assert int(steps[2][0]) <= steps[0].shape[0]  # the list held them all
+    assert int(steps[0][-1]) + 1 >= 3 or d == 640  # several token tiles
+    take = lambda a, i: jnp.take(a, i, axis=0, mode="fill",  # noqa: E731
+                                 fill_value=0)
+    tok = jnp.where(pair_of_row >= 0, pair_of_row // k, t)
+    y = jnp.asarray(rng.randn(rows, d), jnp.float32) * (
+        pair_of_row >= 0)[:, None]
+    w = jnp.asarray(rng.rand(t, k), jnp.float32)
+    x = jnp.asarray(rng.randn(t, d), jnp.float32)
+    g_out = jnp.asarray(rng.randn(t, d), jnp.float32)
+    g_buf = jnp.asarray(rng.randn(rows, d), jnp.float32)
+
+    def combine(y, w):
+        return lm.combine(y, w, row_of_pair, pair_of_row, steps, tile_m)
+
+    def plain_combine(y, w):
+        return jnp.einsum("tk,tkd->td", w, take(y, row_of_pair))
+
+    def dispatch(x):
+        return lm.dispatch(x, row_of_pair, pair_of_row, steps, tile_m)
+
+    out = combine(y, w)
+    _close(out, plain_combine(y, w))
+    if case == "no_pair_and_every_pair":
+        assert float(jnp.max(jnp.abs(out[0]))) == 0.0
+        assert float(jnp.max(jnp.abs(out[t // 2:t // 2 + 64]))) == 0.0
+    got = jax.grad(lambda *a: jnp.sum(combine(*a) * g_out), (0, 1))(y, w)
+    want = jax.grad(lambda *a: jnp.sum(plain_combine(*a) * g_out),
+                    (0, 1))(y, w)
+    _close(got[0], want[0])
+    _close(got[1], want[1], 1e-4)
+    _close(dispatch(x), take(x, tok))
+    _close(jax.grad(lambda x: jnp.sum(dispatch(x) * g_buf))(x),
+           jax.grad(lambda x: jnp.sum(take(x, tok) * g_buf))(x))
+
+
+def _sub_jaxprs(params):
+    for v in params.values():
+        for item in (v if isinstance(v, (tuple, list)) else (v,)):
+            item = getattr(item, "jaxpr", item)
+            if hasattr(item, "eqns"):
+                yield item
+
+
+def _intermediates(jaxpr):
+    """Every value a jaxpr computes, inner jaxprs included — but not a
+    kernel's own body, whose values are blocks in VMEM."""
+    for eqn in jaxpr.eqns:
+        yield from (v.aval for v in eqn.outvars)
+        if eqn.primitive.name != "pallas_call":
+            for sub in _sub_jaxprs(eqn.params):
+                yield from _intermediates(sub)
+
+
+def test_expert_layer_builds_nothing_sized_pairs_by_hidden(setup):
+    """The guard that the pairs-sized gather does not come back: in the
+    gradient of an expert layer (both branches of its cond) only the
+    expert-ordered buffers reach T*K*D elements, and nothing has a
+    (token, choice) pair per row of a [.., D]- or [.., F]-wide array."""
+    cfg, _, v, _, _ = setup
+    c, x = cfg.model.lm, _x()
+    t, k, d = B * N, c.top_k, x.shape[-1]
+    variables = {"params": v["params"]["layer_1"]["moe"],
+                 "batch_stats": v["batch_stats"]["layer_1"]["moe"]}
+    layer = _experts(c)
+
+    def loss(params, x):
+        out, _ = layer.apply(dict(variables, params=params), x)
+        return jnp.sum(out * out)
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1)))(variables["params"], x)
+    tile_m = lm._tile_m(t * k, c.experts_held)
+    assert (t * k) % tile_m  # so whole row tiles tell a buffer from pairs
+    avals = [a for a in _intermediates(jaxpr.jaxpr) if hasattr(a, "shape")]
+    buffers = {a.shape[0] for a in avals
+               if a.shape[1:] == (d,) and a.shape[0] % tile_m == 0}
+    assert len(buffers) == 2  # the cond's usual and worst-case buffers
+    big = [a.shape for a in avals
+           if a.size >= t * k * d and a.shape[0] not in buffers]
+    per_pair = [a.shape for a in avals if a.ndim >= 2 and a.shape[-1] >= 16
+                and a.size // a.shape[-1] == t * k]
+    assert not big and not per_pair, (big, per_pair)
+
+
 # -- the kernels and the loss ------------------------------------------------
 
 def _plain_causal(q, k, v):
@@ -285,6 +418,7 @@ def test_chunked_loss_equals_the_unchunked_one(chunk):
 SCOPES = ("dsod.moe.route", "dsod.moe.experts", "dsod.moe.combine",
           "dsod.attn", "dsod.shortconv", "dsod.densemlp",
           "dsod.kernel.grouped_matmul", "dsod.kernel.grouped_matmul_dw",
+          "dsod.kernel.moe_unpermute",
           "dsod.kernel.flash_attention_causal",
           "dsod.kernel.flash_attention_causal_dq",
           "dsod.kernel.flash_attention_causal_dkv")
@@ -318,7 +452,8 @@ def test_image_models_step_names_none_of_them():
     paths = _scope_paths("basnet_ds")
     assert not [p for p in paths if re.search(
         r"dsod\.(moe|attn|shortconv|densemlp)\b"
-        r"|dsod\.kernel\.(grouped_matmul|flash_attention_causal)", p)]
+        r"|dsod\.kernel\.(grouped_matmul|flash_attention_causal"
+        r"|moe_unpermute)", p)]
 
 
 # -- data and the loop -------------------------------------------------------
