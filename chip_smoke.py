@@ -341,8 +341,7 @@ def phase_server(sz, tmp: str, t_start: float) -> None:
 
 
 def _train_setup(cfg, batch_size: int, hw: int, mesh, total_steps=1000):
-    """The real step builder on a resident batch (bench.py's recipe,
-    through the helper they share)."""
+    """The real step builder on a resident batch."""
     import jax
 
     from distributed_sod_project_tpu.parallel.engine import \

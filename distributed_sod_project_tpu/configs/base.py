@@ -134,7 +134,7 @@ class ModelConfig:
     # everything (min memory, +~1/3 FLOPs); "dots" keeps matmul/conv
     # outputs and recomputes elementwise (the usual best-MFU
     # compromise); "dots_no_batch" keeps only batch-free dots (weights'
-    # contractions).  A/B on hardware via bench.py --set.
+    # contractions).
     remat_policy: str = "none"  # none | dots | dots_no_batch
     # Attention core for the transformer zoo member (vit_sod only):
     # "xla" materializes the score matrix, "flash" runs the Pallas
@@ -146,18 +146,15 @@ class ModelConfig:
     # (pallas/dynamic_filter.py) — no ksize²-wide patch tensor in HBM.
     dlf_impl: str = "xla"  # xla | pallas
     # Decoder resample strategy (minet / hdfnet / gatenet / u2net —
-    # the four decoder users of the upsample+merge idiom).  Subsumes
-    # the DSOD_RESIZE_IMPL env knob (env still honored at the default
-    # for the recorded A/B legs; an explicit non-default value wins):
+    # the four decoder users of the upsample+merge idiom):
     #   fast  — slice/lerp fast paths, layout-stable interleave
     #           (default; all-XLA, jax.image.resize-exact)
-    #   xla   — force the generic jax.image.resize (A/B escape hatch)
-    #   convt — 2x upsamples as depthwise fractionally-strided convs
+    #   xla   — the generic jax.image.resize (the arm tests compare against)
     #   fused — Pallas fused resample-merge (pallas/fused_resample.py):
     #           upsample + add/concat as ONE VMEM pass per image.
     #           Knob-gated pending a hardware A/B win (the pre-committed
     #           non-XLA-default rule; not measured on a chip).
-    resample_impl: str = "fast"  # fast | xla | convt | fused
+    resample_impl: str = "fast"  # fast | xla | fused
     # Conv-block execution strategy (minet / hdfnet / gatenet / u2net —
     # every ConvBNAct in the four decoder families AND their VGG/ResNet
     # backbones routes through the one models/layers.py seam):

@@ -71,8 +71,8 @@ def u2net_ds() -> ExperimentConfig:
         name="u2net_ds",
         data=DataConfig(dataset="duts", image_size=(320, 320)),
         model=ModelConfig(name="u2net", backbone="none", sync_bn=True),
-        # fused_kernel: same 8-ish-output deep-supervision shape the
-        # +7.4% v5e win was measured on (basnet_ds, BASELINE.md).
+        # fused_kernel: the same deep-supervision shape as basnet_ds,
+        # below; no cell measures this config.
         loss=LossConfig(bce=1.0, iou=0.0, ssim=0.0, deep_supervision=True,
                         fused_kernel=True),
         optim=OptimConfig(optimizer="adamw", lr=1e-3, weight_decay=0.0),
@@ -88,9 +88,10 @@ def basnet_ds() -> ExperimentConfig:
         name="basnet_ds",
         data=DataConfig(dataset="duts", image_size=(320, 320)),
         model=ModelConfig(name="basnet", backbone="resnet34", sync_bn=True),
-        # fused_kernel: measured +7.4% img/s on v5e for exactly this
-        # config (BASELINE.md round-2 TPU session; exactness vs the
-        # unfused path is asserted in tests/test_pallas_loss.py).
+        # fused_kernel: on in the cell basnet_ds.train_b16, where the
+        # kernels read fused_ssim 1.95 + fused_loss 0.29 ms of a 214 ms
+        # step (ROADMAP D3); the off side is unmeasured on this tree.
+        # Exactness vs the unfused path: tests/test_pallas_loss.py.
         loss=LossConfig(bce=1.0, iou=1.0, ssim=1.0, deep_supervision=True,
                         fused_kernel=True),
         optim=OptimConfig(optimizer="adamw", lr=1e-3, weight_decay=0.0),

@@ -37,8 +37,8 @@ def _warn_s2d_fallback(shape: Tuple[int, ...]) -> None:
 
     get_logger().warning(
         "DSOD_STEM_IMPL=s2d requested but input H×W %s is odd — "
-        "falling back to the plain 7x7 stem.  Any benchmark tagged "
-        "stem=s2d at this size measured the PLAIN stem.", key)
+        "falling back to the plain 7x7 stem.  A run made under the "
+        "variable at this size measured the PLAIN stem.", key)
 
 
 class BasicBlock(nn.Module):
@@ -120,8 +120,8 @@ class ResNet(nn.Module):
         feats: List[jnp.ndarray] = []
         # DSOD_STEM_IMPL=s2d: compute the stem as space-to-depth + 4×4
         # conv (layers.SpaceToDepthStem) — same arithmetic, same param
-        # tree, TPU-friendlier tiling.  Env-knob A/B like
-        # DSOD_RESIZE_IMPL (bench.py keys baselines on it).
+        # tree, TPU-friendlier tiling.  Never timed on this tree
+        # (ROADMAP D3).
         from ...utils import envvars
 
         if envvars.read("DSOD_STEM_IMPL") == "s2d":
@@ -131,11 +131,9 @@ class ResNet(nn.Module):
                 skw = {k: v for k, v in kw.items() if k != "conv_impl"}
                 x = SpaceToDepthStem(64, name="ConvBNAct_0", **skw)(x, train)
             else:
-                # ADVICE r3: odd H or W forces the plain-stem fallback,
-                # but bench.py tags the baseline key with the env var —
-                # a silent fallback would record numbers labeled s2d
-                # that actually ran the 7x7 stem.  Warn loudly so a
-                # mislabeled A/B leg is visible in its log.
+                # Odd H or W forces the plain stem: say so in the log,
+                # or a run made under the variable would be taken for
+                # an s2d measurement it is not.
                 _warn_s2d_fallback(x.shape)
                 x = ConvBNAct(64, (7, 7), strides=2, **kw)(x, train)
         else:

@@ -103,9 +103,9 @@ class GateNet(nn.Module):
     axis_name: Optional[str] = None
     bn_momentum: float = 0.9
     # Decoder resample strategy (model.resample_impl): fast | xla |
-    # convt | fused.  GateNet's decoder reuses the upsampled state
-    # twice (gate input AND skip concat), so the fused arm runs the
-    # BARE single-pass upsample kernel (no merge epilogue) here.
+    # fused.  GateNet's decoder reuses the upsampled state twice (gate
+    # input AND skip concat), so the fused arm runs the BARE
+    # single-pass upsample kernel (no merge epilogue) here.
     resample_impl: str = "fast"
     # Conv-block strategy (model.conv_impl): xla | fused — see
     # layers.ConvBNAct; threaded to every conv block, backbone included.

@@ -126,7 +126,7 @@ class HDFNet(nn.Module):
     bn_momentum: float = 0.9
     dlf_impl: str = "xla"  # xla (im2col+einsum) | pallas (fused VMEM)
     # Decoder resample strategy (model.resample_impl):
-    # fast | xla | convt | fused — see layers.resample_merge.
+    # fast | xla | fused — see layers.resample_merge.
     resample_impl: str = "fast"
     # Conv-block strategy (model.conv_impl): xla | fused — see
     # layers.ConvBNAct; threaded to every conv block, both backbones

@@ -394,14 +394,11 @@ def _upsample_axis(x, axis: int, s: int):
     The interleave is LAYOUT-STABLE (round 5): the phases concatenate
     along the NEXT axis and one reshape merges the pair — by row-major
     identity ``(…, n, s·m, …) == (…, n, s, m, …) == (…, s·n, m, …)``
-    this produces exactly the same elements as the historical
-    ``stack(axis+1) + reshape`` form, but without inserting size-1 axes
-    XLA:TPU answers with dim-shuffled relayout copies (~1.25 ms per
-    call on ``bf16[64,160,64,160]`` in a July trace of another tree,
-    ~10% of the flagship step in data-formatting total; not
-    re-measured here).  Bit-identical either
-    way; ``DSOD_RESIZE_INTERLEAVE=stack`` keeps the old form as the A/B
-    arm ``tools/hlo_guard.py`` diffs against.
+    this produces exactly the elements of ``stack(axis+1) + reshape``,
+    without inserting size-1 axes XLA:TPU answers with dim-shuffled
+    relayout copies (~1.25 ms per call on ``bf16[64,160,64,160]`` in a
+    July trace of another tree, ~10% of the flagship step in
+    data-formatting total; not re-measured here).
     """
     import jax.lax as lax
 
@@ -422,11 +419,8 @@ def _upsample_axis(x, axis: int, s: int):
         f = jnp.asarray(f, x.dtype)
         phases.append(a * (1 - f) + b * f)
     out_shape = x.shape[:axis] + (n * s,) + x.shape[axis + 1:]
-    from ..utils import envvars
-
-    if (axis + 1 >= x.ndim
-            or envvars.read("DSOD_RESIZE_INTERLEAVE") == "stack"):
-        y = jnp.stack(phases, axis=axis + 1)  # historical form
+    if axis + 1 >= x.ndim:  # the last axis has no next one to fold into
+        y = jnp.stack(phases, axis=axis + 1)
     else:
         y = jnp.concatenate(phases, axis=axis + 1)  # layout-stable
     return y.reshape(out_shape)
@@ -464,50 +458,9 @@ def _downsample2_axis(x, axis: int):
     ], axis)
 
 
-def _upsample2_axis_convt(x, axis: int):
-    """Factor-2 bilinear upsample as a depthwise fractionally-strided
-    conv — the ``DSOD_RESIZE_IMPL=convt`` A/B arm.
-
-    Same numerics as :func:`_upsample_axis` (s=2): the two output
-    phases 0.25·x[i-1]+0.75·x[i] and 0.75·x[i]+0.25·x[i+1] are exactly
-    one length-4 kernel [.25,.75,.75,.25] cross-correlated over the
-    2×-lhs-dilated input; replicate-padding one row each side makes
-    the conv's implicit zero taps reproduce the edge clamping, and
-    VALID output length lands on 2n with no crop (derivation in the
-    round-4 notes, docs/PERFORMANCE.md).
-
-    Why it might win: the round-2 v5e trace shows the stack+reshape
-    interleave of ``_upsample_axis`` costing ~1.25 ms relayout copies
-    per call at b64 (data-formatting = 10% of the step) — a conv's
-    output needs no relayout.  Why it might lose: depthwise convs run
-    on the VPU with kernel overhead per channel.  Not measured on a
-    chip.
-    """
-    import jax.lax as lax
-
-    n = x.shape[axis]
-    first = lax.slice_in_dim(x, 0, 1, axis=axis)
-    last = lax.slice_in_dim(x, n - 1, n, axis=axis)
-    xp = jnp.concatenate([first, x, last], axis=axis)
-    c = x.shape[-1]
-    k = jnp.asarray([0.25, 0.75, 0.75, 0.25], x.dtype)
-    if axis == 1:
-        kern = jnp.tile(k.reshape(4, 1, 1, 1), (1, 1, 1, c))
-        dil = (2, 1)
-        pad = ((0, 0), (0, 0))
-    else:
-        kern = jnp.tile(k.reshape(1, 4, 1, 1), (1, 1, 1, c))
-        dil = (1, 2)
-        pad = ((0, 0), (0, 0))
-    return lax.conv_general_dilated(
-        xp, kern, window_strides=(1, 1), padding=pad,
-        lhs_dilation=dil, dimension_numbers=("NHWC", "HWIO", "NHWC"),
-        feature_group_count=c)
-
-
-RESAMPLE_IMPLS = ("fast", "xla", "convt", "fused")
+RESAMPLE_IMPLS = ("fast", "xla", "fused")
 # What a resample site can come out as.  With no arm named (``impl``
-# None, ``DSOD_RESIZE_IMPL`` unset) the route follows from the shape:
+# None) the route follows from the shape:
 # an exact-2x map of 8+ channels goes through the row-banded Pallas
 # kernel, a 1-channel map through two lane-dense matmuls, anything
 # else through the slice/lerp path.
@@ -545,26 +498,12 @@ def _count_route(route: str, x_shape, hw):
                    "slice/lerp path", tuple(x_shape), tuple(hw))
 
 
-def _resolve_resample_impl(impl: Optional[str]) -> str:
-    """Resolve the execution strategy for a resample call.
-
-    ``model.resample_impl`` (threaded through the decoder modules as an
-    explicit ``impl``) subsumes the ``DSOD_RESIZE_IMPL`` env knob: an
-    explicit non-default impl always wins; at the default (``None`` /
-    ``"fast"``) a set env var still selects the arm, so the
-    BASELINE.md measurement commands keep working unchanged.  With
-    neither, ``None`` resolves to ``"auto"`` (the route follows from
-    the shape) and an explicit ``"fast"`` stays the slice/lerp arm.
-    """
-    from ..utils import envvars
-
-    if impl in (None, "fast"):
-        impl = (envvars.read("DSOD_RESIZE_IMPL")
-                or ("auto" if impl is None else "fast"))
-    if impl != "auto" and impl not in RESAMPLE_IMPLS:
+def _check_resample_impl(impl: Optional[str]) -> None:
+    """``None`` (the route follows from the shape) or a named arm."""
+    if impl is not None and impl not in RESAMPLE_IMPLS:
         raise ValueError(
-            f"resample impl must be one of {RESAMPLE_IMPLS}, got {impl!r}")
-    return impl
+            f"resample impl must be None or one of {RESAMPLE_IMPLS}, "
+            f"got {impl!r}")
 
 
 def _interp_matrix(n: int, out_n: int) -> np.ndarray:
@@ -596,16 +535,13 @@ def _resize_lane_dense(x, hw: Tuple[int, int]):
     return y.astype(x.dtype)[..., None]
 
 
-def _fast_bilinear_axis(x, axis: int, out_n: int, impl: str = "fast"):
+def _fast_bilinear_axis(x, axis: int, out_n: int):
     """One axis of ``resize_to``'s fast path; None if unsupported."""
     n = x.shape[axis]
     if out_n == n:
         return x
     if out_n % n == 0:
-        s = out_n // n
-        if s == 2 and impl == "convt":
-            return _upsample2_axis_convt(x, axis)
-        return _upsample_axis(x, axis, s)
+        return _upsample_axis(x, axis, out_n // n)
     if n == 2 * out_n and n % 2 == 0:
         return _downsample2_axis(x, axis)
     return None
@@ -619,8 +555,8 @@ def resize_to(x, hw: Tuple[int, int], method: str = "bilinear",
     Bilinear integer-factor resizes — every resize the zoo performs —
     never reach ``jax.image.resize`` unless asked to (same numerics
     either way, asserted in tests/test_models.py).  With no ``impl``
-    and no ``DSOD_RESIZE_IMPL`` the route follows from the shape, one
-    pass over HBM in a layout that fills the lanes:
+    the route follows from the shape alone, one pass over HBM in a
+    layout that fills the lanes:
 
     - exact 2x, 8+ channels, a row band that fits VMEM — the Pallas
       kernel (``pallas/fused_resample.py``);
@@ -631,39 +567,35 @@ def resize_to(x, hw: Tuple[int, int], method: str = "bilinear",
     A named ``impl`` pins one arm:
 
     - ``fast``  — slice/lerp with the layout-stable interleave;
-    - ``xla``   — force the generic ``jax.image.resize`` everywhere
-      (the measurement/debug escape hatch behind the BASELINE.md
-      numbers, and the arm the tests compare against);
-    - ``convt`` — 2x upsamples as depthwise fractionally-strided convs;
+    - ``xla``   — the generic ``jax.image.resize`` everywhere (the arm
+      the tests compare against);
     - ``fused`` — the Pallas kernel where its rule admits the site,
       the ``fast`` path otherwise.
 
-    Every arm computes the same bilinear resample; ``fast``/``convt``
-    match bitwise, the others to dtype round-off (the kernel and the
-    lane-dense form lerp in f32, so under bf16 compute they are the
-    MORE precise arms, not bit-equal ones).
+    Every arm computes the same bilinear resample, to dtype round-off
+    (the kernel and the lane-dense form lerp in f32, so under bf16
+    compute they are the MORE precise arms, not bit-equal ones).
     """
-    impl = _resolve_resample_impl(impl)
+    _check_resample_impl(impl)
     hw = tuple(hw)
     if method == "bilinear" and impl != "xla":
         if hw == x.shape[1:3]:
             return x  # resizes nothing: no site
-        if impl in ("auto", "fused"):
+        if impl in (None, "fused"):
             from ..pallas.fused_resample import (fused_resample_available,
                                                  fused_upsample2)
 
             if fused_resample_available(x.shape, hw):
                 _count_route("kernel", x.shape, hw)
                 return fused_upsample2(x)
-        if (impl == "auto" and x.shape[3] == 1
+        if (impl is None and x.shape[3] == 1
                 and hw[0] % x.shape[1] == 0 and hw[1] % x.shape[2] == 0):
             _count_route("lane_dense", x.shape, hw)
             return _resize_lane_dense(x, hw)
         _count_route("fallback", x.shape, hw)
-        arm = impl if impl == "convt" else "fast"
-        h = _fast_bilinear_axis(x, 1, hw[0], arm)
+        h = _fast_bilinear_axis(x, 1, hw[0])
         if h is not None:
-            w = _fast_bilinear_axis(h, 2, hw[1], arm)
+            w = _fast_bilinear_axis(h, 2, hw[1])
             if w is not None:
                 return w
     elif hw != x.shape[1:3]:
@@ -703,14 +635,14 @@ def resample_merge(x, lateral, mode: str = "add", x_first: bool = True,
     lerps in f32 in-kernel where the fast arm lerps in bf16, so the
     arms agree to bf16 round-off (~1e-3), not bitwise.
     """
-    impl = _resolve_resample_impl(impl)
+    _check_resample_impl(impl)
     # With no arm named a CONCAT is left to XLA: it reads the two maps
     # straight into the conv that follows (the concat never exists in
     # HBM), where a kernel-written concat is a second copy of the
     # lateral that the backward keeps (+0.95 GiB on BASNet's step
     # compiled for a v5e — PERF.md, PR 26); the upsample alone still
     # takes the kernel, inside resize_to.
-    if impl == "fused" or (impl == "auto" and mode == "add"):
+    if impl == "fused" or (impl is None and mode == "add"):
         from ..pallas.fused_resample import (fused_resample_available,
                                              fused_upsample2_merge)
 
@@ -722,11 +654,8 @@ def resample_merge(x, lateral, mode: str = "add", x_first: bool = True,
             _count_route("kernel", x.shape, lateral.shape[1:3])
             return fused_upsample2_merge(x, lateral, mode=mode,
                                          x_first=x_first)
-        # Out of the merge kernel's envelope: hand the RESOLVED arm on
-        # and let resize_to pick and count the route itself (it notes a
-        # fallback at trace time) — rewriting 'fused' to 'fast', or
-        # 'auto' back to None, would re-enter env resolution and let a
-        # stray DSOD_RESIZE_IMPL hijack the site.
+        # Out of the merge kernel's envelope: resize_to picks and
+        # counts the route itself (it notes a fallback at trace time).
     up = resize_to(x, (lateral.shape[1], lateral.shape[2]), impl=impl)
     if mode == "add":
         return up + lateral

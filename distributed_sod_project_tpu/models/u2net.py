@@ -102,7 +102,7 @@ class U2Net(nn.Module):
     axis_name: Optional[str] = None
     bn_momentum: float = 0.9
     # Decoder resample strategy (model.resample_impl):
-    # fast | xla | convt | fused — see layers.resample_merge.
+    # fast | xla | fused — see layers.resample_merge.
     resample_impl: str = "fast"
     # Conv-block strategy (model.conv_impl): xla | fused — see
     # layers.ConvBNAct; threaded to every RSU conv block.

@@ -573,7 +573,7 @@ def prepare_train_step(cfg, model, tx, mesh: Mesh, schedule, state, *,
                        steps_per_dispatch: int = 1,
                        scale_hw: Optional[Tuple[int, int]] = None,
                        donate: bool = True, donate_batch: bool = False):
-    """One-call routing for bench.py / tools/dump_hlo.py: select the
+    """One-call routing for chip_smoke.py / tools/dump_hlo.py: select the
     preset, place the state (replicated, or rule/ZeRO-sharded for the
     GSPMD presets — Megatron tables for tp, empty table +
     ``fsdp_fallback_rule`` for fsdp), seed the int8_ef residual when

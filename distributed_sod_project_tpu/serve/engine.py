@@ -533,10 +533,9 @@ class InferenceEngine:
     @classmethod
     def from_random_init(cls, cfg, **kw) -> "InferenceEngine":
         """Randomly-initialised engine for a config — the
-        smoke/bench/loadgen posture where the serving machinery, not a
-        particular checkpoint, is under test.  The single bring-up used
-        by tools/serve.py --init-random AND bench.py --mode serve, so
-        the two can't drift apart."""
+        smoke/loadgen posture where the serving machinery, not a
+        particular checkpoint, is under test (tools/serve.py
+        --init-random, the fleet's replicas)."""
         from ..models import build_model
         from ..train import build_optimizer, create_train_state
 

@@ -92,7 +92,7 @@ def _wrap_state(variables, tx, ema: bool) -> TrainState:
 
 def random_init_setup(cfg, batch_size: int, hw: int,
                       total_steps: int = 1000):
-    """The measurement recipe bench.py and chip_smoke.py share: the
+    """chip_smoke.py's measurement recipe, kept beside the state: the
     config's model and optimizer, one host batch of seeded noise at
     ``batch_size`` x ``hw`` x ``hw`` (depth where the config uses it)
     and the TrainState initialised from it.  Returns ``(model, tx,

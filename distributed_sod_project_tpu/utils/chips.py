@@ -1,8 +1,8 @@
 """Published per-chip peaks, keyed by ``jax.Device.device_kind``.
 
-The ONE table every share-of-peak in the repo divides by (bench.py's
-MFU self-report, utils/capacity.py's live ledger, tools/roofline.py's
-offline model) and the scoped-VMEM rule reads
+The ONE table every share-of-peak in the package divides by
+(utils/capacity.py's live ledger, tools/roofline.py's offline
+model) and the scoped-VMEM rule reads
 (pallas/vmem_budget.py).  A kind that is not in the table is an error,
 never a default: a share of the wrong chip's peak is worse than none.
 """

@@ -1,26 +1,23 @@
 """Central registry of every ``DSOD_*`` environment knob.
 
-Thirteen PRs accreted ~16 env knobs, read wherever they were born —
-and twice (PR 3) a program-affecting one was forgotten from
-``bench.py::_PROGRAM_ENV_VARS``, silently contaminating A/B baseline
-keys.  This module is the single source of truth:
+Env knobs used to be read wherever they were born.  This module is
+the single source of truth:
 
 - every knob is declared ONCE here (name, default, whether it selects
   a different *compiled program*, one-line doc, where it is read);
 - every read goes through :func:`read` — the only place in the
   codebase allowed to touch ``os.environ`` for a ``DSOD_`` name
-  (``tools/dsodlint.py`` check ``env-coherence`` enforces both
-  directions: an unregistered read fails lint, and the
-  ``program_affecting`` rows must equal ``bench.py::_PROGRAM_ENV_VARS``
-  exactly);
+  (``tools/dsodlint.py`` check ``env-coherence``: a read that bypasses
+  it, or names no row here, fails lint);
 - the generated table in docs/PERFORMANCE.md ("Environment knobs") is
   rendered from this registry (:func:`markdown_table`), so the docs
   cannot drift from the code.
 
-``program_affecting=True`` means: two runs with different values of
-this var compile DIFFERENT XLA programs, so bench baselines must key
-on it (the PR-3 contamination lesson).  Host-side knobs (paths,
-process-pool method, fault injection) are False.
+``program_affecting=True`` is documentation for that table: two runs
+with different values of the variable compile DIFFERENT XLA programs,
+so a measurement has to say which value it ran under (ROADMAP D3: each
+such row is to become a constant, a shape rule or go).  Host-side
+knobs (paths, process-pool method, fault injection) are False.
 """
 
 from __future__ import annotations
@@ -38,15 +35,6 @@ class EnvVar(NamedTuple):
 
 
 _ENTRIES = (
-    EnvVar("DSOD_RESIZE_IMPL", None, True,
-           "Decoder resample execution strategy A/B override "
-           "(fast / convt / xla / pallas / pallas_dma); explicit "
-           "model.resample_impl wins.",
-           "models/layers.py"),
-    EnvVar("DSOD_RESIZE_INTERLEAVE", None, True,
-           "'stack' selects the historical stack+reshape upsample "
-           "interleave (relayout-copy A/B arm; tools/hlo_guard.py).",
-           "models/layers.py"),
     EnvVar("DSOD_STEM_IMPL", None, True,
            "'s2d' computes the ResNet stem as space-to-depth + 4x4 "
            "conv (same arithmetic, TPU-friendlier tiling).",
@@ -88,10 +76,6 @@ _ENTRIES = (
            "Any non-empty value disables the persistent XLA "
            "compilation cache setup.",
            "utils/platform.py"),
-    EnvVar("DSOD_BENCH_BASELINE", None, False,
-           "Path override for bench.py's baseline file (default: "
-           "bench_baseline.json next to bench.py).",
-           "bench.py"),
     EnvVar("DSOD_BISECT_EXPORT", None, False,
            "'1' makes tools/bisect_swin_eval.py stage scripts "
            "jax.export for TPU instead of executing (read inside the "
@@ -105,10 +89,6 @@ _ENTRIES = (
 
 REGISTRY: Dict[str, EnvVar] = {e.name: e for e in _ENTRIES}
 
-# The rows bench.py::_PROGRAM_ENV_VARS must mirror exactly (dsodlint
-# check env-coherence compares the two literals both ways).
-PROGRAM_AFFECTING = tuple(e.name for e in _ENTRIES if e.program_affecting)
-
 
 def spec(name: str) -> EnvVar:
     """The registry row for ``name``; loud KeyError for unregistered
@@ -118,8 +98,7 @@ def spec(name: str) -> EnvVar:
     except KeyError:
         raise KeyError(
             f"{name!r} is not a registered DSOD env var — add it to "
-            "utils/envvars.py (and to bench.py::_PROGRAM_ENV_VARS if "
-            "it selects a different compiled program)") from None
+            "utils/envvars.py") from None
 
 
 def read(name: str, env: Optional[dict] = None) -> Optional[str]:

@@ -275,7 +275,6 @@ _NO_CHIP_CLIS = {
     "test.py": ["test.py", "--ckpt-dir", "/nonexistent", "--device", "tpu"],
     "tools/serve.py": ["tools/serve.py", "--config", "minet_r50_dp",
                        "--init-random", "--device", "tpu", "--port", "0"],
-    "bench.py": ["bench.py", "--device", "tpu", "--steps", "1"],
     "chip_smoke.py": ["chip_smoke.py"],
 }
 

@@ -294,65 +294,6 @@ def test_train_loop_emits_data_plane_metrics(tmp_path):
     assert "data_starved_ms" in seen
 
 
-def test_bench_baseline_file_seeds_then_compares(tmp_path, capsys,
-                                                 monkeypatch):
-    """--baseline-file: first run records, second run reports
-    vs_recorded; --fail-below gates with exit code 3."""
-    import bench
-
-    monkeypatch.setenv("DSOD_BENCH_BASELINE", str(tmp_path / "side.json"))
-    bfile = tmp_path / "data_baseline.json"
-    args = ["--device", "cpu", "--mode", "data", "--steps", "2",
-            "--warmup", "0", "--batch-per-chip", "2", "--image-size",
-            "16", "--set", "data.synthetic_size=8",
-            "--set", "data.num_workers=0",
-            "--baseline-file", str(bfile)]
-    assert bench.main(args) == 0
-    out1 = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert out1.get("recorded") is True
-    recorded = json.loads(bfile.read_text())
-    assert len(recorded) == 1
-
-    assert bench.main(args) == 0
-    out2 = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert "vs_recorded" in out2 and out2["vs_recorded"] > 0
-
-    # An absurd floor turns the soft report into a gate.
-    assert bench.main(args + ["--fail-below", "1e9"]) == 3
-
-
-def test_bench_key_tags_s2d_fallback_honestly(tmp_path, capsys,
-                                              monkeypatch):
-    """ADVICE r3: DSOD_STEM_IMPL=s2d at an odd size runs the plain
-    stem — the baseline key must say so instead of recording numbers
-    labeled s2d."""
-    import bench
-
-    monkeypatch.setenv("DSOD_BENCH_BASELINE", str(tmp_path / "b.json"))
-    monkeypatch.setenv("DSOD_STEM_IMPL", "s2d")
-    rc = bench.main([
-        "--device", "cpu", "--mode", "data", "--steps", "1", "--warmup",
-        "0", "--batch-per-chip", "2", "--image-size", "17",
-        "--set", "data.synthetic_size=4", "--set", "data.num_workers=0"])
-    assert rc == 0
-    capsys.readouterr()
-    keys = list(json.loads((tmp_path / "b.json").read_text()))
-    assert len(keys) == 1
-    assert "DSOD_STEM_IMPL=s2d[plain-stem-fallback]" in keys[0]
-
-    # Even size: the honest tag is the plain env value.
-    monkeypatch.setenv("DSOD_BENCH_BASELINE", str(tmp_path / "b2.json"))
-    rc = bench.main([
-        "--device", "cpu", "--mode", "data", "--steps", "1", "--warmup",
-        "0", "--batch-per-chip", "2", "--image-size", "16",
-        "--set", "data.synthetic_size=4", "--set", "data.num_workers=0"])
-    assert rc == 0
-    capsys.readouterr()
-    keys = list(json.loads((tmp_path / "b2.json").read_text()))
-    assert "DSOD_STEM_IMPL=s2d" in keys[0]
-    assert "fallback" not in keys[0]
-
-
 def test_decode_procs_refused_under_skip_budget_guard():
     """Worker processes would privatize the GuardedDataset counters,
     breaking the bounded-corruption invariant — the loader must refuse

@@ -51,11 +51,6 @@ def make_clean_tree(root):
 
             return os.environ.get(name)
     ''')
-    _write(root, "bench.py", '''
-        _PROGRAM_ENV_VARS = (
-            "DSOD_KNOB",
-        )
-    ''')
     _write(root, "tools/metrics_inventory.json", json.dumps({
         "fleet": {"dsod_serve_ok_total": "counter",
                   "dsod_serve_dyn_total": "counter"}}))
@@ -269,37 +264,6 @@ def test_env_coherence_direct_read_and_unregistered(clean_root):
     assert "unregistered:DSOD_SNEAKY" in keys  # and the name is unknown
 
 
-def test_env_coherence_program_affecting_mismatch_both_ways(clean_root):
-    # registry says program-affecting, bench.py doesn't list it
-    _write(clean_root, "distributed_sod_project_tpu/utils/envvars.py", '''
-        class EnvVar:
-            def __init__(self, *a):
-                pass
-
-        _ENTRIES = (
-            EnvVar("DSOD_KNOB", None, True, "doc", "x.py"),
-            EnvVar("DSOD_NEWPROG", None, True, "doc", "x.py"),
-        )
-
-        def read(name, env=None):
-            import os
-
-            return os.environ.get(name)
-    ''')
-    rc, summary, _ = run_lint(clean_root, "--fail-on-new")
-    assert rc == 2 and "DSOD_NEWPROG" in _keys(summary)
-    # bench.py lists a var the registry doesn't mark program-affecting
-    _write(clean_root, "bench.py", '''
-        _PROGRAM_ENV_VARS = (
-            "DSOD_KNOB",
-            "DSOD_NEWPROG",
-            "DSOD_HOSTY",
-        )
-    ''')
-    rc, summary, _ = run_lint(clean_root, "--fail-on-new")
-    assert rc == 2 and "DSOD_HOSTY" in _keys(summary)
-
-
 def test_metrics_coherence_both_directions(clean_root):
     # a literal the inventory doesn't know
     _write(clean_root, "distributed_sod_project_tpu/serve/bad_metric.py",
@@ -405,9 +369,10 @@ def test_baseline_compare_fail_on_new_and_fixed(clean_root):
 
 def test_never_seed_baseline_from_crashed_run(clean_root):
     baseline = os.path.join(clean_root, "baseline.json")
-    # a checker crash (bench.py gone → env-coherence raises) must not
-    # write a baseline, not even with --update-baseline
-    os.remove(os.path.join(clean_root, "bench.py"))
+    # a checker crash (the registry gone → env-coherence raises) must
+    # not write a baseline, not even with --update-baseline
+    os.remove(os.path.join(
+        clean_root, "distributed_sod_project_tpu/utils/envvars.py"))
     rc, summary, _ = run_lint(clean_root, "--update-baseline",
                               baseline=baseline)
     assert rc == 1

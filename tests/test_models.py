@@ -66,8 +66,8 @@ def test_resnet_s2d_stem_matches_plain_stem(monkeypatch):
                                    rtol=1e-4, atol=1e-4)
 
     # Odd spatial size: falls back to the plain stem (no s2d possible)
-    # — and WARNS, because bench.py tags baseline keys with the env var
-    # and a silent fallback would mislabel an A/B leg (ADVICE r3).
+    # — and WARNS: a run made under the variable would otherwise be
+    # taken for an s2d measurement it is not (ADVICE r3).
     # Fully-convolutional → reuse the same params, no third init.
     from distributed_sod_project_tpu.models.backbones import resnet
 
@@ -358,34 +358,3 @@ def test_resize_fast_path_matches_jax_image(shape, hw):
     # Relative: an 8x up-resize cotangent sums 64 contributions, so the
     # f32 round-off scales with |g|.
     assert jnp.allclose(g_ref, g_got, rtol=1e-5, atol=1e-5)
-
-
-def test_resize_convt_variant_matches_fast_path(monkeypatch):
-    """DSOD_RESIZE_IMPL=convt (round 4): the depthwise
-    fractionally-strided-conv formulation of the 2x upsample must
-    match the slice/lerp fast path (itself jax.image.resize-exact) in
-    values AND gradients — it exists purely as the relayout-copy A/B
-    arm (docs/PERFORMANCE.md roofline lever #2), so any numeric drift
-    would invalidate the A/B."""
-    from distributed_sod_project_tpu.models.layers import resize_to
-
-    for shape in [(2, 10, 12, 3), (1, 7, 7, 5)]:
-        hw = (shape[1] * 2, shape[2] * 2)
-        x = jax.random.normal(jax.random.key(1), shape)
-
-        monkeypatch.delenv("DSOD_RESIZE_IMPL", raising=False)
-        ref = resize_to(x, hw)
-        g_ref = jax.grad(lambda v: jnp.sum(jnp.sin(resize_to(v, hw))))(x)
-
-        monkeypatch.setenv("DSOD_RESIZE_IMPL", "convt")
-        got = resize_to(x, hw)
-        g_got = jax.grad(lambda v: jnp.sum(jnp.sin(resize_to(v, hw))))(x)
-
-        assert jnp.abs(ref - got).max() < 2e-6, shape
-        assert jnp.allclose(g_ref, g_got, rtol=1e-5, atol=1e-5), shape
-
-    # Non-2x factors fall back to the slice/lerp path under convt too.
-    monkeypatch.setenv("DSOD_RESIZE_IMPL", "convt")
-    x = jax.random.normal(jax.random.key(2), (1, 5, 5, 2))
-    ref = jax.image.resize(x, (1, 20, 20, 2), "bilinear")
-    assert jnp.abs(resize_to(x, (20, 20)) - ref).max() < 2e-6

@@ -9,12 +9,12 @@ Coverage contract (ISSUE 3 acceptance):
   GateNet (bare upsample);
 - custom-VJP gradients checked against the XLA path's autodiff;
 - execution-strategy invariance of train METRICS across
-  resample_impl={xla,convt,fused} (mirrors the backend-invariance
+  resample_impl={fast,xla,fused} (mirrors the backend-invariance
   posture of tests/test_data_plane.py: the strategy knob must never
   change the training stream);
 - out-of-envelope shapes fall back to the plain path bit-compatibly;
-- the knob is loud on non-decoder models and subsumes
-  DSOD_RESIZE_IMPL;
+- the knob is loud on non-decoder models, an unknown arm raises, and
+  with no arm named the route follows from the shape alone;
 - the Mosaic TPU lowering runs end-to-end via jax.export (no chip).
 """
 
@@ -145,39 +145,64 @@ def test_fused_merge_validates_shapes():
         fr.fused_upsample2_merge(x, _rand(1, 8, 8, 8, seed=9), "mul")
 
 
-def test_interleave_stack_arm_bit_identical(monkeypatch):
-    """The layout-stable concat interleave and the historical
-    stack+reshape arm (DSOD_RESIZE_INTERLEAVE=stack) are the same
-    permutation of the same lerp values — bit-identical, which is why
-    flipping the default needed no numerics A/B (tools/hlo_guard.py
-    diffs their op counts instead)."""
-    x = _rand(2, 5, 6, 8, seed=10)
-    monkeypatch.delenv("DSOD_RESIZE_INTERLEAVE", raising=False)
-    concat_arm = resize_to(x, (15, 18))  # non-2x: generic interleave
-    up2 = resize_to(x, (10, 12))
-    monkeypatch.setenv("DSOD_RESIZE_INTERLEAVE", "stack")
-    stack_arm = resize_to(x, (15, 18))
-    up2_stack = resize_to(x, (10, 12))
-    assert jnp.array_equal(concat_arm, stack_arm)
-    assert jnp.array_equal(up2, up2_stack)
+def test_unknown_arm_raises_and_a_named_arm_is_kept():
+    """``impl`` is None (the route follows from the shape) or one of
+    RESAMPLE_IMPLS, which then keeps its path whatever the shape would
+    have chosen; anything else raises at both entry points."""
+    from distributed_sod_project_tpu.models import layers
+
+    assert layers.RESAMPLE_IMPLS == ("fast", "xla", "fused")
+    x, lat = _rand(8, 4, 4, 16, seed=10), _rand(8, 8, 8, 16, seed=11)
+    for bad in ("banana", "auto", ""):
+        with pytest.raises(ValueError, match="resample impl"):
+            resize_to(x, (8, 8), impl=bad)
+        with pytest.raises(ValueError, match="resample impl"):
+            resample_merge(x, lat, mode="add", impl=bad)
+    ref = jax.image.resize(x, (8, 8, 8, 16), "bilinear")
+    for impl, route in (("fast", "fallback"), ("xla", "fallback"),
+                        ("fused", "kernel")):
+        with layers.resample_routes() as routes:
+            up = resize_to(x, (8, 8), impl=impl)
+            merged = resample_merge(x, lat, mode="add", impl=impl)
+        assert routes[route] == 2 and sum(routes.values()) == 2, (impl, routes)
+        assert float(jnp.abs(up - ref).max()) <= 1e-5
+        assert float(jnp.abs(merged - (ref + lat)).max()) <= 1e-5
 
 
-def test_resample_impl_subsumes_env(monkeypatch):
-    """model.resample_impl subsumes DSOD_RESIZE_IMPL: env selects the
-    arm at the default, an explicit non-default impl wins over env."""
-    from distributed_sod_project_tpu.models.layers import \
-        _resolve_resample_impl
+# One site per route: what the shape shows decides, nothing else.
+_ROUTE_SITES = {
+    "kernel": ((8, 4, 4, 16), (8, 8)),       # exact 2x, 8+ channels
+    "lane_dense": ((8, 4, 4, 1), (16, 8)),   # 1 channel, whole factors
+    "fallback": ((8, 4, 4, 4), (12, 8)),     # 3x by 2x, 4 channels
+}
 
-    monkeypatch.delenv("DSOD_RESIZE_IMPL", raising=False)
-    assert _resolve_resample_impl(None) == "auto"  # route by shape
-    assert _resolve_resample_impl("fast") == "fast"
-    assert _resolve_resample_impl("convt") == "convt"
+
+@pytest.mark.parametrize("route", sorted(_ROUTE_SITES))
+def test_route_is_a_function_of_the_shape_alone(route, monkeypatch):
+    """The variables that used to pick a resample arm from the shell
+    are dead: set to the values that once changed the program, they
+    leave a site's route, its traced program and its values as they
+    are.  (The one place that still names them.)"""
+    from distributed_sod_project_tpu.models import layers
+
+    shape, hw = _ROUTE_SITES[route]
+    x = _rand(*shape, seed=12)
+
+    def site():
+        with layers.resample_routes() as routes:
+            jaxpr = str(jax.make_jaxpr(lambda v: resize_to(v, hw))(x))
+        return routes, jaxpr, resize_to(x, hw)
+
+    for name in ("DSOD_RESIZE_IMPL", "DSOD_RESIZE_INTERLEAVE"):
+        monkeypatch.delenv(name, raising=False)
+    routes, jaxpr, out = site()
+    assert routes == {r: int(r == route) for r in layers.RESAMPLE_ROUTES}
     monkeypatch.setenv("DSOD_RESIZE_IMPL", "xla")
-    assert _resolve_resample_impl(None) == "xla"    # env wins at default
-    assert _resolve_resample_impl("fast") == "xla"
-    assert _resolve_resample_impl("fused") == "fused"  # explicit wins
-    with pytest.raises(ValueError, match="resample impl"):
-        _resolve_resample_impl("banana")
+    monkeypatch.setenv("DSOD_RESIZE_INTERLEAVE", "stack")
+    routes_set, jaxpr_set, out_set = site()
+    assert routes_set == routes
+    assert jaxpr_set == jaxpr
+    assert jnp.array_equal(out_set, out)
 
 
 def test_registry_resample_impl_is_loud_on_non_decoder_models():
@@ -246,7 +271,7 @@ def test_train_metrics_invariant_across_resample_impls():
              "mask": (rng.rand(16, 16, 16, 1) > 0.5).astype(np.float32)}
     mesh = make_mesh(MeshConfig(data=-1), jax.devices()[:2])
     metrics = {}
-    for impl in ("fast", "xla", "convt", "fused"):
+    for impl in ("fast", "xla", "fused"):
         model = _MiniDecoder(impl=impl)
         tx, sched = build_optimizer(OptimConfig(lr=0.1, warmup_steps=0), 10)
         state = create_train_state(jax.random.key(0), model, tx, batch)
@@ -255,7 +280,7 @@ def test_train_metrics_invariant_across_resample_impls():
             schedule=sched, donate=False)
         _, m = step(state, batch)
         metrics[impl] = {k: float(v) for k, v in m.items()}
-    for impl in ("xla", "convt", "fused"):
+    for impl in ("xla", "fused"):
         for k, ref in metrics["fast"].items():
             got = metrics[impl][k]
             assert got == pytest.approx(ref, rel=2e-4, abs=2e-5), (
@@ -278,7 +303,7 @@ def test_zoo_forward_invariant_across_resample_impls(cfg_name, model_name):
            if model_name == "hdfnet" else None)
     cfg = get_config(cfg_name)
     outs = {}
-    for impl in ("fast", "xla", "convt", "fused"):
+    for impl in ("fast", "xla", "fused"):
         mc = dataclasses.replace(
             cfg.model, resample_impl=impl, sync_bn=False,
             compute_dtype="float32",
@@ -286,7 +311,7 @@ def test_zoo_forward_invariant_across_resample_impls(cfg_name, model_name):
         m = build_model(mc)
         v = m.init(jax.random.key(0), img, dep, train=False)
         outs[impl] = m.apply(v, img, dep, train=False)[0]
-    for impl in ("xla", "convt", "fused"):
+    for impl in ("xla", "fused"):
         assert float(jnp.abs(outs[impl] - outs["fast"]).max()) <= 1e-5
 
 
@@ -457,13 +482,12 @@ def test_lane_dense_logit_resize_matches_jax_image_resize(factor, dtype):
         x.astype(jnp.float32), (2, h, 96, 1), "bilinear"))
 
 
-def test_route_follows_the_shape_and_named_arms_pin(monkeypatch):
+def test_route_follows_the_shape_and_named_arms_pin():
     """No arm named: kernel / lane-dense / slice-lerp by what the shape
-    shows; a named arm (argument or DSOD_RESIZE_IMPL) keeps its path,
-    and a site that resizes nothing is no site."""
+    shows; a named arm keeps its path, and a site that resizes nothing
+    is no site."""
     from distributed_sod_project_tpu.models import layers
 
-    monkeypatch.delenv("DSOD_RESIZE_IMPL", raising=False)
     wide, one, few = (_rand(8, 4, 4, c, seed=15) for c in (16, 1, 4))
     with layers.resample_routes() as routes:
         resize_to(wide, (8, 8))            # exact 2x, 16 ch: kernel
@@ -482,9 +506,8 @@ def test_route_follows_the_shape_and_named_arms_pin(monkeypatch):
     with layers.resample_routes() as routes:
         resize_to(wide, (8, 8), impl="fast")
         resize_to(one, (8, 8), impl="fast")
-        monkeypatch.setenv("DSOD_RESIZE_IMPL", "xla")
-        resize_to(wide, (8, 8))
-        resize_to(one, (8, 8))
+        resize_to(wide, (8, 8), impl="xla")
+        resize_to(one, (8, 8), impl="xla")
     assert routes == {"kernel": 0, "lane_dense": 0, "fallback": 4}
 
 
@@ -503,14 +526,13 @@ def basnet64():
     return model, img, init(jax.random.key(0), img[:1])
 
 
-def test_basnet_route_counter_reads_9_6_0(basnet64, monkeypatch, caplog):
+def test_basnet_route_counter_reads_9_6_0(basnet64, caplog):
     """BASNet's nine 2x upsample+concat sites (5 decoder, 4 refine) take
     the kernel, six of its seven side logits the lane-dense form (the
     seventh is already full size), nothing falls back — at 64 px as at
     320 — and the tally is one log line."""
     from distributed_sod_project_tpu.models import layers
 
-    monkeypatch.delenv("DSOD_RESIZE_IMPL", raising=False)
     model, img, variables = basnet64
     from distributed_sod_project_tpu.utils.logging import get_logger
 
@@ -537,7 +559,9 @@ def test_basnet_route_counter_reads_9_6_0(basnet64, monkeypatch, caplog):
 def test_basnet_default_route_matches_xla_arm(basnet64, monkeypatch):
     """BASNet at 64 px, float32 compute: the 8 outputs and the
     parameter gradient of a scalar readout with the default route
-    (kernel + lane-dense) against ``DSOD_RESIZE_IMPL=xla``.  Both arms
+    (kernel + lane-dense) against the ``xla`` arm pinned at every site
+    (BASNet threads no ``impl``; the test hands its module the seam's
+    two functions with the arm bound).  Both arms
     are the same bilinear resample in float32, so they differ by
     float32 round-off carried through ~60 conv layers: outputs within
     2e-4 of the largest output, every leaf's gradient within 1e-3 of
@@ -545,6 +569,10 @@ def test_basnet_default_route_matches_xla_arm(basnet64, monkeypatch):
     rounding per resample, ~4e-3 relative: the kernel lerps in f32
     where the slice/lerp path lerps in bf16 — asserted per site in
     ``test_banded_kernel_matches_xla_arm_fwd_and_vjp``.)"""
+    import functools
+
+    from distributed_sod_project_tpu.models import basnet
+
     model, img, variables = basnet64
 
     def run():
@@ -557,9 +585,10 @@ def test_basnet_default_route_matches_xla_arm(basnet64, monkeypatch):
             jax.value_and_grad(readout, has_aux=True))(variables["params"])
         return outs, grads
 
-    monkeypatch.delenv("DSOD_RESIZE_IMPL", raising=False)
     outs, grads = run()
-    monkeypatch.setenv("DSOD_RESIZE_IMPL", "xla")
+    for seam in (resize_to, resample_merge):
+        monkeypatch.setattr(basnet, seam.__name__,
+                            functools.partial(seam, impl="xla"))
     ref_outs, ref_grads = run()
     scale = max(float(jnp.abs(o).max()) for o in ref_outs)
     for o, ref in zip(outs, ref_outs):

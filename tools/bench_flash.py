@@ -24,8 +24,8 @@ import numpy as np
 def time_fn(f, *args, iters=20):
     out = f(*args)  # compile + warm
     jax.block_until_ready(out)
-    # Host fetch of a value depending on the result (see bench.py's
-    # sync note).
+    # Host fetch of a value depending on the result: returns only
+    # when the device has finished it.
     def sync(o):
         leaf = jax.tree_util.tree_leaves(o)[0]
         return float(leaf.sum())

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Bisect the swin_sod EVAL TPU-worker crash (round-2 session 3).
 
-``bench.py --config swin_sod --mode eval`` crashed the v5e worker
+A timed eval loop of ``swin_sod`` crashed the v5e worker
 twice ("kernel fault", the 2026-07-31 zoo sweep); the train step is fine,
 and eval of every other zoo member is fine.  The train/eval program
 differences are small and enumerable, so each stage below isolates one
@@ -16,8 +16,6 @@ of them, IN A SUBPROCESS, smallest program first:
                   train step's forward, minus grad) — isolates the
                   running-average-BN vs batch-BN program difference
   eval_step       make_eval_step (shard_map + sigmoid)
-  eval_xla_resize eval_step with DSOD_RESIZE_IMPL=xla — isolates the
-                  round-2 slice/lerp resize fast path
   eval_metrics_nofuse  the crasher's program with XLA fusion passes
                   disabled — implicates/exonerates a fused kernel
                   (the scatter-metrics fusion suspect) in one stage
@@ -216,7 +214,6 @@ _STAGES = [
     ("fwd", _FWD, {}, None),
     ("fwd_trainflag", _FWD_TRAINFLAG, {}, None),
     ("eval_step", _EVAL_STEP, {}, None),
-    ("eval_xla_resize", _EVAL_STEP, {"DSOD_RESIZE_IMPL": "xla"}, None),
     ("eval_metrics_nofuse", _EVAL_METRICS, {"XLA_FLAGS": _NOFUSE_FLAGS},
      None),
     ("eval_metrics", _EVAL_METRICS, {}, None),

@@ -23,10 +23,7 @@ everything is ``ast`` over source text):
   mutated under ``with self._lock`` (the PR-7 check-then-put and PR-8
   inflight-gauge bug class).
 - ``env-coherence`` — every ``DSOD_*`` env read goes through
-  ``utils/envvars.py::read`` and every name read is registered there;
-  the registry's ``program_affecting`` rows must equal
-  ``bench.py::_PROGRAM_ENV_VARS`` exactly, both directions (the PR-3
-  baseline-key contamination bug class).
+  ``utils/envvars.py::read`` and every name read is registered there.
 - ``metrics-coherence`` — every ``dsod_*`` metric-family literal in
   source exists in ``tools/metrics_inventory.json`` and every
   inventory family is constructible from source literals (the static
@@ -72,7 +69,7 @@ CHECKS = ("traced-purity", "lock-discipline", "env-coherence",
 
 # What the suite scans (repo-relative).  Tests are deliberately out of
 # scope: fixture code violates invariants on purpose.
-SCAN_ROOTS = ("distributed_sod_project_tpu", "tools", "bench.py")
+SCAN_ROOTS = ("distributed_sod_project_tpu", "tools")
 
 PKG = "distributed_sod_project_tpu"
 
@@ -83,9 +80,8 @@ PKG = "distributed_sod_project_tpu"
 # default posture is that the step builders stay pure.
 TRACED_SEAMS: Set[Tuple[str, str]] = {
     # Build-time-only read: the flash block shapes are static ints
-    # baked into the program at trace time, and both vars are
-    # registered program-affecting (utils/envvars.py) so the bench
-    # baseline key and AOT program caches stay coherent.
+    # baked into the program at trace time (both vars are registered
+    # program-affecting in utils/envvars.py).
     (f"{PKG}/pallas/flash_attention.py", "_env_block"),
 }
 
@@ -132,7 +128,6 @@ TERMINAL_BOOKING_CALLS = {"inc_submitted", "inc_shed", "inc_response",
 TRACE_ENTRY_NAMES = {"jit", "shard_map", "scan", "pallas_call"}
 
 _ENVVARS_FILE = f"{PKG}/utils/envvars.py"
-_BENCH_FILE = "bench.py"
 _INVENTORY = os.path.join(REPO, "tools", "metrics_inventory.json")
 
 _PRAGMA_RE = re.compile(
@@ -755,39 +750,20 @@ def check_lock_discipline(files: Dict[str, SourceFile], report) -> None:
 
 # -- checker: env-coherence --------------------------------------------
 
-def _registry_entries(files: Dict[str, SourceFile]
-                      ) -> Dict[str, bool]:
-    """utils/envvars.py → {name: program_affecting}."""
+def _registry_names(files: Dict[str, SourceFile]) -> Set[str]:
+    """The names utils/envvars.py registers."""
     sf = files.get(_ENVVARS_FILE)
     if sf is None:
         raise RuntimeError(f"{_ENVVARS_FILE} not found")
-    out: Dict[str, bool] = {}
+    out: Set[str] = set()
     for node in ast.walk(sf.tree):
         if isinstance(node, ast.Call) and \
                 _callee_tail(node.func) == "EnvVar" and node.args:
             name = node.args[0]
-            prog = node.args[2] if len(node.args) > 2 else None
             if isinstance(name, ast.Constant) and \
                     isinstance(name.value, str):
-                out[name.value] = bool(
-                    prog.value if isinstance(prog, ast.Constant) else False)
+                out.add(name.value)
     return out
-
-
-def _bench_program_vars(files: Dict[str, SourceFile]) -> Set[str]:
-    sf = files.get(_BENCH_FILE)
-    if sf is None:
-        raise RuntimeError(f"{_BENCH_FILE} not found")
-    for node in sf.tree.body:
-        if isinstance(node, ast.Assign):
-            for t in node.targets:
-                if isinstance(t, ast.Name) and \
-                        t.id == "_PROGRAM_ENV_VARS":
-                    return {
-                        el.value for el in ast.walk(node.value)
-                        if isinstance(el, ast.Constant)
-                        and isinstance(el.value, str)}
-    raise RuntimeError("bench.py::_PROGRAM_ENV_VARS not found")
 
 
 def _module_str_consts(sf: SourceFile) -> Dict[str, str]:
@@ -802,29 +778,14 @@ def _module_str_consts(sf: SourceFile) -> Dict[str, str]:
 
 
 def check_env_coherence(files: Dict[str, SourceFile], report) -> None:
-    registry = _registry_entries(files)
-    bench_vars = _bench_program_vars(files)
+    registry = _registry_names(files)
 
-    for name in registry:
+    for name in sorted(registry):
         if not re.fullmatch(r"DSOD_[A-Z0-9_]+", name):
             report(Finding("env-coherence", _ENVVARS_FILE, 1,
                            "REGISTRY", name,
                            f"registry entry {name!r} is not a DSOD_* "
                            "name"))
-    prog = {n for n, p in registry.items() if p}
-    for name in sorted(prog - bench_vars):
-        report(Finding("env-coherence", _BENCH_FILE, 1,
-                       "_PROGRAM_ENV_VARS", name,
-                       f"program-affecting registry entry {name} is "
-                       "missing from bench.py::_PROGRAM_ENV_VARS "
-                       "(baseline-key contamination)"))
-    for name in sorted(bench_vars - prog):
-        report(Finding("env-coherence", _BENCH_FILE, 1,
-                       "_PROGRAM_ENV_VARS", name,
-                       f"bench.py::_PROGRAM_ENV_VARS entry {name} is "
-                       "not a program_affecting registry row in "
-                       "utils/envvars.py"))
-
     for rel, sf in files.items():
         consts = _module_str_consts(sf)
 

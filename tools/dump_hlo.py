@@ -44,10 +44,7 @@ def dump(config_name: str, out_dir: str, n_devices: int = 8,
     times per run and needs just the StableHLO text.  ``overrides`` are
     extra ``section.field=value`` config overrides applied on top of
     the standard virtual-mesh shrink — e.g. pin an execution-strategy
-    arm (``model.resample_impl=convt``) to dump/diff arm-specific
-    programs.  (The ``fast`` resample arm cannot be pinned this way:
-    it is the env-subsumed default, so hlo_guard pins its arms via the
-    env vars instead.)
+    arm (``model.conv_impl=fused``) to dump/diff arm-specific programs.
 
     ``post_opt=True`` also compiles and writes the POST-optimization
     HLO (``<config>.hlo_post.txt``).  GSPMD presets (fsdp/tp) need it:

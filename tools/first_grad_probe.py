@@ -14,9 +14,10 @@ the ``first_steps.json`` a full run of the SAME seed left in
     chiprun -- python tools/first_grad_probe.py --seed 2600000103 \\
         --against first_steps.json --set loss.fused_kernel=false
 
-The variant is whatever the environment (``DSOD_RESIZE_IMPL=xla``) and
-``--set`` make of the program; one process per variant (three ``fit()``
-calls in one process exceed the host's memory).  Needs the chip.
+The variant is whatever ``--set`` (and a program-affecting ``DSOD_*``
+variable, ``utils/envvars.py``) makes of the program; one process per
+variant (three ``fit()`` calls in one process exceed the host's
+memory).  Needs the chip.
 """
 
 from __future__ import annotations

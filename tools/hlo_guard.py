@@ -1,55 +1,37 @@
 #!/usr/bin/env python
-"""HLO relayout guard — catch data-formatting regressions at t1 time.
+"""HLO structure guard — catch lowered-program regressions at t1 time.
 
-The round-2 v5e trace put ~10% of the flagship step in data-formatting
-relayout copies, and the round-4 roofline named the upsample
-interleave's ``stack+reshape`` form as the biggest single source
-(~1.25 ms dim-shuffled ``bf16[64,160,64,160]`` copies per call).  The
-layout-stable interleave (models/layers.py::_upsample_axis, round 5)
-removes the size-1-axis insertions that force those copies — but
-nothing stops a future change from quietly re-introducing them, and a
-TPU window is needed to SEE them in a trace.
+A TPU window is needed to SEE a relayout copy or a collective in a
+trace; what causes them is already countable on the CPU.  This tool
+lowers a train step (reusing tools/dump_hlo.py, lowering only — no
+compile) and counts, per arm:
 
-This tool makes the regression visible on CPU, per PR: it lowers the
-flagship train step (reusing tools/dump_hlo.py, lowering only — no
-compile) and counts the data-formatting ops in the pre-optimization
-StableHLO — ``reshape``, ``transpose`` and ``broadcast_in_dim`` — for
-two arms of the interleave:
+- the data-formatting ops in the pre-optimization StableHLO —
+  ``reshape``, ``transpose`` and ``broadcast_in_dim`` — for the
+  conv-block arms, on a small carrier (the fused arm lowers every
+  Pallas kernel in interpret mode — minutes of tracing at flagship
+  size):
 
-- ``fast``        — the layout-stable concat-in-next-axis form
-                    (the default path);
-- ``fast_stack``  — the historical stack+reshape form
-                    (``DSOD_RESIZE_INTERLEAVE=stack``).
+  - ``conv_xla``    — model.conv_impl=xla (the default; its counts
+                      drifting is a byte-identity regression canary);
+  - ``conv_fused``  — model.conv_impl=fused (the Pallas conv-stage
+                      kernels; counts pin the fused seam's lowered
+                      structure);
 
-Round 14 adds the conv-block arms on a smaller carrier (the fused arm
-lowers every Pallas kernel in interpret mode — minutes of tracing at
-flagship size):
-
-- ``conv_xla``    — model.conv_impl=xla (the default; its counts
-                    drifting is a byte-identity regression canary);
-- ``conv_fused``  — model.conv_impl=fused (the Pallas conv-stage
-                    kernels; counts pin the fused seam's lowered
-                    structure).
+- the gradient collectives of the rules engine's arms on the flagship
+  (``COMM_ARMS`` below, with the invariants the tool itself asserts,
+  exit 1).
 
 Pre-optimization StableHLO is stable across machines (the same reason
 dump_hlo.py diffs it), so the counts are checked into
 ``tools/hlo_copy_baseline.json`` and every run prints a ONE-LINE JSON
-delta against that baseline — recorded, non-gating in tools/t1.sh
-(pass ``--fail-on-increase`` to gate locally).  Invariants the tool
-itself asserts (exit 1):
-
-- the layout-stable arm counts strictly FEWER formatting ops than the
-  stack arm (the guard's reason to exist);
-
-Counting in pre-opt StableHLO is deliberate: the TPU relayout copies
-appear only after XLA:TPU's layout assignment, which CPU cannot run —
-but every one of them is *caused by* a reshape/transpose pattern that
-is already visible (and countable) before optimization.  Fewer
-formatting ops in ≈ fewer relayout copies out; the exact ms stays a
-chip measurement (not measured on a chip).
+delta against that baseline per group — recorded, non-gating in
+tools/t1.sh (pass ``--fail-on-increase`` to gate locally).  The counts
+are a proxy from before there was a trace: what a resample or a conv
+costs on the chip is read from the benchmark's ``dsod.*`` stages.
 
 Usage:
-    python tools/hlo_guard.py                      # print delta line
+    python tools/hlo_guard.py                      # print delta lines
     python tools/hlo_guard.py --update-baseline    # re-seed the file
     python tools/hlo_guard.py --fail-on-increase   # gate (local use)
 """
@@ -69,21 +51,10 @@ _BASELINE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
 
 # What counts as a data-formatting op in pre-opt StableHLO.  reshape +
 # transpose are the relayout-copy feeders; broadcast_in_dim is counted
-# too because jnp.stack may lower its size-1-axis insertion either way.
+# too because a size-1-axis insertion may lower either way.
 _FORMATTING = ("reshape", "transpose", "broadcast_in_dim")
 
-# The two interleave arms of the SAME default resample path.  Each arm
-# pins EVERY resample-affecting env var (None = must be unset): the
-# agenda scripts export DSOD_RESIZE_INTERLEAVE / DSOD_RESIZE_IMPL for
-# their own A/B legs, and an inherited value would silently lower the
-# same arm twice and trip the fast<stack invariant with a false alarm.
-ARMS = {
-    "fast": {"DSOD_RESIZE_INTERLEAVE": None, "DSOD_RESIZE_IMPL": None},
-    "fast_stack": {"DSOD_RESIZE_INTERLEAVE": "stack",
-                   "DSOD_RESIZE_IMPL": None},
-}
-
-# Conv-block arms (round 14): the SAME formatting-op counts per
+# Conv-block arms (round 14): formatting-op counts per
 # model.conv_impl arm, lowered on a smaller carrier than the flagship —
 # the fused arm lowers the Pallas kernels in interpret mode on CPU
 # (grid loops and im2col slicing all visible as countable ops), which
@@ -96,15 +67,11 @@ CONV_ARMS = {
     "conv_xla": (),
     "conv_fused": ("model.conv_impl=fused",),
 }
-# Resample env vars pinned (unset) around the conv dumps for the same
-# reason as ARMS: an inherited A/B export must not contaminate counts.
-_PINNED_ENV = ("DSOD_RESIZE_INTERLEAVE", "DSOD_RESIZE_IMPL")
-
 # Gradient-collective arms (round 18, ISSUE 18 acceptance): the rules
 # engine's bucketed allreduce fuses each backward-ordered bucket into
 # ONE flat 1-D psum (parallel/rules.py::bucketed_pmean), so the
 # ``stablehlo.all_reduce`` count is the countable structure signal —
-# on the FLAGSHIP config (same carrier as ARMS):
+# on the FLAGSHIP config:
 #
 # - ``comm_mono``     — comm_bucket_mb=0: the monolithic ``lax.pmean``
 #                       spelling, one all_reduce PER GRADIENT LEAF in
@@ -117,8 +84,9 @@ _PINNED_ENV = ("DSOD_RESIZE_INTERLEAVE", "DSOD_RESIZE_IMPL")
 # "≥2 psum buckets at default bucket size" acceptance check — the only
 # all_reduce delta between the two arms IS the extra buckets), and
 # mono > bucketed (bucket fusion actually collapsed the per-leaf
-# reduces).  Counts are recorded in the same baseline with the same
-# never-persist-on-failed-invariant discipline.
+# reduces).  Counts from a run whose own invariant failed are never
+# written to the baseline: a corrupt seed would make every later
+# comparison report delta 0 against garbage.
 #
 # Round 18 adds the pod-scale arms:
 #
@@ -169,63 +137,19 @@ def count_formatting_ops(stablehlo_text: str) -> dict:
     return counts
 
 
-def dump_arm_counts(config: str, out_dir: str, n_devices: int,
-                    image_size: int) -> dict:
-    """Lower the config's train step once per arm; return
-    {arm: counts}."""
-    from dump_hlo import dump  # tools/ sibling (path set above)
-
-    results = {}
-    for arm, env in ARMS.items():
-        saved = {k: os.environ.get(k) for k in env}
-        for k, v in env.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-        try:
-            # NOTE: the env pinning above is the ONLY effective guard
-            # for the 'fast' arm — 'fast' is the env-subsumed default,
-            # so a config override `model.resample_impl=fast` cannot
-            # out-pin an exported DSOD_RESIZE_IMPL (by design:
-            # layers._resolve_resample_impl).  Do not trim ARMS on the
-            # strength of a config override.
-            paths = dump(config, os.path.join(out_dir, arm),
-                         n_devices=n_devices, image_size=image_size,
-                         compile_cost=False)
-        finally:
-            for k, v in saved.items():
-                if v is None:
-                    os.environ.pop(k, None)
-                else:
-                    os.environ[k] = v
-        with open(paths["stablehlo"]) as f:
-            results[arm] = count_formatting_ops(f.read())
-    return results
-
-
 def dump_conv_arm_counts(config: str, out_dir: str, n_devices: int,
                          image_size: int) -> dict:
     """Lower the conv-arm carrier once per model.conv_impl arm (config
-    overrides, not env) with the resample env pinned unset; return
-    {arm: counts}."""
+    overrides); return {arm: counts}."""
     from dump_hlo import dump  # tools/ sibling (path set above)
 
     results = {}
-    saved = {k: os.environ.get(k) for k in _PINNED_ENV}
-    for k in _PINNED_ENV:
-        os.environ.pop(k, None)
-    try:
-        for arm, overrides in CONV_ARMS.items():
-            paths = dump(config, os.path.join(out_dir, arm),
-                         n_devices=n_devices, image_size=image_size,
-                         compile_cost=False, overrides=overrides)
-            with open(paths["stablehlo"]) as f:
-                results[arm] = count_formatting_ops(f.read())
-    finally:
-        for k, v in saved.items():
-            if v is not None:
-                os.environ[k] = v
+    for arm, overrides in CONV_ARMS.items():
+        paths = dump(config, os.path.join(out_dir, arm),
+                     n_devices=n_devices, image_size=image_size,
+                     compile_cost=False, overrides=overrides)
+        with open(paths["stablehlo"]) as f:
+            results[arm] = count_formatting_ops(f.read())
     return results
 
 
@@ -243,8 +167,8 @@ def _count_collectives(stablehlo_text: str) -> dict:
 def dump_comm_arm_counts(config: str, out_dir: str, n_devices: int,
                          image_size: int) -> dict:
     """Lower the flagship step once per gradient-collective arm (config
-    overrides on the rules engine) with the resample env pinned unset;
-    return {arm: {'all_reduce': n, ..., 'total': n}}.  The hierarchical
+    overrides on the rules engine); return {arm: {'all_reduce': n,
+    ..., 'total': n}}.  The hierarchical
     arm lowers on a 4-device virtual mesh (data_hosts=2 needs ≥2 chips
     per host — main() sizes the device pool up front so this works
     in-process); op COUNTS in the traced program are device-count
@@ -253,50 +177,41 @@ def dump_comm_arm_counts(config: str, out_dir: str, n_devices: int,
     from dump_hlo import dump  # tools/ sibling (path set above)
 
     results = {}
-    saved = {k: os.environ.get(k) for k in _PINNED_ENV}
-    for k in _PINNED_ENV:
-        os.environ.pop(k, None)
-    try:
-        for arm, overrides in COMM_ARMS.items():
-            paths = dump(config, os.path.join(out_dir, arm),
-                         n_devices=n_devices, image_size=image_size,
-                         compile_cost=False, overrides=overrides)
-            with open(paths["stablehlo"]) as f:
-                results[arm] = _count_collectives(f.read())
-        for arm, overrides in COMM_HIER_ARMS.items():
-            paths = dump(config, os.path.join(out_dir, arm),
-                         n_devices=max(n_devices, _HIER_DEVICES),
-                         image_size=image_size,
-                         compile_cost=False, overrides=overrides)
-            with open(paths["stablehlo"]) as f:
-                results[arm] = _count_collectives(f.read())
-        for arm, overrides in COMM_FSDP_ARMS.items():
-            paths = dump(config, os.path.join(out_dir, arm),
-                         n_devices=n_devices, image_size=image_size,
-                         compile_cost=False, overrides=overrides,
-                         post_opt=True)
-            with open(paths["hlo_post"]) as f:
-                txt = f.read()
-            counts = {kind.replace("-", "_"): txt.count(f"{kind}(")
-                      for kind in _POST_COLLECTIVES}
-            counts["total"] = counts["all_gather"]
-            results[arm] = counts
-    finally:
-        for k, v in saved.items():
-            if v is not None:
-                os.environ[k] = v
+    for arm, overrides in COMM_ARMS.items():
+        paths = dump(config, os.path.join(out_dir, arm),
+                     n_devices=n_devices, image_size=image_size,
+                     compile_cost=False, overrides=overrides)
+        with open(paths["stablehlo"]) as f:
+            results[arm] = _count_collectives(f.read())
+    for arm, overrides in COMM_HIER_ARMS.items():
+        paths = dump(config, os.path.join(out_dir, arm),
+                     n_devices=max(n_devices, _HIER_DEVICES),
+                     image_size=image_size,
+                     compile_cost=False, overrides=overrides)
+        with open(paths["stablehlo"]) as f:
+            results[arm] = _count_collectives(f.read())
+    for arm, overrides in COMM_FSDP_ARMS.items():
+        paths = dump(config, os.path.join(out_dir, arm),
+                     n_devices=n_devices, image_size=image_size,
+                     compile_cost=False, overrides=overrides,
+                     post_opt=True)
+        with open(paths["hlo_post"]) as f:
+            txt = f.read()
+        counts = {kind.replace("-", "_"): txt.count(f"{kind}(")
+                  for kind in _POST_COLLECTIVES}
+        counts["total"] = counts["all_gather"]
+        results[arm] = counts
     return results
 
 
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--config", default="minet_r50_dp",
-                   help="flagship by default — the config the roofline "
-                        "levers were derived on")
+                   help="carrier of the gradient-collective arms: the "
+                        "flagship by default")
     p.add_argument("--image-size", type=int, default=64,
                    help="small-but-even lowering size: every decoder "
-                        "resample stays an exact factor-2, so the "
-                        "interleave op pattern matches 320px")
+                        "resample stays an exact factor-2")
     p.add_argument("--devices", type=int, default=2,
                    help="virtual CPU mesh size (lowering only; 2 keeps "
                         "the guard fast while exercising the sharded "
@@ -311,9 +226,6 @@ def main(argv=None) -> int:
     p.add_argument("--conv-image-size", type=int, default=32,
                    help="conv-arm lowering size (even, so decoder "
                         "shapes stay exact factor-2)")
-    p.add_argument("--no-conv-arms", action="store_true",
-                   help="skip the conv_impl arm dumps (resample arms "
-                        "only — the pre-r14 behavior)")
     p.add_argument("--no-comm-arms", action="store_true",
                    help="skip the gradient-collective arm dumps "
                         "(round 18: rules-engine bucketed allreduce)")
@@ -336,79 +248,14 @@ def main(argv=None) -> int:
         "--xla_force_host_platform_device_count="
         f"{max(args.devices, _HIER_DEVICES)}")
 
-    tmp = None
-    out_dir = args.out
-    if out_dir is None:
-        import tempfile
-
-        # Cleaned up on exit: each arm's flagship StableHLO dump is
-        # multi-MB and t1.sh runs this on every pass.
-        tmp = tempfile.TemporaryDirectory(prefix="hlo_guard_")
-        out_dir = tmp.name
-    try:
-        arm_counts = dump_arm_counts(args.config, out_dir, args.devices,
-                                     args.image_size)
-    finally:
-        if tmp is not None:
-            tmp.cleanup()
-
     rc = 0
-    fast, stack = arm_counts["fast"], arm_counts["fast_stack"]
-    if fast["total"] >= stack["total"]:
-        # The guard's core invariant: the layout-stable interleave must
-        # emit strictly fewer formatting ops than the stack form.
-        print(f"hlo_guard: layout-stable arm NOT fewer formatting ops "
-              f"({fast['total']} vs {stack['total']})", file=sys.stderr)
-        rc = 1
-
-    baseline = None
+    baseline = {}
     if os.path.exists(args.baseline):
         with open(args.baseline) as f:
             baseline = json.load(f)
-    key = f"{args.config}@{args.image_size}px"
-    if rc != 0:
-        # Never persist counts from a run whose own invariant failed —
-        # a corrupt seed would make every later comparison report
-        # delta 0 against garbage, permanently masking the regression.
-        print(f"hlo_guard: invariant failed — NOT seeding/updating "
-              f"baseline for {key}", file=sys.stderr)
-        print(json.dumps({
-            "metric": f"hlo_formatting_ops[{key}]",
-            "arms": {arm: c["total"] for arm, c in arm_counts.items()},
-            "invariant_failed": True,
-        }), flush=True)
-        return rc
-    if args.update_baseline or baseline is None or key not in baseline:
-        baseline = baseline or {}
-        baseline[key] = arm_counts
-        with open(args.baseline, "w") as f:
-            json.dump(baseline, f, indent=2, sort_keys=True)
-            f.write("\n")
-        recorded = True
-        delta = {arm: 0 for arm in arm_counts}
-    else:
-        recorded = False
-        delta = {arm: arm_counts[arm]["total"]
-                 - baseline[key].get(arm, {}).get("total", 0)
-                 for arm in arm_counts}
-        if args.fail_on_increase and any(d > 0 for d in delta.values()):
-            rc = rc or 2
 
-    # The one-line JSON delta window reports track per PR.
-    print(json.dumps({
-        "metric": f"hlo_formatting_ops[{key}]",
-        "arms": {arm: c["total"] for arm, c in arm_counts.items()},
-        "detail": arm_counts,
-        "delta_vs_baseline": delta,
-        "stack_minus_fast": stack["total"] - fast["total"],
-        **({"recorded": True} if recorded else {}),
-    }), flush=True)
-
-    if args.no_conv_arms:
-        return rc
-
-    # -- conv_impl arms (round 14): same recorded-delta discipline on
-    #    the conv-arm carrier; conv_xla drifting is a byte-identity
+    # -- conv_impl arms (round 14): recorded on first contact, delta-
+    #    compared after; conv_xla drifting is a byte-identity
     #    regression canary, conv_fused drifting means the fused seam's
     #    lowered structure changed.
     tmp2 = None
@@ -416,6 +263,8 @@ def main(argv=None) -> int:
     if out_dir2 is None:
         import tempfile
 
+        # Cleaned up on exit: a StableHLO dump is multi-MB and t1.sh
+        # runs this on every pass.
         tmp2 = tempfile.TemporaryDirectory(prefix="hlo_guard_conv_")
         out_dir2 = tmp2.name
     try:

@@ -62,8 +62,8 @@ from distributed_sod_project_tpu.utils.chips import (  # noqa: E402
 
 # This is an OFFLINE model of one named chip, whatever machine prints
 # it: the v5e the flagship was sized for.  Its peaks are the table's
-# (utils/chips.py) — the same rows the live ledger and bench.py divide
-# by when they run on that chip.  DCN is 16x slower than ICI, which is
+# (utils/chips.py) — the same rows the live ledger divides by when it
+# runs on that chip.  DCN is 16x slower than ICI, which is
 # WHY the hierarchical reduction moves only 1/chips of the bytes
 # across it.
 MODELED_CHIP = "TPU v5 lite"

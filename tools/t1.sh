@@ -5,7 +5,7 @@
 # roadmap promises (the pytest line below is verbatim ROADMAP.md).
 #
 # Smoke-budget audit (PR 13, re-audited PR 20): the non-gating smokes
-# below carry their own wrappers (420+900+420+300+420+420+420+420+420+
+# below carry their own wrappers (900+420+300+420+420+420+420+420+
 # 420+420+300+900+720+720+600+780+600 ≈ 160 min worst case) — far past the
 # 870 s the GATING pytest line gets.  Each wrapper deliberately EXCEEDS
 # its tool's documented internal budget contract (serve_smoke sums to
@@ -27,8 +27,6 @@ fi
 if [ -n "${DSOD_T1_FAST:-}" ]; then
   echo "== DSOD_T1_FAST set: skipping all non-gating smokes =="
 else
-echo "== host data-plane smoke (recorded, non-gating) =="
-bash tools/bench_data.sh || echo "bench_data smoke failed (non-gating)"
 echo "== HLO relayout guard incl. conv_impl + grad-collective comm arms (recorded, non-gating) =="
 timeout -k 10 900 env JAX_PLATFORMS=cpu python tools/hlo_guard.py \
   || echo "hlo_guard smoke failed (non-gating)"
