@@ -172,8 +172,8 @@ class ModelConfig:
     #           non-XLA-default rule; not measured on a chip).
     conv_impl: str = "xla"  # xla | fused
     pretrained: Optional[str] = None  # .npz from tools/port_torch_weights.py
-    # The token model's shape (model.name=lfm2 only; remat is per layer
-    # there).
+    # The token model's shape (model.name=lfm2 only; its remat is per layer
+    # and keeps the values models/lfm2.py::REMAT_SAVES names, no policy field).
     lm: LMConfig = dataclasses.field(default_factory=LMConfig)
     # Structural deep supervision for models where aux heads are
     # optional add-ons (vit_sod's mid-depth head).  U²-Net/BASNet side

@@ -25,7 +25,11 @@ Pre-norm residual blocks, ``h += op(RMSNorm(h))``, ``h += ffn(RMSNorm(h))``:
 
 Compute is ``dtype`` (bf16) with float32 parameters; the router, every
 norm's statistics, the rotary angles and the softmax are float32.
-Each block is rematerialised when ``remat`` is on.
+When ``remat`` is on each block's backward recomputes the block from its
+input, EXCEPT the values named in :data:`REMAT_SAVES`, which are kept
+from the forward: the flash kernel's residuals (q, k, v, output, lse)
+and the routing plan (PERF.md section 6, PR 31: cheap to hold, costly
+to make twice).
 
 Device scopes (PERF.md section 3): the whole stack is ``dsod.encoder``;
 inside it ``dsod.shortconv``, ``dsod.attn``, ``dsod.densemlp``,
@@ -36,6 +40,7 @@ final norm is ``dsod.heads``.
 
 from __future__ import annotations
 
+import collections
 import functools
 from typing import Any
 
@@ -43,8 +48,10 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
-from ..pallas.flash_attention import flash_attention_causal
+from ..pallas.flash_attention import (CAUSAL_RESIDUAL_NAMES,
+                                      flash_attention_causal)
 from ..pallas.grouped_matmul import TILE_M, grouped_matmul
 from ..pallas.moe_unpermute import moe_unpermute, unpermute_steps
 
@@ -336,14 +343,27 @@ class ExpertLayer(nn.Module):
         w_down = self.param("down", init, (e, f, d), self.param_dtype)
         bias = self.variable("batch_stats", "expert_bias", jnp.zeros,
                              (self.experts,), jnp.float32).value
-        tile_m = _tile_m(b * n * self.top_k, e)
-        worst = worst_case_tiles(b * n * self.top_k, e, tile_m)
+        tokens, pairs_all = b * n, b * n * self.top_k
+        tile_m = _tile_m(pairs_all, e)
+        worst = worst_case_tiles(pairs_all, e, tile_m)
         # The usual buffer: 1.5 x the balanced share of the pairs.  The
         # worst case is four times the balanced share, and everything
         # around the grouped products (gathers, SwiGLU, cotangents) costs
         # by the buffer's rows, not by the rows used.
-        usual = -(-(b * n * self.top_k * 3 * e) // (2 * self.experts * tile_m)
-                  ) + e
+        usual = min(worst, -(-(pairs_all * 3 * e)
+                             // (2 * self.experts * tile_m)) + e)
+
+        def plan_for(idx, n_tiles):
+            """-> (plan, counts, dropped): where the pairs of ``idx`` go
+            in a buffer of ``n_tiles`` row tiles, and the un-permute
+            kernel's step list for it."""
+            (row_of_pair, pair_of_row, tile_expert, n_used, counts,
+             dropped) = plan_dispatch(idx, self.first_expert, e, tile_m,
+                                      n_tiles)
+            steps = unpermute_steps(pair_of_row, self.top_k, idx.shape[0],
+                                    tile_m, e)
+            return ((row_of_pair, pair_of_row, tile_expert, n_used, steps),
+                    counts, dropped)
 
         with jax.named_scope("dsod.moe.route"):
             logits = nn.Dense(
@@ -351,62 +371,125 @@ class ExpertLayer(nn.Module):
                 param_dtype=self.param_dtype, name="router",
                 precision=lax.Precision.HIGHEST)(xt.astype(jnp.float32))
             s = jax.nn.sigmoid(logits)
+            # The plan for the buffer that usually holds the pairs is made
+            # HERE, once, above the cond, and kept for the backward with
+            # the chosen experts and their scores (``REMAT_SAVES``): under
+            # 2 MiB a layer at the published size, against a top-k, a
+            # sort, running counts and two gathers of scalars (XLA's run
+            # at ~10 ns an element) made twice.
             _, idx = lax.top_k(s + lax.stop_gradient(bias), self.top_k)
-            idx = idx.astype(jnp.int32)
-            w = jnp.take_along_axis(s, idx, -1)
+            idx = checkpoint_name(idx.astype(jnp.int32), "plan")
+            w, plan, counts, dropped = jax.tree_util.tree_map(
+                lambda t: checkpoint_name(t, "plan"),
+                (jnp.take_along_axis(s, idx, -1), *plan_for(idx, usual)))
             if self.norm_topk_prob:
                 w = w / (jnp.sum(w, -1, keepdims=True) + 1e-6)
             w = w * self.routed_scaling_factor
 
-        def experts(n_tiles, xt, w, idx, w_gate, w_up, w_down):
-            """This chip's part of the sum through a buffer of
-            ``n_tiles`` row tiles -> (out [T, D] f32, counts, dropped)."""
+        def experts(plan, whole, xt, w, w_gate, w_up, w_down):
+            """This chip's part of the sum for the tokens of ``xt``
+            through the buffer ``plan`` lays out -> out [T, D] f32."""
+            row_of_pair, pair_of_row, tile_expert, n_used, steps = plan
             with jax.named_scope("dsod.moe.route"):
-                (row_of_pair, pair_of_row, tile_expert, n_used, counts,
-                 dropped) = plan_dispatch(idx, self.first_expert, e, tile_m,
-                                          n_tiles)
-                steps = unpermute_steps(pair_of_row, self.top_k, b * n,
-                                        tile_m, e)
                 xs = dispatch(xt, row_of_pair, pair_of_row, steps, tile_m)
-            if n_tiles < worst:
+            if whole:
                 # The usual buffer is multiplied WHOLE, its empty tiles
                 # (zero rows) too: a capacity factor of 1.5, the price of
-                # static shapes.  Skipping them (as the worst-case buffer
-                # must, at four times the balanced share) would make the
-                # step's time follow the routing of whatever weights it
-                # is given: +-0.3 % across seeds at random weights
-                # (PERF.md section 6, PR 28), for ~6 % of the step.
-                n_used = jnp.full((1,), n_tiles, jnp.int32)
+                # static shapes.  Skipping them (as a buffer sized for a
+                # worst case must) would make the step's time follow the
+                # routing of whatever weights it is given: +-0.3 % across
+                # seeds at random weights (PERF.md section 6, PR 28), for
+                # ~6 % of the step.
+                n_used = jnp.full((1,), tile_expert.shape[0], jnp.int32)
             with jax.named_scope("dsod.moe.experts"):
                 gmm = lambda a, wt: grouped_matmul(  # noqa: E731
                     a, wt, tile_expert, n_used, tile_m=tile_m)
                 h = nn.silu(gmm(xs, w_gate)) * gmm(xs, w_up)
                 ys = gmm(h, w_down)
             with jax.named_scope("dsod.moe.combine"):
-                out = combine(ys, w, row_of_pair, pair_of_row, steps,
-                              tile_m)
-            return out, counts, dropped
+                return combine(ys, w, row_of_pair, pair_of_row, steps, tile_m)
 
-        args = (xt, w, idx, w_gate, w_up, w_down)
-        if usual >= worst:  # a tiny size: one buffer
-            out, counts, dropped = experts(worst, *args)
+        def in_the_usual_buffer(plan, dropped, xt, w, idx, *weights):
+            return experts(plan, usual < worst, xt, w, *weights), dropped
+
+        def by_group(plan, dropped, xt, w, idx, *weights):
+            """The same sum a GROUP of tokens at a time, each group
+            planned on its own into a buffer that holds ITS worst case:
+            no pair dropped whatever the imbalance, and no buffer, here
+            or in the backward, larger than the usual one.  The fewest
+            groups that allow it: 4 at the published size, 72 row tiles
+            a group against the usual 104."""
+            del plan, dropped  # those are of the usual buffer, overflowed
+            groups = next(g for g in range(1, tokens + 1) if tokens % g == 0
+                          and worst_case_tiles(pairs_all // g, e, tile_m)
+                          <= usual)
+            n_tiles = worst_case_tiles(pairs_all // groups, e, tile_m)
+
+            def one(group):
+                xt, w, idx = group
+                # A scan's body lowers to a function of its own, whose
+                # ops carry the scopes from HERE down: the stage again.
+                with jax.named_scope("dsod.encoder"):
+                    with jax.named_scope("dsod.moe.route"):
+                        plan, _, dropped = plan_for(idx, n_tiles)
+                    return experts(plan, False, xt, w, *weights), dropped
+
+            out, dropped = lax.map(jax.checkpoint(one), tuple(
+                t.reshape(groups, -1, t.shape[-1]) for t in (xt, w, idx)))
+            return out.reshape(tokens, d), jnp.sum(dropped)
+
+        args = (plan, dropped, xt, w, idx, w_gate, w_up, w_down)
+        if usual == worst:  # a tiny size: one buffer holds any routing
+            out, dropped = in_the_usual_buffer(*args)
         else:
-            # No pair is dropped whatever the imbalance: routing that
-            # does not fit the usual buffer takes the worst-case one.
             fits = tiles_needed(idx, self.first_expert, e, tile_m) <= usual
-            # Each branch keeps its inputs alone for the backward and
-            # recomputes inside it: a cond under autodiff otherwise holds
-            # BOTH branches' residuals (zeros for the one not taken), the
-            # worst-case buffers among them (+3.8 GiB compiled for a v5e).
-            out, counts, dropped = lax.cond(
-                fits, jax.checkpoint(functools.partial(experts, usual)),
-                jax.checkpoint(functools.partial(experts, worst)), *args)
+            # Each branch keeps its INPUTS alone for the backward (the
+            # plan among them) and recomputes inside it: a cond under
+            # autodiff otherwise holds BOTH branches' residuals (zeros
+            # for the one not taken).  So nothing inside a branch is
+            # saved by name either: a name kept from one is kept from
+            # both.  And a step's memory is its LARGER branch's, run or
+            # not: ONE worst-case buffer for all the tokens (264 row
+            # tiles) made the whole step 2.3 GiB larger in the compiler's
+            # books than the branch that runs, and the compiler paid for
+            # that by recomputing (PERF.md section 6, PR 31).
+            out, dropped = lax.cond(
+                fits, jax.checkpoint(in_the_usual_buffer),
+                jax.checkpoint(by_group), *args)
         pairs = jnp.sum(counts).astype(jnp.float32)
         counters = {
             "pairs_here": pairs,
             "load_max_over_mean": jnp.max(counts) * e / jnp.maximum(pairs, 1),
             "dropped": dropped.astype(jnp.float32)}
         return out.astype(self.dtype).reshape(b, n, d), counters
+
+
+# What a rematerialised block KEEPS from its forward, by
+# ``checkpoint_name``; everything else its backward recomputes.  The
+# flash kernel's residuals (q, k, v as it takes them 192 MiB, out + lse
+# 132 MiB at the published size; without them the kernel, the rotation,
+# the casts and the transposes run twice) and the routing plan (the
+# chosen experts, their scores, the usual buffer's layout and step
+# list: under 2 MiB a layer; without them top-k, the sort, the running
+# counts and two gathers of scalars run twice).  What else a layer
+# recomputes is buffer-sized (PERF.md section 6, PR 31).
+REMAT_SAVES = CAUSAL_RESIDUAL_NAMES + ("plan",)
+_SAVE_NAMED = jax.checkpoint_policies.save_only_these_names(*REMAT_SAVES)
+
+
+def _saves_counted(saved):
+    """The remat policy, counting into ``saved`` what it lets through:
+    values per name and their ``bytes``.  Autodiff asks a policy once
+    per value while it splits the block, so the count is the step's own
+    and is empty in a trace that is not differentiated."""
+    def policy(prim, *avals, **params):
+        save = _SAVE_NAMED(prim, *avals, **params)
+        if save:
+            saved[params["name"]] += 1
+            saved["bytes"] += sum(a.size * a.dtype.itemsize for a in avals)
+        return save
+
+    return policy
 
 
 class Block(nn.Module):
@@ -454,7 +537,9 @@ class LFM2(nn.Module):
     def __call__(self, tokens, *, train: bool = False):
         del train  # no dropout, no batch statistics
         c = self.cfg
-        block = nn.remat(Block) if self.remat else Block
+        saved = collections.Counter()
+        block = (nn.remat(Block, policy=_saves_counted(saved))
+                 if self.remat else Block)
         per_layer = []
         with jax.named_scope("dsod.encoder"):
             h = nn.Embed(c.vocab, c.hidden, dtype=self.dtype,
@@ -464,6 +549,14 @@ class LFM2(nn.Module):
                                     name=f"layer_{i}")(h)
                 if counters is not None:
                     per_layer.append(counters)
+        if saved:  # this trace is differentiated: the policy was asked
+            from ..utils.logging import get_logger
+
+            get_logger().info(
+                "remat saves (lfm2, %d layers): %s MiB=%.1f",
+                len(c.layer_types),
+                " ".join(f"{k}={saved[k]}" for k in REMAT_SAVES),
+                saved["bytes"] / 2 ** 20)
         with jax.named_scope("dsod.heads"):
             h = RMSNorm(c.norm_eps, self.dtype, name="final_norm")(h)
         return h, moe_counters(per_layer, tokens.size * c.top_k)

@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -611,9 +612,26 @@ def _flash_causal(q, k, v, cfg):
     return _c_fwd_call(q, k, v, cfg)[0]
 
 
+# What a caller's ``jax.checkpoint`` policy may keep so that its backward
+# runs neither the forward kernel nor what made q, k, v a second time
+# (``save_only_these_names(*CAUSAL_RESIDUAL_NAMES)``; inert otherwise).
+CAUSAL_RESIDUAL_NAMES = ("flash_qkv", "flash_out", "flash_lse")
+
+
 def _flash_causal_fwd(q, k, v, cfg):
     out, lse = _c_fwd_call(q, k, v, cfg)
-    return out, (q, k, v, out, lse[:, :, 0])
+    # Named HERE, on the residuals the backward kernels read: naming the
+    # primal output outside the custom_vjp would leave lse to a second
+    # run of the kernel.  q, k, v are named on ALIASES the forward does
+    # not use: ``jax.checkpoint`` rounds a saved value the forward also
+    # uses through ``reduce_precision`` (against excess precision, which
+    # a kernel's operands cannot have), a pass over each array.  ``out``
+    # has to be the primal too (the caller's backward reads it) and
+    # takes that pass.
+    n_qkv, n_out, n_lse = CAUSAL_RESIDUAL_NAMES
+    out = checkpoint_name(out, n_out)
+    return out, (*(checkpoint_name(t, n_qkv) for t in (q, k, v)), out,
+                 checkpoint_name(lse[:, :, 0], n_lse))
 
 
 def _flash_causal_bwd(cfg, res, g):

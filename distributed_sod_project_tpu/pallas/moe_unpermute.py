@@ -26,7 +26,7 @@ for the pair's.
 
 Rows not held are never fetched, zero-filled, written or summed; the
 time follows the held rows (plus one chunk's rounding per run), not the
-buffer: the worst-case buffer costs what the usual one does.
+buffer: a buffer sized for a worst case costs what a full one does.
 """
 
 from __future__ import annotations

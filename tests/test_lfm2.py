@@ -11,6 +11,10 @@ widths, float32, seeded weights (benchmark/harness/weights_lm.py):
   the expert layer's forward or backward;
 - the causal grouped-KV flash kernel (interpret mode), also at a length
   that is no multiple of the block;
+- what a rematerialised block keeps by name (``REMAT_SAVES``): the same
+  gradient bits as no remat, each kernel and sort once in the gradient,
+  the names among one block's saved residuals and no buffer, the step's
+  ``remat saves`` log line;
 - the chunked loss equals the unchunked one;
 - every ``dsod.moe.*`` / ``dsod.attn`` / ``dsod.shortconv`` scope in the
   lowered step and none of them in BASNet's;
@@ -116,19 +120,13 @@ def _experts(c, **kw):
 
 def test_model_loss_and_every_gradient_match_reference(setup):
     _, model, v, tokens, m = setup
-    targets = jnp.roll(tokens, -1, 1)
-
-    def prog(p):
-        h, _ = model.apply({"params": p, "batch_stats": v["batch_stats"]},
-                           tokens, train=True)
-        return tied_cross_entropy(h, p["embed"]["embedding"], targets,
-                                  chunk=64)
 
     def plain(p):
         return ref.batch_loss({"params": p, "batch_stats": v["batch_stats"]},
-                              tokens, targets, m)
+                              tokens, jnp.roll(tokens, -1, 1), m)
 
-    lp, gp = jax.jit(jax.value_and_grad(prog))(v["params"])
+    lp, gp = jax.jit(jax.value_and_grad(_loss_of(model, v, tokens)))(
+        v["params"])
     lr, gr = jax.jit(jax.value_and_grad(plain))(v["params"])
     assert abs(float(lp) - float(lr)) < 1e-5 * float(lr)
     flat = jax.tree_util.tree_flatten_with_path(gp)[0]
@@ -190,6 +188,34 @@ def test_no_pair_dropped_whatever_the_imbalance(setup, held):
     else:
         assert float(counters["pairs_here"]) == 0.0
         assert float(jnp.max(jnp.abs(out))) == 0.0
+
+
+def test_overflowing_routing_has_the_reference_gradient(setup):
+    """The by-group path (every token picks the two held experts: more
+    than the usual buffer holds) under autodiff: the gradient of every
+    parameter and of the input against the plain reference's."""
+    cfg, _, _, _, m = setup
+    c, x = cfg.model.lm, _x(8)
+    p, _ = _whole_layer()
+    mine = dict(p, **{k: p[k][:2] for k in ("gate", "up", "down")})
+    bias = jnp.where(jnp.arange(8) < 2, 10.0, 0.0)
+    g = jax.random.normal(jax.random.key(9), x.shape)
+
+    def layer(params, x):
+        out, counters = _experts(c).apply(
+            {"params": params, "batch_stats": {"expert_bias": bias}}, x)
+        return jnp.sum(out * g), counters
+
+    def plain(params, x):
+        return jnp.sum(_per_seq(lambda s: ref.moe(s, params, bias, m), x) * g)
+
+    (_, counters), got = jax.value_and_grad(layer, (0, 1), has_aux=True)(
+        mine, x)
+    assert float(counters["pairs_here"]) == B * N * c.top_k  # it overflowed
+    want = jax.grad(plain, (0, 1))(mine, x)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        _close(a, b, 1e-4)
 
 
 @pytest.mark.parametrize("counts,a,b", [
@@ -357,12 +383,168 @@ def test_expert_layer_builds_nothing_sized_pairs_by_hidden(setup):
     avals = [a for a in _intermediates(jaxpr.jaxpr) if hasattr(a, "shape")]
     buffers = {a.shape[0] for a in avals
                if a.shape[1:] == (d,) and a.shape[0] % tile_m == 0}
-    assert len(buffers) == 2  # the cond's usual and worst-case buffers
+    # the cond's usual buffer; a group's, when the routing overflows it,
+    # is no larger (here: as large), and no worst-case buffer of all the
+    # tokens at once (5 row tiles) exists
+    assert buffers == {3 * tile_m}
     big = [a.shape for a in avals
            if a.size >= t * k * d and a.shape[0] not in buffers]
     per_pair = [a.shape for a in avals if a.ndim >= 2 and a.shape[-1] >= 16
                 and a.size // a.shape[-1] == t * k]
     assert not big and not per_pair, (big, per_pair)
+
+
+# -- what the per-layer remat keeps ------------------------------------------
+
+def _loss_of(model, v, tokens):
+    targets = jnp.roll(tokens, -1, 1)
+
+    def prog(p):
+        h, _ = model.apply({"params": p, "batch_stats": v["batch_stats"]},
+                           tokens, train=True)
+        return tied_cross_entropy(h, p["embed"]["embedding"], targets,
+                                  chunk=64)
+
+    return prog
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, inner jaxprs included (not a kernel's
+    own body)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name != "pallas_call":
+            for sub in _sub_jaxprs(eqn.params):
+                yield from _eqns(sub)
+
+
+def test_named_saves_give_the_gradient_of_no_remat_to_the_last_bit(setup):
+    """A saved value IS the value its recompute would give, so keeping
+    it changes no bit of any leaf (both arms jitted)."""
+    cfg, model, v, tokens, _ = setup
+    assert model.remat
+    plain = build_model(dataclasses.replace(cfg.model, remat=False))
+    grads = [jax.jit(jax.grad(_loss_of(m, v, tokens)))(v["params"])
+             for m in (model, plain)]
+    flat = jax.tree_util.tree_flatten_with_path(grads[0])[0]
+    for (path, a), b in zip(flat, jax.tree_util.tree_leaves(grads[1])):
+        assert np.array_equal(np.asarray(a), np.asarray(b)), \
+            jax.tree_util.keystr(path)
+        assert float(jnp.max(jnp.abs(b))) > 0, jax.tree_util.keystr(path)
+
+
+@pytest.mark.parametrize("what,per", [
+    ("_c_fwd_kernel", "attention"), ("_c_dq_kernel", "attention"),
+    ("_c_dkv_kernel", "attention"), ("sort", "moe"), ("top_k", "moe")])
+def test_gradient_runs_each_kernel_and_each_sort_once_a_layer(setup, what,
+                                                              per):
+    """In the jaxpr of the gradient the causal forward kernel appears
+    once per attention layer (twice when the remat saved nothing: the
+    forward and its recompute) and the sort and the top-k once per
+    expert layer (the sort stood in both branches of the cond, forward
+    and recompute: four)."""
+    cfg, model, v, tokens, _ = setup
+    c = cfg.model.lm
+    layers = sum(t == per for t in c.layer_types + c.ffn_types)
+    assert layers >= 1
+    jaxpr = jax.make_jaxpr(jax.grad(_loss_of(model, v, tokens)))(v["params"])
+    found = [eqn for eqn in _eqns(jaxpr.jaxpr) if what == (
+        eqn.params["jaxpr"].debug_info.func_name
+        if eqn.primitive.name == "pallas_call" else eqn.primitive.name)]
+    if what == "sort":
+        # of ALL the pairs; the path for routing that overflows the usual
+        # buffer sorts a group of tokens at a time, inside its cond branch
+        pairs = tokens.size * c.top_k
+        found = [q for q in found if q.invars[0].aval.shape == (pairs,)]
+    assert len(found) == layers
+
+
+@pytest.mark.parametrize("op", ["attention", "conv"])
+def test_a_rematerialised_block_keeps_the_names_and_no_buffer(setup, op,
+                                                              capsys):
+    """``print_saved_residuals`` of one block under the model's policy:
+    beside its arguments and its output the block keeps the named values
+    alone — the kernel's q, k, v and lse, its output (JAX rounds a
+    residual that the forward also uses through ``reduce_precision``,
+    and prints that), the chosen experts and their scores, the usual
+    buffer's plan — and no float with a buffer's rows."""
+    import flax.linen as nn
+    from jax.ad_checkpoint import print_saved_residuals
+
+    cfg, _, v, _, _ = setup
+    c, x = cfg.model.lm, _x()
+    layer = "layer_1" if op == "attention" else "layer_2"
+    assert (c.layer_types[int(layer[-1])], c.ffn_types[int(layer[-1])]) \
+        == (op, "moe")
+    variables = {k: v[k][layer] for k in ("params", "batch_stats")}
+    block = nn.remat(lm.Block, policy=jax.checkpoint_policies
+                     .save_only_these_names(*lm.REMAT_SAVES))(
+                         op, "moe", c, jnp.float32)
+
+    def loss(params, x):
+        out, _ = block.apply(dict(variables, params=params), x)
+        return jnp.sum(out * out)
+
+    print_saved_residuals(loss, variables["params"], x)
+    kept = [ln.split(" ", 1) for ln in capsys.readouterr().out.splitlines()
+            if " from the argument " not in ln and "from a constant" not in ln]
+    t, k = B * N, c.top_k
+    named = sorted((shape, why.split("'")[1]) for shape, why in kept
+                   if why.startswith("named "))
+    tile_m = lm._tile_m(t * k, c.experts_held)
+    tiles = -(-(t * k * 3 * c.experts_held)
+              // (2 * c.experts * tile_m)) + c.experts_held   # the usual
+    steps = tiles * tile_m // min(128, tile_m) + (c.experts_held + 1) * (
+        t // min(512, t & -t))
+    plan = sorted(
+        [(f"i32[{t},{k}]", "plan")] * 2                # idx, row_of_pair
+        + [(f"i32[{tiles * tile_m}]", "plan"),         # pair_of_row
+           (f"i32[{tiles}]", "plan")]                  # the tiles' experts
+        + [(f"i32[{steps}]", "plan")] * 2 + [("i32[1]", "plan")])  # steps
+    hq, hkv, hd = c.heads, c.kv_heads, c.head_dim
+    floats = sorted(shape for shape, why in kept
+                    if shape.startswith("f32") and "named" not in why)
+    if op == "attention":
+        np_ = -(-N // 128) * 128  # the kernel's padded length
+        assert named == sorted(plan + [
+            (f"f32[{B * hq},{np_},{hd}]", "flash_qkv"),
+            (f"f32[{B * hkv},{np_},{hd}]", "flash_qkv"),
+            (f"f32[{B * hkv},{np_},{hd}]", "flash_qkv"),
+            (f"f32[{B * hq},{np_}]", "flash_lse")])
+        assert floats == sorted([
+            f"f32[{t},{k}]",                                  # the scores
+            f"f32[{B},{N},{c.hidden}]",                       # the output
+            f"f32[{B * hq},{np_},{hd}]"])                     # flash out
+    else:
+        assert named == plan
+        assert floats == sorted([f"f32[{t},{k}]",
+                                 f"f32[{B},{N},{c.hidden}]"])
+    # and no float with a buffer's rows
+    rows = [re.match(r"[fb]\w+\[(\d+)", shape) for shape, _ in kept]
+    assert not [m.group(0) for m in rows
+                if m and int(m.group(1)) % tile_m == 0]
+
+
+def test_the_step_says_what_its_remat_saves(setup, caplog):
+    """One log line per differentiated trace, counted by the policy
+    itself as autodiff asks it; none from a trace that keeps nothing."""
+    from distributed_sod_project_tpu.utils.logging import get_logger
+
+    cfg, model, v, tokens, _ = setup
+    c = cfg.model.lm
+    get_logger().addHandler(caplog.handler)  # "dsod" does not propagate
+    try:
+        jax.eval_shape(_loss_of(model, v, tokens), v["params"])
+        assert not [r for r in caplog.records
+                    if "remat saves" in r.getMessage()]
+        jax.eval_shape(jax.grad(_loss_of(model, v, tokens)), v["params"])
+    finally:
+        get_logger().removeHandler(caplog.handler)
+    lines = [r.getMessage() for r in caplog.records
+             if "remat saves" in r.getMessage()]
+    assert len(lines) == 1 and lines[0].startswith(
+        "remat saves (lfm2, 5 layers): flash_qkv=3 flash_out=1 flash_lse=1 "
+        "plan=44 MiB=0.")  # 11 values of the plan in each of 4 layers
 
 
 # -- the kernels and the loss ------------------------------------------------
