@@ -88,15 +88,16 @@ class DataConfig:
 
 @dataclasses.dataclass(frozen=True)
 class LMConfig:
-    """Shape of the token model (model.name=lfm2, models/lfm2.py).  The
-    defaults are LFM2-8B-A1B's published widths (LiquidAI, config.json)
-    and the share one chip holds in `lfm2_8b_a1b_ep4`: the layers kept,
-    `experts_held` of `experts` from `first_expert` on, `vocab` rows of
-    the 65,536."""
+    """Shape of a token model (model.name=lfm2 | kimi, models/lfm2.py,
+    models/kimi.py).  The defaults are LFM2-8B-A1B's published widths
+    (LiquidAI, config.json) and the share one chip holds in
+    `lfm2_8b_a1b_ep4`: the layers kept, `experts_held` of `experts` from
+    `first_expert` on, `vocab` rows of the 65,536.  `kimi_vl_a3b_ep8`
+    sets every field it reads (configs/experiments.py)."""
 
     vocab: int = 16384
     hidden: int = 2048
-    layer_types: Tuple[str, ...] = (  # conv | attention, per layer kept
+    layer_types: Tuple[str, ...] = (  # lfm2: conv | attention, per layer kept
         "conv", "attention", "conv", "conv", "conv")
     ffn_types: Tuple[str, ...] = (  # dense | moe, per layer kept
         "dense", "moe", "moe", "moe", "moe")
@@ -114,6 +115,19 @@ class LMConfig:
     rope_theta: float = 1e6
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 1.0
+    topk_eps: float = 1e-6  # in the chosen scores' normaliser
+    # The balancing rule of the bias-routed family: after each step
+    # expert_bias += rate * sign(mean pairs - pairs sent), per expert
+    # layer.  0: the bias stays the buffer it was given (lfm2).
+    bias_update_rate: float = 0.0
+    # Latent attention (kimi, every layer; it reads neither layer_types
+    # nor kv_heads): head_dim is the key's width, of which
+    # rope_dim columns are the shared rotary key's; values are v_dim
+    # wide; keys and values come from a latent of kv_rank columns.
+    rope_dim: int = 0
+    v_dim: int = 0
+    kv_rank: int = 0
+    shared_experts: int = 0  # each expert_width wide, over every token
 
 
 @dataclasses.dataclass(frozen=True)

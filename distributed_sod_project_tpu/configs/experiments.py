@@ -212,3 +212,43 @@ def lfm2_8b_a1b_ep4() -> ExperimentConfig:
         num_epochs=100,
         mesh=MeshConfig(data=1, model=1, seq=1),
     )
+
+
+@register_config("kimi_vl_a3b_ep8")
+def kimi_vl_a3b_ep8() -> ExperimentConfig:
+    """The second token model: the decoder of Kimi-VL-A3B-Instruct
+    (moonshotai, ``text_config``) at its published widths, ONE chip's
+    share of an 8-way expert-parallel stage — routed experts 0-7 of 64
+    in every expert layer (the router stays 64 wide, top-6), both shared
+    experts, all 16 latent-attention heads, rows 0-20,479 of the
+    163,840-row embedding and of the untied head; of the 27 layers the
+    dense layer 0 and expert layers 1-5, the rest on further chips as
+    pipeline stages (the image tower on the stage before: this stage's
+    traffic is text).  The router is balanced by the family's rule
+    (``bias_update_rate``).  Trains on packed synthetic documents, 2
+    sequences of 16,384 tokens a step, AdamW, per-layer remat.
+    ``model.lm.*`` / ``data.seq_len`` shrink it for a CPU drive
+    (tests/test_kimi.py)."""
+    return ExperimentConfig(
+        name="kimi_vl_a3b_ep8",
+        data=DataConfig(dataset="packed_tokens", hflip=False,
+                        synthetic_size=4096, seq_len=16384, vocab=20480),
+        model=ModelConfig(
+            name="kimi", backbone="none", sync_bn=False, remat=True,
+            lm=LMConfig(
+                vocab=20480, hidden=2048,
+                ffn_types=("dense",) + ("moe",) * 5, heads=16, head_dim=192, rope_dim=64, v_dim=128, kv_rank=512,
+                dense_width=11264, expert_width=1408, shared_experts=2,
+                experts=64, experts_held=8, first_expert=0, top_k=6,
+                norm_eps=1e-5, rope_theta=8e5, norm_topk_prob=True,
+                routed_scaling_factor=2.446, topk_eps=1e-20,
+                bias_update_rate=1e-3)),
+        loss=LossConfig(),
+        # The warm-up for the reason lfm2_8b_a1b_ep4 gives; the balancing
+        # rule holds the router's load level beside it.
+        optim=OptimConfig(optimizer="adamw", lr=3e-4, weight_decay=0.1,
+                          schedule="poly", warmup_steps=2000),
+        global_batch_size=2,
+        num_epochs=100,
+        mesh=MeshConfig(data=1, model=1, seq=1),
+    )

@@ -1,5 +1,5 @@
-"""Next-token cross-entropy over a tied output head, never holding the
-whole logit matrix.
+"""Next-token cross-entropy over an output head (the tied embedding, or
+a matrix of the model's own), never holding the whole logit matrix.
 
 At 32,768 tokens over a 16,384-row vocabulary slice the float32 logits
 are 2 GiB (and as much again for their cotangent).  The hidden states
@@ -31,7 +31,8 @@ def tied_cross_entropy(hidden, embedding, targets, *, chunk: int = CHUNK):
     """Mean over all tokens of ``logsumexp(h E^T) - (h E^T)[target]``.
 
     hidden: [..., D] in the compute dtype (after the final norm);
-    embedding: [V, D] (the tied head: the rows of the vocabulary held);
+    embedding: [V, D], the head's rows of the vocabulary held (lfm2:
+    the embedding itself; kimi: ``head/embedding``);
     targets: [...] int, ids inside the slice.  The product runs in
     ``hidden.dtype`` with float32 accumulation; the reduction is float32.
     """

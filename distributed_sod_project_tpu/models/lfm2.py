@@ -320,6 +320,14 @@ class ExpertLayer(nn.Module):
     part of ``sum_e w_e SwiGLU_e(h)`` with no pair dropped.  Returns
     ``(out, counters)``: ``pairs_here`` (pairs routed to held experts),
     ``load_max_over_mean`` (over the held experts) and ``dropped``.
+
+    ``bias_update_rate`` > 0 turns on the family's balancing rule
+    (``noaux_tc``): where the ``batch_stats`` collection is mutable (a
+    train step), the layer hands back ``expert_bias + rate *
+    sign(mean(c) - c)`` with ``c`` the pairs this call's tokens sent to
+    each of ALL ``experts`` (every router output is computed here, so
+    every count is known here), and counts ``bias_abs_max``.  At 0 the
+    bias stays the buffer it was given.
     """
     experts: int
     experts_held: int
@@ -328,6 +336,8 @@ class ExpertLayer(nn.Module):
     width: int
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 1.0
+    topk_eps: float = 1e-6   # in the chosen scores' normaliser
+    bias_update_rate: float = 0.0
     dtype: jnp.dtype = jnp.bfloat16
     param_dtype: jnp.dtype = jnp.float32
 
@@ -341,8 +351,9 @@ class ExpertLayer(nn.Module):
         w_gate = self.param("gate", init, (e, d, f), self.param_dtype)
         w_up = self.param("up", init, (e, d, f), self.param_dtype)
         w_down = self.param("down", init, (e, f, d), self.param_dtype)
-        bias = self.variable("batch_stats", "expert_bias", jnp.zeros,
-                             (self.experts,), jnp.float32).value
+        bias_var = self.variable("batch_stats", "expert_bias", jnp.zeros,
+                                 (self.experts,), jnp.float32)
+        bias = bias_var.value
         tokens, pairs_all = b * n, b * n * self.top_k
         tile_m = _tile_m(pairs_all, e)
         worst = worst_case_tiles(pairs_all, e, tile_m)
@@ -383,7 +394,7 @@ class ExpertLayer(nn.Module):
                 lambda t: checkpoint_name(t, "plan"),
                 (jnp.take_along_axis(s, idx, -1), *plan_for(idx, usual)))
             if self.norm_topk_prob:
-                w = w / (jnp.sum(w, -1, keepdims=True) + 1e-6)
+                w = w / (jnp.sum(w, -1, keepdims=True) + self.topk_eps)
             w = w * self.routed_scaling_factor
 
         def experts(plan, whole, xt, w, w_gate, w_up, w_down):
@@ -461,6 +472,14 @@ class ExpertLayer(nn.Module):
             "pairs_here": pairs,
             "load_max_over_mean": jnp.max(counts) * e / jnp.maximum(pairs, 1),
             "dropped": dropped.astype(jnp.float32)}
+        if (self.bias_update_rate and not self.is_initializing()
+                and self.is_mutable_collection("batch_stats")):
+            with jax.named_scope("dsod.moe.balance"):
+                sent = jnp.sum(idx.reshape(-1)[None, :] == jnp.arange(
+                    self.experts)[:, None], axis=1).astype(jnp.float32)
+                bias_var.value = bias + self.bias_update_rate * jnp.sign(
+                    pairs_all / self.experts - sent)
+                counters["bias_abs_max"] = jnp.max(jnp.abs(bias_var.value))
         return out.astype(self.dtype).reshape(b, n, d), counters
 
 
@@ -477,13 +496,13 @@ REMAT_SAVES = CAUSAL_RESIDUAL_NAMES + ("plan",)
 _SAVE_NAMED = jax.checkpoint_policies.save_only_these_names(*REMAT_SAVES)
 
 
-def _saves_counted(saved):
+def _saves_counted(saved, save_named=_SAVE_NAMED):
     """The remat policy, counting into ``saved`` what it lets through:
     values per name and their ``bytes``.  Autodiff asks a policy once
     per value while it splits the block, so the count is the step's own
     and is empty in a trace that is not differentiated."""
     def policy(prim, *avals, **params):
-        save = _SAVE_NAMED(prim, *avals, **params)
+        save = save_named(prim, *avals, **params)
         if save:
             saved[params["name"]] += 1
             saved["bytes"] += sum(a.size * a.dtype.itemsize for a in avals)
@@ -549,28 +568,37 @@ class LFM2(nn.Module):
                                     name=f"layer_{i}")(h)
                 if counters is not None:
                     per_layer.append(counters)
-        if saved:  # this trace is differentiated: the policy was asked
-            from ..utils.logging import get_logger
-
-            get_logger().info(
-                "remat saves (lfm2, %d layers): %s MiB=%.1f",
-                len(c.layer_types),
-                " ".join(f"{k}={saved[k]}" for k in REMAT_SAVES),
-                saved["bytes"] / 2 ** 20)
+        log_saves("lfm2", len(c.layer_types), saved, REMAT_SAVES)
         with jax.named_scope("dsod.heads"):
             h = RMSNorm(c.norm_eps, self.dtype, name="final_norm")(h)
         return h, moe_counters(per_layer, tokens.size * c.top_k)
 
 
+def log_saves(model: str, layers: int, saved, names) -> None:
+    """One line per DIFFERENTIATED trace saying what the remat policy
+    kept (a trace in which the policy was never asked logs nothing)."""
+    if saved:
+        from ..utils.logging import get_logger
+
+        get_logger().info(
+            "remat saves (%s, %d layers): %s MiB=%.1f", model, layers,
+            " ".join(f"{k}={saved[k]}" for k in names),
+            saved["bytes"] / 2 ** 20)
+
+
 def moe_counters(per_layer, pairs_total: int):
-    """The trainer's three counters from the expert layers' own:
+    """The trainer's counters from the expert layers' own:
     ``moe_pairs_here_share`` (mean over layers of held pairs / all
     pairs), ``moe_load_max_over_mean`` (worst layer),
-    ``moe_dropped_pairs`` (sum)."""
+    ``moe_dropped_pairs`` (sum) and, where the layers balance their
+    router, ``moe_bias_abs_max`` (largest |expert_bias| of any layer)."""
     if not per_layer:
         return {}
     stack = {k: jnp.stack([c[k] for c in per_layer]) for k in per_layer[0]}
-    return {
+    out = {
         "moe_pairs_here_share": jnp.mean(stack["pairs_here"]) / pairs_total,
         "moe_load_max_over_mean": jnp.max(stack["load_max_over_mean"]),
         "moe_dropped_pairs": jnp.sum(stack["dropped"])}
+    if "bias_abs_max" in stack:
+        out["moe_bias_abs_max"] = jnp.max(stack["bias_abs_max"])
+    return out

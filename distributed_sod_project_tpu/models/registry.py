@@ -191,3 +191,13 @@ def _build_lfm2(cfg, *, dtype, param_dtype, axis_name):
 
     return LFM2(cfg=cfg.lm, remat=cfg.remat, dtype=dtype,
                 param_dtype=param_dtype)
+
+
+@register_model("kimi")
+def _build_kimi(cfg, *, dtype, param_dtype, axis_name):
+    """The second token model (latent attention, shared + balanced
+    routed experts): its shape is ``cfg.lm``, as for ``lfm2``."""
+    from .kimi import KimiDecoder
+
+    return KimiDecoder(cfg=cfg.lm, remat=cfg.remat, dtype=dtype,
+                       param_dtype=param_dtype)

@@ -670,3 +670,313 @@ def flash_attention_causal(q, k, v, *, block: int | None = None,
     out = _flash_causal(fold(q), fold(k), fold(v),
                         (block, hq // hkv, interpret))
     return out[:, :n].reshape(b, hq, n, d)
+
+
+# ---------------------------------------------------------------------------
+# causal latent attention (the second token model's, models/kimi.py)
+# ---------------------------------------------------------------------------
+#
+# The causal tiling above with the three things multi-head latent
+# attention changes.  (1) A key is wider than a value: each head's key
+# is ``[k_nope (dn) ; k_rope (dr)]`` against a value of ``dv`` columns,
+# and dn + dr (192) is no multiple of the 128 lanes — so the two parts
+# arrive as arrays of their own and a tile pair's scores are the SUM of
+# two products, one contracting dn columns and one dr.  (2) The rotary
+# part of the key is ONE head shared by every query head: its index map
+# divides the folded head index by the head count, and the dk/dv kernel
+# writes each head's float32 share of ``dk_rope``, which the wrapper
+# sums over the heads.  (3) No kv grouping: k_nope and v are per head.
+
+MLA_RESIDUAL_NAMES = ("mla_q", "mla_out", "mla_lse")
+
+
+def _mla_p(qn_ref, qr_ref, kn_ref, kr_ref, lse_or_none, *, scale, masked):
+    """One tile pair's masked, scaled scores over both key parts
+    (forward: no lse yet), or ``p = exp(scores - lse)``."""
+    dims = (((1,), (1,)), ((), ()))
+    s = (lax.dot_general(qn_ref[0], kn_ref[0], dims,
+                         preferred_element_type=jnp.float32)
+         + lax.dot_general(qr_ref[0], kr_ref[0], dims,
+                           preferred_element_type=jnp.float32)) * scale
+    if masked:
+        row = lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        col = lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(col <= row, s, _MASK_VALUE)
+    if lse_or_none is None:
+        return s
+    return jnp.exp(s - _widen(lse_or_none, s.shape[1]))
+
+
+def _m_fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref,
+                  m_s, l_s, acc_s, *, scale: float):
+    i, j = pl.program_id(1), pl.program_id(2)
+    d = acc_s.shape[1]
+
+    @pl.when(j == 0)
+    def _():
+        m_s[...] = jnp.full(m_s.shape, _MASK_VALUE, jnp.float32)
+        l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
+        acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
+
+    def visit(masked):
+        s = _mla_p(qn_ref, qr_ref, kn_ref, kr_ref, None, scale=scale,
+                   masked=masked)
+        m_prev = m_s[...]
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])
+        p = jnp.exp(s - _widen(m_next, s.shape[1]))
+        corr = jnp.exp(m_prev - m_next)
+        l_s[...] = l_s[...] * corr + jnp.sum(p, axis=1)[:, None]
+        m_s[...] = m_next
+        pv = lax.dot_general(p.astype(v_ref.dtype), v_ref[0],
+                             (((1,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+        acc_s[...] = acc_s[...] * _widen(corr, d) + pv
+
+    pl.when(j < i)(lambda: visit(False))
+    pl.when(j == i)(lambda: visit(True))
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        o_ref[0] = (acc_s[...] / _widen(l_s[...], d)).astype(o_ref.dtype)
+        lse_ref[0] = m_s[...] + jnp.log(l_s[...])
+
+
+def _m_dq_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, out_ref,
+                 lse_ref, dqn_ref, dqr_ref, dqn_s, dqr_s, *, scale: float):
+    i, j = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(j == 0)
+    def _():
+        dqn_s[...] = jnp.zeros(dqn_s.shape, jnp.float32)
+        dqr_s[...] = jnp.zeros(dqr_s.shape, jnp.float32)
+
+    def visit(masked):
+        p = _mla_p(qn_ref, qr_ref, kn_ref, kr_ref, lse_ref[0], scale=scale,
+                   masked=masked)
+        ds = _causal_ds(p, (do_ref, out_ref), v_ref, scale=scale).astype(
+            kn_ref.dtype)
+        dims = (((1,), (0,)), ((), ()))
+        dqn_s[...] += lax.dot_general(ds, kn_ref[0], dims,
+                                      preferred_element_type=jnp.float32)
+        dqr_s[...] += lax.dot_general(ds, kr_ref[0], dims,
+                                      preferred_element_type=jnp.float32)
+
+    pl.when(j < i)(lambda: visit(False))
+    pl.when(j == i)(lambda: visit(True))
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        dqn_ref[0] = dqn_s[...].astype(dqn_ref.dtype)
+        dqr_ref[0] = dqr_s[...].astype(dqr_ref.dtype)
+
+
+def _m_dkv_kernel(kn_ref, kr_ref, v_ref, qn_ref, qr_ref, do_ref, out_ref,
+                  lse_ref, dkn_ref, dkr_ref, dv_ref, dkn_s, dkr_s, dv_s,
+                  *, scale: float):
+    i, j = pl.program_id(1), pl.program_id(2)   # kv block; q block
+
+    @pl.when(j == 0)
+    def _():
+        dkn_s[...] = jnp.zeros(dkn_s.shape, jnp.float32)
+        dkr_s[...] = jnp.zeros(dkr_s.shape, jnp.float32)
+        dv_s[...] = jnp.zeros(dv_s.shape, jnp.float32)
+
+    def visit(masked):
+        p = _mla_p(qn_ref, qr_ref, kn_ref, kr_ref, lse_ref[0], scale=scale,
+                   masked=masked)
+        dims = (((0,), (0,)), ((), ()))
+        dv_s[...] += lax.dot_general(p.astype(do_ref.dtype), do_ref[0], dims,
+                                     preferred_element_type=jnp.float32)
+        ds = _causal_ds(p, (do_ref, out_ref), v_ref, scale=scale).astype(
+            qn_ref.dtype)
+        dkn_s[...] += lax.dot_general(ds, qn_ref[0], dims,
+                                      preferred_element_type=jnp.float32)
+        dkr_s[...] += lax.dot_general(ds, qr_ref[0], dims,
+                                      preferred_element_type=jnp.float32)
+
+    pl.when(j > i)(lambda: visit(False))
+    pl.when(j == i)(lambda: visit(True))
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        dkn_ref[0] = dkn_s[...].astype(dkn_ref.dtype)
+        dkr_ref[0] = dkr_s[...]
+        dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
+
+
+def _mla_grid(qn, qr, v, cfg, *, kv_resident: bool):
+    """-> (grid, scale, specs): the BlockSpecs of a q-side tile of each
+    width, a kv-side one, the shared rotary key's and the lse row's, for
+    the grid order (bh, q block, kv block) or, ``kv_resident``, (bh, kv
+    block, q block); blocks the diagonal rules out are clamped to the
+    nearest needed one, so they cost no DMA either."""
+    blk, heads, _ = cfg
+    bh, np_, dn = qn.shape
+    dr, dv = qr.shape[2], v.shape[2]
+    if np_ % blk:  # a floor-divided grid would leave rows unwritten
+        raise ValueError(f"block {blk} does not divide the padded length "
+                         f"{np_}")
+    nb = np_ // blk
+    if kv_resident:
+        q_ix = lambda b, i, j: (b, jnp.maximum(j, i), 0)  # noqa: E731
+        k_ix = lambda b, i, j: (b, i, 0)  # noqa: E731
+        kr_ix = lambda b, i, j: (b // heads, i, 0)  # noqa: E731
+    else:
+        q_ix = lambda b, i, j: (b, i, 0)  # noqa: E731
+        k_ix = lambda b, i, j: (b, jnp.minimum(j, i), 0)  # noqa: E731
+        kr_ix = lambda b, i, j: (b // heads, jnp.minimum(j, i), 0)  # noqa: E731
+    spec = lambda d, ix: pl.BlockSpec((1, blk, d), ix)  # noqa: E731
+    return (bh, nb, nb), 1.0 / (dn + dr) ** 0.5, dict(
+        qn=spec(dn, q_ix), qr=spec(dr, q_ix), qv=spec(dv, q_ix),
+        row=spec(_LANES, q_ix), kn=spec(dn, k_ix), kr=spec(dr, kr_ix),
+        kv=spec(dv, k_ix), kr_own=spec(dr, k_ix))
+
+
+def _mla_pairs(qn, qr, v):
+    """(score elements on or under the diagonal, key width, value
+    width), for the kernels' cost estimates."""
+    bh, np_, dn = qn.shape
+    return bh * np_ * np_ // 2, dn + qr.shape[2], v.shape[2]
+
+
+@jax.named_scope("dsod.kernel.flash_attention_mla")
+def _m_fwd_call(qn, qr, kn, kr, v, cfg):
+    grid, scale, s = _mla_grid(qn, qr, v, cfg, kv_resident=False)
+    bh, np_, _ = qn.shape
+    blk, dv = cfg[0], v.shape[2]
+    pairs, dk, _ = _mla_pairs(qn, qr, v)
+    return pl.pallas_call(
+        partial(_m_fwd_kernel, scale=scale),
+        grid=grid,
+        in_specs=[s["qn"], s["qr"], s["kn"], s["kr"], s["kv"]],
+        out_specs=[s["qv"], s["row"]],
+        out_shape=[jax.ShapeDtypeStruct((bh, np_, dv), qn.dtype),
+                   jax.ShapeDtypeStruct((bh, np_, _LANES), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((blk, _LANES), jnp.float32),
+                        pltpu.VMEM((blk, _LANES), jnp.float32),
+                        pltpu.VMEM((blk, dv), jnp.float32)],
+        cost_estimate=pl.CostEstimate(
+            flops=2 * pairs * (dk + dv), transcendentals=pairs,
+            bytes_accessed=(2 * qn.size + 2 * v.size) * qn.dtype.itemsize),
+        interpret=cfg[2],
+    )(qn, qr, kn, kr, v)
+
+
+@jax.named_scope("dsod.kernel.flash_attention_mla_dq")
+def _m_dq_call(qn, qr, kn, kr, v, out, lse, do, cfg):
+    grid, scale, s = _mla_grid(qn, qr, v, cfg, kv_resident=False)
+    blk = cfg[0]
+    pairs, dk, dv = _mla_pairs(qn, qr, v)
+    return pl.pallas_call(
+        partial(_m_dq_kernel, scale=scale),
+        grid=grid,
+        in_specs=[s["qn"], s["qr"], s["kn"], s["kr"], s["kv"], s["qv"],
+                  s["qv"], s["row"]],
+        out_specs=[s["qn"], s["qr"]],
+        out_shape=[jax.ShapeDtypeStruct(qn.shape, qn.dtype),
+                   jax.ShapeDtypeStruct(qr.shape, qr.dtype)],
+        scratch_shapes=[pltpu.VMEM((blk, qn.shape[2]), jnp.float32),
+                        pltpu.VMEM((blk, qr.shape[2]), jnp.float32)],
+        cost_estimate=pl.CostEstimate(
+            flops=2 * pairs * (2 * dk + dv), transcendentals=pairs,
+            bytes_accessed=(3 * qn.size + 3 * v.size) * qn.dtype.itemsize),
+        interpret=cfg[2],
+    )(qn, qr, kn, kr, v, do, out, lse)
+
+
+@jax.named_scope("dsod.kernel.flash_attention_mla_dkv")
+def _m_dkv_call(qn, qr, kn, kr, v, out, lse, do, cfg):
+    """kv block resident, the head's q blocks stream past.  The shared
+    rotary key's cotangent comes out per head, float32 (summed over the
+    heads by the caller)."""
+    grid, scale, s = _mla_grid(qn, qr, v, cfg, kv_resident=True)
+    blk = cfg[0]
+    pairs, dk, dv = _mla_pairs(qn, qr, v)
+    return pl.pallas_call(
+        partial(_m_dkv_kernel, scale=scale),
+        grid=grid,
+        in_specs=[s["kn"], s["kr"], s["kv"], s["qn"], s["qr"], s["qv"],
+                  s["qv"], s["row"]],
+        out_specs=[s["kn"], s["kr_own"], s["kv"]],
+        out_shape=[jax.ShapeDtypeStruct(kn.shape, kn.dtype),
+                   jax.ShapeDtypeStruct(qr.shape, jnp.float32),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((blk, kn.shape[2]), jnp.float32),
+                        pltpu.VMEM((blk, qr.shape[2]), jnp.float32),
+                        pltpu.VMEM((blk, v.shape[2]), jnp.float32)],
+        cost_estimate=pl.CostEstimate(
+            flops=2 * pairs * (2 * dk + 2 * dv), transcendentals=pairs,
+            bytes_accessed=(3 * qn.size + 4 * v.size) * qn.dtype.itemsize),
+        interpret=cfg[2],
+    )(kn, kr, v, qn, qr, do, out, lse)
+
+
+def _m_bwd_call(qn, qr, kn, kr, v, out, lse_row, do, cfg):
+    # One scope per pallas_call and nothing else under it (the trace
+    # reader counts a kernel's calls by its scope).
+    lse = jnp.broadcast_to(lse_row[..., None], qn.shape[:2] + (_LANES,))
+    dqn, dqr = _m_dq_call(qn, qr, kn, kr, v, out, lse, do, cfg)
+    dkn, dkr_heads, dv = _m_dkv_call(qn, qr, kn, kr, v, out, lse, do, cfg)
+    dkr = jnp.sum(dkr_heads.reshape((kr.shape[0], -1) + kr.shape[1:]),
+                  axis=1).astype(kr.dtype)
+    return dqn, dqr, dkn, dkr, dv
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _flash_mla(qn, qr, kn, kr, v, cfg):
+    return _m_fwd_call(qn, qr, kn, kr, v, cfg)[0]
+
+
+def _flash_mla_fwd(qn, qr, kn, kr, v, cfg):
+    out, lse = _m_fwd_call(qn, qr, kn, kr, v, cfg)
+    # As for the causal kernel: out and lse are named on the residuals,
+    # q on aliases the forward does not use.  The keys and values are
+    # NOT named: they are an up-projection of a latent 8 x narrower,
+    # which a caller keeps or makes again (models/kimi.py).
+    n_q, n_out, n_lse = MLA_RESIDUAL_NAMES
+    out = checkpoint_name(out, n_out)
+    return out, (checkpoint_name(qn, n_q), checkpoint_name(qr, n_q), kn, kr,
+                 v, out, checkpoint_name(lse[:, :, 0], n_lse))
+
+
+def _flash_mla_bwd(cfg, res, g):
+    return _m_bwd_call(*res, g, cfg)
+
+
+_flash_mla.defvjp(_flash_mla_fwd, _flash_mla_bwd)
+
+
+def flash_attention_mla(q_nope, q_rope, k_nope, k_rope, v, *,
+                        block: int | None = None,
+                        interpret: bool | None = None) -> jnp.ndarray:
+    """Causal attention whose keys are ``[k_nope ; k_rope]`` with ONE
+    rotary key head for all query heads, and whose values have a width
+    of their own.
+
+    q_nope, k_nope: [B, H, N, dn]; q_rope: [B, H, N, dr]; k_rope:
+    [B, N, dr]; v: [B, H, N, dv] -> [B, H, N, dv].  Scores are
+    ``(q_nope . k_nope + q_rope . k_rope) / sqrt(dn + dr)``.  Any N
+    (zero-padded to the block); each width <= 128 or a multiple of 128.
+    Differentiable via the Pallas backward kernels.
+    """
+    b, h, n, dn = q_nope.shape
+    dr, dv = q_rope.shape[-1], v.shape[-1]
+    if (q_rope.shape != (b, h, n, dr) or k_nope.shape != q_nope.shape
+            or k_rope.shape != (b, n, dr) or v.shape != (b, h, n, dv)):
+        raise ValueError(
+            f"bad latent-attention shapes: q {q_nope.shape} {q_rope.shape} "
+            f"k {k_nope.shape} {k_rope.shape} v {v.shape}")
+    for d in (dn, dr, dv):
+        if d > _LANES and d % _LANES:
+            raise ValueError(f"head width {d} unsupported")
+    if block is None:
+        block = min(_CAUSAL_BLOCK, -(-n // _LANES) * _LANES)
+    if block % _LANES:
+        raise ValueError("block must be a multiple of 128")
+    np_ = -(-n // block) * block
+    interpret = (jax.default_backend() == "cpu" if interpret is None
+                 else interpret)
+    fold = lambda t: _pad_n(t.reshape(-1, n, t.shape[-1]), np_)  # noqa: E731
+    out = _flash_mla(fold(q_nope), fold(q_rope), fold(k_nope), fold(k_rope),
+                     fold(v), (block, h, interpret))
+    return out[:, :n].reshape(b, h, n, dv)

@@ -349,16 +349,24 @@ def make_unified_train_step(
     def _forward_loss_tokens(state, batch):
         from ..losses.token_ce import tied_cross_entropy
 
-        def loss_fn(params):
-            hidden, counters = model.apply(
-                {"params": params, "batch_stats": state.batch_stats},
-                batch["tokens"], train=True)
-            total = tied_cross_entropy(
-                hidden, params["embed"]["embedding"], batch["targets"])
-            return total, dict(counters, total=total)
+        # The head's matrix: the embedding, unless the model names its
+        # own.  The buffers come back from the model, as BatchNorm's do
+        # from an image model: a router balanced by rule moves its
+        # selection bias every step (models/lfm2.py::ExpertLayer).
+        module, leaf = getattr(model, "head", ("embed", "embedding"))
 
-        grads, comps = jax.grad(loss_fn, has_aux=True)(state.params)
-        return grads, comps, state.batch_stats
+        def loss_fn(params):
+            (hidden, counters), mut = model.apply(
+                {"params": params, "batch_stats": state.batch_stats},
+                batch["tokens"], train=True, mutable=["batch_stats"])
+            total = tied_cross_entropy(
+                hidden, params[module][leaf], batch["targets"])
+            return total, (dict(counters, total=total),
+                           mut.get("batch_stats", state.batch_stats))
+
+        grads, (comps, new_stats) = jax.grad(loss_fn, has_aux=True)(
+            state.params)
+        return grads, comps, new_stats
 
     def step_fn_tokens(state: TrainState, batch):
         # No image to rescale, no dropout draw, no resample site to

@@ -141,6 +141,12 @@ _flash = partial(fa.flash_attention, interpret=False)
 _causal = partial(fa.flash_attention_causal, interpret=False)
 _CAUSAL_QKV = (_S((1, 32, 8192, 64), BF),) + (_S((1, 8, 8192, 64), BF),) * 2
 _gmm = partial(gm.grouped_matmul, interpret=False)
+_mla = partial(fa.flash_attention_mla, interpret=False)
+# kimi_vl_a3b_ep8: one 16,384-token sequence of 16 heads (the cell runs
+# two): q_nope, q_rope, k_nope, the ONE shared rotary key, v.
+_MLA_ARGS = (_S((1, 16, 16384, 128), BF), _S((1, 16, 16384, 64), BF),
+             _S((1, 16, 16384, 128), BF), _S((1, 16384, 64), BF),
+             _S((1, 16, 16384, 128), BF))
 
 
 def _gmm_args(a, b, tiles=24, experts=8):
@@ -221,7 +227,21 @@ CASES = {
     "flash_attention_causal.bwd@8192": (
         jax.grad(lambda q, k, v: _causal(q, k, v).astype(F32).sum(),
                  argnums=(0, 1, 2)), _CAUSAL_QKV, 3),
-    # ... and its grouped expert products at the published widths:
+    # kimi_vl_a3b_ep8: keys of 128 + 64 columns against values of 128.
+    "flash_attention_mla.fwd@16384": (_mla, _MLA_ARGS, 1),
+    "flash_attention_mla.bwd@16384": (
+        jax.grad(lambda *a: _mla(*a).astype(F32).sum(),
+                 argnums=(0, 1, 2, 3, 4)), _MLA_ARGS, 3),
+    # ... and its grouped expert products at 2048 -> 1408 -> 2048 over
+    # the usual buffer's 80 row tiles.
+    "grouped_matmul.dx+dw@2048x1408": (
+        jax.grad(lambda x, w, te, nu: _gmm(x, w, te, nu).astype(F32).sum(),
+                 argnums=(0, 1)), _gmm_args(2048, 1408, tiles=80), 2),
+    "grouped_matmul.dx+dw@1408x2048": (
+        jax.grad(lambda x, w, te, nu: _gmm(x, w, te, nu).astype(F32).sum(),
+                 argnums=(0, 1)), _gmm_args(1408, 2048, tiles=80), 2),
+    "moe_unpermute@40960x6": _unpermute(40960, top_k=6),
+    # lfm2_8b_a1b_ep4's grouped expert products at the published widths:
     # 8 experts of 2048 -> 1792 -> 2048 over 16 + 8 row tiles of 512.
     "grouped_matmul.fwd@2048x1792": (_gmm, _gmm_args(2048, 1792), 1),
     "grouped_matmul.dx+dw@2048x1792": (

@@ -188,3 +188,75 @@ def test_flash_lowers_for_real_tpu():
                                                      g_, cfg)),
             platforms=["tpu"])(q, q, q, q, lse, q)
         assert "tpu_custom_call" in exp.mlir_module()
+
+
+# -- causal latent attention (models/kimi.py) --------------------------------
+
+def _mla_inputs(n, b=2, h=3, dn=32, dr=16, dv=24, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed + n), 6)
+    shapes = ((b, h, n, dn), (b, h, n, dr), (b, h, n, dn), (b, n, dr),
+              (b, h, n, dv), (b, h, n, dv))
+    return [jax.random.normal(k, s) for k, s in zip(ks, shapes)]
+
+
+def _plain_mla(qn, qr, kn, kr, v):
+    """[k_nope ; k_rope] keys, ONE rotary key head for all query heads,
+    values of their own width, causal softmax: plain jnp."""
+    n = qn.shape[2]
+    s = (jnp.einsum("bhqd,bhkd->bhqk", qn, kn)
+         + jnp.einsum("bhqd,bkd->bhqk", qr, kr)) \
+        / np.sqrt(qn.shape[-1] + qr.shape[-1])
+    s = jnp.where(jnp.tril(jnp.ones((n, n), bool)), s, -jnp.inf)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
+
+
+# lengths that are and are not multiples of the tile; one tile and many
+@pytest.mark.parametrize("n,block", [(256, 128), (160, 128), (72, None),
+                                     (384, 128)])
+@pytest.mark.parametrize("what", ["forward", "dq", "dkv"])
+def test_flash_mla_matches_plain_attention(n, block, what):
+    from distributed_sod_project_tpu.pallas.flash_attention import \
+        flash_attention_mla
+
+    *args, cot = _mla_inputs(n)
+    kernel = lambda *a: flash_attention_mla(*a, block=block)  # noqa: E731
+    if what == "forward":
+        got, want = kernel(*args), _plain_mla(*args)
+        assert got.shape == cot.shape
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-6)
+        return
+    argnums = (0, 1) if what == "dq" else (2, 3, 4)
+    got = jax.grad(lambda *a: jnp.sum(kernel(*a) * cot), argnums)(*args)
+    want = jax.grad(lambda *a: jnp.sum(_plain_mla(*a) * cot), argnums)(*args)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b),
+            atol=3e-6 * float(jnp.max(jnp.abs(b))))
+
+
+def test_flash_mla_raises_where_a_tile_does_not_divide():
+    """A floor-divided grid would leave the last rows unwritten, which
+    interpret mode at one block cannot show (PERF.md, PR 28): the calls
+    refuse a block that does not divide the padded length, and the
+    wrapper a block or a width the lanes cannot hold."""
+    from distributed_sod_project_tpu.pallas.flash_attention import (
+        _m_bwd_call, _m_fwd_call, flash_attention_mla)
+
+    qn, qr, kn, kr, v, cot = (t.reshape((-1,) + t.shape[-2:])
+                              for t in _mla_inputs(192))
+    bad = (128, 3, True)  # 192 rows, blocks of 128
+    with pytest.raises(ValueError, match="does not divide"):
+        _m_fwd_call(qn, qr, kn, kr, v, bad)
+    with pytest.raises(ValueError, match="does not divide"):
+        _m_bwd_call(qn, qr, kn, kr, v, cot, jnp.zeros(qn.shape[:2]), cot,
+                    bad)
+    args = _mla_inputs(192)[:5]
+    with pytest.raises(ValueError, match="multiple of 128"):
+        flash_attention_mla(*args, block=96)
+    with pytest.raises(ValueError, match="shapes"):
+        flash_attention_mla(args[0], args[1], args[2], args[3][:, :100],
+                            args[4])
+    wide = _mla_inputs(128, dn=192)[:5]
+    with pytest.raises(ValueError, match="width 192"):
+        flash_attention_mla(*wide)

@@ -124,10 +124,15 @@ def test_fit_under_profiler_names_loop_and_data_plane(tmp_path, monkeypatch):
         >= set(range(4))
 
 
-_TOKEN_MODELS = {"lfm2_8b_a1b_ep4"}  # a token batch, no convolution
+# A token batch, no convolution: config -> the widths it is traced at.
 _TINY_LM = ["model.lm.vocab=512", "model.lm.hidden=64", "model.lm.heads=4",
-            "model.lm.kv_heads=2", "model.lm.head_dim=16",
             "model.lm.dense_width=96", "model.lm.expert_width=48"]
+_TOKEN_MODELS = {
+    "lfm2_8b_a1b_ep4": _TINY_LM + ["model.lm.kv_heads=2",
+                                   "model.lm.head_dim=16"],
+    "kimi_vl_a3b_ep8": _TINY_LM + [
+        "model.lm.head_dim=24", "model.lm.rope_dim=8", "model.lm.v_dim=16",
+        "model.lm.kv_rank=32"]}
 
 
 def _lowered_step_text(name: str, size: int = 64) -> str:
@@ -142,8 +147,8 @@ def _lowered_step_text(name: str, size: int = 64) -> str:
 
     cfg = apply_overrides(get_config(name), [
         "global_batch_size=2", f"data.image_size={size},{size}",
-        "mesh.data=1", "mesh.model=1", "mesh.seq=1"] + (
-            _TINY_LM if name in _TOKEN_MODELS else []))
+        "mesh.data=1", "mesh.model=1", "mesh.seq=1"]
+        + _TOKEN_MODELS.get(name, []))
     mesh = make_mesh(cfg.mesh, jax.devices()[:1])
     model = build_model(cfg.model)
     tx, sched = build_optimizer(cfg.optim, 100)

@@ -27,10 +27,13 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-_TINY_LM = ["model.lm.vocab=512", "model.lm.hidden=64", "model.lm.heads=4",
-            "model.lm.kv_heads=2", "model.lm.head_dim=16",
-            "model.lm.dense_width=96", "model.lm.expert_width=48",
-            "data.vocab=512"]
+_TINY = ["model.lm.vocab=512", "model.lm.hidden=64", "model.lm.heads=4",
+         "model.lm.dense_width=96", "model.lm.expert_width=48",
+         "data.vocab=512"]
+_TINY_LM = {  # model.name -> the token model's shrink
+    "lfm2": _TINY + ["model.lm.kv_heads=2", "model.lm.head_dim=16"],
+    "kimi": _TINY + ["model.lm.head_dim=24", "model.lm.rope_dim=8",
+                     "model.lm.v_dim=16", "model.lm.kv_rank=32"]}
 
 
 def dump(config_name: str, out_dir: str, n_devices: int = 8,
@@ -75,10 +78,10 @@ def dump(config_name: str, out_dir: str, n_devices: int = 8,
 
     cfg = get_config(config_name)
     shrink = [f"data.image_size={image_size},{image_size}"]
-    if cfg.model.name == "lfm2":
-        # The token model's shrink: tiny widths, --image-size tokens a
+    if cfg.model.name in _TINY_LM:
+        # A token model's shrink: tiny widths, --image-size tokens a
         # sequence (the structure of the program is what is diffed).
-        shrink = [f"data.seq_len={image_size}"] + _TINY_LM
+        shrink = [f"data.seq_len={image_size}"] + _TINY_LM[cfg.model.name]
     cfg = apply_overrides(cfg, [
         f"global_batch_size={batch_per_device * n_devices}",
         "mesh.data=-1", "mesh.model=1", "mesh.seq=1",
