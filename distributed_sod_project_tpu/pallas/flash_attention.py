@@ -683,9 +683,20 @@ def flash_attention_causal(q, k, v, *, block: int | None = None,
 # arrive as arrays of their own and a tile pair's scores are the SUM of
 # two products, one contracting dn columns and one dr.  (2) The rotary
 # part of the key is ONE head shared by every query head: its index map
-# divides the folded head index by the head count, and the dk/dv kernel
+# divides the folded head index by the head count, and the backward
 # writes each head's float32 share of ``dk_rope``, which the wrapper
 # sums over the heads.  (3) No kv grouping: k_nope and v are per head.
+#
+# The backward is ONE kernel that makes a tile pair's p and ds once and
+# takes dq, dk and dv from them.  A kv block is resident while its
+# head's q blocks stream past (dk, dv: a block-sized float32 accumulator
+# each, as above); dq accumulates in float32 VMEM scratch that holds the
+# WHOLE row range of one (batch, head).  kv blocks run in ascending
+# order and only blocks i <= j touch q block j, so its dq is complete at
+# the diagonal pair (i, i), the first one kv block i visits: it is
+# written there, to an output block the pipeline flushes when i moves
+# on, and never goes to HBM as partial sums.  The scoped-VMEM limit
+# follows from the shapes (``_mla_bwd_vmem_bytes``).
 
 MLA_RESIDUAL_NAMES = ("mla_q", "mla_out", "mla_lse")
 
@@ -741,39 +752,15 @@ def _m_fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref,
         lse_ref[0] = m_s[...] + jnp.log(l_s[...])
 
 
-def _m_dq_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, out_ref,
-                 lse_ref, dqn_ref, dqr_ref, dqn_s, dqr_s, *, scale: float):
-    i, j = pl.program_id(1), pl.program_id(2)
+def _m_bwd_kernel(kn_ref, kr_ref, v_ref, qn_ref, qr_ref, do_ref, out_ref,
+                  lse_ref, dqn_ref, dqr_ref, dkn_ref, dkr_ref, dv_ref,
+                  dqn_s, dqr_s, dkn_s, dkr_s, dv_s, *, scale: float):
+    i, j = pl.program_id(1), pl.program_id(2)   # kv block; q block
 
-    @pl.when(j == 0)
+    @pl.when((i == 0) & (j == 0))
     def _():
         dqn_s[...] = jnp.zeros(dqn_s.shape, jnp.float32)
         dqr_s[...] = jnp.zeros(dqr_s.shape, jnp.float32)
-
-    def visit(masked):
-        p = _mla_p(qn_ref, qr_ref, kn_ref, kr_ref, lse_ref[0], scale=scale,
-                   masked=masked)
-        ds = _causal_ds(p, (do_ref, out_ref), v_ref, scale=scale).astype(
-            kn_ref.dtype)
-        dims = (((1,), (0,)), ((), ()))
-        dqn_s[...] += lax.dot_general(ds, kn_ref[0], dims,
-                                      preferred_element_type=jnp.float32)
-        dqr_s[...] += lax.dot_general(ds, kr_ref[0], dims,
-                                      preferred_element_type=jnp.float32)
-
-    pl.when(j < i)(lambda: visit(False))
-    pl.when(j == i)(lambda: visit(True))
-
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _():
-        dqn_ref[0] = dqn_s[...].astype(dqn_ref.dtype)
-        dqr_ref[0] = dqr_s[...].astype(dqr_ref.dtype)
-
-
-def _m_dkv_kernel(kn_ref, kr_ref, v_ref, qn_ref, qr_ref, do_ref, out_ref,
-                  lse_ref, dkn_ref, dkr_ref, dv_ref, dkn_s, dkr_s, dv_s,
-                  *, scale: float):
-    i, j = pl.program_id(1), pl.program_id(2)   # kv block; q block
 
     @pl.when(j == 0)
     def _():
@@ -784,18 +771,30 @@ def _m_dkv_kernel(kn_ref, kr_ref, v_ref, qn_ref, qr_ref, do_ref, out_ref,
     def visit(masked):
         p = _mla_p(qn_ref, qr_ref, kn_ref, kr_ref, lse_ref[0], scale=scale,
                    masked=masked)
-        dims = (((0,), (0,)), ((), ()))
-        dv_s[...] += lax.dot_general(p.astype(do_ref.dtype), do_ref[0], dims,
+        over_q = (((0,), (0,)), ((), ()))   # contract the pair's q rows
+        over_k = (((1,), (0,)), ((), ()))   # ... its kv rows
+        dv_s[...] += lax.dot_general(p.astype(do_ref.dtype), do_ref[0],
+                                     over_q,
                                      preferred_element_type=jnp.float32)
         ds = _causal_ds(p, (do_ref, out_ref), v_ref, scale=scale).astype(
             qn_ref.dtype)
-        dkn_s[...] += lax.dot_general(ds, qn_ref[0], dims,
+        dkn_s[...] += lax.dot_general(ds, qn_ref[0], over_q,
                                       preferred_element_type=jnp.float32)
-        dkr_s[...] += lax.dot_general(ds, qr_ref[0], dims,
+        dkr_s[...] += lax.dot_general(ds, qr_ref[0], over_q,
                                       preferred_element_type=jnp.float32)
+        dqn_s[j] += lax.dot_general(ds, kn_ref[0], over_k,
+                                    preferred_element_type=jnp.float32)
+        dqr_s[j] += lax.dot_general(ds, kr_ref[0], over_k,
+                                    preferred_element_type=jnp.float32)
 
     pl.when(j > i)(lambda: visit(False))
-    pl.when(j == i)(lambda: visit(True))
+
+    @pl.when(j == i)
+    def _():
+        visit(True)
+        # Every kv block <= i has added to q block i by now.
+        dqn_ref[0] = dqn_s[i].astype(dqn_ref.dtype)
+        dqr_ref[0] = dqr_s[i].astype(dqr_ref.dtype)
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _():
@@ -862,51 +861,55 @@ def _m_fwd_call(qn, qr, kn, kr, v, cfg):
     )(qn, qr, kn, kr, v)
 
 
-@jax.named_scope("dsod.kernel.flash_attention_mla_dq")
-def _m_dq_call(qn, qr, kn, kr, v, out, lse, do, cfg):
-    grid, scale, s = _mla_grid(qn, qr, v, cfg, kv_resident=False)
-    blk = cfg[0]
-    pairs, dk, dv = _mla_pairs(qn, qr, v)
-    return pl.pallas_call(
-        partial(_m_dq_kernel, scale=scale),
-        grid=grid,
-        in_specs=[s["qn"], s["qr"], s["kn"], s["kr"], s["kv"], s["qv"],
-                  s["qv"], s["row"]],
-        out_specs=[s["qn"], s["qr"]],
-        out_shape=[jax.ShapeDtypeStruct(qn.shape, qn.dtype),
-                   jax.ShapeDtypeStruct(qr.shape, qr.dtype)],
-        scratch_shapes=[pltpu.VMEM((blk, qn.shape[2]), jnp.float32),
-                        pltpu.VMEM((blk, qr.shape[2]), jnp.float32)],
-        cost_estimate=pl.CostEstimate(
-            flops=2 * pairs * (2 * dk + dv), transcendentals=pairs,
-            bytes_accessed=(3 * qn.size + 3 * v.size) * qn.dtype.itemsize),
-        interpret=cfg[2],
-    )(qn, qr, kn, kr, v, do, out, lse)
+def _mla_bwd_vmem_bytes(np_, blk, widths, itemsize):
+    """What the fused backward holds in VMEM: the whole row range's two
+    float32 dq accumulators; each operand and result tile twice (the
+    pipeline's two buffers); the kv block's three accumulators; and room
+    for the float32 score-sized temporaries of one tile pair (s, p, dp,
+    ds, their casts and transposes)."""
+    dn, dr, dv = (-(-d // _LANES) * _LANES for d in widths)
+    acc = 4 * np_ * (dn + dr)
+    tiles = 2 * blk * (itemsize * (4 * dn + 3 * dr + 4 * dv)
+                       + 4 * (_LANES + dr))
+    return acc + tiles + 4 * blk * (dn + dr + dv) + 12 * 4 * blk * blk
 
 
-@jax.named_scope("dsod.kernel.flash_attention_mla_dkv")
-def _m_dkv_call(qn, qr, kn, kr, v, out, lse, do, cfg):
-    """kv block resident, the head's q blocks stream past.  The shared
-    rotary key's cotangent comes out per head, float32 (summed over the
-    heads by the caller)."""
+@jax.named_scope("dsod.kernel.flash_attention_mla_bwd")
+def _m_bwd_kernel_call(qn, qr, kn, kr, v, out, lse, do, cfg):
+    """One visit of each tile pair gives dq, dk and dv (the comment that
+    heads this section).  The shared rotary key's cotangent comes out
+    per head, float32 (summed over the heads by the caller)."""
+    from .vmem_budget import fitted_vmem_params
+
     grid, scale, s = _mla_grid(qn, qr, v, cfg, kv_resident=True)
-    blk = cfg[0]
-    pairs, dk, dv = _mla_pairs(qn, qr, v)
+    blk, nb = cfg[0], grid[1]
+    np_, dn = qn.shape[1:]
+    dr, dv = qr.shape[2], v.shape[2]
+    pairs = _mla_pairs(qn, qr, v)[0]
+    acc = lambda *shape: pltpu.VMEM(shape, jnp.float32)  # noqa: E731
     return pl.pallas_call(
-        partial(_m_dkv_kernel, scale=scale),
+        partial(_m_bwd_kernel, scale=scale),
         grid=grid,
         in_specs=[s["kn"], s["kr"], s["kv"], s["qn"], s["qr"], s["qv"],
                   s["qv"], s["row"]],
-        out_specs=[s["kn"], s["kr_own"], s["kv"]],
-        out_shape=[jax.ShapeDtypeStruct(kn.shape, kn.dtype),
+        # dq's finished block (i, written at the diagonal) rides the kv
+        # block's index map: the kv-side specs of its two widths.
+        out_specs=[s["kn"], s["kr_own"], s["kn"], s["kr_own"], s["kv"]],
+        out_shape=[jax.ShapeDtypeStruct(qn.shape, qn.dtype),
+                   jax.ShapeDtypeStruct(qr.shape, qr.dtype),
+                   jax.ShapeDtypeStruct(kn.shape, kn.dtype),
                    jax.ShapeDtypeStruct(qr.shape, jnp.float32),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        scratch_shapes=[pltpu.VMEM((blk, kn.shape[2]), jnp.float32),
-                        pltpu.VMEM((blk, qr.shape[2]), jnp.float32),
-                        pltpu.VMEM((blk, v.shape[2]), jnp.float32)],
+        scratch_shapes=[acc(nb, blk, dn), acc(nb, blk, dr), acc(blk, dn),
+                        acc(blk, dr), acc(blk, dv)],
+        compiler_params=fitted_vmem_params(
+            _mla_bwd_vmem_bytes(np_, blk, (dn, dr, dv), qn.dtype.itemsize),
+            f"flash_attention_mla's backward over {np_} rows"),
         cost_estimate=pl.CostEstimate(
-            flops=2 * pairs * (2 * dk + 2 * dv), transcendentals=pairs,
-            bytes_accessed=(3 * qn.size + 4 * v.size) * qn.dtype.itemsize),
+            flops=2 * pairs * (3 * (dn + dr) + 2 * dv),
+            transcendentals=pairs,
+            bytes_accessed=4 * (qn.size + qr.size + v.size)
+            * qn.dtype.itemsize + 4 * lse.size),
         interpret=cfg[2],
     )(kn, kr, v, qn, qr, do, out, lse)
 
@@ -915,8 +918,8 @@ def _m_bwd_call(qn, qr, kn, kr, v, out, lse_row, do, cfg):
     # One scope per pallas_call and nothing else under it (the trace
     # reader counts a kernel's calls by its scope).
     lse = jnp.broadcast_to(lse_row[..., None], qn.shape[:2] + (_LANES,))
-    dqn, dqr = _m_dq_call(qn, qr, kn, kr, v, out, lse, do, cfg)
-    dkn, dkr_heads, dv = _m_dkv_call(qn, qr, kn, kr, v, out, lse, do, cfg)
+    dqn, dqr, dkn, dkr_heads, dv = _m_bwd_kernel_call(
+        qn, qr, kn, kr, v, out, lse, do, cfg)
     dkr = jnp.sum(dkr_heads.reshape((kr.shape[0], -1) + kr.shape[1:]),
                   axis=1).astype(kr.dtype)
     return dqn, dqr, dkn, dkr, dv
@@ -957,7 +960,10 @@ def flash_attention_mla(q_nope, q_rope, k_nope, k_rope, v, *,
     [B, N, dr]; v: [B, H, N, dv] -> [B, H, N, dv].  Scores are
     ``(q_nope . k_nope + q_rope . k_rope) / sqrt(dn + dr)``.  Any N
     (zero-padded to the block); each width <= 128 or a multiple of 128.
-    Differentiable via the Pallas backward kernels.
+    Differentiable: the backward is one Pallas kernel that visits each
+    tile pair once for dq, dk and dv, its float32 dq accumulators held
+    in VMEM for the whole sequence of a head — a sequence too long for
+    the chip's VMEM raises.
     """
     b, h, n, dn = q_nope.shape
     dr, dv = q_rope.shape[-1], v.shape[-1]

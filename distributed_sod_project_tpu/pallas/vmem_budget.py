@@ -57,3 +57,21 @@ def rows_per_band(n_rows: int, *, per_row: int, fixed: int,
         return 0
     n_bands = -(-n_rows // fit)
     return -(-n_rows // n_bands)
+
+
+def fitted_vmem_params(need_bytes: int, what: str) -> pltpu.CompilerParams:
+    """A scoped-VMEM ceiling of ``need_bytes`` — what a kernel's shapes
+    say it holds — for the chip this process compiles for; a
+    ``ValueError`` where that passes the chip's VMEM (utils/chips.py).
+    Off-TPU the kernels run in interpret mode: no limit, no params."""
+    from ..utils.chips import chip_peaks
+
+    kind = _device_kind()
+    if kind is None:
+        return pltpu.CompilerParams()
+    limit = chip_peaks(kind).vmem_bytes
+    if need_bytes > limit:
+        raise ValueError(
+            f"{what} needs {need_bytes / 2**20:.1f} MiB of VMEM; a "
+            f"{kind} has {limit / 2**20:.0f} MiB")
+    return pltpu.CompilerParams(vmem_limit_bytes=need_bytes)

@@ -144,9 +144,14 @@ _gmm = partial(gm.grouped_matmul, interpret=False)
 _mla = partial(fa.flash_attention_mla, interpret=False)
 # kimi_vl_a3b_ep8: one 16,384-token sequence of 16 heads (the cell runs
 # two): q_nope, q_rope, k_nope, the ONE shared rotary key, v.
-_MLA_ARGS = (_S((1, 16, 16384, 128), BF), _S((1, 16, 16384, 64), BF),
-             _S((1, 16, 16384, 128), BF), _S((1, 16384, 64), BF),
-             _S((1, 16, 16384, 128), BF))
+def _mla_args(n):
+    return (_S((1, 16, n, 128), BF), _S((1, 16, n, 64), BF),
+            _S((1, 16, n, 128), BF), _S((1, n, 64), BF),
+            _S((1, 16, n, 128), BF))
+
+
+_mla_grad = jax.grad(lambda *a: _mla(*a).astype(F32).sum(),
+                     argnums=(0, 1, 2, 3, 4))
 
 
 def _gmm_args(a, b, tiles=24, experts=8):
@@ -228,10 +233,13 @@ CASES = {
         jax.grad(lambda q, k, v: _causal(q, k, v).astype(F32).sum(),
                  argnums=(0, 1, 2)), _CAUSAL_QKV, 3),
     # kimi_vl_a3b_ep8: keys of 128 + 64 columns against values of 128.
-    "flash_attention_mla.fwd@16384": (_mla, _MLA_ARGS, 1),
-    "flash_attention_mla.bwd@16384": (
-        jax.grad(lambda *a: _mla(*a).astype(F32).sum(),
-                 argnums=(0, 1, 2, 3, 4)), _MLA_ARGS, 3),
+    "flash_attention_mla.fwd@16384": (_mla, _mla_args(16384), 1),
+    # The backward is ONE kernel whose float32 dq accumulators hold the
+    # head's whole sequence in VMEM: 16 MiB of the 32.5 MiB scoped limit
+    # the shapes derive at the cell's 16,384 rows, 64 of 80.5 at 65,536
+    # (interpret mode cannot show a VMEM overflow; the compiler does).
+    "flash_attention_mla.bwd@16384": (_mla_grad, _mla_args(16384), 2),
+    "flash_attention_mla.bwd@65536": (_mla_grad, _mla_args(65536), 2),
     # ... and its grouped expert products at 2048 -> 1408 -> 2048 over
     # the usual buffer's 80 row tiles.
     "grouped_matmul.dx+dw@2048x1408": (

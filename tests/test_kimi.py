@@ -11,6 +11,7 @@ tiny widths, float32, seeded weights (benchmark/harness/weights_lm.py):
 - the bias moves by exactly ``gamma`` against the sign of each expert's
   surplus, only where its buffer is mutable, and takes no gradient;
 - what a rematerialised layer keeps by name, and the step's log line;
+- one forward and one backward attention kernel a layer in the gradient;
 - every new ``dsod.*`` scope in the lowered step, inside the encoder
   stage, and no matrix product outside a stage;
 - the first token model's step is the program it was (StableHLO sha256);
@@ -323,14 +324,33 @@ def test_the_step_says_what_its_remat_saves(setup, caplog):
     assert km.REMAT_SAVES == ("mla_out", "mla_lse", "plan")
 
 
+@pytest.mark.parametrize("kernel", ["_m_fwd_kernel", "_m_bwd_kernel"])
+def test_gradient_runs_one_forward_and_one_backward_kernel_a_layer(setup,
+                                                                   kernel):
+    """In the jaxpr of the config's gradient (all 6 layers, tiny widths)
+    latent attention is 6 forward calls (out and lse are kept, so no
+    recompute) and 6 backward calls: ONE kernel gives dq, dk and dv."""
+    from test_lfm2 import _eqns
+
+    cfg, model, v, tokens, _ = setup
+    layers = len(cfg.model.lm.ffn_types)
+    assert layers == len(get_config("kimi_vl_a3b_ep8").model.lm.ffn_types) \
+        == 6
+    jaxpr = jax.make_jaxpr(jax.grad(_loss_of(model, v, tokens)))(v["params"])
+    names = [eqn.params["jaxpr"].debug_info.func_name
+             for eqn in _eqns(jaxpr.jaxpr)
+             if eqn.primitive.name == "pallas_call"]
+    assert names.count(kernel) == layers
+    assert sum(n.startswith("_m_") for n in names) == 2 * layers
+
+
 # -- scopes -------------------------------------------------------------------
 
 SCOPES = ("dsod.attn", "dsod.densemlp", "dsod.moe.route", "dsod.moe.experts",
           "dsod.moe.combine", "dsod.moe.shared", "dsod.moe.balance",
           "dsod.kernel.grouped_matmul", "dsod.kernel.grouped_matmul_dw",
           "dsod.kernel.moe_unpermute", "dsod.kernel.flash_attention_mla",
-          "dsod.kernel.flash_attention_mla_dq",
-          "dsod.kernel.flash_attention_mla_dkv")
+          "dsod.kernel.flash_attention_mla_bwd")
 _STAGE = re.compile(r"dsod\.(encoder|decoder|heads|loss|update)\b")
 
 

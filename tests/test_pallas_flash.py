@@ -235,6 +235,67 @@ def test_flash_mla_matches_plain_attention(n, block, what):
             atol=3e-6 * float(jnp.max(jnp.abs(b))))
 
 
+# at least 3 blocks a side: pairs on the diagonal, below it (2-3 q blocks
+# add to one dq accumulator; kv block 0 sees every q block) and skipped
+@pytest.mark.parametrize("n,block,h", [(384, 128, 3), (512, 128, 2),
+                                       (768, 256, 2)])
+def test_flash_mla_fused_backward_matches_plain_gradient(n, block, h):
+    """The ONE backward kernel, called as the custom VJP calls it,
+    against the float32 gradient of plain latent attention: dq (whole-
+    sequence accumulators that must start from zero at every (batch,
+    head) row: the rows hold different data), per-head dk_nope and dv,
+    and the shared rotary key's cotangent summed over the heads."""
+    from distributed_sod_project_tpu.pallas.flash_attention import (
+        _m_bwd_call, _m_fwd_call)
+
+    *args, cot = _mla_inputs(n, b=2, h=h)
+    fold = lambda t: t.reshape((-1,) + t.shape[-2:])  # noqa: E731
+    folded = [fold(t) for t in args]
+    cfg = (block, h, True)
+    out, lse = _m_fwd_call(*folded, cfg)
+    got = _m_bwd_call(*folded, out, lse[:, :, 0], fold(cot), cfg)
+    want = jax.grad(lambda *a: jnp.sum(_plain_mla(*a) * cot),
+                    (0, 1, 2, 3, 4))(*args)
+    for name, a, b in zip(("dq_nope", "dq_rope", "dk_nope", "dk_rope",
+                           "dv"), got, want):
+        assert a.shape == fold(b).shape, name
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(fold(b)), err_msg=name,
+            atol=3e-6 * float(jnp.max(jnp.abs(b))))
+
+
+def test_flash_mla_backward_refuses_a_sequence_its_vmem_cannot_hold(
+        monkeypatch):
+    """The dq accumulators hold a head's whole sequence in VMEM: on a
+    chip whose VMEM they pass, the call raises and names the limit (a
+    compile error from Mosaic says far less); the scoped limit it asks
+    for otherwise is what the shapes derive.  Shapes only: nothing
+    runs."""
+    from distributed_sod_project_tpu.pallas import vmem_budget as vb
+    from distributed_sod_project_tpu.pallas.flash_attention import (
+        _m_bwd_call, _mla_bwd_vmem_bytes)
+
+    monkeypatch.setattr(vb, "_device_kind", lambda: "TPU v5 lite")
+
+    def call(n):
+        bf = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16)  # noqa: E731
+        return jax.eval_shape(
+            lambda *a: _m_bwd_call(*a, (512, 16, False)),
+            bf(16, n, 128), bf(16, n, 64), bf(16, n, 128), bf(1, n, 64),
+            bf(16, n, 128), bf(16, n, 128),
+            jax.ShapeDtypeStruct((16, n), jnp.float32), bf(16, n, 128))
+
+    assert call(16384)[1].shape == (16, 16384, 64)
+    need = _mla_bwd_vmem_bytes(16384, 512, (128, 64, 128), 2)
+    assert 16 * 2**20 < need - 16384 * 1024 < 32 * 2**20  # beside 16 MiB
+    assert vb.fitted_vmem_params(need, "x").vmem_limit_bytes == need
+    with pytest.raises(ValueError, match=r"131072 rows needs 144\.5 MiB of "
+                                         r"VMEM; a TPU v5 lite has 128 MiB"):
+        call(131072)
+    monkeypatch.setattr(vb, "_device_kind", lambda: None)  # interpret mode
+    assert vb.fitted_vmem_params(2**40, "x").vmem_limit_bytes is None
+
+
 def test_flash_mla_raises_where_a_tile_does_not_divide():
     """A floor-divided grid would leave the last rows unwritten, which
     interpret mode at one block cannot show (PERF.md, PR 28): the calls
