@@ -88,12 +88,15 @@ class DataConfig:
 
 @dataclasses.dataclass(frozen=True)
 class LMConfig:
-    """Shape of a token model (model.name=lfm2 | kimi, models/lfm2.py,
-    models/kimi.py).  The defaults are LFM2-8B-A1B's published widths
-    (LiquidAI, config.json) and the share one chip holds in
-    `lfm2_8b_a1b_ep4`: the layers kept, `experts_held` of `experts` from
-    `first_expert` on, `vocab` rows of the 65,536.  `kimi_vl_a3b_ep8`
-    sets every field it reads (configs/experiments.py)."""
+    """Shape of a token model (model.name=lfm2 | kimi | granite,
+    models/lfm2.py, models/kimi.py, models/granite.py).  The defaults
+    are LFM2-8B-A1B's published widths (LiquidAI, config.json) and the
+    share one chip holds in `lfm2_8b_a1b_ep4`: the layers kept,
+    `experts_held` of `experts` from `first_expert` on, `vocab` rows of
+    the 65,536.  `kimi_vl_a3b_ep8` and `granite_4_0_h_micro_pp4` set
+    every field they read (configs/experiments.py).  The state-space
+    sizes and the four multipliers are read by `granite` alone, whose
+    attention layer is position-free (it reads no `rope_theta`)."""
 
     vocab: int = 16384
     hidden: int = 2048
@@ -128,6 +131,25 @@ class LMConfig:
     v_dim: int = 0
     kv_rank: int = 0
     shared_experts: int = 0  # each expert_width wide, over every token
+    # The selective state-space layer (granite; layer_types: mamba |
+    # attention): ssm_heads heads of ssm_head_dim columns, each carrying
+    # a float32 state of ssm_head_dim x ssm_state; B and C are ssm_state
+    # wide and shared by all heads (one group); the depthwise causal
+    # conv over x | B | C has ssm_conv taps and a bias; ssm_chunk is the
+    # chunk of the scan (pallas/ssd_scan.py), which has to divide the
+    # sequence.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_conv: int = 4
+    ssm_chunk: int = 256
+    # The family's four multipliers (granite): on the embedding, on each
+    # residual branch, on the attention scores (0: 1/sqrt(head_dim)) and
+    # the divisor of the logits.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)

@@ -252,3 +252,42 @@ def kimi_vl_a3b_ep8() -> ExperimentConfig:
         num_epochs=100,
         mesh=MeshConfig(data=1, model=1, seq=1),
     )
+
+
+@register_config("granite_4_0_h_micro_pp4")
+def granite_4_0_h_micro_pp4() -> ExperimentConfig:
+    """The third token model: granite-4.0-h-micro (ibm-granite,
+    ``granitemoehybrid``, dense) at its published widths, the FIRST of 4
+    pipeline stages — layers 0-9 of the 40, one whole period of the
+    layer pattern (five Mamba-2 layers, one position-free grouped-query
+    attention layer, four Mamba-2 layers), no layer divided — with rows
+    0-12,543 of the 100,352-row tied embedding (the rows are divided
+    over 8 chips; the tied head and the loss stay on this chip so that
+    a step is a whole step).  Trains on packed synthetic documents, 1
+    sequence of 16,384 tokens a step, AdamW, per-layer remat.
+    ``model.lm.*`` / ``data.seq_len`` shrink it for a CPU drive
+    (tests/test_granite.py)."""
+    return ExperimentConfig(
+        name="granite_4_0_h_micro_pp4",
+        data=DataConfig(dataset="packed_tokens", hflip=False,
+                        synthetic_size=4096, seq_len=16384, vocab=12544),
+        model=ModelConfig(
+            name="granite", backbone="none", sync_bn=False, remat=True,
+            lm=LMConfig(
+                vocab=12544, hidden=2048,
+                layer_types=("mamba",) * 5 + ("attention",)
+                + ("mamba",) * 4,
+                ffn_types=("dense",) * 10, heads=32, kv_heads=8,
+                head_dim=64, dense_width=8192, norm_eps=1e-5,
+                ssm_heads=64, ssm_head_dim=64, ssm_state=128, ssm_conv=4,
+                ssm_chunk=256, embedding_multiplier=12.0,
+                residual_multiplier=0.22, attention_multiplier=0.015625,
+                logits_scaling=8.0)),
+        loss=LossConfig(),
+        # AdamW and the warm-up of the two other token configs.
+        optim=OptimConfig(optimizer="adamw", lr=3e-4, weight_decay=0.1,
+                          schedule="poly", warmup_steps=2000),
+        global_batch_size=1,
+        num_epochs=100,
+        mesh=MeshConfig(data=1, model=1, seq=1),
+    )

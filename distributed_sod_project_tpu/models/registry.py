@@ -201,3 +201,14 @@ def _build_kimi(cfg, *, dtype, param_dtype, axis_name):
 
     return KimiDecoder(cfg=cfg.lm, remat=cfg.remat, dtype=dtype,
                        param_dtype=param_dtype)
+
+
+@register_model("granite")
+def _build_granite(cfg, *, dtype, param_dtype, axis_name):
+    """The third token model (Mamba-2 state-space layers with a
+    position-free attention layer to every nine): its shape is
+    ``cfg.lm``, as for ``lfm2``."""
+    from .granite import Granite
+
+    return Granite(cfg=cfg.lm, remat=cfg.remat, dtype=dtype,
+                   param_dtype=param_dtype)

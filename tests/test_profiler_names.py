@@ -132,7 +132,11 @@ _TOKEN_MODELS = {
                                    "model.lm.head_dim=16"],
     "kimi_vl_a3b_ep8": _TINY_LM + [
         "model.lm.head_dim=24", "model.lm.rope_dim=8", "model.lm.v_dim=16",
-        "model.lm.kv_rank=32"]}
+        "model.lm.kv_rank=32"],
+    "granite_4_0_h_micro_pp4": _TINY_LM + [
+        "model.lm.kv_heads=2", "model.lm.head_dim=16", "model.lm.ssm_heads=8",
+        "model.lm.ssm_head_dim=16", "model.lm.ssm_state=16",
+        "model.lm.ssm_chunk=64"]}
 
 
 def _lowered_step_text(name: str, size: int = 64) -> str:

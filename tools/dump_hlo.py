@@ -33,7 +33,10 @@ _TINY = ["model.lm.vocab=512", "model.lm.hidden=64", "model.lm.heads=4",
 _TINY_LM = {  # model.name -> the token model's shrink
     "lfm2": _TINY + ["model.lm.kv_heads=2", "model.lm.head_dim=16"],
     "kimi": _TINY + ["model.lm.head_dim=24", "model.lm.rope_dim=8",
-                     "model.lm.v_dim=16", "model.lm.kv_rank=32"]}
+                     "model.lm.v_dim=16", "model.lm.kv_rank=32"],
+    "granite": _TINY + ["model.lm.kv_heads=2", "model.lm.head_dim=16",
+                        "model.lm.ssm_heads=8", "model.lm.ssm_head_dim=16",
+                        "model.lm.ssm_state=16", "model.lm.ssm_chunk=32"]}
 
 
 def dump(config_name: str, out_dir: str, n_devices: int = 8,
