@@ -36,9 +36,9 @@ recomputes the block from its input except the values
 :data:`REMAT_SAVES` names.
 
 Device scopes (PERF.md section 3): ``dsod.encoder`` over the stack;
-``dsod.ssm`` around the mixer, inside it ``dsod.ssm.conv``,
-``dsod.ssm.scan`` (delta, the running sums, the two kernels) and
-``dsod.ssm.gate`` (the D skip and the gated norm);
+``dsod.ssm`` around the mixer, inside it ``dsod.ssm.conv`` (the two
+conv kernels), ``dsod.ssm.scan`` (delta, the running sums, the two
+kernels) and ``dsod.ssm.gate`` (the D skip and the gated norm);
 ``dsod.attn`` and ``dsod.densemlp`` as in ``lfm2.py``; the final norm is
 ``dsod.heads``.  Counters beside ``grad_norm``: ``ssm_decay_min`` (the
 smallest ``exp(delta A)`` of any head, token and layer of the step: how
@@ -54,6 +54,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..pallas.causal_conv import causal_conv_silu
 from ..pallas.flash_attention import (CAUSAL_RESIDUAL_NAMES,
                                       flash_attention_causal)
 from ..pallas.ssd_scan import ssd_scan
@@ -98,21 +99,21 @@ class Attention(nn.Module):
 
 
 class CausalConv(nn.Module):
-    """Depthwise causal convolution with a bias.  ``kernel`` is [L, D];
+    """``silu(conv(x) + bias)``: a depthwise causal convolution with a
+    bias and its activation, float32 arithmetic on operands and a result
+    of ``x.dtype`` (``pallas/causal_conv.py``).  ``kernel`` is [L, D];
     tap j multiplies the input L-1-j positions back."""
     taps: int = 4
     param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
-    def __call__(self, x):                       # [B, N, D] float32
+    def __call__(self, x):                       # [B, N, D]
         d = x.shape[-1]
         k = self.param("kernel", nn.initializers.lecun_normal(),
                        (self.taps, d), self.param_dtype)
         bias = self.param("bias", nn.initializers.zeros, (d,),
                           self.param_dtype)
-        n = x.shape[1]
-        xp = jnp.pad(x, ((0, 0), (self.taps - 1, 0), (0, 0)))
-        return sum(xp[:, j:j + n] * k[j] for j in range(self.taps)) + bias
+        return causal_conv_silu(x, k, bias)
 
 
 def _a_log_init(key, shape, dtype):
@@ -145,9 +146,7 @@ class Mamba2Mixer(nn.Module):
                         self.param_dtype)(u)
         z, xbc, dt = jnp.split(zxbcdt, [inner, 2 * inner + 2 * s], -1)
         with jax.named_scope("dsod.ssm.conv"):
-            xbc = nn.silu(CausalConv(self.taps, self.param_dtype,
-                                     name="conv")(xbc.astype(jnp.float32))
-                          ).astype(self.dtype)
+            xbc = CausalConv(self.taps, self.param_dtype, name="conv")(xbc)
         x, bm, cm = jnp.split(xbc, [inner, inner + s], -1)
         x = x.reshape(b, n, h, p)
         vec = lambda name, init: self.param(  # noqa: E731

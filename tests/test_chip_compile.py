@@ -34,11 +34,12 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 _P = "distributed_sod_project_tpu.pallas."
-fc, fr, dfm, fl, fs, fa, vb, gm, mu, ssd = (
+fc, fr, dfm, fl, fs, fa, vb, gm, mu, ssd, cc = (
     importlib.import_module(_P + m)
     for m in ("fused_conv", "fused_resample", "dynamic_filter",
               "fused_loss", "fused_ssim", "flash_attention",
-              "vmem_budget", "grouped_matmul", "moe_unpermute", "ssd_scan"))
+              "vmem_budget", "grouped_matmul", "moe_unpermute", "ssd_scan",
+              "causal_conv"))
 
 _S = collections.namedtuple("_S", "shape dtype")  # an argument's spec
 B = 2  # the kernels grid over images; the tile is what the compiler prices
@@ -162,6 +163,13 @@ _SSD_ARGS = (_S((1, 16384, 64, 64), BF), _S((1, 16384, 64), F32),
              _S((1, 16384, 128), BF))
 
 
+# ... and its mixer's conv + bias + SiLU over x | B | C: 4,352 columns of
+# one 16,384-token sequence, 4 float32 taps and a bias.
+_cconv = partial(cc.causal_conv_silu, interpret=False)
+_CCONV_ARGS = (_S((1, 16384, 4352), BF), _S((4, 4352), F32),
+               _S((4352,), F32))
+
+
 def _gmm_args(a, b, tiles=24, experts=8):
     return (_S((tiles * 512, a), BF), _S((experts, a, b), F32),
             _S((tiles,), jnp.int32), _S((1,), jnp.int32))
@@ -275,6 +283,13 @@ CASES = {
     "ssd_scan.bwd@16384": (
         jax.grad(lambda *a: _ssd(*a).astype(F32).sum(),
                  argnums=(0, 1, 2, 3, 4)), _SSD_ARGS, 2),
+    # ... and the conv kernel pair in front of it: the forward, and the
+    # gradient (the forward's output is not needed: ONE backward kernel
+    # that makes the pre-activation again and gives dx, dk and dbias).
+    "causal_conv.fwd@16384x4352": (_cconv, _CCONV_ARGS, 1),
+    "causal_conv.bwd@16384x4352": (
+        jax.grad(lambda *a: _cconv(*a).astype(F32).sum(),
+                 argnums=(0, 1, 2)), _CCONV_ARGS, 1),
     "moe_unpermute@53248": _unpermute(53248),
     "moe_unpermute@135168": _unpermute(135168),
 }
@@ -294,10 +309,11 @@ def test_the_state_space_step_compiles_for_v5e_and_fits(chip, topo,
                                                         monkeypatch):
     """``granite_4_0_h_micro_pp4``'s whole train step at the cell's size
     (published widths, 10 layers, 16,384 tokens) compiled for a described
-    v5e: 30 kernels (18 forward scans, 9 backward, the three causal flash
-    kernels), and state + temporaries inside the chip's 15.75 GiB.  (The
-    compiler's own books, which decide whether it rematerialises, are
-    read from its log: .claude/skills/verify.)"""
+    v5e: 57 kernels (18 forward scans and 9 backward, 18 forward convs
+    and 9 backward, the three causal flash kernels), and state +
+    temporaries inside the chip's 15.75 GiB.  (The compiler's own books,
+    which decide whether it rematerialises, are read from its log:
+    .claude/skills/verify.)"""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -328,7 +344,7 @@ def test_the_state_space_step_compiles_for_v5e_and_fits(chip, topo,
         remat_policy=cfg.model.remat_policy)
     lowered = step.lower(jax.tree_util.tree_map(on(P()), state),
                          jax.tree_util.tree_map(on(P("data")), batch))
-    assert lowered.as_text().count("tpu_custom_call") == 30
+    assert lowered.as_text().count("tpu_custom_call") == 57
     mem = lowered.compile().memory_analysis()
     state_gib = mem.argument_size_in_bytes / 2 ** 30
     assert 8.5 < state_gib < 8.8          # 772 M parameters x 12 bytes

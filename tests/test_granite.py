@@ -17,7 +17,8 @@ seeded weights (benchmark/harness/weights_ssm.py):
 - what a rematerialised layer keeps by name, and the step's log line;
 - the scan's carried state is float32 under bfloat16 operands;
 - one forward scan kernel a Mamba-2 layer in the forward, two and one
-  backward in the gradient;
+  backward in the gradient; the same count of the conv's kernel pair,
+  layer by layer;
 - every new ``dsod.*`` scope in the lowered step, inside the encoder
   stage;
 - the first two token models' steps are the programs they were
@@ -25,6 +26,7 @@ seeded weights (benchmark/harness/weights_ssm.py):
 - three steps of ``fit()`` with the two counters on the stream.
 """
 
+import collections
 import dataclasses
 import hashlib
 import logging
@@ -367,6 +369,30 @@ def test_gradient_runs_the_scan_kernels_it_should(setup):
     assert names.count("_c_dq_kernel") == names.count("_c_dkv_kernel") == 1
 
 
+def test_gradient_runs_the_conv_kernels_it_should(setup):
+    """Layer by layer in the same jaxpr: each Mamba-2 layer runs the conv's
+    forward kernel twice (``x`` is no named save, so the layer's backward
+    makes it and the conv's output again) and its ONE backward kernel
+    once; the attention layer runs neither."""
+    from test_lfm2 import _eqns
+
+    _, model, v, tokens, _ = setup
+    jaxpr = jax.make_jaxpr(jax.grad(_loss_of(model, tokens)))(v["params"])
+    calls = collections.Counter()
+    for eqn in _eqns(jaxpr.jaxpr):
+        if eqn.primitive.name == "pallas_call":
+            stack = str(eqn.source_info.name_stack)
+            kernel = re.search(r"dsod\.kernel\.(\w+)$", stack).group(1)
+            if kernel.startswith("causal_conv"):
+                assert "/dsod.ssm.conv/" in stack, stack
+                calls[re.search(r"layer_(\d+)", stack).group(1), kernel] += 1
+    mamba = [str(i) for i, op in enumerate(model.cfg.layer_types)
+             if op == "mamba"]
+    assert len(mamba) == 9
+    assert calls == {(i, k): n for i in mamba for k, n in (
+        ("causal_conv", 2), ("causal_conv_bwd", 1))}
+
+
 def test_the_scan_carries_its_state_in_float32_under_bfloat16_operands():
     """The configuration states a float32 carried state.  On the chip a
     bfloat16 state stays inside the cell's limits (PERF.md section 7),
@@ -392,7 +418,8 @@ def test_the_scan_carries_its_state_in_float32_under_bfloat16_operands():
 
 SCOPES = ("dsod.ssm", "dsod.ssm.conv", "dsod.ssm.scan", "dsod.ssm.gate",
           "dsod.attn", "dsod.densemlp", "dsod.kernel.ssd_scan",
-          "dsod.kernel.ssd_scan_bwd", "dsod.kernel.flash_attention_causal",
+          "dsod.kernel.ssd_scan_bwd", "dsod.kernel.causal_conv",
+          "dsod.kernel.causal_conv_bwd", "dsod.kernel.flash_attention_causal",
           "dsod.kernel.flash_attention_causal_dq",
           "dsod.kernel.flash_attention_causal_dkv")
 _STAGE = re.compile(r"dsod\.(encoder|decoder|heads|loss|update)\b")
@@ -414,6 +441,8 @@ def test_lowered_step_names_the_new_scopes(lowered_text, scope):
     assert {"encoder"} in stages and all(s <= {"encoder"} for s in stages)
     if scope.startswith("dsod.kernel.ssd_scan"):
         assert all("dsod.ssm.scan" in p for p in under)
+    if scope.startswith("dsod.kernel.causal_conv"):
+        assert all("dsod.ssm.conv" in p for p in under)
 
 
 def test_no_product_of_the_step_is_outside_a_stage(lowered_text):
