@@ -26,4 +26,12 @@ because the upstream-style name ``distributed-sod-project_tpu`` is not a
 valid Python identifier.
 """
 
+import time as _time
+
+# The package's first line, on the clock of the host-clock sink
+# (utils/tracing.py): ``fit()`` records ``dsod.setup.before_fit`` from
+# here to its entry — the caller's imports of JAX and of the program,
+# the backend's start, its own set-up.  Nothing heavy is imported here.
+T_IMPORT = _time.perf_counter()
+
 __version__ = "0.1.0"

@@ -11,6 +11,11 @@ built by the unified rules engine (`parallel/engine.py`).
 
 from __future__ import annotations
 
+import collections
+import gc
+import itertools
+import os
+import statistics
 import time
 from typing import Callable, Dict, Optional
 
@@ -19,10 +24,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ckpt import CheckpointManager
-from ..configs.base import ExperimentConfig
-from ..data import prefetch_to_device, resolve_dataset
+from ..configs.base import (ExperimentConfig, validate_parallel,
+                            validate_steps_per_dispatch)
+from ..data import chunk_batches, prefetch_to_device, resolve_dataset
 from ..models import build_model
 from ..parallel.mesh import make_mesh, replicated_sharding
+from ..utils import tracing
 from ..utils.logging import get_logger, is_primary_process
 from ..utils.timing import StepTimer
 from .optim import build_optimizer
@@ -83,13 +90,15 @@ def fit(
     watchdog; ``cfg.data.skip_budget`` tolerates corrupt samples;
     ``DSOD_FAULTS`` injects deterministic faults (chaos tests).
     """
-    import os
-
     from ..resilience import inject
-    from ..utils.observability import (MetricWriter, PreemptionGuard,
-                                       profile_window)
+    from ..utils.observability import (MetricWriter, PipelineStats,
+                                       PreemptionGuard, profile_window)
+    from ..utils.tracing import Tracer, mint_trace_id, span
 
     log = get_logger()
+    # dsod.setup.before_fit ends and dsod.setup.build opens HERE, on
+    # the host clock (docs/OBSERVABILITY.md "Set-up phases").
+    watch = _HostWatch(log)
     hooks = hooks or {}
     workdir = workdir or cfg.checkpoint_dir
     plan = inject.plan_from_env()
@@ -131,7 +140,6 @@ def fit(
             f"the data mesh axis ({data_size})")
 
     from ..data.tfdata import make_loader
-
     from ..parallel.mesh import host_batch_shard
 
     # Mesh-position-derived, NOT process_index: hosts that share a
@@ -166,21 +174,14 @@ def fit(
         data_guard = GuardedDataset(dataset, cfg.data.skip_budget,
                                     fault_plan=plan)
         dataset = data_guard
-    # Spans (utils/tracing.py::span; docs/OBSERVABILITY.md): the loop
-    # and the data plane name what they do under ``dsod.train.*`` /
-    # ``dsod.data.*`` as profiler annotations — in the .xplane.pb of
-    # any profiler session, on the device ops' clock, a flag check
-    # otherwise — and sampled chunks (cfg.trace_sample) keep the same
-    # intervals in the /debug/traces ring, correlated to step numbers.
-    # sample=0 (default): no clock reads, no Tracer calls.
-    from ..utils.tracing import Tracer, mint_trace_id, span
-
-    tracer = Tracer(sample=cfg.trace_sample)
+    # Spans (utils/tracing.py::span; docs/OBSERVABILITY.md): what loop
+    # and data plane do under ``dsod.train.*`` / ``dsod.data.*``, as
+    # profiler annotations, as seconds per name on the host clock, and
+    # for sampled chunks (cfg.trace_sample) in the /debug/traces ring.
+    tracer = Tracer(sample=cfg.trace_sample, clock=tracing.now)
     # Host-data-plane telemetry: every blocking point in the loader /
     # prefetch stages reports here; the per-interval deltas ride the
     # metric stream (data_starved_ms: the loop's wait for a batch).
-    from ..utils.observability import PipelineStats
-
     data_stats = PipelineStats(keep_spans=tracer.enabled)
     loader = make_loader(
         dataset, cfg.data,
@@ -204,10 +205,9 @@ def fit(
     # Chunk-boundary contract: every cadence knob AND the loader's
     # actual epoch period must be multiples of k (loud ValueError
     # naming the offending pair — configs/base.py).
-    from ..configs.base import validate_steps_per_dispatch
-
     validate_steps_per_dispatch(cfg.replace(steps_per_dispatch=k),
                                 loader.steps_per_epoch)
+    watch.lap("loader_start")
     total_steps = steps_per_epoch * cfg.num_epochs
     if max_steps is not None:
         total_steps = min(total_steps, max_steps)
@@ -221,13 +221,16 @@ def fit(
     model = build_model(cfg.model)
     tx, schedule = build_optimizer(cfg.optim, total_steps)
 
+    watch.lap("model")
     sample = next(iter(loader))
-    from ..utils.checks import validate_first_batch  # by the model's kind
+    watch.lap("loader_first_batch")
+    from ..utils.checks import periodic_validate, validate_first_batch
 
     validate_first_batch(sample, cfg, model)
     state = create_train_state(jax.random.key(cfg.seed), model, tx, sample,
                                pretrained=cfg.model.pretrained,
                                ema=cfg.optim.ema_decay > 0)
+    watch.lap("state_init")
     # Training numerics telemetry (utils/modelhealth.py;
     # docs/OBSERVABILITY.md "Model health"): the step emits per-group
     # grad norms / nonfinite provenance / update ratio, the monitor
@@ -352,6 +355,7 @@ def fit(
                     "with steps_per_dispatch=1 (or a k dividing "
                     f"{start_step}) until the next aligned checkpoint")
 
+    watch.lap("checkpoint")
     # Step builder: every preset routes through the unified rule-driven
     # builder (parallel/engine.py — the only step builder since the
     # round-18 legacy deletion): shard_map DP for the CNN zoo
@@ -359,8 +363,6 @@ def fit(
     # sharded, any ZeRO level is on, or parallel.preset=fsdp shards the
     # params themselves, and the sequence-parallel preset when ``seq``
     # is sharded (ring attention over token blocks, vit_sod only).
-    from ..configs.base import validate_parallel
-
     validate_parallel(cfg)
     from ..parallel import engine as engine_mod
 
@@ -546,6 +548,7 @@ def fit(
         sp_dims = ("data", "seq") if use_sp else ("data",)
         batch_spec_override = P(*(((None,) + sp_dims) if k > 1 else sp_dims))
 
+    watch.lap("step_build")
     writer = MetricWriter(os.path.join(workdir, "tb")
                           if cfg.tensorboard else None)
     eval_fn = (_make_inline_eval(cfg, model, mesh)
@@ -635,6 +638,7 @@ def fit(
         health=health_monitor, alerts=health_alerts,
         capacity=capacity, slo=slo_tracker, registry=registry,
         recorder=recorder)
+    watch.lap("telemetry")
     # A restore means this step's checkpoint already exists on disk — a
     # zero-progress run must not force-save over it (orbax raises).
     last_saved = resumed_from
@@ -664,8 +668,6 @@ def fit(
     # encodes cfg.num_epochs × steps_per_epoch): when cfg.steps_per_epoch
     # overrides the accounting, the loader may need more or fewer passes
     # than cfg.num_epochs.
-    import itertools
-
     def _process_log(at_step, metrics_host, at_epoch):
         """The log-boundary block, shared by the k=1 inline path and the
         chunked flush.  Chunked metrics leaves are (k,)-stacked; the log
@@ -809,8 +811,9 @@ def fit(
             hooks["on_chunk_metrics"](at_step, metrics_host)
         stop = _poll_stop(guard, at_step, sync_every) or stop
         if at_step % cfg.log_every_steps == 0 or at_step == total_steps:
-            with span("dsod.train.log", root, step=at_step):
+            with span("dsod.train.log", root, step=at_step) as logged:
                 _process_log(at_step, metrics_host, at_epoch)
+            watch.flushed(at_step, logged.t1)
         if with_state:
             _run_state_events(at_step, root)
         _finish_chunk_trace(root, at_step)
@@ -828,8 +831,6 @@ def fit(
             # Host-side periodic re-validation rides BEFORE the H2D
             # prefetch (cheap numpy pass, no device sync); off unless
             # cfg.data.validate_every > 0.
-            from ..utils.checks import periodic_validate
-
             host_batches = periodic_validate(iter(loader),
                                              cfg.data.validate_every)
             if k > 1:
@@ -837,8 +838,6 @@ def fit(
                 # leading axis BEFORE the H2D stage, so one transfer
                 # ships a whole dispatch's worth (ring-buffer-aware —
                 # see data/pipeline.py::chunk_batches).
-                from ..data import chunk_batches
-
                 host_batches = chunk_batches(host_batches, k,
                                              stats=data_stats)
             # mesh= (not sharding=): each host contributes its local
@@ -867,7 +866,7 @@ def fit(
                     # unless this chunk is sampled.
                     root = None
                     if tracer.enabled:
-                        t_now = time.monotonic()
+                        t_now = tracing.now()
                         root = tracer.begin(
                             "chunk", mint_trace_id(),
                             t0=t_prev_end if t_prev_end is not None
@@ -880,6 +879,7 @@ def fit(
                         batch = plan.maybe_poison_batch(step + 1, batch)
                     # Host-side dispatch time (the device runs async;
                     # completed-work time shows up in the flush span).
+                    watch.before_dispatch()
                     with span("dsod.train.dispatch", root):
                         if step == profile_at:
                             with profile_window(profile_dir):
@@ -887,6 +887,7 @@ def fit(
                                 jax.block_until_ready(metrics["total"])
                         else:
                             state, metrics = train_step(state, batch)
+                    watch.after_dispatch()
                     step += k
                     if k > 1:
                         # Lagged flush: observe chunk n only after chunk
@@ -897,7 +898,7 @@ def fit(
                             _flush_chunk(with_state=False)
                         pending = (step, metrics, epoch, root)
                         if tracer.enabled:
-                            t_prev_end = time.monotonic()
+                            t_prev_end = tracing.now()
                         continue
                     # ---- k == 1: the historical per-step path.
                     if plan is not None:
@@ -918,12 +919,14 @@ def fit(
                         with span("dsod.train.flush", root):
                             metrics_host = jax.device_get(metrics)
                         _observe_health(metrics_host)
-                        with span("dsod.train.log", root, step=step):
+                        with span("dsod.train.log", root,
+                                  step=step) as logged:
                             _process_log(step, metrics_host, epoch)
+                        watch.flushed(step, logged.t1)
                     _run_state_events(step, root)
                     _finish_chunk_trace(root, step)
                     if tracer.enabled:
-                        t_prev_end = time.monotonic()
+                        t_prev_end = tracing.now()
             if step >= total_steps or stop:
                 break
         if pending is not None:
@@ -949,6 +952,7 @@ def fit(
                 last_eval_step = step
             mgr.save(step, state, metrics=eval_metrics or None, force=True)
     finally:
+        watch.close()
         if recorder is not None:
             import sys as _sys
 
@@ -1085,3 +1089,167 @@ def _make_inline_eval(cfg: ExperimentConfig, model, mesh) -> Callable:
                 if isinstance(v, float)}
 
     return eval_fn
+
+
+class _HostWatch:
+    """``fit()``'s two readers of the host-clock sink
+    (``utils/tracing.py``), both log lines and nothing more.
+
+    ``setup:`` — once, after the ``SETUP_TICKS``-th logging boundary or
+    at the run's end: the sibling ``dsod.setup.*`` phases from the
+    package's import to that boundary, and what JAX's compile events
+    (``utils/platform.py::CompileStats``) say of them.  The watch opens
+    ``dsod.setup.build`` when it is made (``fit()``'s entry), swaps it
+    for ``dsod.setup.first_step`` around the first call of the step,
+    and records one candidate ``dsod.setup.warmup`` per boundary: the
+    caller (the benchmark's window) knows which one opened ITS clock.
+
+    ``stall:`` — for a logging interval that took over ``STALL_RATIO``
+    times the median of the intervals since set-up: what the loop and
+    the data plane's threads spent under each span name in it, the
+    CPU seconds of the process and of this thread (a descheduled or
+    blocked process reads wall >> CPU), the garbage collector's work
+    and the compile events that ended in it.  A steady run logs none.
+    """
+
+    SETUP_TICKS = 8
+    STALL_RATIO = 1.5
+    MIN_HISTORY = 2
+
+    def __init__(self, log):
+        from .. import T_IMPORT
+        from ..utils.platform import CompileStats
+
+        self._log = log
+        self._compiles = CompileStats()
+        self._mark = self._compiles.mark()
+        tracing.reset_setup()
+        tracing.record_setup("dsod.setup.before_fit", T_IMPORT,
+                             tracing.now())
+        self._open = tracing.span("dsod.setup.build").__enter__()
+        self._lap = self._open.t0
+        self._first_step_end = None
+        self._ticks = 0
+        self._setup_logged = False
+        self._walls = collections.deque(maxlen=64)
+        self._last = None  # the open interval's start: see _snapshot
+        self._gc_n, self._gc_s, self._gc_t0 = 0, 0.0, None
+        gc.callbacks.append(self._on_gc)
+
+    # -- set-up ---------------------------------------------------------
+
+    def lap(self, name: str) -> None:
+        """A child of ``dsod.setup.build``, from the last lap (or
+        ``fit()``'s entry) to now: the laps are siblings that touch, so
+        they add up to ``build``."""
+        t = tracing.now()
+        tracing.record_setup("dsod.setup.build." + name, self._lap, t,
+                                   "dsod.setup.build")
+        self._lap = t
+
+    def before_dispatch(self) -> None:
+        """The first call of the step ends ``build`` and is
+        ``first_step``: trace, lowering, compile or cache load."""
+        if self._open is not None:  # only the first call finds one
+            self.lap("first_device_batch")  # loop entry, prefetch, H2D
+            self._open.__exit__(None, None, None)
+            self._open = tracing.span(
+                "dsod.setup.first_step").__enter__()
+
+    def after_dispatch(self) -> None:
+        if self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._first_step_end, self._open = self._open.t1, None
+
+    def _log_setup(self) -> None:
+        self._setup_logged = True
+        spans = tracing.setup_spans()
+        top = {}
+        for name, t0, t1, parent in spans:
+            if parent is None:
+                top[name] = t1 - t0  # warmup: the newest candidate stays
+        phases = []
+        for phase in ("before_fit", "build", "first_step", "warmup"):
+            if "dsod.setup." + phase not in top:
+                continue
+            text = f"{phase} {top['dsod.setup.' + phase]:.1f}"
+            kids = [f"{n.rsplit('.', 1)[-1]} {t1 - t0:.1f}"
+                    for n, t0, t1, parent in spans
+                    if parent == "dsod.setup." + phase]
+            if phase == "before_fit":
+                text += " s"
+            elif phase == "warmup":
+                text += f" ({self._ticks} ticks)"
+            if kids:
+                text += f" ({', '.join(kids)})"
+            phases.append(text)
+        made = self._compiles.since(self._mark)
+        self._log.info(
+            "setup: %s | trace %.1f lower %.1f compile %.1f (hits %d "
+            "misses %d)", " | ".join(phases), made["trace"], made["lower"],
+            made["compile"], made["cache_hits"], made["cache_misses"])
+        # The three largest of each kind, by function: seconds x events
+        # (a step traced twice reads x2).
+        self._log.info("setup: largest %s", " | ".join(
+            kind + " " + ", ".join(f"{name} {s:.1f} x{n}" for name, s, n
+                                   in made["largest"].get(kind, ()))
+            for kind in ("trace", "lower", "compile")))
+
+    # -- intervals ------------------------------------------------------
+
+    def _on_gc(self, phase, _info) -> None:
+        if phase == "start":
+            self._gc_t0 = time.perf_counter()
+        elif self._gc_t0 is not None:
+            self._gc_n += 1
+            self._gc_s += time.perf_counter() - self._gc_t0
+            self._gc_t0 = None
+
+    def _snapshot(self, step, t):
+        return (step, t, tracing.span_totals(), time.process_time(),
+                time.thread_time(), self._gc_n, self._gc_s)
+
+    def flushed(self, step: int, t: float) -> None:
+        """A logging boundary: the ``on_metrics`` hook returned at
+        ``t`` (the end of its ``dsod.train.log`` span)."""
+        if self._ticks < self.SETUP_TICKS:
+            self._ticks += 1
+            if self._first_step_end is not None:
+                tracing.record_setup(
+                    "dsod.setup.warmup", self._first_step_end, t)
+                self._compiles.checkpoint(t)
+            if self._ticks == self.SETUP_TICKS:
+                self._log_setup()
+                self._last = self._snapshot(step, t)
+            return
+        step0, t0, totals0, cpu0, thread0, gc_n0, gc_s0 = self._last
+        self._last = self._snapshot(step, t)
+        _, _, totals, cpu, thread, gc_n, gc_s = self._last
+        wall = t - t0
+        if (len(self._walls) >= self.MIN_HISTORY and wall
+                > self.STALL_RATIO * statistics.median(self._walls)):
+            spent = {n: s - totals0.get(n, 0.0) for n, s in totals.items()}
+            made = self._compiles.between(t0, t)
+            self._log.warning(
+                "stall: steps %d-%d wall %.3f s (median %.3f s of %d "
+                "intervals) | %s | cpu process %.3f s loop thread %.3f s "
+                "| gc %d collections %.3f s | compiles %s",
+                step0 + 1, step, wall, statistics.median(self._walls),
+                len(self._walls),
+                " ".join(f"{n} {s:.3f}" for n, s in sorted(spent.items())
+                         if s >= 0.0005) or "no span",
+                cpu - cpu0, thread - thread0, gc_n - gc_n0, gc_s - gc_s0,
+                ", ".join(f"{kind} {name} {s:.2f} s"
+                          for kind, _t, s, name in made[:6]) or "none")
+        self._walls.append(wall)
+
+    def close(self) -> None:
+        """``fit()``'s ``finally``: a run shorter than the set-up ticks
+        still says where its set-up went; nothing stays registered."""
+        if self._open is not None:  # raised before or inside the step
+            self._open.__exit__(None, None, None)
+            self._open = None
+        if not self._setup_logged and self._first_step_end is not None:
+            self._log_setup()
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)

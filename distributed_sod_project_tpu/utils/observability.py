@@ -20,7 +20,6 @@ import collections
 import contextlib
 import signal
 import threading
-import time
 from typing import Dict, Optional
 
 from .logging import get_logger, is_primary_process
@@ -66,9 +65,11 @@ class PipelineStats:
     TensorBoard curves are per-interval, not monotone totals.
 
     ``keep_spans``: also keep each timed region as ``(name, t0, t1,
-    attrs)`` on ``time.monotonic`` (the :class:`Tracer` ring's clock)
-    until :meth:`drain_spans` — the train loop moves them into the
-    sampled chunk's trace.  Bounded; off by default.
+    attrs)`` — the span's own two clock reads (``utils/tracing.py``:
+    the clock ``fit()`` gives the :class:`Tracer` ring) — until
+    :meth:`drain_spans`: the train loop moves them into the sampled
+    chunk's trace.  Bounded; off by default.  (The host-clock sink
+    keeps seconds per NAME, not intervals: it cannot stand in.)
     """
 
     def __init__(self, keep_spans: bool = False):
@@ -82,17 +83,15 @@ class PipelineStats:
 
     @contextlib.contextmanager
     def timed(self, key: str, **attrs):
-        """THE timing seam of the data plane: time the body once, add
-        it to counter ``key`` (``data_<what>_ms``) and emit the span
-        ``dsod.data.<what>`` with ``attrs``."""
+        """THE timing seam of the data plane: the span
+        ``dsod.data.<what>`` with ``attrs`` times the body once, and
+        its seconds are added to counter ``key`` (``data_<what>_ms``)."""
         name = "dsod.data." + key[len("data_"):-len("_ms")]
-        t0 = time.monotonic()
-        with span(name, **attrs):
+        with span(name, **attrs) as region:
             yield
-        t1 = time.monotonic()
-        self.add(key, (t1 - t0) * 1000.0)
+        self.add(key, (region.t1 - region.t0) * 1000.0)
         if self._spans is not None:
-            self._spans.append((name, t0, t1, attrs))
+            self._spans.append((name, region.t0, region.t1, attrs))
 
     def drain_spans(self) -> list:
         """The timed regions kept since the last drain (``keep_spans``)."""

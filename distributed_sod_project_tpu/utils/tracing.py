@@ -46,8 +46,9 @@ from collections import OrderedDict
 from typing import Dict, List, Optional, Tuple
 
 __all__ = [
-    "Span", "Tracer", "format_timing", "mint_trace_id", "parse_timing",
-    "span", "trace_sampled",
+    "Span", "Tracer", "format_timing", "mint_trace_id", "now",
+    "parse_timing", "record_setup", "reset_setup", "setup_spans", "span",
+    "span_totals", "trace_sampled",
 ]
 
 _SAMPLE_MOD = 1 << 24
@@ -317,25 +318,104 @@ class Tracer:
             return self._completed
 
 
-# -- dsod.* spans on the profiler's clock -------------------------------
+# -- dsod.* spans: the profiler's clock and the host's -------------------
 
 _profiler = None  # jax.profiler, imported on first use: this module
 # stays importable (and the load generator stays light) without JAX.
 
+# The host-clock sink (always on; docs/OBSERVABILITY.md "Host-clock
+# sink").  Every span reads ``_clock`` — the clock of
+# ``benchmark/run.py::T_START`` and of the runners' ticks — on entry
+# and on exit, profiler session or not, and adds its seconds to a
+# per-name total of ITS thread (no lock on the hot path; the readers
+# merge).  Names under ``dsod.setup.`` also land as intervals in one
+# bounded list: the set-up phases happen once, the loop's spans
+# thousands of times a minute.
+_clock = time.perf_counter
+SETUP = "dsod.setup."
+MAX_SETUP_SPANS = 64
+_sink_lock = threading.Lock()
+_local = threading.local()
+_threads: List[Tuple[threading.Thread, Dict[str, float]]] = []
+_retired: Dict[str, float] = {}  # totals of threads that have ended
+_setup_spans: List[Tuple[str, float, float, Optional[str]]] = []
+
+
+def now() -> float:
+    """The sink's clock, for intervals a caller records itself."""
+    return _clock()
+
+
+def _thread_totals() -> Dict[str, float]:
+    """This thread's seconds per span name, made on first use."""
+    try:
+        return _local.totals
+    except AttributeError:
+        totals = _local.totals = {}
+        with _sink_lock:
+            _threads.append((threading.current_thread(), totals))
+        return totals
+
+
+def span_totals() -> Dict[str, float]:
+    """Seconds inside each span name since the process started, summed
+    over threads; a nested span counts in full under its own name AND
+    inside its parent's.  A reader keeps the last answer and subtracts
+    (``fit()``'s ``stall:`` line does, per logging interval)."""
+    with _sink_lock:
+        out, live = dict(_retired), []
+        for thread, totals in _threads:
+            for name, s in dict(totals).items():
+                out[name] = out.get(name, 0.0) + s
+            if thread.is_alive():
+                live.append((thread, totals))
+            else:  # a loader thread of a finished epoch: fold it away
+                for name, s in totals.items():
+                    _retired[name] = _retired.get(name, 0.0) + s
+        _threads[:] = live
+    return out
+
+
+def record_setup(name: str, t0: float, t1: float,
+                 parent: Optional[str] = None) -> None:
+    """One finished ``dsod.setup.*`` interval on ``_clock`` into the
+    bounded list, under the name of the phase it is part of, if any —
+    :class:`span` calls it on exit; ``fit()`` calls it for the
+    intervals it only knows afterwards (``before_fit``, the laps of
+    ``build``, each candidate end of ``warmup``).  The first
+    ``MAX_SETUP_SPANS`` stay."""
+    with _sink_lock:
+        if len(_setup_spans) < MAX_SETUP_SPANS:
+            _setup_spans.append((name, t0, t1, parent))
+
+
+def setup_spans() -> List[Tuple[str, float, float, Optional[str]]]:
+    """``(name, t0, t1, parent name)`` of the newest ``fit()``'s set-up."""
+    with _sink_lock:
+        return list(_setup_spans)
+
+
+def reset_setup() -> None:
+    """``fit()``'s entry: forget an earlier run's set-up (tier-1
+    workers run many)."""
+    with _sink_lock:
+        del _setup_spans[:]
+
 
 class span:
-    """One host interval under one ``dsod.*`` name, for both readers.
+    """One host interval under one ``dsod.*`` name, for every reader.
 
     Opens a ``jax.profiler.TraceAnnotation`` (``StepTraceAnnotation``
     when ``step_num`` is given), so the interval lands in whatever
-    ``.xplane.pb`` a profiler session is writing — on the clock of the
-    device ops — and costs a flag check when none is.  With ``root``,
-    the root :class:`Span` of a SAMPLED chunk, the same interval is
-    also recorded into that chunk's trace in the ring (``/debug/traces``)
-    under the same name; without it no clock is read.
+    ``.xplane.pb`` a profiler session is writing, on the clock of the
+    device ops.  The same interval, from ONE pair of ``_clock`` reads
+    (``t0``, ``t1``), goes to the host-clock sink above and, with
+    ``root`` — the root :class:`Span` of a SAMPLED chunk, whose
+    ``Tracer`` ``fit()`` builds on the same clock —, into that chunk's
+    trace in the ring (``/debug/traces``) under the same name.
     """
 
-    __slots__ = ("_ann", "_root", "_name", "_attrs", "_t0")
+    __slots__ = ("_ann", "_root", "_attrs", "name", "t0", "t1")
 
     def __init__(self, name: str, root: Optional[Span] = None, *,
                  step_num: Optional[int] = None, **attrs):
@@ -346,20 +426,25 @@ class span:
                      if step_num is None else
                      _profiler.StepTraceAnnotation(name, step_num=step_num,
                                                    **attrs))
-        self._root, self._name, self._attrs = root, name, attrs
+        self._root, self.name, self._attrs = root, name, attrs
 
     def __enter__(self) -> "span":
-        if self._root is not None:
-            self._t0 = self._root._tracer._clock()
+        self.t0 = _clock()
         self._ann.__enter__()
         return self
 
     def __exit__(self, *exc) -> bool:
         self._ann.__exit__(*exc)
+        name, t0 = self.name, self.t0
+        t1 = self.t1 = _clock()
+        totals = _thread_totals()
+        totals[name] = totals.get(name, 0.0) + (t1 - t0)
+        if name.startswith(SETUP):
+            record_setup(name, t0, t1)
         root = self._root
         if root is not None:
             root._tracer.record(
-                root.trace_id, self._name, self._t0, root._tracer._clock(),
+                root.trace_id, name, t0, t1,
                 parent_id=root.span_id, attrs=self._attrs)
         return False
 

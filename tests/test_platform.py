@@ -138,4 +138,6 @@ def test_compile_stats_counts_backend_compiles():
     jax.jit(lambda x: x * 3.0 + 1.0).lower(jnp.ones((7, 5))).compile()
     d = stats.as_dict()
     assert d["seconds"] > 0
-    assert set(d) == {"seconds", "cache_hits", "cache_misses"}
+    assert set(d) == {"seconds", "trace_seconds", "lower_seconds",
+                      "cache_hits", "cache_misses"}
+    assert d["trace_seconds"] > 0 and d["lower_seconds"] > 0

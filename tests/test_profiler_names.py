@@ -9,8 +9,8 @@ on the host loop and the data plane.
   ``data_starved_ms`` counter (one timed region feeds both);
 - every registered config's lowered step carries the stage scopes, no
   op sits in two stages, and no convolution is outside a stage;
-- with no sampled chunk the span helper reads no clock and records
-  nothing.
+- sampled chunk or not, the span helper reads the host clock twice
+  (tests/test_host_clock_sink.py has the sink's own tests).
 """
 
 import collections
@@ -210,30 +210,35 @@ def test_fused_kernels_sit_under_kernel_scopes():
         assert all(set(_STAGE.findall(v)) == {"loss"} for v in under), kernel
 
 
-def test_span_reads_no_clock_and_records_nothing_when_unsampled(monkeypatch):
-    """trace_sample=0 and no profiler session: the helper is an
-    annotation's flag check — no clock, no Tracer call."""
+def test_span_reads_the_clock_twice_sampled_or_not(monkeypatch):
+    """trace_sample=0 and no profiler session: the helper still reads
+    the host clock, ONCE on entry and ONCE on exit (the host-clock sink
+    is always on), and makes no Tracer call; a sampled chunk's ring gets
+    the same two values, not two more reads."""
     reads = []
     tr = tracing.Tracer(sample=0.0, clock=lambda: reads.append(1) or 0.0)
     reads.clear()  # the constructor anchors its clock to wall time once
     assert tr.begin("chunk", tracing.mint_trace_id(), root=True) is None
-    monkeypatch.setattr(tracing.time, "monotonic",
-                        lambda: reads.append(1) or 0.0)
+    monkeypatch.setattr(tracing, "_clock",
+                        lambda: reads.append(1) or float(len(reads)))
     with tracing.span("dsod.train.dispatch", None):
         with tracing.span("dsod.train.step", None, step_num=3, steps=1):
             pass
-    assert reads == []
+    assert len(reads) == 4
     assert tr.snapshot()["held"] == 0
     # Sampled: the same call records the interval under the same name,
-    # parented to the chunk's root.
-    tr = tracing.Tracer(sample=1.0)
+    # parented to the chunk's root, from the same pair of reads.
+    tr = tracing.Tracer(sample=1.0, clock=tracing.now)
     root = tr.begin("chunk", tracing.mint_trace_id(), root=True)
+    reads.clear()
     with tracing.span("dsod.train.log", root, step=4):
         pass
+    assert len(reads) == 2
     root.end()
     (trace,) = tr.snapshot()["traces"]
     log = [s for s in trace["spans"] if s["name"] == "dsod.train.log"]
     assert log and log[0]["attrs"] == {"step": 4}
+    assert log[0]["dur_ms"] == 1000.0
 
 
 def test_pipeline_stats_timed_is_counter_and_span_in_one():
