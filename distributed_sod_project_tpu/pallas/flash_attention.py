@@ -28,8 +28,9 @@ Design (mirrors the layout conventions of the other kernels here):
 - Backward is two more kernels (custom VJP, no O(N²) residual): dq
   accumulates over KV blocks; dk/dv swap the grid so the KV block is
   resident while Q blocks stream past.  Both rebuild ``p`` from the
-  saved lse row, flash-attention style; ``delta = Σ do·out`` is reduced
-  in-kernel from the streamed q/out tiles.
+  saved lse row, flash-attention style.  (The two causal variants
+  further down fuse them into one kernel each and reduce
+  ``delta = Σ do·out`` in-kernel from the streamed do/out tiles.)
 
 Exactness: forward AND gradients match the XLA oracle to float32
 round-off (tests/test_pallas_flash.py); the real-TPU Mosaic lowering is
@@ -395,15 +396,27 @@ def flash_attention_with_lse(q, k, v, *, block_q: int | None = None,
 # (no mask), on it (a local row >= col mask), or above it: those are
 # SKIPPED — no compute, and the index maps clamp to the last needed
 # block so no DMA either.  (2) Hq query heads share Hkv key/value heads:
-# the kv index map divides the folded head index by the group size, and
-# the dk/dv kernel keeps one kv block resident while the q blocks of all
-# G heads of its group stream past.  (3) delta = sum(out * do) is
-# reduced in-kernel from the streamed out/do tiles instead of being
-# broadcast to a lane-replicated HBM array; the matmul operands stay in
-# the input dtype (bf16 on the chip) with float32 accumulation.
-# Zero-padded rows past N need no key mask of their own: a valid query
-# row never sees a padded (later) key, and padded query rows carry zero
-# cotangents.
+# the forward's kv index map divides the folded head index by the group
+# size G.  (3) delta = sum(out * do) is reduced in-kernel from the
+# streamed out/do tiles instead of being broadcast to a lane-replicated
+# HBM array; the matmul operands stay in the input dtype (bf16 on the
+# chip) with float32 accumulation.  Zero-padded rows past N need no key
+# mask of their own: a valid query row never sees a padded (later) key,
+# and padded query rows carry zero cotangents.
+#
+# The backward is ONE kernel that makes a tile pair's p and ds once and
+# takes dq, dk and dv from them (the latent kernel's design below,
+# carried to grouped kv heads).  A kv block is resident while the q
+# blocks of all G heads of its group stream past, head after head (dk,
+# dv: a block-sized float32 accumulator each); dq accumulates in float32
+# VMEM scratch that holds the WHOLE row range of the group's G heads.
+# kv blocks run in ascending order and only blocks i <= j touch q block
+# j, so a head's dq block i is complete at the diagonal pair (i, i), the
+# first pair of that head kv block i visits: it is written there, to an
+# output block the pipeline flushes when the head or i moves on, and
+# never goes to HBM as partial sums.  The scoped-VMEM limit follows from
+# the shapes (``_causal_bwd_vmem_bytes``); a sequence too long for the
+# chip's VMEM raises.
 
 _CAUSAL_BLOCK = 512
 
@@ -465,33 +478,15 @@ def _causal_ds(p, q_side, v_ref, *, scale):
     return p * (dp - delta) * scale
 
 
-def _c_dq_kernel(q_ref, k_ref, v_ref, do_ref, out_ref, lse_ref, dq_ref,
-                 dq_s, *, scale: float):
-    i, j = pl.program_id(1), pl.program_id(2)
+def _c_bwd_kernel(k_ref, v_ref, q_ref, do_ref, out_ref, lse_ref,
+                  dq_ref, dk_ref, dv_ref, dq_s, dk_s, dv_s,
+                  *, scale: float, nb: int):
+    i, t = pl.program_id(1), pl.program_id(2)   # kv block; (head, q block)
+    j = t % nb
 
-    @pl.when(j == 0)
+    @pl.when((i == 0) & (t == 0))
     def _():
         dq_s[...] = jnp.zeros(dq_s.shape, jnp.float32)
-
-    def visit(masked):
-        p = _causal_p(q_ref, k_ref, lse_ref[0], scale=scale, masked=masked)
-        ds = _causal_ds(p, (do_ref, out_ref), v_ref, scale=scale)
-        dq_s[...] += lax.dot_general(ds.astype(k_ref.dtype), k_ref[0],
-                                     (((1,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-
-    pl.when(j < i)(lambda: visit(False))
-    pl.when(j == i)(lambda: visit(True))
-
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _():
-        dq_ref[0] = dq_s[...].astype(dq_ref.dtype)
-
-
-def _c_dkv_kernel(k_ref, v_ref, q_ref, do_ref, out_ref, lse_ref,
-                  dk_ref, dv_ref, dk_s, dv_s, *, scale: float, nq: int):
-    i, t = pl.program_id(1), pl.program_id(2)   # kv block; (head, q block)
-    j = t % nq
 
     @pl.when(t == 0)
     def _():
@@ -500,16 +495,24 @@ def _c_dkv_kernel(k_ref, v_ref, q_ref, do_ref, out_ref, lse_ref,
 
     def visit(masked):
         p = _causal_p(q_ref, k_ref, lse_ref[0], scale=scale, masked=masked)
+        over_q = (((0,), (0,)), ((), ()))   # contract the pair's q rows
         dv_s[...] += lax.dot_general(p.astype(do_ref.dtype), do_ref[0],
-                                     (((0,), (0,)), ((), ())),
+                                     over_q,
                                      preferred_element_type=jnp.float32)
-        ds = _causal_ds(p, (do_ref, out_ref), v_ref, scale=scale)
-        dk_s[...] += lax.dot_general(ds.astype(q_ref.dtype), q_ref[0],
-                                     (((0,), (0,)), ((), ())),
+        ds = _causal_ds(p, (do_ref, out_ref), v_ref, scale=scale).astype(
+            q_ref.dtype)
+        dk_s[...] += lax.dot_general(ds, q_ref[0], over_q,
                                      preferred_element_type=jnp.float32)
+        dq_s[t] += lax.dot_general(ds, k_ref[0], (((1,), (0,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
 
     pl.when(j > i)(lambda: visit(False))
-    pl.when(j == i)(lambda: visit(True))
+
+    @pl.when(j == i)
+    def _():
+        visit(True)
+        # Every kv block <= i has added to this head's q block i by now.
+        dq_ref[0] = dq_s[t].astype(dq_ref.dtype)
 
     @pl.when(t == pl.num_programs(2) - 1)
     def _():
@@ -544,35 +547,26 @@ def _c_fwd_call(q, k, v, cfg):
     )(q, k, v)
 
 
-@jax.named_scope("dsod.kernel.flash_attention_causal_dq")
-def _c_dq_call(q, k, v, out, lse, do, cfg):
-    blk, group, interpret = cfg
-    bh, np_, d = q.shape
-    nb = np_ // blk
-    qs = pl.BlockSpec((1, blk, d), lambda b, i, j: (b, i, 0))
-    kvs = pl.BlockSpec((1, blk, d),
-                       lambda b, i, j: (b // group, jnp.minimum(j, i), 0))
-    row = pl.BlockSpec((1, blk, _LANES), lambda b, i, j: (b, i, 0))
-    return pl.pallas_call(
-        partial(_c_dq_kernel, scale=1.0 / d**0.5),
-        grid=(bh, nb, nb),
-        in_specs=[qs, kvs, kvs, qs, qs, row],
-        out_specs=qs,
-        out_shape=jax.ShapeDtypeStruct((bh, np_, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((blk, d), jnp.float32)],
-        cost_estimate=pl.CostEstimate(
-            flops=3 * bh * np_ * np_ * d,
-            transcendentals=bh * np_ * np_ // 2,
-            bytes_accessed=4 * q.size * q.dtype.itemsize),
-        interpret=interpret,
-    )(q, k, v, do, out, lse)
+def _causal_bwd_vmem_bytes(group, np_, blk, d, itemsize):
+    """What the fused backward holds in VMEM: the float32 dq accumulator
+    of the group's G heads over the whole row range; each operand and
+    result tile twice (the pipeline's two buffers); the kv block's two
+    accumulators; and room for the float32 score-sized temporaries of one
+    tile pair (s, p, dp, ds, their casts and transposes)."""
+    d = -(-d // _LANES) * _LANES
+    tiles = 2 * blk * (itemsize * 8 * d + 4 * _LANES)
+    return (4 * group * np_ * d + tiles + 4 * blk * 2 * d
+            + 12 * 4 * blk * blk)
 
 
-@jax.named_scope("dsod.kernel.flash_attention_causal_dkv")
-def _c_dkv_call(q, k, v, out, lse, do, cfg):
-    """kv block resident; the q blocks of the group's G heads stream
-    past, t = head-in-group * nb + q block, blocks above the diagonal
-    clamped to the first needed one (and skipped)."""
+@jax.named_scope("dsod.kernel.flash_attention_causal_bwd")
+def _c_bwd_kernel_call(q, k, v, out, lse, do, cfg):
+    """One visit of each tile pair gives dq, dk and dv (the comment that
+    heads this section): kv block resident; the q blocks of the group's
+    G heads stream past, t = head-in-group * nb + q block, blocks above
+    the diagonal clamped to the first needed one (and skipped)."""
+    from .vmem_budget import fitted_vmem_params
+
     blk, group, interpret = cfg
     bh, np_, d = q.shape
     nb = np_ // blk
@@ -581,19 +575,29 @@ def _c_dkv_call(q, k, v, out, lse, do, cfg):
     qs = pl.BlockSpec((1, blk, d), q_ix)
     row = pl.BlockSpec((1, blk, _LANES), q_ix)
     kvs = pl.BlockSpec((1, blk, d), lambda b, i, t: (b, i, 0))
+    # dq's finished block (head t // nb, block i: written at the diagonal)
+    # stays put while that head's q blocks pass and is flushed after them.
+    dqs = pl.BlockSpec((1, blk, d),
+                       lambda b, i, t: (b * group + t // nb, i, 0))
+    acc = lambda *shape: pltpu.VMEM(shape, jnp.float32)  # noqa: E731
     return pl.pallas_call(
-        partial(_c_dkv_kernel, scale=1.0 / d**0.5, nq=nb),
+        partial(_c_bwd_kernel, scale=1.0 / d**0.5, nb=nb),
         grid=(k.shape[0], nb, group * nb),
         in_specs=[kvs, kvs, qs, qs, qs, row],
-        out_specs=[kvs, kvs],
-        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+        out_specs=[dqs, kvs, kvs],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        scratch_shapes=[pltpu.VMEM((blk, d), jnp.float32),
-                        pltpu.VMEM((blk, d), jnp.float32)],
+        scratch_shapes=[acc(group * nb, blk, d), acc(blk, d), acc(blk, d)],
+        compiler_params=fitted_vmem_params(
+            _causal_bwd_vmem_bytes(group, np_, blk, d, q.dtype.itemsize),
+            f"flash_attention_causal's backward over {np_} rows of "
+            f"{group} heads a kv head"),
         cost_estimate=pl.CostEstimate(
             flops=5 * bh * np_ * np_ * d,
             transcendentals=bh * np_ * np_ // 2,
-            bytes_accessed=4 * q.size * q.dtype.itemsize),
+            bytes_accessed=(4 * q.size + 2 * k.size + 2 * v.size)
+            * q.dtype.itemsize + 4 * lse.size),
         interpret=interpret,
     )(k, v, q, do, out, lse)
 
@@ -602,9 +606,7 @@ def _c_bwd_call(q, k, v, out, lse_row, do, cfg):
     # One scope per pallas_call and nothing else under it: the trace
     # reader counts a kernel's calls by its scope.
     lse = jnp.broadcast_to(lse_row[..., None], q.shape[:2] + (_LANES,))
-    dq = _c_dq_call(q, k, v, out, lse, do, cfg)
-    dk, dv = _c_dkv_call(q, k, v, out, lse, do, cfg)
-    return dq, dk, dv
+    return tuple(_c_bwd_kernel_call(q, k, v, out, lse, do, cfg))
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -648,7 +650,10 @@ def flash_attention_causal(q, k, v, *, block: int | None = None,
     q: [B, Hq, N, D]; k, v: [B, Hkv, N, D] with Hkv dividing Hq (query
     head h reads kv head h // (Hq / Hkv)); any N (zero-padded to the
     block), D <= 128 or a multiple of 128.  Tiles above the diagonal are
-    skipped.  Differentiable via the Pallas backward kernels.
+    skipped.  Differentiable: the backward is one Pallas kernel that
+    visits each tile pair once for dq, dk and dv, its float32 dq
+    accumulators held in VMEM for the whole sequence of a kv head's
+    group — a sequence too long for the chip's VMEM raises.
     """
     if k.shape != v.shape or q.ndim != 4 or k.ndim != 4:
         raise ValueError(f"bad q/k/v shapes {q.shape} {k.shape} {v.shape}")

@@ -366,7 +366,7 @@ def test_gradient_runs_the_scan_kernels_it_should(setup):
     assert names.count("_bwd_kernel") == 9
     assert names.count("_fwd_kernel") == 18
     assert names.count("_c_fwd_kernel") == 1
-    assert names.count("_c_dq_kernel") == names.count("_c_dkv_kernel") == 1
+    assert names.count("_c_bwd_kernel") == 1
 
 
 def test_gradient_runs_the_conv_kernels_it_should(setup):
@@ -420,8 +420,7 @@ SCOPES = ("dsod.ssm", "dsod.ssm.conv", "dsod.ssm.scan", "dsod.ssm.gate",
           "dsod.attn", "dsod.densemlp", "dsod.kernel.ssd_scan",
           "dsod.kernel.ssd_scan_bwd", "dsod.kernel.causal_conv",
           "dsod.kernel.causal_conv_bwd", "dsod.kernel.flash_attention_causal",
-          "dsod.kernel.flash_attention_causal_dq",
-          "dsod.kernel.flash_attention_causal_dkv")
+          "dsod.kernel.flash_attention_causal_bwd")
 _STAGE = re.compile(r"dsod\.(encoder|decoder|heads|loss|update)\b")
 
 
@@ -457,7 +456,7 @@ def test_no_product_of_the_step_is_outside_a_stage(lowered_text):
 
 @pytest.mark.parametrize("config,sha", [
     ("lfm2_8b_a1b_ep4",
-     "8cce388a40cc0abec4b8596a0aaca2753a1fa06833fc5ce818dbdea0509a67d2"),
+     "f90e4a230e59a8376448465e29800a08df1da8d553da36444eac232ed3b309f7"),
     ("kimi_vl_a3b_ep8",
      "8e8bd7f41f6c2eb833d7f31c5167bb97a408f785b46cac118368a5225de99b15")])
 def test_the_older_token_models_steps_are_the_programs_they_were(
@@ -465,7 +464,8 @@ def test_the_older_token_models_steps_are_the_programs_they_were(
     """``tools/dump_hlo.py`` on both older token configs, as its command
     line runs it (a process of its own: this suite's conftest sets a
     matmul precision, which is part of a program): the StableHLO of the
-    commit before this model (PR 36's tree, 74d629d), to the byte.  A PR
+    commit before this model (PR 36's tree, 74d629d; the first one's
+    with PR 40's one-kernel causal backward), to the byte.  A PR
     that means to change one of those steps changes its hash with it and
     says so in PERF.md."""
     tools = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",

@@ -387,9 +387,10 @@ def test_the_first_token_models_step_is_the_program_it_was(tmp_path):
     """``tools/dump_hlo.py`` on ``lfm2_8b_a1b_ep4``, as its command line
     runs it (a process of its own: this suite's conftest sets a matmul
     precision, which is part of a program): the StableHLO that the
-    commit before this model gave (PR 31's tree, aee3230), to the byte.
-    A PR that means to change that step changes this hash with it and
-    says so in PERF.md."""
+    commit before this model gave (PR 31's tree, aee3230) with the one
+    change a later PR meant (PR 40: the causal backward is one kernel),
+    to the byte.  A PR that means to change that step changes this hash
+    with it and says so in PERF.md."""
     import subprocess
 
     tools = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
@@ -403,7 +404,7 @@ def test_the_first_token_models_step_is_the_program_it_was(tmp_path):
         capture_output=True, timeout=600)
     with open(tmp_path / "lfm2_8b_a1b_ep4.stablehlo.txt", "rb") as f:
         assert hashlib.sha256(f.read()).hexdigest() == (
-            "8cce388a40cc0abec4b8596a0aaca2753a1fa06833fc5ce818dbdea0509a67d2")
+            "f90e4a230e59a8376448465e29800a08df1da8d553da36444eac232ed3b309f7")
 
 
 # -- the loop -----------------------------------------------------------------

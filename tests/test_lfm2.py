@@ -434,8 +434,8 @@ def test_named_saves_give_the_gradient_of_no_remat_to_the_last_bit(setup):
 
 
 @pytest.mark.parametrize("what,per", [
-    ("_c_fwd_kernel", "attention"), ("_c_dq_kernel", "attention"),
-    ("_c_dkv_kernel", "attention"), ("sort", "moe"), ("top_k", "moe")])
+    ("_c_fwd_kernel", "attention"), ("_c_bwd_kernel", "attention"),
+    ("sort", "moe"), ("top_k", "moe")])
 def test_gradient_runs_each_kernel_and_each_sort_once_a_layer(setup, what,
                                                               per):
     """In the jaxpr of the gradient the causal forward kernel appears
@@ -558,14 +558,20 @@ def _plain_causal(q, k, v):
     return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
 
 
-@pytest.mark.parametrize("n,block", [(256, 128), (160, 128), (72, None),
-                                     (384, 128)])
-def test_flash_causal_grouped_kv_matches_plain_attention(n, block):
+# n, block, query heads, kv heads: group sizes 1, 2 and 4; lengths the
+# block does not divide (padding rows); 1, 2, 3, 4 and 5 blocks, so that
+# the fused backward finishes a q block's dq at the FIRST kv block that
+# visits it (block 0, or one block in all) and at a LATER one.
+@pytest.mark.parametrize("n,block,hq,hkv", [
+    (256, 128, 4, 2), (160, 128, 4, 2), (72, None, 4, 2), (384, 128, 4, 2),
+    (72, None, 2, 2), (256, 128, 2, 2), (512, 128, 2, 2), (520, 128, 2, 2),
+    (72, None, 4, 1), (256, 128, 4, 1), (512, 128, 4, 1), (520, 128, 4, 1)])
+def test_flash_causal_grouped_kv_matches_plain_attention(n, block, hq, hkv):
     ks = jax.random.split(jax.random.key(n), 4)
-    q = jax.random.normal(ks[0], (2, 4, n, 16))
-    k = jax.random.normal(ks[1], (2, 2, n, 16))
-    v = jax.random.normal(ks[2], (2, 2, n, 16))
-    g = jax.random.normal(ks[3], (2, 4, n, 16))
+    q = jax.random.normal(ks[0], (2, hq, n, 16))
+    k = jax.random.normal(ks[1], (2, hkv, n, 16))
+    v = jax.random.normal(ks[2], (2, hkv, n, 16))
+    g = jax.random.normal(ks[3], (2, hq, n, 16))
     flash = lambda *a: flash_attention_causal(*a, block=block)  # noqa: E731
     _close(flash(q, k, v), _plain_causal(q, k, v))
     got = jax.grad(lambda *a: jnp.sum(flash(*a) * g), (0, 1, 2))(q, k, v)
@@ -573,6 +579,89 @@ def test_flash_causal_grouped_kv_matches_plain_attention(n, block):
                     (0, 1, 2))(q, k, v)
     for a, b in zip(got, want):
         _close(a, b, 1e-4)
+
+
+def _causal_grad_kernels(hq=4, n=512):
+    """The ``pallas_call`` equations in the gradient of the causal
+    kernel over q, k and v: 2 sequences of ``hq`` bfloat16 query heads on
+    one kv head, blocks of 128."""
+    q = jnp.zeros((2, hq, n, 16), jnp.bfloat16)
+    kv = jnp.zeros((2, 1, n, 16), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(flash_attention_causal(*a, block=128).astype(
+            jnp.float32)), (0, 1, 2)))(q, kv, kv)
+    return [eqn for eqn in _eqns(jaxpr.jaxpr)
+            if eqn.primitive.name == "pallas_call"]
+
+
+def test_flash_causal_gradient_is_two_kernels_and_no_partial_sums():
+    """Forward and ONE backward kernel; the backward's three results are
+    dq, dk and dv themselves, in the operands' type and shape — no
+    float32 partial sums of dq leave the kernel to be added up in HBM."""
+    fwd, bwd = _causal_grad_kernels()
+    names = [e.params["jaxpr"].debug_info.func_name for e in (fwd, bwd)]
+    assert names == ["_c_fwd_kernel", "_c_bwd_kernel"]
+    assert [(v.aval.shape, v.aval.dtype) for v in bwd.outvars] == [
+        ((8, 512, 16), jnp.bfloat16)] + [((2, 512, 16), jnp.bfloat16)] * 2
+
+
+@pytest.mark.parametrize("hq,n", [(4, 512), (2, 128), (4, 640)])
+def test_flash_causal_backward_writes_each_dq_block_once(hq, n):
+    """Walk the backward's grid in the order the chip does and follow
+    dq's output block: the pipeline flushes a block when its index moves
+    on, so every (head, q block) has to come up in ONE unbroken run of
+    steps (a second run would overwrite what the first flushed) and that
+    run has to hold the diagonal pair, the only step that writes it."""
+    bwd = _causal_grad_kernels(hq=hq, n=n)[1]
+    gm = bwd.params["grid_mapping"]
+    dq_map = gm.block_mappings[gm.num_inputs].index_map_jaxpr
+    nb = n // 128
+    assert gm.grid == (2, nb, hq * nb)
+    runs, wrote = [], []
+    for step in np.ndindex(*gm.grid):
+        _, i, t = step
+        at = tuple(int(x) for x in jax.core.eval_jaxpr(
+            dq_map.jaxpr, dq_map.consts, *(jnp.int32(x) for x in step)))
+        if not runs or runs[-1] != at:
+            runs.append(at)
+            wrote.append(0)
+        if t % nb == i:   # the kernel's ``pl.when(j == i)``
+            wrote[-1] += 1
+            assert at == (step[0] * hq + t // nb, i, 0)
+    assert len(set(runs)) == len(runs) == 2 * hq * nb
+    assert wrote == [1] * len(runs)
+
+
+def test_flash_causal_backward_refuses_a_group_its_vmem_cannot_hold(
+        monkeypatch):
+    """The dq accumulators hold the whole sequence of a kv head's G query
+    heads in VMEM, a 128-lane row each: the scoped limit the call asks
+    for is what the shapes derive (both cells' sizes), and on a chip
+    whose VMEM they pass it raises and names the need.  Shapes only:
+    nothing runs."""
+    from distributed_sod_project_tpu.pallas import vmem_budget as vb
+    from distributed_sod_project_tpu.pallas.flash_attention import (
+        _c_bwd_call, _causal_bwd_vmem_bytes)
+
+    monkeypatch.setattr(vb, "_device_kind", lambda: "TPU v5 lite")
+
+    def call(n, hq=32, hkv=8):
+        bf = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16)  # noqa: E731
+        return jax.eval_shape(
+            lambda *a: _c_bwd_call(*a, (512, hq // hkv, False)),
+            bf(hq, n, 64), bf(hkv, n, 64), bf(hkv, n, 64), bf(hq, n, 64),
+            jax.ShapeDtypeStruct((hq, n), jnp.float32), bf(hq, n, 64))
+
+    assert [t.shape for t in call(16384)] == [
+        (32, 16384, 64), (8, 16384, 64), (8, 16384, 64)]
+    for n, acc_mib in ((8192, 16), (16384, 32)):
+        need = _causal_bwd_vmem_bytes(4, n, 512, 64, 2)
+        assert 14 * 2**20 < need - acc_mib * 2**20 < 16 * 2**20
+    with pytest.raises(ValueError, match=r"65536 rows of 4 heads a kv head "
+                                         r"needs 143\.0 MiB of VMEM; a TPU "
+                                         r"v5 lite has 128 MiB"):
+        call(65536)
+    assert call(65536, hq=8)[0].shape == (8, 65536, 64)  # groups of one
 
 
 @pytest.mark.parametrize("chunk", [64, 100, 4096])
@@ -602,8 +691,7 @@ SCOPES = ("dsod.moe.route", "dsod.moe.experts", "dsod.moe.combine",
           "dsod.kernel.grouped_matmul", "dsod.kernel.grouped_matmul_dw",
           "dsod.kernel.moe_unpermute",
           "dsod.kernel.flash_attention_causal",
-          "dsod.kernel.flash_attention_causal_dq",
-          "dsod.kernel.flash_attention_causal_dkv")
+          "dsod.kernel.flash_attention_causal_bwd")
 _STAGE = re.compile(r"dsod\.(encoder|decoder|heads|loss|update)\b")
 
 

@@ -201,6 +201,21 @@ def test_injected_dispatch_stall_flips_watchdog_health(monkeypatch):
 # ------------------------------------------- router absorbs the chaos
 
 
+def _settled_stats(fleet, timeout=5.0):
+    """A terminal is booked after the response bytes flush, so a stats
+    read right after the client's 200 can see the submission and not
+    yet its ``served`` (tests/test_cache.py::_consistent_stats): wait
+    the gap out; the last read goes back as it is, so a real hole still
+    fails the caller."""
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        st = fleet.stats()
+        if st["fleet"]["consistent"]:
+            return st
+        time.sleep(0.02)
+    return fleet.stats()
+
+
 def test_router_retry_absorbs_injected_5xx_burst(monkeypatch):
     """A replica answering an injected 5xx burst behind a live listener
     is exactly what the retry path exists for: the client sees 200, the
@@ -218,7 +233,7 @@ def test_router_retry_absorbs_injected_5xx_burst(monkeypatch):
         status, headers, _ = _post(url)
         assert status == 200
         assert headers["X-Model"] == "m"
-        s = fleet.stats()
+        s = _settled_stats(fleet)
         assert s["router"]["retries_total"] == 1
         assert s["fleet"]["submitted"] == 1
         assert s["fleet"]["served"] == 1
@@ -248,7 +263,7 @@ def test_router_retry_absorbs_injected_midbody_reset(monkeypatch):
         status, _, body = _post(url)
         assert status == 200
         np.load(io.BytesIO(body), allow_pickle=False)
-        s = fleet.stats()
+        s = _settled_stats(fleet)
         assert s["router"]["retries_total"] == 1
         assert s["router"]["transport_errors_total"] == 0  # absorbed
         assert s["fleet"]["consistent"] is True
