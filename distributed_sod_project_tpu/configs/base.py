@@ -88,15 +88,18 @@ class DataConfig:
 
 @dataclasses.dataclass(frozen=True)
 class LMConfig:
-    """Shape of a token model (model.name=lfm2 | kimi | granite,
-    models/lfm2.py, models/kimi.py, models/granite.py).  The defaults
+    """Shape of a token model (model.name=lfm2 | kimi | granite | ouro,
+    models/lfm2.py, models/kimi.py, models/granite.py, models/ouro.py).
+    The defaults
     are LFM2-8B-A1B's published widths (LiquidAI, config.json) and the
     share one chip holds in `lfm2_8b_a1b_ep4`: the layers kept,
     `experts_held` of `experts` from `first_expert` on, `vocab` rows of
-    the 65,536.  `kimi_vl_a3b_ep8` and `granite_4_0_h_micro_pp4` set
-    every field they read (configs/experiments.py).  The state-space
-    sizes and the four multipliers are read by `granite` alone, whose
-    attention layer is position-free (it reads no `rope_theta`)."""
+    the 65,536.  `kimi_vl_a3b_ep8`, `granite_4_0_h_micro_pp4` and
+    `ouro_2_6b_pp6` set every field they read (configs/experiments.py).
+    The state-space sizes and the four multipliers are read by `granite`
+    alone, whose attention layer is position-free (it reads no
+    `rope_theta`); the passes and the exit term by `ouro` alone, which
+    reads `layer_types` for its length only (every layer is attention)."""
 
     vocab: int = 16384
     hidden: int = 2048
@@ -150,6 +153,11 @@ class LMConfig:
     residual_multiplier: float = 1.0
     attention_multiplier: float = 0.0
     logits_scaling: float = 1.0
+    # The looped stack (ouro): the layers kept are run ut_steps times on
+    # the same weights; exit_beta weighs the entropy of the exit
+    # distribution in the loss (losses/token_ce.py).
+    ut_steps: int = 1
+    exit_beta: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)

@@ -291,3 +291,38 @@ def granite_4_0_h_micro_pp4() -> ExperimentConfig:
         num_epochs=100,
         mesh=MeshConfig(data=1, model=1, seq=1),
     )
+
+
+@register_config("ouro_2_6b_pp6")
+def ouro_2_6b_pp6() -> ExperimentConfig:
+    """The fourth token model: Ouro-2.6B (ByteDance, ``ouro``) at its
+    published widths, the FIRST of 6 pipeline stages — layers 0-7 of the
+    48, no layer divided, every head — with the WHOLE 49,152-row
+    embedding and the whole untied head (head, gate and loss stay on
+    this chip so that a step is a whole step).  The stage's 8 layers are
+    run 4 times on the same weights (``total_ut_steps``); the loss is
+    the exit-weighted sum of the four passes' cross-entropies less 0.1 x
+    the exit distribution's entropy.  Trains on packed synthetic
+    documents, 1 sequence of 8,192 tokens a step, AdamW, per-visit
+    remat.  ``model.lm.*`` / ``data.seq_len`` shrink it for a CPU drive
+    (tests/test_ouro.py)."""
+    return ExperimentConfig(
+        name="ouro_2_6b_pp6",
+        data=DataConfig(dataset="packed_tokens", hflip=False,
+                        synthetic_size=4096, seq_len=8192, vocab=49152),
+        model=ModelConfig(
+            name="ouro", backbone="none", sync_bn=False, remat=True,
+            lm=LMConfig(
+                vocab=49152, hidden=2048,
+                layer_types=("attention",) * 8, ffn_types=("dense",) * 8,
+                heads=16, kv_heads=16, head_dim=128, dense_width=5632,
+                norm_eps=1e-6, rope_theta=1e6, ut_steps=4,
+                exit_beta=0.1)),
+        loss=LossConfig(),
+        # AdamW and the warm-up of the three other token configs.
+        optim=OptimConfig(optimizer="adamw", lr=3e-4, weight_decay=0.1,
+                          schedule="poly", warmup_steps=2000),
+        global_batch_size=1,
+        num_epochs=100,
+        mesh=MeshConfig(data=1, model=1, seq=1),
+    )

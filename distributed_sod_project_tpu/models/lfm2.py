@@ -574,14 +574,16 @@ class LFM2(nn.Module):
         return h, moe_counters(per_layer, tokens.size * c.top_k)
 
 
-def log_saves(model: str, layers: int, saved, names) -> None:
+def log_saves(model: str, layers: int, saved, names,
+              unit: str = "layers") -> None:
     """One line per DIFFERENTIATED trace saying what the remat policy
-    kept (a trace in which the policy was never asked logs nothing)."""
+    kept (a trace in which the policy was never asked logs nothing).
+    ``unit``: what ``layers`` counts (a looped model's are visits)."""
     if saved:
         from ..utils.logging import get_logger
 
         get_logger().info(
-            "remat saves (%s, %d layers): %s MiB=%.1f", model, layers,
+            "remat saves (%s, %d %s): %s MiB=%.1f", model, layers, unit,
             " ".join(f"{k}={saved[k]}" for k in names),
             saved["bytes"] / 2 ** 20)
 

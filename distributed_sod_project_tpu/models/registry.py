@@ -212,3 +212,14 @@ def _build_granite(cfg, *, dtype, param_dtype, axis_name):
 
     return Granite(cfg=cfg.lm, remat=cfg.remat, dtype=dtype,
                    param_dtype=param_dtype)
+
+
+@register_model("ouro")
+def _build_ouro(cfg, *, dtype, param_dtype, axis_name):
+    """The fourth token model (one stack of layers run several times on
+    shared weights, an exit gate, the exit-weighted loss): its shape is
+    ``cfg.lm``, as for ``lfm2``."""
+    from .ouro import Ouro
+
+    return Ouro(cfg=cfg.lm, remat=cfg.remat, dtype=dtype,
+                param_dtype=param_dtype)

@@ -347,20 +347,20 @@ def make_unified_train_step(
             f"grad_compression={grad_compression!r}")
 
     def _forward_loss_tokens(state, batch):
-        from ..losses.token_ce import tied_cross_entropy
-
-        # The head's matrix: the embedding, unless the model names its
-        # own.  The buffers come back from the model, as BatchNorm's do
-        # from an image model: a router balanced by rule moves its
-        # selection bias every step (models/lfm2.py::ExpertLayer).
-        module, leaf = getattr(model, "head", ("embed", "embedding"))
+        # The buffers come back from the model, as BatchNorm's do from
+        # an image model: a router balanced by rule moves its selection
+        # bias every step (models/lfm2.py::ExpertLayer).  The loss is
+        # ``_token_loss`` below.  This function keeps the line numbers
+        # it had before that seam: the frames above a model's kernels
+        # (``model.apply``, ``jax.grad``, the caller of this function)
+        # are part of the older token steps' compile-cache keys.
 
         def loss_fn(params):
             (hidden, counters), mut = model.apply(
                 {"params": params, "batch_stats": state.batch_stats},
                 batch["tokens"], train=True, mutable=["batch_stats"])
-            total = tied_cross_entropy(
-                hidden, params[module][leaf], batch["targets"])
+            total, counters = _token_loss(
+                hidden, counters, params, batch["targets"])
             return total, (dict(counters, total=total),
                            mut.get("batch_stats", state.batch_stats))
 
@@ -374,6 +374,24 @@ def make_unified_train_step(
         grads, comps, new_stats = _forward_loss_tokens(state, batch)
         grads, comps, _ = _reduce(grads, comps)
         return _finish(state, grads, comps, new_stats)
+
+    def _token_loss(outputs, counters, params, targets):
+        """-> (total, counters).  A model that names its own loss
+        (``token_loss``: models/ouro.py, four heads' cross-entropies
+        under a learned exit distribution) is given its outputs, the
+        parameters and the targets; every other token model hands back
+        ONE hidden state, whose loss is the chunked cross-entropy over
+        the head's matrix: the embedding, unless the model names its
+        own (``head``)."""
+        from ..losses.token_ce import tied_cross_entropy
+
+        own = getattr(model, "token_loss", None)
+        if own is not None:
+            total, more = own(outputs, params, targets)
+            return total, dict(counters, **more)
+        module, leaf = getattr(model, "head", ("embed", "embedding"))
+        return tied_cross_entropy(outputs, params[module][leaf],
+                                  targets), counters
 
     inner_fn = (step_fn_tokens if tokens
                 else step_fn_ef if ef else step_fn)

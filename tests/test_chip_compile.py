@@ -259,6 +259,10 @@ CASES = {
     "flash_attention_causal.bwd@8192": (_causal_grad, _causal_qkv(8192), 2),
     "flash_attention_causal.bwd@16384": (_causal_grad, _causal_qkv(16384),
                                          2),
+    # ouro_2_6b_pp6: 16 / 16 heads of 128 (no grouping, full lanes): a
+    # 4 MiB dq accumulator a head at the cell's 8,192 rows.
+    "flash_attention_causal.bwd@8192x16x128": (
+        _causal_grad, (_S((1, 16, 8192, 128), BF),) * 3, 2),
     # kimi_vl_a3b_ep8: keys of 128 + 64 columns against values of 128.
     "flash_attention_mla.fwd@16384": (_mla, _mla_args(16384), 1),
     # The backward is ONE kernel whose float32 dq accumulators hold the
@@ -359,6 +363,51 @@ def test_the_state_space_step_compiles_for_v5e_and_fits(chip, topo,
     mem = lowered.compile().memory_analysis()
     state_gib = mem.argument_size_in_bytes / 2 ** 30
     assert 8.5 < state_gib < 8.8          # 772 M parameters x 12 bytes
+    assert state_gib + mem.temp_size_in_bytes / 2 ** 30 < 15.75
+
+
+def test_the_looped_step_compiles_for_v5e_and_fits(chip, topo, monkeypatch):
+    """``ouro_2_6b_pp6``'s whole train step at the cell's size (published
+    widths, 8 layers run 4 times, 8,192 tokens, the whole vocabulary)
+    compiled for a described v5e: 64 kernels (32 visits, a forward and a
+    fused backward causal flash kernel each), no op rematerialised by
+    the compiler, and state + temporaries inside the chip's 15.75 GiB.
+    (The compiler's own books read 12.71 of 14.49 GiB: PERF.md section 4;
+    they are read from its log, .claude/skills/verify.)"""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from distributed_sod_project_tpu.configs import get_config
+    from distributed_sod_project_tpu.models import build_model
+    from distributed_sod_project_tpu.parallel.engine import \
+        make_unified_train_step
+    from distributed_sod_project_tpu.train import (build_optimizer,
+                                                   create_train_state)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_config("ouro_2_6b_pp6")
+    n = cfg.data.seq_len
+    mesh = Mesh(np.array([topo.devices[0]]).reshape(1, 1, 1),
+                ("data", "model", "seq"))
+    model = build_model(cfg.model)
+    tx, sched = build_optimizer(cfg.optim, 20000)
+    batch = {k: np.zeros((1, n), np.int32) for k in ("tokens", "targets")}
+    state = jax.eval_shape(lambda: create_train_state(
+        jax.random.key(0), model, tx, batch))
+    on = lambda spec: lambda x: jax.ShapeDtypeStruct(  # noqa: E731
+        x.shape, x.dtype, sharding=NamedSharding(mesh, spec))
+    step = make_unified_train_step(
+        model, cfg.loss, tx, mesh, preset="dp", schedule=sched,
+        donate_batch=True, remat=cfg.model.remat,
+        remat_policy=cfg.model.remat_policy)
+    lowered = step.lower(jax.tree_util.tree_map(on(P()), state),
+                         jax.tree_util.tree_map(on(P("data")), batch))
+    assert lowered.as_text().count("tpu_custom_call") == 64
+    compiled = lowered.compile()
+    assert ".remat" not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    state_gib = mem.argument_size_in_bytes / 2 ** 30
+    assert 6.8 < state_gib < 6.9          # 612.4 M parameters x 12 bytes
     assert state_gib + mem.temp_size_in_bytes / 2 ** 30 < 15.75
 
 

@@ -562,16 +562,21 @@ def _plain_causal(q, k, v):
 # block does not divide (padding rows); 1, 2, 3, 4 and 5 blocks, so that
 # the fused backward finishes a q block's dq at the FIRST kv block that
 # visits it (block 0, or one block in all) and at a LATER one.
-@pytest.mark.parametrize("n,block,hq,hkv", [
-    (256, 128, 4, 2), (160, 128, 4, 2), (72, None, 4, 2), (384, 128, 4, 2),
-    (72, None, 2, 2), (256, 128, 2, 2), (512, 128, 2, 2), (520, 128, 2, 2),
-    (72, None, 4, 1), (256, 128, 4, 1), (512, 128, 4, 1), (520, 128, 4, 1)])
-def test_flash_causal_grouped_kv_matches_plain_attention(n, block, hq, hkv):
+# The last two: the fourth token model's heads (models/ouro.py), 16 / 16
+# of 128 columns, no grouping, full lanes.
+@pytest.mark.parametrize("n,block,hq,hkv,d", [
+    (256, 128, 4, 2, 16), (160, 128, 4, 2, 16), (72, None, 4, 2, 16),
+    (384, 128, 4, 2, 16), (72, None, 2, 2, 16), (256, 128, 2, 2, 16),
+    (512, 128, 2, 2, 16), (520, 128, 2, 2, 16), (72, None, 4, 1, 16),
+    (256, 128, 4, 1, 16), (512, 128, 4, 1, 16), (520, 128, 4, 1, 16),
+    (384, 128, 16, 16, 128), (200, None, 16, 16, 128)])
+def test_flash_causal_grouped_kv_matches_plain_attention(n, block, hq, hkv,
+                                                         d):
     ks = jax.random.split(jax.random.key(n), 4)
-    q = jax.random.normal(ks[0], (2, hq, n, 16))
-    k = jax.random.normal(ks[1], (2, hkv, n, 16))
-    v = jax.random.normal(ks[2], (2, hkv, n, 16))
-    g = jax.random.normal(ks[3], (2, hq, n, 16))
+    q = jax.random.normal(ks[0], (2, hq, n, d))
+    k = jax.random.normal(ks[1], (2, hkv, n, d))
+    v = jax.random.normal(ks[2], (2, hkv, n, d))
+    g = jax.random.normal(ks[3], (2, hq, n, d))
     flash = lambda *a: flash_attention_causal(*a, block=block)  # noqa: E731
     _close(flash(q, k, v), _plain_causal(q, k, v))
     got = jax.grad(lambda *a: jnp.sum(flash(*a) * g), (0, 1, 2))(q, k, v)

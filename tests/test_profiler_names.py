@@ -136,7 +136,9 @@ _TOKEN_MODELS = {
     "granite_4_0_h_micro_pp4": _TINY_LM + [
         "model.lm.kv_heads=2", "model.lm.head_dim=16", "model.lm.ssm_heads=8",
         "model.lm.ssm_head_dim=16", "model.lm.ssm_state=16",
-        "model.lm.ssm_chunk=64"]}
+        "model.lm.ssm_chunk=64"],
+    "ouro_2_6b_pp6": _TINY_LM + ["model.lm.kv_heads=4",
+                                 "model.lm.head_dim=16"]}
 
 
 def _lowered_step_text(name: str, size: int = 64) -> str:

@@ -36,7 +36,8 @@ _TINY_LM = {  # model.name -> the token model's shrink
                      "model.lm.v_dim=16", "model.lm.kv_rank=32"],
     "granite": _TINY + ["model.lm.kv_heads=2", "model.lm.head_dim=16",
                         "model.lm.ssm_heads=8", "model.lm.ssm_head_dim=16",
-                        "model.lm.ssm_state=16", "model.lm.ssm_chunk=32"]}
+                        "model.lm.ssm_state=16", "model.lm.ssm_chunk=32"],
+    "ouro": _TINY + ["model.lm.kv_heads=4", "model.lm.head_dim=16"]}
 
 
 def dump(config_name: str, out_dir: str, n_devices: int = 8,
