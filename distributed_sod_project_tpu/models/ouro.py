@@ -11,8 +11,10 @@ cross-entropy, this one names its own loss (:meth:`Ouro.token_loss`):
 logits [R, B, N]), counters)``.  ``RMSNorm``, ``SwiGLU``, ``rope``, the
 per-layer remat with counted saves and the causal flash kernel are the
 first token model's, the untied ``embed/kernel`` and ``head/embedding``
-the second's: imported, not copied.  Width ``hidden`` throughout, no
-bias but the gate's; with ``N*`` RMSNorms with a learned scale:
+the second's: imported, not copied; the rotary kernel pair
+(``pallas/rotary.py``) is this model's own.  Width ``hidden``
+throughout, no bias but the gate's; with ``N*`` RMSNorms with a learned
+scale:
 
 - block (sandwich norm): ``a = x + N2(Attn(N1(x)))``, ``y = a +
   N4(SwiGLU(N3(a)))``; ``Attn``: q, k, v, o projections to ``heads`` x
@@ -40,14 +42,22 @@ that the compiled step reserves, and at the cell's size that program no
 longer loads beside the benchmark's first-call copy of the weights.
 
 Compute is ``dtype`` (bf16) with float32 parameters; every norm's
-statistics, the rotary angles, the softmax, the gate and the loss are
-float32.  When ``remat`` is on each VISIT of a block (``layers x R`` a
-step) recomputes the block from its input in the backward except the
-values :data:`REMAT_SAVES` names.
+statistics, the softmax, the gate and the loss are float32, and so is
+the rotation: float32 angles, tables, products and sum on the
+projection's ``dtype`` output, ONE rounding back to ``dtype`` — inside
+the rotary kernel where a head fills the chip's 128 lanes (q and k in
+one call, each read and written once; its backward the same kernel with
+the sine negated, no residual but the tables), in XLA on a float32 copy
+(``rope``) at any other width (:func:`rotated`).  When ``remat`` is on
+each VISIT of a block (``layers x R`` a step) recomputes the block from
+its input in the backward except the values :data:`REMAT_SAVES` names:
+the rotation runs twice forward and once backward a visit.
 
 Device scopes (PERF.md section 3): ``dsod.encoder`` over embedding and
 loop; ``dsod.loop`` around the looped stack, inside it ``dsod.attn``
-(the kernel call alone under ``dsod.attn.core``), ``dsod.densemlp`` and
+(the flash call alone under ``dsod.attn.core``; the rotation's
+``dsod.kernel.rotary`` / ``dsod.kernel.rotary_bwd`` beside it, outside
+the core), ``dsod.densemlp`` and
 ``dsod.loop.exit`` (final norm and gate; the distribution and entropy
 carry the same name inside ``dsod.loss``); the R head products are
 ``dsod.heads``.  Counters beside ``grad_norm``: ``loop_exit_mass_t``,
@@ -67,6 +77,7 @@ from jax import lax
 from ..losses.token_ce import exit_weighted_cross_entropy
 from ..pallas.flash_attention import (CAUSAL_RESIDUAL_NAMES,
                                       flash_attention_causal)
+from ..pallas.rotary import rotate_half
 from .kimi import Embed, Head
 from .lfm2 import RMSNorm, SwiGLU, _dense, _saves_counted, log_saves, rope
 
@@ -76,6 +87,20 @@ from .lfm2 import RMSNorm, SwiGLU, _dense, _saves_counted, log_saves, rope
 # (96 MiB a visit: 3 GiB).
 REMAT_SAVES = CAUSAL_RESIDUAL_NAMES[1:]
 _SAVE_NAMED = jax.checkpoint_policies.save_only_these_names(*REMAT_SAVES)
+
+
+def rotated(q, k, theta: float):
+    """q, k: [B, N, H, d] in the compute dtype -> both rotated,
+    head-major [B, H, N, d].  A head that fills the chip's 128 lanes goes
+    through the rotary kernel (both tensors in one call, read and written
+    once in their own dtype); any other width through ``rope`` on a
+    float32 copy.  The same arithmetic either way: float32 tables,
+    products and sum, one rounding."""
+    if q.shape[-1] % 128 == 0:
+        return rotate_half(
+            (q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3)), theta)
+    return tuple(rope(t.astype(jnp.float32), theta).astype(
+        t.dtype).transpose(0, 2, 1, 3) for t in (q, k))
 
 
 class Attention(nn.Module):
@@ -95,11 +120,7 @@ class Attention(nn.Module):
             return _dense(h * hd, name, self.dtype, self.param_dtype)(
                 x).reshape(b, n, h, hd)
 
-        def rotated(t):
-            return rope(t.astype(jnp.float32), self.rope_theta).astype(
-                self.dtype).transpose(0, 2, 1, 3)
-
-        q, k = rotated(heads("q_proj")), rotated(heads("k_proj"))
+        q, k = rotated(heads("q_proj"), heads("k_proj"), self.rope_theta)
         v = heads("v_proj").transpose(0, 2, 1, 3)
         with jax.named_scope("dsod.attn.core"):
             o = flash_attention_causal(q, k, v)

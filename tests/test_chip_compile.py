@@ -34,12 +34,12 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 _P = "distributed_sod_project_tpu.pallas."
-fc, fr, dfm, fl, fs, fa, vb, gm, mu, ssd, cc = (
+fc, fr, dfm, fl, fs, fa, vb, gm, mu, ssd, cc, rot = (
     importlib.import_module(_P + m)
     for m in ("fused_conv", "fused_resample", "dynamic_filter",
               "fused_loss", "fused_ssim", "flash_attention",
               "vmem_budget", "grouped_matmul", "moe_unpermute", "ssd_scan",
-              "causal_conv"))
+              "causal_conv", "rotary"))
 
 _S = collections.namedtuple("_S", "shape dtype")  # an argument's spec
 B = 2  # the kernels grid over images; the tile is what the compiler prices
@@ -177,6 +177,13 @@ _CCONV_ARGS = (_S((1, 16384, 4352), BF), _S((4, 4352), F32),
                _S((4352,), F32))
 
 
+# ouro_2_6b_pp6's rotation of q and k in ONE call: 16 heads of 128 over
+# one 8,192-token sequence, head-major.
+_rotary = lambda q, k: rot.rotate_half(  # noqa: E731
+    (q, k), 1e6, interpret=False)
+_ROTARY_ARGS = (_S((16, 8192, 128), BF),) * 2
+
+
 def _gmm_args(a, b, tiles=24, experts=8):
     return (_S((tiles * 512, a), BF), _S((experts, a, b), F32),
             _S((tiles,), jnp.int32), _S((1,), jnp.int32))
@@ -305,6 +312,11 @@ CASES = {
     "causal_conv.bwd@16384x4352": (
         jax.grad(lambda *a: _cconv(*a).astype(F32).sum(),
                  argnums=(0, 1, 2)), _CCONV_ARGS, 1),
+    # ... ouro_2_6b_pp6's rotary pair: the forward, and the gradient (the
+    # same kernel with the sine negated: nothing of the forward runs).
+    "rotary.fwd@16x8192x128": (_rotary, _ROTARY_ARGS, 1),
+    "rotary.bwd@16x8192x128": _resample_vjp(
+        _rotary, _ROTARY_ARGS, *_ROTARY_ARGS) + (1,),
     "moe_unpermute@53248": _unpermute(53248),
     "moe_unpermute@135168": _unpermute(135168),
 }
@@ -369,10 +381,13 @@ def test_the_state_space_step_compiles_for_v5e_and_fits(chip, topo,
 def test_the_looped_step_compiles_for_v5e_and_fits(chip, topo, monkeypatch):
     """``ouro_2_6b_pp6``'s whole train step at the cell's size (published
     widths, 8 layers run 4 times, 8,192 tokens, the whole vocabulary)
-    compiled for a described v5e: 64 kernels (32 visits, a forward and a
-    fused backward causal flash kernel each), no op rematerialised by
-    the compiler, and state + temporaries inside the chip's 15.75 GiB.
-    (The compiler's own books read 12.71 of 14.49 GiB: PERF.md section 4;
+    compiled for a described v5e: 160 kernels (32 visits: a forward and a
+    fused backward causal flash kernel each, and the rotation of q and k
+    in one call forward, recomputed and backward), no op rematerialised
+    by the compiler, state + temporaries inside the chip's 15.75 GiB, and
+    no float32 copy of q or k under ``dsod.attn`` (before PR 42 XLA wrote
+    192 of them a step, 64 MiB each, around the rotation).
+    (The compiler's own books read 12.51 of 14.54 GiB: PERF.md section 4;
     they are read from its log, .claude/skills/verify.)"""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -402,9 +417,11 @@ def test_the_looped_step_compiles_for_v5e_and_fits(chip, topo, monkeypatch):
         remat_policy=cfg.model.remat_policy)
     lowered = step.lower(jax.tree_util.tree_map(on(P()), state),
                          jax.tree_util.tree_map(on(P("data")), batch))
-    assert lowered.as_text().count("tpu_custom_call") == 64
+    assert lowered.as_text().count("tpu_custom_call") == 160
     compiled = lowered.compile()
     assert ".remat" not in compiled.as_text()
+    assert [ln[:200] for ln in compiled.as_text().splitlines()
+            if " = f32[1,8192,16,128]" in ln and "dsod.attn" in ln] == []
     mem = compiled.memory_analysis()
     state_gib = mem.argument_size_in_bytes / 2 ** 30
     assert 6.8 < state_gib < 6.9          # 612.4 M parameters x 12 bytes
