@@ -88,14 +88,18 @@ class DataConfig:
 
 @dataclasses.dataclass(frozen=True)
 class LMConfig:
-    """Shape of a token model (model.name=lfm2 | kimi | granite | ouro,
-    models/lfm2.py, models/kimi.py, models/granite.py, models/ouro.py).
+    """Shape of a token model (model.name=lfm2 | kimi | granite | ouro |
+    nemotron_h, models/lfm2.py, models/kimi.py, models/granite.py,
+    models/ouro.py, models/nemotron_h.py).
     The defaults
     are LFM2-8B-A1B's published widths (LiquidAI, config.json) and the
     share one chip holds in `lfm2_8b_a1b_ep4`: the layers kept,
     `experts_held` of `experts` from `first_expert` on, `vocab` rows of
     the 65,536.  `kimi_vl_a3b_ep8`, `granite_4_0_h_micro_pp4` and
-    `ouro_2_6b_pp6` set every field they read (configs/experiments.py).
+    `ouro_2_6b_pp6` set every field they read (configs/experiments.py),
+    and so does `nemotron_3_super_tp8_ep64`, whose `heads`, `kv_heads`
+    and `ssm_heads` are the chip's share of each mixer's heads and whose
+    `layer_types` are mamba | attention | moe, ONE mixer a layer.
     The state-space sizes and the four multipliers are read by `granite`
     alone, whose attention layer is position-free (it reads no
     `rope_theta`); the passes and the exit term by `ouro` alone, which
@@ -158,6 +162,13 @@ class LMConfig:
     # distribution in the loss (losses/token_ce.py).
     ut_steps: int = 1
     exit_beta: float = 0.0
+    # The latent expert layer (nemotron_h): the routed experts are
+    # two-matrix relu(.)^2 feed-forwards of expert_width columns that
+    # read and write a latent of latent_width columns, between a down-
+    # and an up-projection; ONE shared expert of shared_width columns
+    # reads the hidden state itself.
+    latent_width: int = 0
+    shared_width: int = 0
 
 
 @dataclasses.dataclass(frozen=True)

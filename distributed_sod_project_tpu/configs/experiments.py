@@ -326,3 +326,48 @@ def ouro_2_6b_pp6() -> ExperimentConfig:
         num_epochs=100,
         mesh=MeshConfig(data=1, model=1, seq=1),
     )
+
+
+@register_config("nemotron_3_super_tp8_ep64")
+def nemotron_3_super_tp8_ep64() -> ExperimentConfig:
+    """The fifth token model: Nemotron 3 Super 120B-A12B (nvidia,
+    ``nemotron_h``) at its published widths, ONE chip's share of a
+    deployment that divides every layer over 64 chips — the routed
+    experts 64 ways (experts 0-7 of 512; the router stays 512 wide,
+    top-22) and each mixer's heads 8 ways (Mamba-2 heads 0-15 of 128
+    with B/C group 0 of 8; query heads 0-3 of 32 on key-value head 0 of
+    2) — with rows 0-16,383 of the 131,072-row embedding and of the
+    untied head, and of the 88 layers the first 11, one period of the
+    pattern in its published 5 : 5 : 1 (``MEMEMEM*EME``); the other 77
+    lie on 7 further pipeline stages, the multi-token-prediction module
+    with the last.  The router is balanced by the family's rule.  Trains
+    on packed synthetic documents, 1 sequence of 8,192 tokens a step,
+    AdamW, per-layer remat.  ``model.lm.*`` / ``data.seq_len`` shrink it
+    for a CPU drive (tests/test_nemotron_h.py)."""
+    from ..models.nemotron_h import PATTERN
+
+    return ExperimentConfig(
+        name="nemotron_3_super_tp8_ep64",
+        data=DataConfig(dataset="packed_tokens", hflip=False,
+                        synthetic_size=4096, seq_len=8192, vocab=16384),
+        model=ModelConfig(
+            name="nemotron_h", backbone="none", sync_bn=False, remat=True,
+            lm=LMConfig(
+                vocab=16384, hidden=4096,
+                layer_types=tuple(PATTERN[c] for c in "MEMEMEM*EME"),
+                ffn_types=(), heads=4, kv_heads=1, head_dim=128,
+                expert_width=2688, latent_width=1024, shared_width=5376,
+                experts=512, experts_held=8, first_expert=0, top_k=22,
+                norm_eps=1e-5, norm_topk_prob=True,
+                routed_scaling_factor=5.0, topk_eps=1e-20,
+                bias_update_rate=1e-3,
+                ssm_heads=16, ssm_head_dim=64, ssm_state=128, ssm_conv=4,
+                ssm_chunk=128)),
+        loss=LossConfig(),
+        # AdamW and the warm-up of the four other token configs.
+        optim=OptimConfig(optimizer="adamw", lr=3e-4, weight_decay=0.1,
+                          schedule="poly", warmup_steps=2000),
+        global_batch_size=1,
+        num_epochs=100,
+        mesh=MeshConfig(data=1, model=1, seq=1),
+    )

@@ -223,3 +223,14 @@ def _build_ouro(cfg, *, dtype, param_dtype, axis_name):
 
     return Ouro(cfg=cfg.lm, remat=cfg.remat, dtype=dtype,
                 param_dtype=param_dtype)
+
+
+@register_model("nemotron_h")
+def _build_nemotron_h(cfg, *, dtype, param_dtype, axis_name):
+    """The fifth token model (ONE mixer a layer: Mamba-2, position-free
+    attention or latent sparse experts; the chip's share of each
+    mixer's heads): its shape is ``cfg.lm``, as for ``lfm2``."""
+    from .nemotron_h import NemotronH
+
+    return NemotronH(cfg=cfg.lm, remat=cfg.remat, dtype=dtype,
+                     param_dtype=param_dtype)

@@ -332,15 +332,9 @@ def test_kernel_compiles_for_v5e(chip, name):
     assert compiled.as_text().count("tpu_custom_call") >= n_calls
 
 
-def test_the_state_space_step_compiles_for_v5e_and_fits(chip, topo,
-                                                        monkeypatch):
-    """``granite_4_0_h_micro_pp4``'s whole train step at the cell's size
-    (published widths, 10 layers, 16,384 tokens) compiled for a described
-    v5e: 56 kernels (18 forward scans and 9 backward, 18 forward convs
-    and 9 backward, the two causal flash kernels), and state +
-    temporaries inside the chip's 15.75 GiB.  (The compiler's own books,
-    which decide whether it rematerialises, are read from its log:
-    .claude/skills/verify.)"""
+def _lowered_token_step(name, topo, monkeypatch):
+    """The registered token config's whole train step at the cell's own
+    size, lowered for the described chip on abstract state."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -354,7 +348,7 @@ def test_the_state_space_step_compiles_for_v5e_and_fits(chip, topo,
     # jax.default_backend() still says cpu here: steer the flash kernels
     # (and whatever else asks) to Mosaic for the length of this test.
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg = get_config("granite_4_0_h_micro_pp4")
+    cfg = get_config(name)
     n = cfg.data.seq_len
     mesh = Mesh(np.array([topo.devices[0]]).reshape(1, 1, 1),
                 ("data", "model", "seq"))
@@ -369,13 +363,31 @@ def test_the_state_space_step_compiles_for_v5e_and_fits(chip, topo,
         model, cfg.loss, tx, mesh, preset="dp", schedule=sched,
         donate_batch=True, remat=cfg.model.remat,
         remat_policy=cfg.model.remat_policy)
-    lowered = step.lower(jax.tree_util.tree_map(on(P()), state),
-                         jax.tree_util.tree_map(on(P("data")), batch))
-    assert lowered.as_text().count("tpu_custom_call") == 56
-    mem = lowered.compile().memory_analysis()
+    return step.lower(jax.tree_util.tree_map(on(P()), state),
+                      jax.tree_util.tree_map(on(P("data")), batch))
+
+
+def _state_gib_and_fits(compiled):
+    mem = compiled.memory_analysis()
     state_gib = mem.argument_size_in_bytes / 2 ** 30
-    assert 8.5 < state_gib < 8.8          # 772 M parameters x 12 bytes
     assert state_gib + mem.temp_size_in_bytes / 2 ** 30 < 15.75
+    return state_gib
+
+
+def test_the_state_space_step_compiles_for_v5e_and_fits(chip, topo,
+                                                        monkeypatch):
+    """``granite_4_0_h_micro_pp4``'s whole train step at the cell's size
+    (published widths, 10 layers, 16,384 tokens) compiled for a described
+    v5e: 56 kernels (18 forward scans and 9 backward, 18 forward convs
+    and 9 backward, the two causal flash kernels), and state +
+    temporaries inside the chip's 15.75 GiB.  (The compiler's own books,
+    which decide whether it rematerialises, are read from its log:
+    .claude/skills/verify.)"""
+    lowered = _lowered_token_step("granite_4_0_h_micro_pp4", topo,
+                                  monkeypatch)
+    assert lowered.as_text().count("tpu_custom_call") == 56
+    # 772 M parameters x 12 bytes
+    assert 8.5 < _state_gib_and_fits(lowered.compile()) < 8.8
 
 
 def test_the_looped_step_compiles_for_v5e_and_fits(chip, topo, monkeypatch):
@@ -389,43 +401,39 @@ def test_the_looped_step_compiles_for_v5e_and_fits(chip, topo, monkeypatch):
     192 of them a step, 64 MiB each, around the rotation).
     (The compiler's own books read 12.51 of 14.54 GiB: PERF.md section 4;
     they are read from its log, .claude/skills/verify.)"""
-    import numpy as np
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-    from distributed_sod_project_tpu.configs import get_config
-    from distributed_sod_project_tpu.models import build_model
-    from distributed_sod_project_tpu.parallel.engine import \
-        make_unified_train_step
-    from distributed_sod_project_tpu.train import (build_optimizer,
-                                                   create_train_state)
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg = get_config("ouro_2_6b_pp6")
-    n = cfg.data.seq_len
-    mesh = Mesh(np.array([topo.devices[0]]).reshape(1, 1, 1),
-                ("data", "model", "seq"))
-    model = build_model(cfg.model)
-    tx, sched = build_optimizer(cfg.optim, 20000)
-    batch = {k: np.zeros((1, n), np.int32) for k in ("tokens", "targets")}
-    state = jax.eval_shape(lambda: create_train_state(
-        jax.random.key(0), model, tx, batch))
-    on = lambda spec: lambda x: jax.ShapeDtypeStruct(  # noqa: E731
-        x.shape, x.dtype, sharding=NamedSharding(mesh, spec))
-    step = make_unified_train_step(
-        model, cfg.loss, tx, mesh, preset="dp", schedule=sched,
-        donate_batch=True, remat=cfg.model.remat,
-        remat_policy=cfg.model.remat_policy)
-    lowered = step.lower(jax.tree_util.tree_map(on(P()), state),
-                         jax.tree_util.tree_map(on(P("data")), batch))
+    lowered = _lowered_token_step("ouro_2_6b_pp6", topo, monkeypatch)
     assert lowered.as_text().count("tpu_custom_call") == 160
     compiled = lowered.compile()
     assert ".remat" not in compiled.as_text()
     assert [ln[:200] for ln in compiled.as_text().splitlines()
             if " = f32[1,8192,16,128]" in ln and "dsod.attn" in ln] == []
-    mem = compiled.memory_analysis()
-    state_gib = mem.argument_size_in_bytes / 2 ** 30
-    assert 6.8 < state_gib < 6.9          # 612.4 M parameters x 12 bytes
-    assert state_gib + mem.temp_size_in_bytes / 2 ** 30 < 15.75
+    # 612.4 M parameters x 12 bytes
+    assert 6.8 < _state_gib_and_fits(compiled) < 6.9
+
+
+def test_the_hybrid_step_compiles_for_v5e_and_fits(chip, topo, monkeypatch):
+    """``nemotron_3_super_tp8_ep64``'s whole train step at the cell's size
+    (published widths, 11 layers, 8,192 tokens) compiled for a described
+    v5e — the shapes the shared kernels had not met: grouped products of
+    [rows, 1024] x [1024, 2688] in 140 row tiles of 256 (2,688 = 21 x 128
+    takes column tiles of 896 and 384), the un-permute at 22 choices,
+    the scan at 16 heads and chunk 128, the conv over 1,280 columns, the
+    causal flash pair at 4 query heads on 1 key-value head of 128 — 162
+    kernels (five Mamba-2 layers: 10 scans + 5 backward, 10 convs + 5
+    backward; the attention layer's pair; five expert layers: 40 grouped
+    products, 10 ``dw`` and 15 un-permutes in the branch that runs, as
+    many again in the one that takes a routing the usual buffer cannot
+    hold), no op
+    rematerialised by the compiler, state + temporaries inside the chip's
+    15.75 GiB.  (The compiler's own books read 11.37 of 14.65 GiB: PERF.md
+    section 4.)"""
+    lowered = _lowered_token_step("nemotron_3_super_tp8_ep64", topo,
+                                  monkeypatch)
+    assert lowered.as_text().count("tpu_custom_call") == 162
+    compiled = lowered.compile()
+    assert ".remat" not in compiled.as_text()
+    # 700.9 M parameters x 12 bytes
+    assert 7.8 < _state_gib_and_fits(compiled) < 7.9
 
 
 def test_availability_rules_match_the_compiler():

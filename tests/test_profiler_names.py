@@ -138,7 +138,13 @@ _TOKEN_MODELS = {
         "model.lm.ssm_head_dim=16", "model.lm.ssm_state=16",
         "model.lm.ssm_chunk=64"],
     "ouro_2_6b_pp6": _TINY_LM + ["model.lm.kv_heads=4",
-                                 "model.lm.head_dim=16"]}
+                                 "model.lm.head_dim=16"],
+    "nemotron_3_super_tp8_ep64": _TINY_LM + [
+        "model.lm.kv_heads=1", "model.lm.head_dim=16",
+        "model.lm.latent_width=32", "model.lm.shared_width=96",
+        "model.lm.experts=16", "model.lm.experts_held=4", "model.lm.top_k=3",
+        "model.lm.ssm_heads=8", "model.lm.ssm_head_dim=16",
+        "model.lm.ssm_state=16", "model.lm.ssm_chunk=64"]}
 
 
 def _lowered_step_text(name: str, size: int = 64) -> str:

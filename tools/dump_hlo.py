@@ -37,7 +37,13 @@ _TINY_LM = {  # model.name -> the token model's shrink
     "granite": _TINY + ["model.lm.kv_heads=2", "model.lm.head_dim=16",
                         "model.lm.ssm_heads=8", "model.lm.ssm_head_dim=16",
                         "model.lm.ssm_state=16", "model.lm.ssm_chunk=32"],
-    "ouro": _TINY + ["model.lm.kv_heads=4", "model.lm.head_dim=16"]}
+    "ouro": _TINY + ["model.lm.kv_heads=4", "model.lm.head_dim=16"],
+    "nemotron_h": _TINY + ["model.lm.kv_heads=1", "model.lm.head_dim=16",
+                           "model.lm.latent_width=32",
+                           "model.lm.shared_width=96", "model.lm.experts=16",
+                           "model.lm.experts_held=4", "model.lm.top_k=3",
+                           "model.lm.ssm_heads=8", "model.lm.ssm_head_dim=16",
+                           "model.lm.ssm_state=16", "model.lm.ssm_chunk=32"]}
 
 
 def dump(config_name: str, out_dir: str, n_devices: int = 8,
