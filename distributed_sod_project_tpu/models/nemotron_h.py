@@ -60,7 +60,10 @@ final norm is ``dsod.heads``.  Counters beside ``grad_norm``: the expert
 layers' (``lfm2.moe_counters``), the mixers' (``granite.
 ssm_counters``) and the hottest expert layer's share of the pairs and
 of its usual buffer (``moe_pairs_here_share_max``,
-``moe_buffer_fill_max``: over 1, that layer took the by-group path).
+``moe_buffer_fill_max``: over 1, that layer took the by-group path), and
+``moe_weight_fetch_share``: the weight blocks the up-projection's grouped
+product copies in over its grid steps, mean over the expert layers (1.0
+= a block a step; ``pallas/grouped_matmul.py`` says which steps fetch).
 """
 
 from __future__ import annotations
@@ -75,7 +78,8 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from ..pallas.flash_attention import CAUSAL_RESIDUAL_NAMES
-from ..pallas.grouped_matmul import TILE_M, grouped_matmul
+from ..pallas.grouped_matmul import (TILE_M, grid_order, grouped_matmul,
+                                     weight_block_fetches)
 from ..pallas.moe_unpermute import unpermute_steps
 from .granite import Attention, Mamba2Mixer, ssm_counters
 from .kimi import Embed, Head
@@ -139,7 +143,8 @@ def held_experts_sum(xt, idx, w, weights, ffn, *, experts: int,
     xt: [T, A]; idx: [T, K] int32 over ALL ``experts``; w: [T, K]
     float32.  -> (out [T, B] float32, pairs per held expert, dropped,
     the share of the usual buffer's tiles this routing needs: over 1 it
-    took the by-group path).
+    took the by-group path, the share of the grid steps of the usual
+    buffer's product with ``weights[0]`` that fetch a weight block).
     (That class keeps its own copy of this walk: its lines are part of
     two older cells' compile-cache keys, PERF.md section 6, PRs 27-28.)
     """
@@ -170,6 +175,9 @@ def held_experts_sum(xt, idx, w, weights, ffn, *, experts: int,
     with jax.named_scope("dsod.moe.route"):
         plan, counts, dropped = jax.tree_util.tree_map(
             lambda t: checkpoint_name(t, "plan"), plan_for(idx, usual))
+    nj, row_inner = grid_order(usual, tile_m, e, weights[0].shape[2])
+    fetched = weight_block_fetches(plan[2], jnp.maximum(plan[3], floor), nj,
+                                   row_inner) / (usual * nj)
 
     def through(plan, multiplied, xt, w, *weights):
         row_of_pair, pair_of_row, tile_expert, n_used, steps = plan
@@ -211,7 +219,7 @@ def held_experts_sum(xt, idx, w, weights, ffn, *, experts: int,
         out, dropped = lax.cond(
             needed <= usual, jax.checkpoint(in_the_usual_buffer),
             jax.checkpoint(by_group), *args)
-    return out, counts, dropped, needed / usual
+    return out, counts, dropped, needed / usual, fetched
 
 
 class LatentExpertLayer(nn.Module):
@@ -261,7 +269,7 @@ class LatentExpertLayer(nn.Module):
             w = w * self.routed_scaling_factor
         with jax.named_scope("dsod.moe.latent"):
             z = _dense(lat, "latent_down", self.dtype, self.param_dtype)(xt)
-        r, counts, dropped, fill = held_experts_sum(
+        r, counts, dropped, fill, fetched = held_experts_sum(
             z, idx, w, (w_up, w_down),
             lambda gmm, xs, up, down: gmm(relu2(gmm(xs, up)), down),
             experts=self.experts, first_expert=self.first_expert)
@@ -273,7 +281,8 @@ class LatentExpertLayer(nn.Module):
             "pairs_here": pairs,
             "load_max_over_mean": jnp.max(counts) * e / jnp.maximum(pairs, 1),
             "dropped": dropped.astype(jnp.float32),
-            "buffer_fill": fill.astype(jnp.float32)}
+            "buffer_fill": fill.astype(jnp.float32),
+            "weight_fetch_share": fetched.astype(jnp.float32)}
         if (self.bias_update_rate and not self.is_initializing()
                 and self.is_mutable_collection("batch_stats")):
             with jax.named_scope("dsod.moe.balance"):
@@ -353,6 +362,8 @@ class NemotronH(nn.Module):
                     [m["pairs_here"] for m in moe])) / pairs_all
                 counters["moe_buffer_fill_max"] = jnp.max(jnp.stack(
                     [m["buffer_fill"] for m in moe]))
+                counters["moe_weight_fetch_share"] = jnp.mean(jnp.stack(
+                    [m["weight_fetch_share"] for m in moe]))
         log_saves("nemotron_h", len(c.layer_types), saved, REMAT_SAVES)
         with jax.named_scope("dsod.heads"):
             h = RMSNorm(c.norm_eps, self.dtype, name="final_norm")(h)

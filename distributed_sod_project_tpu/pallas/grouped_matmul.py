@@ -8,9 +8,30 @@ expert at least one tile).  A row tile therefore belongs to exactly one
 expert, named by the scalar-prefetched ``tile_expert`` map, and the
 kernel is a plain tiled matmul whose weight block is chosen per tile:
 no per-row masks, no expert loop, no token dropped — the buffer is
-sized for the worst imbalance and the tiles past ``n_used`` (most of
-them, when routing is balanced) are neither loaded nor multiplied, only
-written as zeros.
+sized for the worst imbalance, and the tiles past ``n_used`` (most of
+them, when routing is balanced) are not multiplied.
+
+What a grid step moves.  Pallas copies a block in only when its block
+index differs from the step before, so the index maps decide the
+traffic (:func:`weight_block_fetches` counts it):
+
+- a tile past ``n_used`` names the ``x`` block AND the weight block the
+  last used step left resident (:func:`_weight_block`), so it fetches
+  nothing; it costs its grid step and the zero rows it writes (what
+  follows reads the whole buffer);
+- with the column blocks inner (grid ``(row tile, column block)``)
+  every used tile fetches its expert's whole matrix again wherever
+  there is more than one column block, and ``x`` once;
+- with the row tiles inner (grid ``(column block, row tile)``) a weight
+  block stays put across an expert's consecutive row tiles and across
+  the unused tail: a pass fetches ``expert runs x column blocks`` weight
+  blocks, and ``x`` once a column block.
+
+:func:`_row_inner` picks the order from the call's static shapes by the
+bytes each moves; every output block is the same ``dot_general`` of the
+same operands either way, so the results are the same to the last bit.
+``_tgmm`` freezes both its input blocks past ``n_used`` and keeps its
+output block while the expert stays.
 
 Three calls, one custom VJP:
 
@@ -58,14 +79,45 @@ def _tile_n(n: int, cap: int = 1024) -> int:
     return next(t for t in range(cap, 0, -128) if n % t == 0)
 
 
-def _params():
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary"),
-        vmem_limit_bytes=_VMEM_LIMIT)
+def _row_inner(r: int, tile_m: int, e: int, b: int, nj: int) -> bool:
+    """Whether ``r`` row tiles of ``e`` experts against ``nj`` column
+    blocks of a ``b``-wide output move fewer bytes with the row tiles
+    inner: ``x`` is then read ``nj`` times and each expert's matrix
+    once, where the other order reads ``x`` once and a matrix per row
+    tile (both sides in units of the contracted width)."""
+    return (nj - 1) * tile_m * r + e * b < r * b
 
 
-def _gmm_kernel(te_ref, nu_ref, x_ref, w_ref, o_ref, *, transpose_w):
-    i = pl.program_id(0)
+def grid_order(r: int, tile_m: int, e: int, b: int):
+    """(column blocks, whether the row tiles are the inner grid axis) of
+    the ``_gmm`` call over ``r`` row tiles whose output is ``b`` wide."""
+    nj = b // _tile_n(b)
+    return nj, _row_inner(r, tile_m, e, b, nj)
+
+
+def _weight_block(i, j, te, nu, nj: int, row_inner: bool):
+    """The (expert, column block) that the grid step of row tile ``i``
+    and column block ``j`` has resident: a tile past ``nu`` names what
+    the last used step left there."""
+    expert = te[jnp.minimum(i, nu - 1)]
+    return expert, j if row_inner else jnp.where(i < nu, j, nj - 1)
+
+
+def weight_block_fetches(tile_expert, n_used, nj: int, row_inner: bool):
+    """The weight blocks one ``_gmm`` call copies in: the grid steps, in
+    the order the grid walks them, whose :func:`_weight_block` differs
+    from the step before (the first step fetches)."""
+    te, nu = jnp.asarray(tile_expert), jnp.asarray(n_used).reshape(-1)[0]
+    i, j = jnp.arange(te.shape[0]), jnp.arange(nj)
+    i, j = ((jnp.tile(i, nj), jnp.repeat(j, i.size)) if row_inner
+            else (jnp.repeat(i, nj), jnp.tile(j, i.size)))
+    expert, col = _weight_block(i, j, te, nu, nj, row_inner)
+    return 1 + jnp.sum((expert[1:] != expert[:-1]) | (col[1:] != col[:-1]))
+
+
+def _gmm_kernel(te_ref, nu_ref, x_ref, w_ref, o_ref, *, transpose_w,
+                row_axis):
+    i = pl.program_id(row_axis)
 
     @pl.when(i < nu_ref[0])
     def _():
@@ -86,23 +138,36 @@ def _gmm(x, w, tile_expert, n_used, *, tile_m, transpose_w, interpret):
     -> [M, B], the expert per row tile from ``tile_expert``."""
     m, a = x.shape
     b = w.shape[1] if transpose_w else w.shape[2]
-    tn = _tile_n(b)
-    last = lambda i, nu: jnp.minimum(i, nu[0] - 1)  # noqa: E731
-    w_spec = (pl.BlockSpec((1, tn, a), lambda i, j, te, nu: (te[i], j, 0))
-              if transpose_w else
-              pl.BlockSpec((1, a, tn), lambda i, j, te, nu: (te[i], 0, j)))
+    r = m // tile_m
+    nj, row_inner = grid_order(r, tile_m, w.shape[0], b)
+    tn = b // nj
+
+    def at(f):  # an index map over (row tile, column block), either order
+        if row_inner:
+            return lambda j, i, te, nu: f(i, j, te, nu)
+        return f
+
+    def w_map(i, j, te, nu):
+        expert, col = _weight_block(i, j, te, nu[0], nj, row_inner)
+        return (expert, col, 0) if transpose_w else (expert, 0, col)
+
     return pl.pallas_call(
-        partial(_gmm_kernel, transpose_w=transpose_w),
+        partial(_gmm_kernel, transpose_w=transpose_w,
+                row_axis=int(row_inner)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(m // tile_m, b // tn),
-            in_specs=[pl.BlockSpec((tile_m, a),
-                                   lambda i, j, te, nu: (last(i, nu), 0)),
-                      w_spec],
+            grid=(nj, r) if row_inner else (r, nj),
+            in_specs=[
+                pl.BlockSpec((tile_m, a), at(lambda i, j, te, nu: (
+                    jnp.minimum(i, nu[0] - 1), 0))),
+                pl.BlockSpec((1, tn, a) if transpose_w else (1, a, tn),
+                             at(w_map))],
             out_specs=pl.BlockSpec((tile_m, tn),
-                                   lambda i, j, te, nu: (i, j))),
+                                   at(lambda i, j, te, nu: (i, j)))),
         out_shape=jax.ShapeDtypeStruct((m, b), x.dtype),
-        compiler_params=_params(),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(tile_expert, n_used, x, w)
 

@@ -184,8 +184,8 @@ _rotary = lambda q, k: rot.rotate_half(  # noqa: E731
 _ROTARY_ARGS = (_S((16, 8192, 128), BF),) * 2
 
 
-def _gmm_args(a, b, tiles=24, experts=8):
-    return (_S((tiles * 512, a), BF), _S((experts, a, b), F32),
+def _gmm_args(a, b, tiles=24, experts=8, tile_m=512):
+    return (_S((tiles * tile_m, a), BF), _S((experts, a, b), F32),
             _S((tiles,), jnp.int32), _S((1,), jnp.int32))
 
 
@@ -279,7 +279,9 @@ CASES = {
     "flash_attention_mla.bwd@16384": (_mla_grad, _mla_args(16384), 2),
     "flash_attention_mla.bwd@65536": (_mla_grad, _mla_args(65536), 2),
     # ... and its grouped expert products at 2048 -> 1408 -> 2048 over
-    # the usual buffer's 80 row tiles.
+    # the usual buffer's 80 row tiles: 2048 -> 1408 is eleven column
+    # blocks of 128 and keeps the column blocks inner (and so does the
+    # other entry's dx), the rest take the row tiles inner.
     "grouped_matmul.dx+dw@2048x1408": (
         jax.grad(lambda x, w, te, nu: _gmm(x, w, te, nu).astype(F32).sum(),
                  argnums=(0, 1)), _gmm_args(2048, 1408, tiles=80), 2),
@@ -296,6 +298,16 @@ CASES = {
     "grouped_matmul.dx+dw@1792x2048": (
         jax.grad(lambda x, w, te, nu: _gmm(x, w, te, nu).astype(F32).sum(),
                  argnums=(0, 1)), _gmm_args(1792, 2048), 2),
+    # nemotron_3_super_tp8_ep64's up-projection, 8 experts of 1024 ->
+    # 2688 over the usual buffer's 140 row tiles of 256: three column
+    # blocks of 896 with the ROW TILES INNER (forward and dx; dw in 384s).
+    "grouped_matmul.fwd@1024x2688": (
+        partial(_gmm, tile_m=256),
+        _gmm_args(1024, 2688, tiles=140, tile_m=256), 1),
+    "grouped_matmul.dx+dw@1024x2688": (
+        jax.grad(lambda x, w, te, nu: _gmm(x, w, te, nu, tile_m=256).astype(
+            F32).sum(), argnums=(0, 1)),
+        _gmm_args(1024, 2688, tiles=140, tile_m=256), 2),
     # ... and the un-permute-and-sum out of the usual buffer (1.5 x the
     # balanced share: 104 row tiles) and the worst-case one (264).
     # granite_4_0_h_micro_pp4's chunked scan: forward (y and the chunk

@@ -456,16 +456,18 @@ def test_no_product_of_the_step_is_outside_a_stage(lowered_text):
 
 @pytest.mark.parametrize("config,sha", [
     ("lfm2_8b_a1b_ep4",
-     "f90e4a230e59a8376448465e29800a08df1da8d553da36444eac232ed3b309f7"),
+     "71bed8f99051351058b5fb3b07547fbff36765f9a099bbfad4394fc60676dd0b"),
     ("kimi_vl_a3b_ep8",
-     "8e8bd7f41f6c2eb833d7f31c5167bb97a408f785b46cac118368a5225de99b15")])
+     "6cb879af71d7e74f51b247b04ecbd370006c93e44a7953941e04947cd3b201b7")])
 def test_the_older_token_models_steps_are_the_programs_they_were(
         tmp_path, config, sha):
     """``tools/dump_hlo.py`` on both older token configs, as its command
     line runs it (a process of its own: this suite's conftest sets a
     matmul precision, which is part of a program): the StableHLO of the
     commit before this model (PR 36's tree, 74d629d; the first one's
-    with PR 40's one-kernel causal backward), to the byte.  A PR
+    with PR 40's one-kernel causal backward; both with PR 44's grouped
+    product, whose weight block moves only where the expert or the
+    column block does), to the byte.  A PR
     that means to change one of those steps changes its hash with it and
     says so in PERF.md."""
     tools = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",

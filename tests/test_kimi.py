@@ -387,8 +387,10 @@ def test_the_first_token_models_step_is_the_program_it_was(tmp_path):
     """``tools/dump_hlo.py`` on ``lfm2_8b_a1b_ep4``, as its command line
     runs it (a process of its own: this suite's conftest sets a matmul
     precision, which is part of a program): the StableHLO that the
-    commit before this model gave (PR 31's tree, aee3230) with the one
-    change a later PR meant (PR 40: the causal backward is one kernel),
+    commit before this model gave (PR 31's tree, aee3230) with the two
+    changes later PRs meant (PR 40: the causal backward is one kernel;
+    PR 44: the grouped product's weight block moves only where the
+    expert or the column block does),
     to the byte.  A PR that means to change that step changes this hash
     with it and says so in PERF.md."""
     import subprocess
@@ -404,7 +406,7 @@ def test_the_first_token_models_step_is_the_program_it_was(tmp_path):
         capture_output=True, timeout=600)
     with open(tmp_path / "lfm2_8b_a1b_ep4.stablehlo.txt", "rb") as f:
         assert hashlib.sha256(f.read()).hexdigest() == (
-            "f90e4a230e59a8376448465e29800a08df1da8d553da36444eac232ed3b309f7")
+            "71bed8f99051351058b5fb3b07547fbff36765f9a099bbfad4394fc60676dd0b")
 
 
 # -- the loop -----------------------------------------------------------------

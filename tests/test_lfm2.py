@@ -35,6 +35,7 @@ from distributed_sod_project_tpu.configs import apply_overrides, get_config
 from distributed_sod_project_tpu.losses.token_ce import tied_cross_entropy
 from distributed_sod_project_tpu.models import build_model
 from distributed_sod_project_tpu.models import lfm2 as lm
+from distributed_sod_project_tpu.pallas import grouped_matmul as gm
 from distributed_sod_project_tpu.pallas.flash_attention import \
     flash_attention_causal
 from distributed_sod_project_tpu.pallas.grouped_matmul import grouped_matmul
@@ -218,6 +219,23 @@ def test_overflowing_routing_has_the_reference_gradient(setup):
         _close(a, b, 1e-4)
 
 
+def _buffer(counts, a, tile, unused, rng):
+    """An expert-ordered buffer of ragged groups with ``unused`` tiles
+    at its end: x, each row's expert (-1 on padding), the tile map."""
+    tiles = [max(-(-n // tile), 1) for n in counts]
+    rows = (sum(tiles) + unused) * tile
+    x, expert_of_row = np.zeros((rows, a), np.float32), np.full(rows, -1)
+    r0 = 0
+    for i, (n, t) in enumerate(zip(counts, tiles)):
+        x[r0:r0 + n] = rng.randn(n, a)
+        expert_of_row[r0:r0 + n] = i
+        r0 += t * tile
+    te = np.repeat(np.arange(len(counts)), tiles).tolist()
+    return (jnp.asarray(x), expert_of_row,
+            jnp.asarray(te + [te[-1]] * unused, jnp.int32),
+            jnp.asarray([sum(tiles)], jnp.int32))
+
+
 @pytest.mark.parametrize("counts,a,b", [
     ((40, 0, 100, 7), 24, 40), ((0, 0, 0, 64), 24, 40),
     ((16, 16, 16, 16), 24, 40),
@@ -228,21 +246,11 @@ def test_grouped_matmul_matches_a_loop_over_experts(counts, a, b):
     """Forward, dx and dw, with empty experts and ragged groups."""
     tile = 16
     e = len(counts)
-    tiles = [max(-(-n // tile), 1) for n in counts]
-    rows = (sum(tiles) + 2) * tile  # two unused tiles at the end
-    x = np.zeros((rows, a), np.float32)
-    expert_of_row = np.full(rows, -1)
-    r0 = 0
     rng = np.random.RandomState(0)
-    for i, (n, t) in enumerate(zip(counts, tiles)):
-        x[r0:r0 + n] = rng.randn(n, a)
-        expert_of_row[r0:r0 + n] = i
-        r0 += t * tile
-    te = np.repeat(np.arange(e), tiles).tolist()
-    te = np.asarray(te + [te[-1]] * 2, np.int32)
-    nu = np.asarray([sum(tiles)], np.int32)
+    # two unused tiles at the end
+    x, expert_of_row, te, nu = _buffer(counts, a, tile, 2, rng)
     w = jnp.asarray(rng.randn(e, a, b), jnp.float32)
-    g = jnp.asarray(rng.randn(rows, b), jnp.float32)
+    g = jnp.asarray(rng.randn(x.shape[0], b), jnp.float32)
 
     def loop(x, w):
         onehot = (expert_of_row[:, None] == np.arange(e)[None]).astype(
@@ -250,16 +258,90 @@ def test_grouped_matmul_matches_a_loop_over_experts(counts, a, b):
         return jnp.einsum("re,ra,eab->rb", onehot, x, w)
 
     def kernel(x, w):
-        return grouped_matmul(x, w, jnp.asarray(te), jnp.asarray(nu),
-                              tile_m=tile)
+        return grouped_matmul(x, w, te, nu, tile_m=tile)
 
-    x = jnp.asarray(x)
     _close(kernel(x, w), loop(x, w))
     for i in (0, 1):
         got = jax.grad(lambda *a: jnp.sum(kernel(*a) * g), i)(x, w)
         want = jax.grad(lambda *a: jnp.sum(loop(*a) * g), i)(x, w)
         valid = (expert_of_row >= 0)[:, None] if i == 0 else 1.0
         _close(got * valid, want)
+
+
+@pytest.mark.parametrize("counts,a,b", [
+    # more than one column block (256 = 2 x 128, 384 = 3 x 128 under the
+    # caps this test gives ``_tile_n``) and three skipped tiles at the end
+    ((40, 0, 100, 7), 128, 256), ((5, 30), 256, 384),
+    ((0, 0, 0, 64), 128, 384)])
+@pytest.mark.parametrize("what", ["forward", "dx", "dw"])
+def test_both_grid_orders_match_the_loop_and_each_other_bit_for_bit(
+        monkeypatch, counts, a, b, what):
+    tile, e = 16, len(counts)
+    rng = np.random.RandomState(1)
+    x, expert_of_row, te, nu = _buffer(counts, a, tile, 3, rng)
+    w = jnp.asarray(rng.randn(e, a, b), jnp.float32)
+    g = jnp.asarray(rng.randn(x.shape[0], b), jnp.float32)
+    onehot = jnp.asarray(expert_of_row[:, None] == np.arange(e)[None],
+                         jnp.float32)
+    tile_n = gm._tile_n
+    monkeypatch.setattr(gm, "_tile_n", lambda n, cap=128: tile_n(n, 128))
+
+    def of(product):
+        if what == "forward":
+            return product(x, w)
+        return jax.grad(lambda *t: jnp.sum(product(*t) * g),
+                        ("dx", "dw").index(what))(x, w)
+
+    got = {}
+    for row_inner in (False, True):
+        monkeypatch.setattr(gm, "_row_inner", lambda *_: row_inner)
+        assert gm.grid_order(x.shape[0] // tile, tile, e, b) == (
+            b // 128, row_inner)
+        got[row_inner] = of(lambda x, w: grouped_matmul(x, w, te, nu,
+                                                        tile_m=tile))
+    assert np.array_equal(np.asarray(got[False]), np.asarray(got[True]))
+    want = of(lambda x, w: jnp.einsum("re,ra,eab->rb", onehot, x, w))
+    valid = (expert_of_row >= 0)[:, None] if what == "dx" else 1.0
+    _close(got[True] * valid, want)
+    if what != "dw":  # the skipped tiles' rows are written, as zeros
+        assert not np.asarray(got[True])[-3 * tile:].any()
+
+
+@pytest.mark.parametrize("te,nu,nj,col_inner,row_inner", [
+    # one column block: a fetch per run of an expert, in either order
+    ([0, 0, 1, 2, 2, 2], 6, 1, 3, 3), ([0, 0, 1, 2, 2, 2], 4, 1, 3, 3),
+    # three column blocks: a fetch per step of a used tile / per (run,
+    # column block); the skipped tail (te repeats the last used expert)
+    # adds none in either order
+    ([0, 0, 1, 2], 4, 3, 12, 9), ([0, 0, 1, 2, 2, 2, 2], 4, 3, 12, 9),
+    ([0, 1, 1, 1, 1, 1], 3, 2, 6, 4), ([0] * 8, 1, 4, 4, 4),
+    # the hybrid cell's usual buffer: 8 experts in 74 multiplied tiles
+    # of 140, three column blocks: 222 of 420 steps fetch, or 24
+    (sorted(list(range(8)) * 9 + [7, 7]) + [7] * 66, 74, 3, 222, 24)])
+def test_weight_block_fetches_counts_the_steps_whose_block_changes(
+        te, nu, nj, col_inner, row_inner):
+    te = np.asarray(te, np.int32)
+    assert int(gm.weight_block_fetches(te, [nu], nj, False)) == col_inner
+    assert int(jax.jit(gm.weight_block_fetches, static_argnums=(2, 3))(
+        te, jnp.asarray([nu]), nj, True)) == row_inner
+    runs = 1 + int(np.sum(te[1:nu] != te[:nu - 1]))
+    assert row_inner == runs * nj
+
+
+@pytest.mark.parametrize("b,tile_m,tiles,nj,row_inner", [
+    # every ``_gmm`` shape the three configurations run, forward and dx,
+    # by its output's width over the cell's usual buffer
+    pytest.param(2688, 256, 140, 3, True, id="nemotron-1024-to-2688"),
+    pytest.param(1024, 256, 140, 1, True, id="nemotron-2688-to-1024"),
+    pytest.param(1792, 512, 104, 2, True, id="lfm2-2048-to-1792"),
+    pytest.param(2048, 512, 104, 2, True, id="lfm2-1792-to-2048"),
+    pytest.param(2048, 512, 80, 2, True, id="kimi-1408-to-2048"),
+    # 1,408 = 11 x 128 and 11 is prime: eleven column blocks, and x
+    # read eleven times would cost more than the matrices do
+    pytest.param(1408, 512, 80, 11, False, id="kimi-2048-to-1408")])
+def test_the_grid_order_follows_the_bytes_at_the_cells_shapes(
+        b, tile_m, tiles, nj, row_inner):
+    assert gm.grid_order(tiles, tile_m, 8, b) == (nj, row_inner)
 
 
 def _routing(case, t, k, experts, held, rng):

@@ -163,7 +163,7 @@ def test_hidden_states_counters_loss_and_every_gradient_match_reference(
     assert set(counters) == {"ssm_decay_min", "ssm_delta_max",
                              "moe_pairs_here_share", "moe_load_max_over_mean",
                              "moe_dropped_pairs", "moe_pairs_here_share_max",
-                             "moe_buffer_fill_max"}
+                             "moe_buffer_fill_max", "moe_weight_fetch_share"}
     assert float(counters["moe_dropped_pairs"]) == 0.0
     # the hottest layer's share of the pairs, and of its usual buffer
     per_layer = [sum(float(jnp.sum(s[name][:4])) for s in sent)
@@ -173,6 +173,8 @@ def test_hidden_states_counters_loss_and_every_gradient_match_reference(
     assert float(counters["moe_pairs_here_share_max"]) >= float(
         counters["moe_pairs_here_share"])
     assert 0 < float(counters["moe_buffer_fill_max"]) <= 1
+    # the up-projection copies a weight block in on fewer steps than it has
+    assert 0 < float(counters["moe_weight_fetch_share"]) < 1
     held = np.mean([sum(float(jnp.sum(s[name][:4])) for s in sent)
                     for name in sent[0]])
     assert float(counters["moe_pairs_here_share"]) == pytest.approx(
@@ -578,13 +580,14 @@ def test_no_product_of_the_step_is_outside_a_stage(lowered_text):
     ("ouro_2_6b_pp6",
      "b34ace52ceeb3482c1c609c64bebb57c944fd334a1b20f3038b2725c8eedb9fa"),
     ("nemotron_3_super_tp8_ep64",
-     "5d8f006ac8b875b213b6e3fce7a964d2a5af09cc74cb59eb4695071dcf431100")])
+     "eface19571ba238f85b06842ee01f6025070bb2a5e09e4419b35f7a5f9646905")])
 def test_the_step_is_the_program_this_file_pins(tmp_path, config, sha):
     """``tools/dump_hlo.py`` as its command line runs it (a process of
     its own), to the byte: the fourth token model's step as the commit
     before this model had it (PR 42's tree, ce641e3; tests/test_granite.py
     and tests/test_ouro.py pin the first three), and this model's own
-    step as the PR that added it left it.  A PR that means to change
+    step as the PR that added it left it but for PR 44's grouped
+    product and its counter.  A PR that means to change
     either changes its hash with it and says so in PERF.md."""
     tools = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
                          "tools")
@@ -616,4 +619,5 @@ def test_three_steps_of_fit_at_tiny_size(tmp_path):
         assert h["moe_dropped_pairs"] == 0
         assert 0 < h["moe_pairs_here_share"] < 1
         assert h["moe_bias_abs_max"] > 0
+        assert 0 < h["moe_weight_fetch_share"] < 1
         assert 0 < h["ssm_decay_min"] < 1 and h["ssm_delta_max"] > 0

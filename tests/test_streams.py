@@ -472,7 +472,7 @@ def test_home_replica_death_rehomes_the_stream_exactly(two_tiny):
     srv, url = _start_http(fleet)
     try:
         _post(url, _img(0, 16, 16), model="a", stream="cam-1")
-        stats = _get_json(url, "/stats")
+        stats = _consistent_stats(url)  # the home is pinned as it books
         per = {s["stream"]: s for s in stats["streams"]["per_stream"]}
         home = per["cam-1"]["home"]
         assert home in ("a#0", "a#1")
