@@ -58,7 +58,8 @@ from ..pallas.causal_conv import causal_conv_silu
 from ..pallas.flash_attention import (CAUSAL_RESIDUAL_NAMES,
                                       flash_attention_causal)
 from ..pallas.ssd_scan import ssd_scan
-from .lfm2 import RMSNorm, SwiGLU, _dense, _saves_counted, log_saves
+from .lfm2 import (RMSNorm, SwiGLU, _dense, _saves_counted, log_flash_grid,
+                   log_saves)
 
 # What a rematerialised layer KEEPS: the flash kernel's output and lse
 # (without them its forward runs twice).  Not the scan's output and
@@ -233,6 +234,7 @@ class Granite(nn.Module):
                     per_layer.append(counters)
             counters = ssm_counters(per_layer)
         log_saves("granite", len(c.layer_types), saved, REMAT_SAVES)
+        log_flash_grid(saved, tokens.shape[1])
         with jax.named_scope("dsod.heads"):
             h = RMSNorm(c.norm_eps, self.dtype, name="final_norm")(h)
             h = h * jnp.asarray(1.0 / c.logits_scaling, self.dtype)

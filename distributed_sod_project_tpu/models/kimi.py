@@ -53,7 +53,7 @@ import jax.numpy as jnp
 
 from ..pallas.flash_attention import MLA_RESIDUAL_NAMES, flash_attention_mla
 from .lfm2 import (ExpertLayer, RMSNorm, SwiGLU, _dense, _saves_counted,
-                   log_saves, moe_counters, rope)
+                   log_flash_grid, log_saves, moe_counters, rope)
 
 REMAT_SAVES = MLA_RESIDUAL_NAMES[1:] + ("plan",)  # out, lse; not q
 _SAVE_NAMED = jax.checkpoint_policies.save_only_these_names(*REMAT_SAVES)
@@ -193,6 +193,7 @@ class KimiDecoder(nn.Module):
             # encoder's, not unscoped time)
             counters = moe_counters(per_layer, tokens.size * c.top_k)
         log_saves("kimi", len(c.ffn_types), saved, REMAT_SAVES)
+        log_flash_grid(saved, tokens.shape[1])
         with jax.named_scope("dsod.heads"):
             h = RMSNorm(c.norm_eps, self.dtype, name="final_norm")(h)
             h = Head(c.vocab, self.param_dtype, name="head")(h)

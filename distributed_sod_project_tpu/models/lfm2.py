@@ -50,8 +50,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
-from ..pallas.flash_attention import (CAUSAL_RESIDUAL_NAMES,
-                                      flash_attention_causal)
+from ..pallas.flash_attention import (CAUSAL_RESIDUAL_NAMES, causal_blocks,
+                                      causal_pairs, flash_attention_causal)
 from ..pallas.grouped_matmul import TILE_M, grouped_matmul
 from ..pallas.moe_unpermute import moe_unpermute, unpermute_steps
 
@@ -569,6 +569,7 @@ class LFM2(nn.Module):
                 if counters is not None:
                     per_layer.append(counters)
         log_saves("lfm2", len(c.layer_types), saved, REMAT_SAVES)
+        log_flash_grid(saved, tokens.shape[1])
         with jax.named_scope("dsod.heads"):
             h = RMSNorm(c.norm_eps, self.dtype, name="final_norm")(h)
         return h, moe_counters(per_layer, tokens.size * c.top_k)
@@ -586,6 +587,19 @@ def log_saves(model: str, layers: int, saved, names,
             "remat saves (%s, %d %s): %s MiB=%.1f", model, layers, unit,
             " ".join(f"{k}={saved[k]}" for k in names),
             saved["bytes"] / 2 ** 20)
+
+
+def log_flash_grid(saved, seq_len: int) -> None:
+    """Beside ``log_saves``, under its rule: how many grid steps a head
+    the causal flash kernels take over ``seq_len`` tokens (the tile pairs
+    on or under the diagonal, from the function that builds the kernels'
+    tables) of the rectangle's."""
+    if saved:
+        from ..utils.logging import get_logger
+
+        nb = causal_blocks(seq_len)[1]
+        get_logger().info("flash grid: steps=%d of %d a head",
+                          causal_pairs(nb)[0][0].size, nb * nb)
 
 
 def moe_counters(per_layer, pairs_total: int):

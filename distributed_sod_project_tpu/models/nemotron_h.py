@@ -84,8 +84,8 @@ from ..pallas.moe_unpermute import unpermute_steps
 from .granite import Attention, Mamba2Mixer, ssm_counters
 from .kimi import Embed, Head
 from .lfm2 import (RMSNorm, _dense, _saves_counted, combine, dispatch,
-                   log_saves, moe_counters, plan_dispatch, tiles_needed,
-                   worst_case_tiles)
+                   log_flash_grid, log_saves, moe_counters, plan_dispatch,
+                   tiles_needed, worst_case_tiles)
 
 # What a rematerialised layer KEEPS: the attention kernel's output and
 # lse, and the expert layers' routing plan (chosen experts, scores,
@@ -365,6 +365,7 @@ class NemotronH(nn.Module):
                 counters["moe_weight_fetch_share"] = jnp.mean(jnp.stack(
                     [m["weight_fetch_share"] for m in moe]))
         log_saves("nemotron_h", len(c.layer_types), saved, REMAT_SAVES)
+        log_flash_grid(saved, tokens.shape[1])
         with jax.named_scope("dsod.heads"):
             h = RMSNorm(c.norm_eps, self.dtype, name="final_norm")(h)
             h = Head(c.vocab, self.param_dtype, name="head")(h)

@@ -79,7 +79,8 @@ from ..pallas.flash_attention import (CAUSAL_RESIDUAL_NAMES,
                                       flash_attention_causal)
 from ..pallas.rotary import rotate_half
 from .kimi import Embed, Head
-from .lfm2 import RMSNorm, SwiGLU, _dense, _saves_counted, log_saves, rope
+from .lfm2 import (RMSNorm, SwiGLU, _dense, _saves_counted, log_flash_grid,
+                   log_saves, rope)
 
 # What a rematerialised VISIT keeps: the flash kernel's output and lse
 # (33 MiB a visit at 8,192 tokens, 32 visits; without them the forward
@@ -210,6 +211,7 @@ class Ouro(nn.Module):
             states, gates = jnp.stack(states), jnp.stack(gates)
         log_saves("ouro", len(c.layer_types) * c.ut_steps, saved,
                   REMAT_SAVES, unit="visits")
+        log_flash_grid(saved, tokens.shape[1])
         with jax.named_scope("dsod.heads"):
             Head(c.vocab, self.param_dtype, name="head")(states)
         return (states, gates), {}

@@ -393,32 +393,71 @@ def flash_attention_with_lse(q, k, v, *, block_q: int | None = None,
 #
 # Same online-softmax tiling as above with three differences.  (1) One
 # square block size, so a tile pair is either wholly below the diagonal
-# (no mask), on it (a local row >= col mask), or above it: those are
-# SKIPPED — no compute, and the index maps clamp to the last needed
-# block so no DMA either.  (2) Hq query heads share Hkv key/value heads:
-# the forward's kv index map divides the folded head index by the group
-# size G.  (3) delta = sum(out * do) is reduced in-kernel from the
+# (no mask), on it (a local row >= col mask), or above it: those are NOT
+# IN THE GRID.  The last grid axis runs over the pairs on or under the
+# diagonal alone, in the order the accumulators rely on, and every index
+# map reads the pair's block indices from scalar-prefetched tables
+# (``causal_pairs``: numpy constants made at trace time from the block
+# count and the group size).  (2) Hq query heads share Hkv key/value
+# heads: the forward's kv index map divides the folded head index by the
+# group size G.  (3) delta = sum(out * do) is reduced in-kernel from the
 # streamed out/do tiles instead of being broadcast to a lane-replicated
 # HBM array; the matmul operands stay in the input dtype (bf16 on the
 # chip) with float32 accumulation.  Zero-padded rows past N need no key
 # mask of their own: a valid query row never sees a padded (later) key,
 # and padded query rows carry zero cotangents.
 #
+# The forward walks q block i outer and kv block j from 0 up to i: a
+# row's statistics start at j == 0 and its output is written at the
+# diagonal, its last pair.
+#
 # The backward is ONE kernel that makes a tile pair's p and ds once and
 # takes dq, dk and dv from them (the latent kernel's design below,
-# carried to grouped kv heads).  A kv block is resident while the q
-# blocks of all G heads of its group stream past, head after head (dk,
-# dv: a block-sized float32 accumulator each); dq accumulates in float32
-# VMEM scratch that holds the WHOLE row range of the group's G heads.
-# kv blocks run in ascending order and only blocks i <= j touch q block
-# j, so a head's dq block i is complete at the diagonal pair (i, i), the
-# first pair of that head kv block i visits: it is written there, to an
-# output block the pipeline flushes when the head or i moves on, and
-# never goes to HBM as partial sums.  The scoped-VMEM limit follows from
-# the shapes (``_causal_bwd_vmem_bytes``); a sequence too long for the
-# chip's VMEM raises.
+# carried to grouped kv heads).  A kv block i is resident while the q
+# blocks j = i .. nb - 1 of all G heads of its group stream past, head
+# after head (dk, dv: a block-sized float32 accumulator each); dq
+# accumulates in float32 VMEM scratch that holds the WHOLE row range of
+# the group's G heads.  kv blocks run in ascending order and only blocks
+# i <= j touch q block j, so a head's dq block i is complete at the
+# diagonal pair (i, i), the first pair of that head kv block i visits: it
+# is written there, to an output block the pipeline flushes when the head
+# or i moves on, and never goes to HBM as partial sums.  The scoped-VMEM
+# limit follows from the shapes (``_causal_bwd_vmem_bytes``); a sequence
+# too long for the chip's VMEM raises.
 
 _CAUSAL_BLOCK = 512
+
+
+def causal_blocks(n: int, block: int | None = None) -> tuple[int, int]:
+    """(block size, blocks a side) the causal kernels tile ``n`` rows
+    with: one square block of at most 512 rows, ``n`` padded up to it."""
+    if block is None:
+        block = min(_CAUSAL_BLOCK, -(-n // _LANES) * _LANES)
+    if block % _LANES:
+        raise ValueError("block must be a multiple of 128")
+    return block, -(-n // block)
+
+
+def causal_pairs(nb: int, group: int = 1):
+    """The tile pairs on or under the diagonal of ``nb`` blocks a side,
+    in the two orders the causal kernels' last grid axis walks them
+    (int32 numpy tables, read through scalar prefetch).
+
+    -> (forward, backward).  ``forward = (q block, kv block)``, one entry
+    a pair: q block i outer, kv block j = 0 .. i, so a row starts at
+    j == 0 and ends on its diagonal.  ``backward = (kv block, head in
+    group, q block)``, ``group`` entries a pair: kv block i outer, under
+    it each of the group's heads in turn, its q blocks j = i .. nb - 1,
+    so every head starts a kv block on the diagonal."""
+    rows = np.arange(nb, dtype=np.int32)
+    ahead = nb - rows                        # pairs kv block i visits a head
+    fwd = (np.repeat(rows, rows + 1),
+           np.concatenate([rows[:i + 1] for i in rows]))
+    bwd = (np.repeat(rows, group * ahead),
+           np.concatenate([np.repeat(np.arange(group, dtype=np.int32), a)
+                           for a in ahead]),
+           np.concatenate([np.tile(rows[i:], group) for i in rows]))
+    return fwd, bwd
 
 
 def _causal_p(q_ref, k_ref, lse_or_none, *, scale, masked):
@@ -435,9 +474,10 @@ def _causal_p(q_ref, k_ref, lse_or_none, *, scale, masked):
     return jnp.exp(s - _widen(lse_or_none, s.shape[1]))
 
 
-def _c_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s,
-                  *, scale: float):
-    i, j = pl.program_id(1), pl.program_id(2)
+def _c_fwd_kernel(qb_ref, kb_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
+                  m_s, l_s, acc_s, *, scale: float):
+    pair = pl.program_id(1)
+    i, j = qb_ref[pair], kb_ref[pair]           # q block; kv block <= i
     d = acc_s.shape[1]
 
     @pl.when(j == 0)
@@ -460,10 +500,10 @@ def _c_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s,
         acc_s[...] = acc_s[...] * _widen(corr, d) + pv
 
     pl.when(j < i)(lambda: visit(False))
-    pl.when(j == i)(lambda: visit(True))
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when(j == i)   # the diagonal: the row's last pair
     def _():
+        visit(True)
         o_ref[0] = (acc_s[...] / _widen(l_s[...], d)).astype(o_ref.dtype)
         lse_ref[0] = m_s[...] + jnp.log(l_s[...])
 
@@ -478,17 +518,19 @@ def _causal_ds(p, q_side, v_ref, *, scale):
     return p * (dp - delta) * scale
 
 
-def _c_bwd_kernel(k_ref, v_ref, q_ref, do_ref, out_ref, lse_ref,
-                  dq_ref, dk_ref, dv_ref, dq_s, dk_s, dv_s,
-                  *, scale: float, nb: int):
-    i, t = pl.program_id(1), pl.program_id(2)   # kv block; (head, q block)
-    j = t % nb
+def _c_bwd_kernel(kb_ref, head_ref, qb_ref, k_ref, v_ref, q_ref, do_ref,
+                  out_ref, lse_ref, dq_ref, dk_ref, dv_ref, dq_s, dk_s, dv_s,
+                  *, scale: float, nb: int, group: int):
+    pair = pl.program_id(1)
+    # kv block; head in group; q block >= i
+    i, g, j = kb_ref[pair], head_ref[pair], qb_ref[pair]
+    t = g * nb + j
 
-    @pl.when((i == 0) & (t == 0))
+    @pl.when(pair == 0)
     def _():
         dq_s[...] = jnp.zeros(dq_s.shape, jnp.float32)
 
-    @pl.when(t == 0)
+    @pl.when((g == 0) & (j == i))   # the kv block's first pair
     def _():
         dk_s[...] = jnp.zeros(dk_s.shape, jnp.float32)
         dv_s[...] = jnp.zeros(dv_s.shape, jnp.float32)
@@ -514,7 +556,7 @@ def _c_bwd_kernel(k_ref, v_ref, q_ref, do_ref, out_ref, lse_ref,
         # Every kv block <= i has added to this head's q block i by now.
         dq_ref[0] = dq_s[t].astype(dq_ref.dtype)
 
-    @pl.when(t == pl.num_programs(2) - 1)
+    @pl.when((g == group - 1) & (j == nb - 1))   # ... and its last
     def _():
         dk_ref[0] = dk_s[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
@@ -524,27 +566,30 @@ def _c_bwd_kernel(k_ref, v_ref, q_ref, do_ref, out_ref, lse_ref,
 def _c_fwd_call(q, k, v, cfg):
     blk, group, interpret = cfg
     bh, np_, d = q.shape
-    nb = np_ // blk
-    qs = pl.BlockSpec((1, blk, d), lambda b, i, j: (b, i, 0))
+    pairs, _ = causal_pairs(np_ // blk)
+    q_ix = lambda b, p, qb, kb: (b, qb[p], 0)  # noqa: E731
+    qs = pl.BlockSpec((1, blk, d), q_ix)
     kvs = pl.BlockSpec((1, blk, d),
-                       lambda b, i, j: (b // group, jnp.minimum(j, i), 0))
-    row = pl.BlockSpec((1, blk, _LANES), lambda b, i, j: (b, i, 0))
+                       lambda b, p, qb, kb: (b // group, kb[p], 0))
+    row = pl.BlockSpec((1, blk, _LANES), q_ix)
     return pl.pallas_call(
         partial(_c_fwd_kernel, scale=1.0 / d**0.5),
-        grid=(bh, nb, nb),
-        in_specs=[qs, kvs, kvs],
-        out_specs=[qs, row],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(bh, pairs[0].size),
+            in_specs=[qs, kvs, kvs],
+            out_specs=[qs, row],
+            scratch_shapes=[pltpu.VMEM((blk, _LANES), jnp.float32),
+                            pltpu.VMEM((blk, _LANES), jnp.float32),
+                            pltpu.VMEM((blk, d), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct((bh, np_, d), q.dtype),
                    jax.ShapeDtypeStruct((bh, np_, _LANES), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((blk, _LANES), jnp.float32),
-                        pltpu.VMEM((blk, _LANES), jnp.float32),
-                        pltpu.VMEM((blk, d), jnp.float32)],
         cost_estimate=pl.CostEstimate(
             flops=2 * bh * np_ * np_ * d,
             transcendentals=bh * np_ * np_ // 2,
             bytes_accessed=2 * (q.size + k.size) * q.dtype.itemsize),
         interpret=interpret,
-    )(q, k, v)
+    )(*pairs, q, k, v)
 
 
 def _causal_bwd_vmem_bytes(group, np_, blk, d, itemsize):
@@ -562,33 +607,38 @@ def _causal_bwd_vmem_bytes(group, np_, blk, d, itemsize):
 @jax.named_scope("dsod.kernel.flash_attention_causal_bwd")
 def _c_bwd_kernel_call(q, k, v, out, lse, do, cfg):
     """One visit of each tile pair gives dq, dk and dv (the comment that
-    heads this section): kv block resident; the q blocks of the group's
-    G heads stream past, t = head-in-group * nb + q block, blocks above
-    the diagonal clamped to the first needed one (and skipped)."""
+    heads this section): kv block resident; under it the q blocks from
+    the diagonal on of the group's G heads stream past, head after head
+    (``causal_pairs``' backward order)."""
     from .vmem_budget import fitted_vmem_params
 
     blk, group, interpret = cfg
     bh, np_, d = q.shape
     nb = np_ // blk
-    q_ix = lambda b, i, t: (b * group + t // nb,  # noqa: E731
-                            jnp.maximum(t % nb, i), 0)
+    _, pairs = causal_pairs(nb, group)
+    q_ix = lambda b, p, kb, head, qb: (  # noqa: E731
+        b * group + head[p], qb[p], 0)
     qs = pl.BlockSpec((1, blk, d), q_ix)
     row = pl.BlockSpec((1, blk, _LANES), q_ix)
-    kvs = pl.BlockSpec((1, blk, d), lambda b, i, t: (b, i, 0))
-    # dq's finished block (head t // nb, block i: written at the diagonal)
-    # stays put while that head's q blocks pass and is flushed after them.
-    dqs = pl.BlockSpec((1, blk, d),
-                       lambda b, i, t: (b * group + t // nb, i, 0))
+    kvs = pl.BlockSpec((1, blk, d), lambda b, p, kb, head, qb: (b, kb[p], 0))
+    # dq's finished block (head, block i: written at the diagonal, the
+    # head's first pair under kv block i) stays put while that head's q
+    # blocks pass and is flushed after them.
+    dqs = pl.BlockSpec((1, blk, d), lambda b, p, kb, head, qb: (
+        b * group + head[p], kb[p], 0))
     acc = lambda *shape: pltpu.VMEM(shape, jnp.float32)  # noqa: E731
     return pl.pallas_call(
-        partial(_c_bwd_kernel, scale=1.0 / d**0.5, nb=nb),
-        grid=(k.shape[0], nb, group * nb),
-        in_specs=[kvs, kvs, qs, qs, qs, row],
-        out_specs=[dqs, kvs, kvs],
+        partial(_c_bwd_kernel, scale=1.0 / d**0.5, nb=nb, group=group),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(k.shape[0], pairs[0].size),
+            in_specs=[kvs, kvs, qs, qs, qs, row],
+            out_specs=[dqs, kvs, kvs],
+            scratch_shapes=[acc(group * nb, blk, d), acc(blk, d),
+                            acc(blk, d)]),
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        scratch_shapes=[acc(group * nb, blk, d), acc(blk, d), acc(blk, d)],
         compiler_params=fitted_vmem_params(
             _causal_bwd_vmem_bytes(group, np_, blk, d, q.dtype.itemsize),
             f"flash_attention_causal's backward over {np_} rows of "
@@ -599,7 +649,7 @@ def _c_bwd_kernel_call(q, k, v, out, lse, do, cfg):
             bytes_accessed=(4 * q.size + 2 * k.size + 2 * v.size)
             * q.dtype.itemsize + 4 * lse.size),
         interpret=interpret,
-    )(k, v, q, do, out, lse)
+    )(*pairs, k, v, q, do, out, lse)
 
 
 def _c_bwd_call(q, k, v, out, lse_row, do, cfg):
@@ -649,8 +699,9 @@ def flash_attention_causal(q, k, v, *, block: int | None = None,
 
     q: [B, Hq, N, D]; k, v: [B, Hkv, N, D] with Hkv dividing Hq (query
     head h reads kv head h // (Hq / Hkv)); any N (zero-padded to the
-    block), D <= 128 or a multiple of 128.  Tiles above the diagonal are
-    skipped.  Differentiable: the backward is one Pallas kernel that
+    block), D <= 128 or a multiple of 128.  The grid holds only the tile
+    pairs on or under the diagonal (``causal_pairs``): one above it is no
+    grid step.  Differentiable: the backward is one Pallas kernel that
     visits each tile pair once for dq, dk and dv, its float32 dq
     accumulators held in VMEM for the whole sequence of a kv head's
     group — a sequence too long for the chip's VMEM raises.
@@ -664,11 +715,8 @@ def flash_attention_causal(q, k, v, *, block: int | None = None,
                          "groups of query heads over shared kv heads")
     if d > _LANES and d % _LANES:
         raise ValueError(f"head dim {d} unsupported")
-    if block is None:
-        block = min(_CAUSAL_BLOCK, -(-n // _LANES) * _LANES)
-    if block % _LANES:
-        raise ValueError("block must be a multiple of 128")
-    np_ = -(-n // block) * block
+    block, nb = causal_blocks(n, block)
+    np_ = nb * block
     interpret = (jax.default_backend() == "cpu" if interpret is None
                  else interpret)
     fold = lambda t: _pad_n(t.reshape(-1, n, d), np_)  # noqa: E731
@@ -692,6 +740,9 @@ def flash_attention_causal(q, k, v, *, block: int | None = None,
 # writes each head's float32 share of ``dk_rope``, which the wrapper
 # sums over the heads.  (3) No kv grouping: k_nope and v are per head.
 #
+# The forward's grid, as above, holds only the pairs on or under the
+# diagonal (``causal_pairs``), read from scalar-prefetched tables.
+#
 # The backward is ONE kernel that makes a tile pair's p and ds once and
 # takes dq, dk and dv from them.  A kv block is resident while its
 # head's q blocks stream past (dk, dv: a block-sized float32 accumulator
@@ -702,6 +753,17 @@ def flash_attention_causal(q, k, v, *, block: int | None = None,
 # written there, to an output block the pipeline flushes when i moves
 # on, and never goes to HBM as partial sums.  The scoped-VMEM limit
 # follows from the shapes (``_mla_bwd_vmem_bytes``).
+#
+# The backward ALONE keeps the rectangular grid (bh, kv block, q block):
+# a pair above the diagonal is a grid step with its compute under a false
+# ``pl.when`` and its q-side index maps clamped to the diagonal's block,
+# so nothing is copied.  Measured on the chip at the cell's shape
+# (PERF.md section 6, PR 45): such a step costs ~0.2 us, but a step whose
+# thirteen block specs read their indices from the tables costs ~0.3 us
+# MORE than one that computes them from the grid indices (the same
+# rectangle walked through tables: 58.09 -> 68.23 ms a call), so the
+# pair grid ran this kernel SLOWER (60.04 ms) where the three kernels
+# with five to nine specs gained.
 
 MLA_RESIDUAL_NAMES = ("mla_q", "mla_out", "mla_lse")
 
@@ -723,9 +785,10 @@ def _mla_p(qn_ref, qr_ref, kn_ref, kr_ref, lse_or_none, *, scale, masked):
     return jnp.exp(s - _widen(lse_or_none, s.shape[1]))
 
 
-def _m_fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref,
-                  m_s, l_s, acc_s, *, scale: float):
-    i, j = pl.program_id(1), pl.program_id(2)
+def _m_fwd_kernel(qb_ref, kb_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref,
+                  o_ref, lse_ref, m_s, l_s, acc_s, *, scale: float):
+    pair = pl.program_id(1)
+    i, j = qb_ref[pair], kb_ref[pair]           # q block; kv block <= i
     d = acc_s.shape[1]
 
     @pl.when(j == 0)
@@ -749,10 +812,10 @@ def _m_fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref,
         acc_s[...] = acc_s[...] * _widen(corr, d) + pv
 
     pl.when(j < i)(lambda: visit(False))
-    pl.when(j == i)(lambda: visit(True))
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when(j == i)   # the diagonal: the row's last pair
     def _():
+        visit(True)
         o_ref[0] = (acc_s[...] / _widen(l_s[...], d)).astype(o_ref.dtype)
         lse_ref[0] = m_s[...] + jnp.log(l_s[...])
 
@@ -809,11 +872,13 @@ def _m_bwd_kernel(kn_ref, kr_ref, v_ref, qn_ref, qr_ref, do_ref, out_ref,
 
 
 def _mla_grid(qn, qr, v, cfg, *, kv_resident: bool):
-    """-> (grid, scale, specs): the BlockSpecs of a q-side tile of each
-    width, a kv-side one, the shared rotary key's and the lse row's, for
-    the grid order (bh, q block, kv block) or, ``kv_resident``, (bh, kv
-    block, q block); blocks the diagonal rules out are clamped to the
-    nearest needed one, so they cost no DMA either."""
+    """-> (grid, tables, scale, specs).  The forward's grid is (bh,
+    pairs) with the two tables of ``causal_pairs``' forward order for
+    scalar prefetch; the backward's, ``kv_resident``, is (bh, kv block, q
+    block) with no table, a q block above the diagonal clamped to the
+    diagonal's so that it costs no DMA.  The BlockSpecs: a q-side tile of
+    each width, a kv-side one, the shared rotary key's and the lse
+    row's."""
     blk, heads, _ = cfg
     bh, np_, dn = qn.shape
     dr, dv = qr.shape[2], v.shape[2]
@@ -822,15 +887,19 @@ def _mla_grid(qn, qr, v, cfg, *, kv_resident: bool):
                          f"{np_}")
     nb = np_ // blk
     if kv_resident:
-        q_ix = lambda b, i, j: (b, jnp.maximum(j, i), 0)  # noqa: E731
-        k_ix = lambda b, i, j: (b, i, 0)  # noqa: E731
-        kr_ix = lambda b, i, j: (b // heads, i, 0)  # noqa: E731
+        grid, tables = (bh, nb, nb), ()
+        q_of = lambda i, j: jnp.maximum(j, i)  # noqa: E731
+        k_of = lambda i, j: i  # noqa: E731
     else:
-        q_ix = lambda b, i, j: (b, i, 0)  # noqa: E731
-        k_ix = lambda b, i, j: (b, jnp.minimum(j, i), 0)  # noqa: E731
-        kr_ix = lambda b, i, j: (b // heads, jnp.minimum(j, i), 0)  # noqa: E731
+        tables, _ = causal_pairs(nb)
+        grid = (bh, tables[0].size)
+        q_of = lambda p, qb, kb: qb[p]  # noqa: E731
+        k_of = lambda p, qb, kb: kb[p]  # noqa: E731
+    q_ix = lambda b, *at: (b, q_of(*at), 0)  # noqa: E731
+    k_ix = lambda b, *at: (b, k_of(*at), 0)  # noqa: E731
+    kr_ix = lambda b, *at: (b // heads, k_of(*at), 0)  # noqa: E731
     spec = lambda d, ix: pl.BlockSpec((1, blk, d), ix)  # noqa: E731
-    return (bh, nb, nb), 1.0 / (dn + dr) ** 0.5, dict(
+    return grid, tables, 1.0 / (dn + dr) ** 0.5, dict(
         qn=spec(dn, q_ix), qr=spec(dr, q_ix), qv=spec(dv, q_ix),
         row=spec(_LANES, q_ix), kn=spec(dn, k_ix), kr=spec(dr, kr_ix),
         kv=spec(dv, k_ix), kr_own=spec(dr, k_ix))
@@ -845,25 +914,27 @@ def _mla_pairs(qn, qr, v):
 
 @jax.named_scope("dsod.kernel.flash_attention_mla")
 def _m_fwd_call(qn, qr, kn, kr, v, cfg):
-    grid, scale, s = _mla_grid(qn, qr, v, cfg, kv_resident=False)
+    grid, tabs, scale, s = _mla_grid(qn, qr, v, cfg, kv_resident=False)
     bh, np_, _ = qn.shape
     blk, dv = cfg[0], v.shape[2]
     pairs, dk, _ = _mla_pairs(qn, qr, v)
     return pl.pallas_call(
         partial(_m_fwd_kernel, scale=scale),
-        grid=grid,
-        in_specs=[s["qn"], s["qr"], s["kn"], s["kr"], s["kv"]],
-        out_specs=[s["qv"], s["row"]],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(tabs),
+            grid=grid,
+            in_specs=[s["qn"], s["qr"], s["kn"], s["kr"], s["kv"]],
+            out_specs=[s["qv"], s["row"]],
+            scratch_shapes=[pltpu.VMEM((blk, _LANES), jnp.float32),
+                            pltpu.VMEM((blk, _LANES), jnp.float32),
+                            pltpu.VMEM((blk, dv), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct((bh, np_, dv), qn.dtype),
                    jax.ShapeDtypeStruct((bh, np_, _LANES), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((blk, _LANES), jnp.float32),
-                        pltpu.VMEM((blk, _LANES), jnp.float32),
-                        pltpu.VMEM((blk, dv), jnp.float32)],
         cost_estimate=pl.CostEstimate(
             flops=2 * pairs * (dk + dv), transcendentals=pairs,
             bytes_accessed=(2 * qn.size + 2 * v.size) * qn.dtype.itemsize),
         interpret=cfg[2],
-    )(qn, qr, kn, kr, v)
+    )(*tabs, qn, qr, kn, kr, v)
 
 
 def _mla_bwd_vmem_bytes(np_, blk, widths, itemsize):
@@ -886,7 +957,7 @@ def _m_bwd_kernel_call(qn, qr, kn, kr, v, out, lse, do, cfg):
     per head, float32 (summed over the heads by the caller)."""
     from .vmem_budget import fitted_vmem_params
 
-    grid, scale, s = _mla_grid(qn, qr, v, cfg, kv_resident=True)
+    grid, _, scale, s = _mla_grid(qn, qr, v, cfg, kv_resident=True)
     blk, nb = cfg[0], grid[1]
     np_, dn = qn.shape[1:]
     dr, dv = qr.shape[2], v.shape[2]
@@ -965,10 +1036,12 @@ def flash_attention_mla(q_nope, q_rope, k_nope, k_rope, v, *,
     [B, N, dr]; v: [B, H, N, dv] -> [B, H, N, dv].  Scores are
     ``(q_nope . k_nope + q_rope . k_rope) / sqrt(dn + dr)``.  Any N
     (zero-padded to the block); each width <= 128 or a multiple of 128.
-    Differentiable: the backward is one Pallas kernel that visits each
-    tile pair once for dq, dk and dv, its float32 dq accumulators held
-    in VMEM for the whole sequence of a head — a sequence too long for
-    the chip's VMEM raises.
+    The forward's grid holds only the tile pairs on or under the diagonal
+    (``causal_pairs``).  Differentiable: the backward is one Pallas
+    kernel that visits each tile pair once for dq, dk and dv (its grid is
+    the rectangle, the pairs above the diagonal skipped), its float32 dq
+    accumulators held in VMEM for the whole sequence of a head — a
+    sequence too long for the chip's VMEM raises.
     """
     b, h, n, dn = q_nope.shape
     dr, dv = q_rope.shape[-1], v.shape[-1]
@@ -980,11 +1053,8 @@ def flash_attention_mla(q_nope, q_rope, k_nope, k_rope, v, *,
     for d in (dn, dr, dv):
         if d > _LANES and d % _LANES:
             raise ValueError(f"head width {d} unsupported")
-    if block is None:
-        block = min(_CAUSAL_BLOCK, -(-n // _LANES) * _LANES)
-    if block % _LANES:
-        raise ValueError("block must be a multiple of 128")
-    np_ = -(-n // block) * block
+    block, nb = causal_blocks(n, block)
+    np_ = nb * block
     interpret = (jax.default_backend() == "cpu" if interpret is None
                  else interpret)
     fold = lambda t: _pad_n(t.reshape(-1, n, t.shape[-1]), np_)  # noqa: E731

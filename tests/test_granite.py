@@ -347,6 +347,18 @@ def test_the_step_says_what_its_remat_saves(setup, caplog):
     assert not set(ssd.SSD_RESIDUAL_NAMES) & set(gr.REMAT_SAVES)
 
 
+
+def test_the_step_says_its_flash_grid(setup, caplog):
+    from test_lfm2 import flash_grid_lines
+
+    _, model, v, tokens, _ = setup
+    loss = _loss_of(model, tokens)
+    said, quiet = flash_grid_lines(
+        caplog, lambda: jax.eval_shape(jax.grad(loss), v["params"]),
+        lambda: jax.eval_shape(loss, v["params"]))
+    assert said == ["flash grid: steps=1 of 1 a head"] and not quiet
+
+
 def test_gradient_runs_the_scan_kernels_it_should(setup):
     """In the jaxpr of the config's gradient (all 10 layers, tiny
     widths): 9 backward scan kernels, and 18 forward ones (the remat
@@ -456,9 +468,9 @@ def test_no_product_of_the_step_is_outside_a_stage(lowered_text):
 
 @pytest.mark.parametrize("config,sha", [
     ("lfm2_8b_a1b_ep4",
-     "71bed8f99051351058b5fb3b07547fbff36765f9a099bbfad4394fc60676dd0b"),
+     "64c67471bb7894da77c5f2c61f88423e2433ea9fd0840880f6f7353582353757"),
     ("kimi_vl_a3b_ep8",
-     "6cb879af71d7e74f51b247b04ecbd370006c93e44a7953941e04947cd3b201b7")])
+     "f2f7822374b39c5b885103c7cd5c9afc83db4f2979aa2101c980ed2360d5c0a5")])
 def test_the_older_token_models_steps_are_the_programs_they_were(
         tmp_path, config, sha):
     """``tools/dump_hlo.py`` on both older token configs, as its command
@@ -467,7 +479,8 @@ def test_the_older_token_models_steps_are_the_programs_they_were(
     commit before this model (PR 36's tree, 74d629d; the first one's
     with PR 40's one-kernel causal backward; both with PR 44's grouped
     product, whose weight block moves only where the expert or the
-    column block does), to the byte.  A PR
+    column block does, and with PR 45's causal flash grids, which hold
+    only the tile pairs on or under the diagonal), to the byte.  A PR
     that means to change one of those steps changes its hash with it and
     says so in PERF.md."""
     tools = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",

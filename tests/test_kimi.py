@@ -324,6 +324,18 @@ def test_the_step_says_what_its_remat_saves(setup, caplog):
     assert km.REMAT_SAVES == ("mla_out", "mla_lse", "plan")
 
 
+
+def test_the_step_says_its_flash_grid(setup, caplog):
+    from test_lfm2 import flash_grid_lines
+
+    _, model, v, tokens, _ = setup
+    loss = _loss_of(model, v, tokens)
+    said, quiet = flash_grid_lines(
+        caplog, lambda: jax.eval_shape(jax.grad(loss), v["params"]),
+        lambda: jax.eval_shape(loss, v["params"]))
+    assert said == ["flash grid: steps=1 of 1 a head"] and not quiet
+
+
 @pytest.mark.parametrize("kernel", ["_m_fwd_kernel", "_m_bwd_kernel"])
 def test_gradient_runs_one_forward_and_one_backward_kernel_a_layer(setup,
                                                                    kernel):
@@ -390,7 +402,8 @@ def test_the_first_token_models_step_is_the_program_it_was(tmp_path):
     commit before this model gave (PR 31's tree, aee3230) with the two
     changes later PRs meant (PR 40: the causal backward is one kernel;
     PR 44: the grouped product's weight block moves only where the
-    expert or the column block does),
+    expert or the column block does; PR 45: the causal flash kernels'
+    grids hold only the tile pairs on or under the diagonal),
     to the byte.  A PR that means to change that step changes this hash
     with it and says so in PERF.md."""
     import subprocess
@@ -406,7 +419,7 @@ def test_the_first_token_models_step_is_the_program_it_was(tmp_path):
         capture_output=True, timeout=600)
     with open(tmp_path / "lfm2_8b_a1b_ep4.stablehlo.txt", "rb") as f:
         assert hashlib.sha256(f.read()).hexdigest() == (
-            "71bed8f99051351058b5fb3b07547fbff36765f9a099bbfad4394fc60676dd0b")
+            "64c67471bb7894da77c5f2c61f88423e2433ea9fd0840880f6f7353582353757")
 
 
 # -- the loop -----------------------------------------------------------------

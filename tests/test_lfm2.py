@@ -36,8 +36,8 @@ from distributed_sod_project_tpu.losses.token_ce import tied_cross_entropy
 from distributed_sod_project_tpu.models import build_model
 from distributed_sod_project_tpu.models import lfm2 as lm
 from distributed_sod_project_tpu.pallas import grouped_matmul as gm
-from distributed_sod_project_tpu.pallas.flash_attention import \
-    flash_attention_causal
+from distributed_sod_project_tpu.pallas.flash_attention import (
+    causal_pairs, flash_attention_causal)
 from distributed_sod_project_tpu.pallas.grouped_matmul import grouped_matmul
 from distributed_sod_project_tpu.pallas.moe_unpermute import unpermute_steps
 
@@ -629,6 +629,56 @@ def test_the_step_says_what_its_remat_saves(setup, caplog):
         "plan=44 MiB=0.")  # 11 values of the plan in each of 4 layers
 
 
+
+def flash_grid_lines(caplog, differentiated, plain):
+    """The ``flash grid`` log lines of a differentiated trace and of a
+    forward-only one (``log_flash_grid`` speaks where ``log_saves``
+    does): two thunks that trace the model."""
+    import logging
+
+    logger = logging.getLogger("dsod")  # does not propagate
+    logger.addHandler(caplog.handler)
+    try:
+        with caplog.at_level(logging.INFO, logger="dsod"):
+            out = []
+            for trace in (differentiated, plain):
+                caplog.clear()
+                trace()
+                out.append([r.getMessage() for r in caplog.records
+                            if r.getMessage().startswith("flash grid:")])
+    finally:
+        logger.removeHandler(caplog.handler)
+    return out
+
+
+def test_the_step_says_its_flash_grid(setup, caplog):
+    """160 tokens are one block of 256: one pair a head, of one."""
+    _, model, v, tokens, _ = setup
+    loss = _loss_of(model, v, tokens)
+    said, quiet = flash_grid_lines(
+        caplog, lambda: jax.eval_shape(jax.grad(loss), v["params"]),
+        lambda: jax.eval_shape(loss, v["params"]))
+    assert said == ["flash grid: steps=1 of 1 a head"] and not quiet
+
+
+# the looped and the LFM2 cell's 8k, the latent-attention and the
+# state-space cell's 16k, lengths of 3 and 5 blocks, one short block
+@pytest.mark.parametrize("seq_len,line", [
+    (8192, "steps=136 of 256"), (16384, "steps=528 of 1024"),
+    (1300, "steps=6 of 9"), (2560, "steps=15 of 25"), (100, "steps=1 of 1")])
+def test_flash_grid_line_counts_the_kernels_own_pairs(seq_len, line,
+                                                      caplog):
+    from distributed_sod_project_tpu.pallas.flash_attention import \
+        causal_blocks
+
+    said, quiet = flash_grid_lines(
+        caplog, lambda: lm.log_flash_grid({"flash_out": 1}, seq_len),
+        lambda: lm.log_flash_grid({}, seq_len))
+    assert said == [f"flash grid: {line} a head"] and not quiet
+    nb = causal_blocks(seq_len)[1]
+    assert line == (f"steps={causal_pairs(nb)[0][0].size} of {nb * nb}")
+
+
 # -- the kernels and the loss ------------------------------------------------
 
 def _plain_causal(q, k, v):
@@ -668,17 +718,34 @@ def test_flash_causal_grouped_kv_matches_plain_attention(n, block, hq, hkv,
         _close(a, b, 1e-4)
 
 
-def _causal_grad_kernels(hq=4, n=512):
-    """The ``pallas_call`` equations in the gradient of the causal
-    kernel over q, k and v: 2 sequences of ``hq`` bfloat16 query heads on
-    one kv head, blocks of 128."""
+def _causal_grad_jaxpr(hq=4, n=512):
+    """The gradient of the causal kernel over q, k and v: 2 sequences of
+    ``hq`` bfloat16 query heads on one kv head, blocks of 128."""
     q = jnp.zeros((2, hq, n, 16), jnp.bfloat16)
     kv = jnp.zeros((2, 1, n, 16), jnp.bfloat16)
-    jaxpr = jax.make_jaxpr(jax.grad(
+    return jax.make_jaxpr(jax.grad(
         lambda *a: jnp.sum(flash_attention_causal(*a, block=128).astype(
             jnp.float32)), (0, 1, 2)))(q, kv, kv)
-    return [eqn for eqn in _eqns(jaxpr.jaxpr)
+
+
+def _causal_grad_kernels(hq=4, n=512):
+    """Its ``pallas_call`` equations: forward, backward."""
+    return [eqn for eqn in _eqns(_causal_grad_jaxpr(hq, n).jaxpr)
             if eqn.primitive.name == "pallas_call"]
+
+
+def _block_index(mapping, tables):
+    """(grid indices) -> block index, from a ``pallas_call`` block
+    mapping whose index map reads scalar-prefetched ``tables``."""
+    from jax._src.state.discharge import discharge_state
+
+    closed = mapping.index_map_jaxpr
+    pure, consts = discharge_state(closed.jaxpr, closed.consts)
+    n_out = len(closed.jaxpr.outvars)
+    at = jax.jit(lambda *step: jax.core.eval_jaxpr(
+        pure, consts, *step, *tables)[:n_out])
+    return lambda *step: tuple(
+        int(x) for x in at(*(jnp.int32(x) for x in step)))
 
 
 def test_flash_causal_gradient_is_two_kernels_and_no_partial_sums():
@@ -699,24 +766,70 @@ def test_flash_causal_backward_writes_each_dq_block_once(hq, n):
     on, so every (head, q block) has to come up in ONE unbroken run of
     steps (a second run would overwrite what the first flushed) and that
     run has to hold the diagonal pair, the only step that writes it."""
-    bwd = _causal_grad_kernels(hq=hq, n=n)[1]
+    jaxpr = _causal_grad_jaxpr(hq=hq, n=n)
+    bwd = [eqn for eqn in _eqns(jaxpr.jaxpr)
+           if eqn.primitive.name == "pallas_call"][1]
     gm = bwd.params["grid_mapping"]
-    dq_map = gm.block_mappings[gm.num_inputs].index_map_jaxpr
     nb = n // 128
-    assert gm.grid == (2, nb, hq * nb)
+    tables = causal_pairs(nb, hq)[1]
+    # the tables the traced program carries are the helper's
+    for table in tables:
+        assert any(np.array_equal(c, table) for c in jaxpr.consts)
+    assert gm.grid == (2, hq * nb * (nb + 1) // 2)
+    dq_at = _block_index(gm.block_mappings[gm.num_inputs], tables)
     runs, wrote = [], []
-    for step in np.ndindex(*gm.grid):
-        _, i, t = step
-        at = tuple(int(x) for x in jax.core.eval_jaxpr(
-            dq_map.jaxpr, dq_map.consts, *(jnp.int32(x) for x in step)))
+    for b, pair in np.ndindex(*gm.grid):
+        i, g, j = (int(t[pair]) for t in tables)
+        at = dq_at(b, pair)
         if not runs or runs[-1] != at:
             runs.append(at)
             wrote.append(0)
-        if t % nb == i:   # the kernel's ``pl.when(j == i)``
+            assert j == i   # a run opens on the diagonal ...
+        if j == i:   # ... the kernel's ``pl.when(j == i)``
             wrote[-1] += 1
-            assert at == (step[0] * hq + t // nb, i, 0)
+            assert at == (b * hq + g, i, 0)
     assert len(set(runs)) == len(runs) == 2 * hq * nb
     assert wrote == [1] * len(runs)
+
+
+@pytest.mark.parametrize("hq,n", [(4, 512), (1, 640), (2, 128)])
+def test_flash_causal_grids_hold_the_pairs_under_the_diagonal_alone(hq, n):
+    """The grid each ``pallas_call`` equation carries is (heads, pairs):
+    ``causal_pairs``' count, the one ``log_flash_grid`` prints, and not
+    the rectangle's."""
+    fwd, bwd = _causal_grad_kernels(hq=hq, n=n)
+    nb = n // 128
+    (q_of, _), (kv_of, _, _) = causal_pairs(nb, hq)
+    assert q_of.size == nb * (nb + 1) // 2 and kv_of.size == hq * q_of.size
+    assert fwd.params["grid_mapping"].grid == (2 * hq, q_of.size)
+    assert bwd.params["grid_mapping"].grid == (2, kv_of.size)
+    for eqn, tables in ((fwd, 2), (bwd, 3)):
+        assert eqn.params["grid_mapping"].num_index_operands == tables
+
+
+# nb = 5 (no power of two) and a length the block does not divide (nb =
+# 5 with padding rows), one head a kv head and four, both float types
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("n,hq,hkv", [(640, 2, 2), (640, 4, 1),
+                                      (600, 2, 2), (600, 4, 1)])
+def test_flash_causal_pair_grid_matches_plain_attention(n, hq, hkv, dtype):
+    """Forward, dq, dk and dv over a grid of 15 pairs a head (of 25)."""
+    ks = jax.random.split(jax.random.key(n + hq), 4)
+    q, k, v, g = (jax.random.normal(key, (2, h, n, 32)).astype(dtype)
+                  for key, h in zip(ks, (hq, hkv, hkv, hq)))
+    f32 = lambda *a: [t.astype(jnp.float32) for t in a]  # noqa: E731
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    flash = lambda *a: flash_attention_causal(*a, block=128)  # noqa: E731
+    out = flash(q, k, v)
+    assert out.dtype == dtype
+    _close(out.astype(jnp.float32), _plain_causal(*f32(q, k, v)), tol)
+    got = jax.grad(lambda *a: jnp.sum((flash(*a) * g).astype(jnp.float32)),
+                   (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(_plain_causal(*a) * f32(g)[0]),
+                    (0, 1, 2))(*f32(q, k, v))
+    for a, b in zip(got, want):
+        assert a.dtype == dtype
+        _close(a.astype(jnp.float32), b, 5 * tol)
 
 
 def test_flash_causal_backward_refuses_a_group_its_vmem_cannot_hold(

@@ -533,6 +533,18 @@ def test_the_step_says_what_its_remat_saves(short, caplog):
     assert not set(ssd.SSD_RESIDUAL_NAMES) & set(nh.REMAT_SAVES)
 
 
+
+def test_the_step_says_its_flash_grid(short, caplog):
+    from test_lfm2 import flash_grid_lines
+
+    _, model, v, tokens, _ = short
+    loss = _loss_of(model, v, tokens)
+    said, quiet = flash_grid_lines(
+        caplog, lambda: jax.eval_shape(jax.grad(loss), v["params"]),
+        lambda: jax.eval_shape(loss, v["params"]))
+    assert said == ["flash grid: steps=1 of 1 a head"] and not quiet
+
+
 # -- scopes -------------------------------------------------------------------
 
 SCOPES = ("dsod.ssm", "dsod.ssm.conv", "dsod.ssm.scan", "dsod.ssm.gate",
@@ -578,9 +590,9 @@ def test_no_product_of_the_step_is_outside_a_stage(lowered_text):
 
 @pytest.mark.parametrize("config,sha", [
     ("ouro_2_6b_pp6",
-     "b34ace52ceeb3482c1c609c64bebb57c944fd334a1b20f3038b2725c8eedb9fa"),
+     "ec4cabce12c073955b2077f9e80d15c2fff424f93c8b5ca5e2a34ee1cfe85181"),
     ("nemotron_3_super_tp8_ep64",
-     "eface19571ba238f85b06842ee01f6025070bb2a5e09e4419b35f7a5f9646905")])
+     "929d5936b27b67aaffd2b1385ff8685402719f7bd0a5a358b39256fc2e4b91f9")])
 def test_the_step_is_the_program_this_file_pins(tmp_path, config, sha):
     """``tools/dump_hlo.py`` as its command line runs it (a process of
     its own), to the byte: the fourth token model's step as the commit

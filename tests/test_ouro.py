@@ -376,6 +376,20 @@ def test_the_step_says_what_its_remat_saves_per_visit(setup, caplog):
         8 * B * 4 * N * (16 + 1) * 4 / 2 ** 20, abs=0.06)
 
 
+
+def test_the_step_says_its_flash_grid_once(setup, caplog):
+    """One line a trace, not one a visit."""
+    from test_lfm2 import flash_grid_lines
+
+    _, model, v, tokens, _ = setup
+    loss = _loss_of(model, tokens)
+    said, quiet = flash_grid_lines(
+        caplog, lambda: jax.eval_shape(jax.grad(loss, has_aux=True),
+                                       v["params"]),
+        lambda: jax.eval_shape(loss, v["params"]))
+    assert said == ["flash grid: steps=1 of 1 a head"] and not quiet
+
+
 def test_the_gradient_runs_one_forward_kernel_a_visit(setup):
     """One forward and one fused backward kernel a VISIT (layers x
     passes): the forward's out and lse are kept, not made again."""
@@ -456,7 +470,7 @@ def test_the_state_space_step_is_the_program_it_was(tmp_path):
 
 
 GRANITE_SHA = (
-    "90b0ff829daf58019a3d5a0c419287dd85e5ef38fc06b3780407b7b87f0f6360")
+    "086f1f0c1c391aaf596551052ca1ffa7836d0226ef53bc26aa73a5cc73d01793")
 
 
 # -- the loop ----------------------------------------------------------------

@@ -321,3 +321,99 @@ def test_flash_mla_raises_where_a_tile_does_not_divide():
     wide = _mla_inputs(128, dn=192)[:5]
     with pytest.raises(ValueError, match="width 192"):
         flash_attention_mla(*wide)
+
+
+# -- the causal kernels' grid: the pairs on or under the diagonal ------------
+
+@pytest.mark.parametrize("group", [1, 4])
+@pytest.mark.parametrize("nb", [1, 2, 5, 16, 32])
+def test_causal_pairs_hold_the_triangle_once_in_the_accumulators_order(
+        nb, group):
+    """Every pair on or under the diagonal exactly once (a head), none
+    above it, in the two orders the kernels' accumulators rely on: the
+    forward's row runs kv block 0 .. i and ENDS on its diagonal (where
+    out and lse are written); the backward's kv blocks ascend, and under
+    each every head of the group in turn STARTS on the diagonal (where
+    its dq block is complete and written) and runs to the last q
+    block (where dk and dv are)."""
+    from distributed_sod_project_tpu.pallas.flash_attention import \
+        causal_pairs
+
+    (q_of, k_of), (kv_of, head_of, qb_of) = causal_pairs(nb, group)
+    triangle = [(i, j) for i in range(nb) for j in range(i + 1)]
+    for table in (q_of, k_of, kv_of, head_of, qb_of):
+        assert table.dtype == np.int32 and table.ndim == 1
+    # forward: q block outer, kv ascending to the diagonal
+    assert list(zip(q_of.tolist(), k_of.tolist())) == triangle
+    # backward: kv block outer, then the head, then q from the diagonal on
+    want = [(i, g, j) for i in range(nb) for g in range(group)
+            for j in range(i, nb)]
+    got = list(zip(kv_of.tolist(), head_of.tolist(), qb_of.tolist()))
+    assert got == want
+    assert len(got) == group * len(triangle) == group * nb * (nb + 1) // 2
+    assert sorted((j, i) for i, _, j in got) == sorted(triangle * group)
+
+
+def _mla_grad_jaxpr(n, h=2):
+    from distributed_sod_project_tpu.pallas.flash_attention import \
+        flash_attention_mla
+
+    *args, cot = _mla_inputs(n, b=2, h=h)
+    return jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(flash_attention_mla(*a, block=128) * cot),
+        (0, 1, 2, 3, 4)))(*args)
+
+
+@pytest.mark.parametrize("n", [128, 640, 600])
+def test_flash_mla_forward_grid_holds_the_pairs_under_the_diagonal_alone(n):
+    """The latent forward's ``pallas_call`` equation carries the grid
+    (batch x heads, pairs) with ``causal_pairs``' count and its two
+    tables among the traced program's constants; the backward keeps the
+    rectangle (bh, kv block, q block) and no table: on the chip it was
+    slower on the pair grid (PERF.md section 6, PR 45)."""
+    from distributed_sod_project_tpu.pallas.flash_attention import \
+        causal_pairs
+
+    from test_lfm2 import _eqns
+
+    jaxpr = _mla_grad_jaxpr(n)
+    fwd, bwd = (eqn.params["grid_mapping"] for eqn in _eqns(jaxpr.jaxpr)
+                if eqn.primitive.name == "pallas_call")
+    nb = -(-n // 128)
+    tables, _ = causal_pairs(nb)
+    assert fwd.grid == (4, nb * (nb + 1) // 2) == (4, tables[0].size)
+    assert fwd.num_index_operands == 2
+    for table in tables:
+        assert any(np.array_equal(c, table) for c in jaxpr.consts)
+    assert bwd.grid == (4, nb, nb) and bwd.num_index_operands == 0
+
+
+# nb = 5 (no power of two), with and without padding rows, both float
+# types; the rotary key is one head for h = 1 and for h = 4 query heads
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("n,h", [(640, 1), (640, 4), (600, 1), (600, 4)])
+def test_flash_mla_pair_grid_matches_plain_attention(n, h, dtype):
+    """Forward and every gradient over a grid of 15 pairs a head (of 25)."""
+    from distributed_sod_project_tpu.pallas.flash_attention import \
+        flash_attention_mla
+
+    *args, cot = (t.astype(dtype) for t in _mla_inputs(n, b=2, h=h))
+    f32 = lambda ts: [t.astype(jnp.float32) for t in ts]  # noqa: E731
+    tol = 3e-6 if dtype == jnp.float32 else 2e-2
+    kernel = lambda *a: flash_attention_mla(*a, block=128)  # noqa: E731
+    out = kernel(*args)
+    assert out.dtype == dtype and out.shape == cot.shape
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(_plain_mla(*f32(args))),
+        atol=tol)
+    every = (0, 1, 2, 3, 4)
+    got = jax.grad(lambda *a: jnp.sum((kernel(*a) * cot).astype(
+        jnp.float32)), every)(*args)
+    want = jax.grad(lambda *a: jnp.sum(_plain_mla(*a) * f32([cot])[0]),
+                    every)(*f32(args))
+    for name, a, b in zip(("dq_nope", "dq_rope", "dk_nope", "dk_rope",
+                           "dv"), got, want):
+        assert a.dtype == dtype, name
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b), err_msg=name,
+            atol=5 * tol * float(jnp.max(jnp.abs(b))))
