@@ -52,7 +52,8 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..pallas.flash_attention import (CAUSAL_RESIDUAL_NAMES, causal_blocks,
                                       causal_pairs, flash_attention_causal)
-from ..pallas.grouped_matmul import TILE_M, grouped_matmul
+from ..pallas.grouped_matmul import (TILE_M, grid_order, grouped_matmul,
+                                     weight_block_fetches)
 from ..pallas.moe_unpermute import moe_unpermute, unpermute_steps
 
 
@@ -305,11 +306,159 @@ def _combine_bwd(tile_m, res, g):
 combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-def _tile_m(pairs: int, experts_held: int) -> int:
-    """Row-tile height: ``TILE_M``, or at a tiny test size the largest
-    power of two (>= 8) under an expert's balanced share of the pairs."""
-    share = max(pairs // experts_held, 8)
+def _tile_m(pairs: int, experts: int) -> int:
+    """Row-tile height: ``TILE_M``, or the largest power of two (>= 8)
+    under the share of ``pairs`` that one of ``experts`` gets."""
+    share = max(pairs // experts, 8)
     return min(TILE_M, 1 << (share.bit_length() - 1))
+
+
+def held_experts_sum(xt, idx, w, weights, ffn, *, experts: int,
+                     first_expert: int, capacity: float, whole,
+                     tile_m: int = None, weigh=None):
+    """``out[t] = sum over k with idx[t, k] held of w[t, k] *
+    ffn_e(xt[t])`` in float32, no pair dropped: the ONE way through the
+    expert-ordered buffer (plan, row gather, grouped products,
+    un-permute kernel) for any expert: ``ffn(gmm, xs, *weights)`` with
+    ``gmm(a, w_stacked)`` the grouped product over the buffer's rows and
+    ``weights`` the held experts' stacked matrices.
+
+    The usual buffer is ``capacity`` x the held experts' BALANCED share
+    of the pairs, and everything around the grouped products (gathers,
+    activations, cotangents) costs by its rows, not by the rows used.
+    Of it ``whole`` x the balanced share is multiplied whatever it
+    holds, empty tiles (zero rows) too, the price of static shapes: the
+    step's time does not follow the routing of whatever weights it is
+    given (+-0.3 % across seeds at random weights, for ~6 % of the
+    step: PERF.md section 6, PR 28); ``None`` multiplies all of it.  A
+    routing the usual buffer cannot hold is taken a GROUP of tokens at
+    a time (the other branch of a cond), each group planned on its own
+    into a buffer that holds ITS worst case: no buffer, here or in the
+    backward, larger than the usual one.  Both are the caller's shape
+    rule, as is ``tile_m``, the row-tile height (default: :func:`_tile_m`
+    of an expert's balanced share).
+
+    xt: [T, A]; idx: [T, K] int32 over ALL ``experts``; w: [T, K]
+    float32, or the chosen scores that ``weigh`` turns into it once the
+    plan is made (under ``dsod.moe.route``: where the first expert
+    layer's program has always made its weights).  -> (out [T, B]
+    float32, pairs per held expert, dropped, (row tiles this routing
+    needs, row tiles of the usual buffer: past it the by-group path
+    ran), the share of the grid steps of the usual buffer's product
+    with ``weights[0]`` that fetch a weight block — ``None`` where all
+    of the buffer is multiplied, a constant of the shapes).
+    """
+    tokens, top_k = idx.shape
+    e = weights[0].shape[0]
+    pairs_all = tokens * top_k
+    if tile_m is None:  # 352 pairs an expert -> 256 rows
+        tile_m = _tile_m(pairs_all, experts)
+    # A token's choices differ, so it sends a held expert one pair at most.
+    held_max = tokens * min(top_k, e)
+    worst = worst_case_tiles(held_max, e, tile_m)
+
+    def tiles(factor):  # row tiles of ``factor`` x the balanced share
+        return int(-(-factor * pairs_all * e // (experts * tile_m))) + e
+
+    usual = min(worst, tiles(capacity))
+    # Row tiles multiplied whatever they hold: of the usual buffer
+    # ``floor``, of any other ``none``.  (Under a ``whole`` the count of
+    # used tiles meets a maximum with 0 there: a no-op the latent
+    # layers' step has held since PR 43, kept with it to the byte.)
+    none = None if whole is None else 0
+    floor = (none if usual == worst  # a tiny size: it holds any routing
+             else usual if whole is None else min(usual, tiles(whole)))
+
+    def plan_for(idx, n_tiles):
+        """-> (plan, counts, dropped): where the pairs of ``idx`` go in
+        a buffer of ``n_tiles`` row tiles, and the un-permute kernel's
+        step list for it."""
+        (row_of_pair, pair_of_row, tile_expert, n_used, counts,
+         dropped) = plan_dispatch(idx, first_expert, e, tile_m, n_tiles)
+        steps = unpermute_steps(pair_of_row, top_k, idx.shape[0], tile_m, e)
+        return ((row_of_pair, pair_of_row, tile_expert, n_used, steps),
+                counts, dropped)
+
+    with jax.named_scope("dsod.moe.route"):
+        # The plan for the buffer that usually holds the pairs is made
+        # HERE, once, above the cond, and kept for the backward with the
+        # chosen experts and their scores (``REMAT_SAVES``): under 2 MiB
+        # a layer at the published size, against a sort, running counts
+        # and two gathers of scalars (XLA's run at ~10 ns an element)
+        # made twice.
+        plan, counts, dropped = jax.tree_util.tree_map(
+            lambda t: checkpoint_name(t, "plan"), plan_for(idx, usual))
+        if weigh is not None:
+            w = weigh(w)
+    fetched = None
+    if whole is not None:
+        nj, row_inner = grid_order(usual, tile_m, e, weights[0].shape[2])
+        fetched = weight_block_fetches(
+            plan[2], jnp.maximum(plan[3], floor), nj,
+            row_inner) / (usual * nj)
+
+    def through(plan, multiplied, xt, w, *weights):
+        """This chip's part of the sum for the tokens of ``xt`` through
+        the buffer ``plan`` lays out, at least ``multiplied`` of its
+        tiles multiplied -> out [T, B] f32."""
+        row_of_pair, pair_of_row, tile_expert, n_used, steps = plan
+        with jax.named_scope("dsod.moe.route"):
+            xs = dispatch(xt, row_of_pair, pair_of_row, steps, tile_m)
+        if multiplied is not None:
+            n_used = (jnp.full((1,), tile_expert.shape[0], jnp.int32)
+                      if multiplied == tile_expert.shape[0]
+                      else jnp.maximum(n_used, multiplied))
+        with jax.named_scope("dsod.moe.experts"):
+            ys = ffn(lambda a, wt: grouped_matmul(
+                a, wt, tile_expert, n_used, tile_m=tile_m), xs, *weights)
+        with jax.named_scope("dsod.moe.combine"):
+            return combine(ys, w, row_of_pair, pair_of_row, steps, tile_m)
+
+    def in_the_usual_buffer(plan, dropped, xt, w, idx, *weights):
+        return through(plan, floor, xt, w, *weights), dropped
+
+    def by_group(plan, dropped, xt, w, idx, *weights):
+        """The fewest groups whose worst case the usual buffer holds: 4
+        at the first model's published size, 72 row tiles a group
+        against the usual 104."""
+        del plan, dropped  # those are of the usual buffer, overflowed
+        groups = next(g for g in range(1, tokens + 1) if tokens % g == 0
+                      and worst_case_tiles(held_max // g, e, tile_m)
+                      <= usual)
+        n_tiles = worst_case_tiles(held_max // groups, e, tile_m)
+
+        def one(group):
+            xt, w, idx = group
+            # A scan's body lowers to a function of its own, whose ops
+            # carry the scopes from HERE down: the stage again.
+            with jax.named_scope("dsod.encoder"):
+                with jax.named_scope("dsod.moe.route"):
+                    plan, _, dropped = plan_for(idx, n_tiles)
+                return through(plan, none, xt, w, *weights), dropped
+
+        out, dropped = lax.map(jax.checkpoint(one), tuple(
+            t.reshape(groups, -1, t.shape[-1]) for t in (xt, w, idx)))
+        return out.reshape(tokens, -1), jnp.sum(dropped)
+
+    args = (plan, dropped, xt, w, idx) + tuple(weights)
+    needed = tiles_needed(idx, first_expert, e, tile_m)
+    if usual == worst:
+        out, dropped = in_the_usual_buffer(*args)
+    else:
+        # Each branch keeps its INPUTS alone for the backward (the plan
+        # among them) and recomputes inside it: a cond under autodiff
+        # otherwise holds BOTH branches' residuals (zeros for the one
+        # not taken).  So nothing inside a branch is saved by name
+        # either: a name kept from one is kept from both.  And a step's
+        # memory is its LARGER branch's, run or not: ONE worst-case
+        # buffer for all the tokens (264 row tiles) made the whole step
+        # 2.3 GiB larger in the compiler's books than the branch that
+        # runs, and the compiler paid for that by recomputing (PERF.md
+        # section 6, PR 31).
+        out, dropped = lax.cond(
+            needed <= usual, jax.checkpoint(in_the_usual_buffer),
+            jax.checkpoint(by_group), *args)
+    return out, counts, dropped, (needed, usual), fetched
 
 
 class ExpertLayer(nn.Module):
@@ -354,119 +503,36 @@ class ExpertLayer(nn.Module):
         bias_var = self.variable("batch_stats", "expert_bias", jnp.zeros,
                                  (self.experts,), jnp.float32)
         bias = bias_var.value
-        tokens, pairs_all = b * n, b * n * self.top_k
-        tile_m = _tile_m(pairs_all, e)
-        worst = worst_case_tiles(pairs_all, e, tile_m)
-        # The usual buffer: 1.5 x the balanced share of the pairs.  The
-        # worst case is four times the balanced share, and everything
-        # around the grouped products (gathers, SwiGLU, cotangents) costs
-        # by the buffer's rows, not by the rows used.
-        usual = min(worst, -(-(pairs_all * 3 * e)
-                             // (2 * self.experts * tile_m)) + e)
-
-        def plan_for(idx, n_tiles):
-            """-> (plan, counts, dropped): where the pairs of ``idx`` go
-            in a buffer of ``n_tiles`` row tiles, and the un-permute
-            kernel's step list for it."""
-            (row_of_pair, pair_of_row, tile_expert, n_used, counts,
-             dropped) = plan_dispatch(idx, self.first_expert, e, tile_m,
-                                      n_tiles)
-            steps = unpermute_steps(pair_of_row, self.top_k, idx.shape[0],
-                                    tile_m, e)
-            return ((row_of_pair, pair_of_row, tile_expert, n_used, steps),
-                    counts, dropped)
-
+        pairs_all = b * n * self.top_k
         with jax.named_scope("dsod.moe.route"):
             logits = nn.Dense(
                 self.experts, use_bias=False, dtype=jnp.float32,
                 param_dtype=self.param_dtype, name="router",
                 precision=lax.Precision.HIGHEST)(xt.astype(jnp.float32))
             s = jax.nn.sigmoid(logits)
-            # The plan for the buffer that usually holds the pairs is made
-            # HERE, once, above the cond, and kept for the backward with
-            # the chosen experts and their scores (``REMAT_SAVES``): under
-            # 2 MiB a layer at the published size, against a top-k, a
-            # sort, running counts and two gathers of scalars (XLA's run
-            # at ~10 ns an element) made twice.
             _, idx = lax.top_k(s + lax.stop_gradient(bias), self.top_k)
             idx = checkpoint_name(idx.astype(jnp.int32), "plan")
-            w, plan, counts, dropped = jax.tree_util.tree_map(
-                lambda t: checkpoint_name(t, "plan"),
-                (jnp.take_along_axis(s, idx, -1), *plan_for(idx, usual)))
+            chosen = jnp.take_along_axis(s, idx, -1)
+
+        def weigh(w):
+            # Named (an op of the program, for a float) and normalised
+            # once the plan is made: where this layer's step has had
+            # both since PR 31, held to the byte.
+            w = checkpoint_name(w, "plan")
             if self.norm_topk_prob:
                 w = w / (jnp.sum(w, -1, keepdims=True) + self.topk_eps)
-            w = w * self.routed_scaling_factor
+            return w * self.routed_scaling_factor
 
-        def experts(plan, whole, xt, w, w_gate, w_up, w_down):
-            """This chip's part of the sum for the tokens of ``xt``
-            through the buffer ``plan`` lays out -> out [T, D] f32."""
-            row_of_pair, pair_of_row, tile_expert, n_used, steps = plan
-            with jax.named_scope("dsod.moe.route"):
-                xs = dispatch(xt, row_of_pair, pair_of_row, steps, tile_m)
-            if whole:
-                # The usual buffer is multiplied WHOLE, its empty tiles
-                # (zero rows) too: a capacity factor of 1.5, the price of
-                # static shapes.  Skipping them (as a buffer sized for a
-                # worst case must) would make the step's time follow the
-                # routing of whatever weights it is given: +-0.3 % across
-                # seeds at random weights (PERF.md section 6, PR 28), for
-                # ~6 % of the step.
-                n_used = jnp.full((1,), tile_expert.shape[0], jnp.int32)
-            with jax.named_scope("dsod.moe.experts"):
-                gmm = lambda a, wt: grouped_matmul(  # noqa: E731
-                    a, wt, tile_expert, n_used, tile_m=tile_m)
-                h = nn.silu(gmm(xs, w_gate)) * gmm(xs, w_up)
-                ys = gmm(h, w_down)
-            with jax.named_scope("dsod.moe.combine"):
-                return combine(ys, w, row_of_pair, pair_of_row, steps, tile_m)
-
-        def in_the_usual_buffer(plan, dropped, xt, w, idx, *weights):
-            return experts(plan, usual < worst, xt, w, *weights), dropped
-
-        def by_group(plan, dropped, xt, w, idx, *weights):
-            """The same sum a GROUP of tokens at a time, each group
-            planned on its own into a buffer that holds ITS worst case:
-            no pair dropped whatever the imbalance, and no buffer, here
-            or in the backward, larger than the usual one.  The fewest
-            groups that allow it: 4 at the published size, 72 row tiles
-            a group against the usual 104."""
-            del plan, dropped  # those are of the usual buffer, overflowed
-            groups = next(g for g in range(1, tokens + 1) if tokens % g == 0
-                          and worst_case_tiles(pairs_all // g, e, tile_m)
-                          <= usual)
-            n_tiles = worst_case_tiles(pairs_all // groups, e, tile_m)
-
-            def one(group):
-                xt, w, idx = group
-                # A scan's body lowers to a function of its own, whose
-                # ops carry the scopes from HERE down: the stage again.
-                with jax.named_scope("dsod.encoder"):
-                    with jax.named_scope("dsod.moe.route"):
-                        plan, _, dropped = plan_for(idx, n_tiles)
-                    return experts(plan, False, xt, w, *weights), dropped
-
-            out, dropped = lax.map(jax.checkpoint(one), tuple(
-                t.reshape(groups, -1, t.shape[-1]) for t in (xt, w, idx)))
-            return out.reshape(tokens, d), jnp.sum(dropped)
-
-        args = (plan, dropped, xt, w, idx, w_gate, w_up, w_down)
-        if usual == worst:  # a tiny size: one buffer holds any routing
-            out, dropped = in_the_usual_buffer(*args)
-        else:
-            fits = tiles_needed(idx, self.first_expert, e, tile_m) <= usual
-            # Each branch keeps its INPUTS alone for the backward (the
-            # plan among them) and recomputes inside it: a cond under
-            # autodiff otherwise holds BOTH branches' residuals (zeros
-            # for the one not taken).  So nothing inside a branch is
-            # saved by name either: a name kept from one is kept from
-            # both.  And a step's memory is its LARGER branch's, run or
-            # not: ONE worst-case buffer for all the tokens (264 row
-            # tiles) made the whole step 2.3 GiB larger in the compiler's
-            # books than the branch that runs, and the compiler paid for
-            # that by recomputing (PERF.md section 6, PR 31).
-            out, dropped = lax.cond(
-                fits, jax.checkpoint(in_the_usual_buffer),
-                jax.checkpoint(by_group), *args)
+        # This layer's buffer rule: 1.5 x the balanced share of the
+        # pairs (the worst case is four times the share), all of it
+        # multiplied.
+        out, counts, dropped, _, _ = held_experts_sum(
+            xt, idx, chosen, (w_gate, w_up, w_down),
+            lambda gmm, xs, gate, up, down: gmm(
+                nn.silu(gmm(xs, gate)) * gmm(xs, up), down),
+            experts=self.experts, first_expert=self.first_expert,
+            capacity=1.5, whole=None, tile_m=_tile_m(pairs_all, e),
+            weigh=weigh)
         pairs = jnp.sum(counts).astype(jnp.float32)
         counters = {
             "pairs_here": pairs,

@@ -11,8 +11,9 @@ after the final norm, counters)``).  The Mamba-2 mixer and the
 position-free attention layer are ``models/granite.py``'s classes with
 other numbers (a mixer that holds ONE of the published 8 B/C groups and
 its 16 heads norms over that group's 1,024 columns, which IS the
-published group norm); ``RMSNorm``, the counted per-layer remat, the
-dispatch plan, the row gathers and the un-permute kernel are
+published group norm); ``RMSNorm``, the counted per-layer remat and
+the walk through the expert-ordered buffer (the dispatch plan, the row
+gathers, the grouped products, the un-permute kernel) are
 ``models/lfm2.py``'s; ``Embed`` and ``Head`` are ``models/kimi.py``'s.
 Imported, not copied.  Width ``hidden`` throughout, no bias but the
 conv's, ``eps`` = ``layer_norm_epsilon``:
@@ -78,14 +79,11 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from ..pallas.flash_attention import CAUSAL_RESIDUAL_NAMES
-from ..pallas.grouped_matmul import (TILE_M, grid_order, grouped_matmul,
-                                     weight_block_fetches)
-from ..pallas.moe_unpermute import unpermute_steps
 from .granite import Attention, Mamba2Mixer, ssm_counters
 from .kimi import Embed, Head
-from .lfm2 import (RMSNorm, _dense, _saves_counted, combine, dispatch,
-                   log_flash_grid, log_saves, moe_counters, plan_dispatch,
-                   tiles_needed, worst_case_tiles)
+from .lfm2 import (RMSNorm, _dense, _saves_counted,
+                   held_experts_sum as _held_experts_sum, log_flash_grid,
+                   log_saves, moe_counters)
 
 # What a rematerialised layer KEEPS: the attention kernel's output and
 # lse, and the expert layers' routing plan (chosen experts, scores,
@@ -130,96 +128,13 @@ class ReLU2MLP(nn.Module):
 
 def held_experts_sum(xt, idx, w, weights, ffn, *, experts: int,
                      first_expert: int):
-    """``out[t] = sum over k with idx[t, k] held of w[t, k] *
-    ffn_e(xt[t])`` in float32, no pair dropped: ``lfm2.ExpertLayer``'s
-    way through the expert-ordered buffer (plan, row gather, grouped
-    products, un-permute kernel; a usual buffer of ``CAPACITY`` x the
-    balanced share of which ``WHOLE`` x is multiplied whatever it holds,
-    a routing that overflows it taken a group of tokens at a time) for
-    ANY expert: ``ffn(gmm, xs, *weights)`` with ``gmm(a, w_stacked)``
-    the grouped product over the buffer's rows and ``weights`` the held
-    experts' stacked matrices.
-
-    xt: [T, A]; idx: [T, K] int32 over ALL ``experts``; w: [T, K]
-    float32.  -> (out [T, B] float32, pairs per held expert, dropped,
-    the share of the usual buffer's tiles this routing needs: over 1 it
-    took the by-group path, the share of the grid steps of the usual
-    buffer's product with ``weights[0]`` that fetch a weight block).
-    (That class keeps its own copy of this walk: its lines are part of
-    two older cells' compile-cache keys, PERF.md section 6, PRs 27-28.)
-    """
-    tokens, top_k = idx.shape
-    e = weights[0].shape[0]
-    pairs_all = tokens * top_k
-    # Row-tile height: an expert's balanced share of the pairs, rounded
-    # down to a power of two (352 -> 256 at the published size).
-    tile_m = min(TILE_M, 1 << (max(pairs_all // experts, 8).bit_length() - 1))
-    # A token's choices differ, so it sends a held expert one pair at most.
-    held_max = tokens * min(top_k, e)
-    worst = worst_case_tiles(held_max, e, tile_m)
-
-    def tiles(factor):  # row tiles of ``factor`` x the balanced share
-        return int(-(-factor * pairs_all * e // (experts * tile_m))) + e
-
-    usual = min(worst, tiles(CAPACITY))
-    # static shapes' price: up to here the empty tiles are multiplied
-    floor = min(usual, tiles(WHOLE)) if usual < worst else 0
-
-    def plan_for(idx, n_tiles):
-        (row_of_pair, pair_of_row, tile_expert, n_used, counts,
-         dropped) = plan_dispatch(idx, first_expert, e, tile_m, n_tiles)
-        steps = unpermute_steps(pair_of_row, top_k, idx.shape[0], tile_m, e)
-        return ((row_of_pair, pair_of_row, tile_expert, n_used, steps),
-                counts, dropped)
-
-    with jax.named_scope("dsod.moe.route"):
-        plan, counts, dropped = jax.tree_util.tree_map(
-            lambda t: checkpoint_name(t, "plan"), plan_for(idx, usual))
-    nj, row_inner = grid_order(usual, tile_m, e, weights[0].shape[2])
-    fetched = weight_block_fetches(plan[2], jnp.maximum(plan[3], floor), nj,
-                                   row_inner) / (usual * nj)
-
-    def through(plan, multiplied, xt, w, *weights):
-        row_of_pair, pair_of_row, tile_expert, n_used, steps = plan
-        with jax.named_scope("dsod.moe.route"):
-            xs = dispatch(xt, row_of_pair, pair_of_row, steps, tile_m)
-        n_used = jnp.maximum(n_used, multiplied)
-        with jax.named_scope("dsod.moe.experts"):
-            ys = ffn(lambda a, wt: grouped_matmul(
-                a, wt, tile_expert, n_used, tile_m=tile_m), xs, *weights)
-        with jax.named_scope("dsod.moe.combine"):
-            return combine(ys, w, row_of_pair, pair_of_row, steps, tile_m)
-
-    def in_the_usual_buffer(plan, dropped, xt, w, idx, *weights):
-        return through(plan, floor, xt, w, *weights), dropped
-
-    def by_group(plan, dropped, xt, w, idx, *weights):
-        del plan, dropped  # those are of the usual buffer, overflowed
-        groups = next(g for g in range(1, tokens + 1) if tokens % g == 0
-                      and worst_case_tiles(held_max // g, e, tile_m)
-                      <= usual)
-        n_tiles = worst_case_tiles(held_max // groups, e, tile_m)
-
-        def one(group):
-            xt, w, idx = group
-            with jax.named_scope("dsod.encoder"):  # a scan's body: again
-                with jax.named_scope("dsod.moe.route"):
-                    plan, _, dropped = plan_for(idx, n_tiles)
-                return through(plan, 0, xt, w, *weights), dropped
-
-        out, dropped = lax.map(jax.checkpoint(one), tuple(
-            t.reshape(groups, -1, t.shape[-1]) for t in (xt, w, idx)))
-        return out.reshape(tokens, -1), jnp.sum(dropped)
-
-    args = (plan, dropped, xt, w, idx) + tuple(weights)
-    needed = tiles_needed(idx, first_expert, e, tile_m)
-    if usual == worst:  # a tiny size: one buffer holds any routing
-        out, dropped = in_the_usual_buffer(*args)
-    else:  # each branch keeps its inputs alone (lfm2.ExpertLayer)
-        out, dropped = lax.cond(
-            needed <= usual, jax.checkpoint(in_the_usual_buffer),
-            jax.checkpoint(by_group), *args)
-    return out, counts, dropped, needed / usual, fetched
+    """``lfm2.held_experts_sum`` (the one way through the expert-ordered
+    buffer) under this model's buffer rule: a usual buffer of
+    ``CAPACITY`` x the balanced share of which ``WHOLE`` x is multiplied
+    whatever it holds, and the default row tile."""
+    return _held_experts_sum(xt, idx, w, weights, ffn, experts=experts,
+                             first_expert=first_expert, capacity=CAPACITY,
+                             whole=WHOLE)
 
 
 class LatentExpertLayer(nn.Module):
@@ -269,10 +184,11 @@ class LatentExpertLayer(nn.Module):
             w = w * self.routed_scaling_factor
         with jax.named_scope("dsod.moe.latent"):
             z = _dense(lat, "latent_down", self.dtype, self.param_dtype)(xt)
-        r, counts, dropped, fill, fetched = held_experts_sum(
+        r, counts, dropped, (needed, usual), fetched = held_experts_sum(
             z, idx, w, (w_up, w_down),
             lambda gmm, xs, up, down: gmm(relu2(gmm(xs, up)), down),
             experts=self.experts, first_expert=self.first_expert)
+        fill = needed / usual  # over 1 it took the by-group path
         with jax.named_scope("dsod.moe.latent"):
             out = _dense(d, "latent_up", self.dtype, self.param_dtype)(
                 r.astype(self.dtype))

@@ -11,11 +11,83 @@ where ``logits_list[0]`` is the primary full-resolution saliency logit.
 
 from __future__ import annotations
 
-from typing import Callable, Dict
+from typing import Callable, Dict, NamedTuple
 
 import jax.numpy as jnp
+import numpy as np
 
 _REGISTRY: Dict[str, Callable] = {}
+
+
+class Kind(NamedTuple):
+    """What the program asks of a model's kind, in ONE place: an image
+    model (``image`` / ``mask`` / ``depth`` batches, the zoo-wide call
+    convention above) or a token model (``tokens`` / ``targets``
+    batches, the contract of ``models/lfm2.py``).  Two kinds and five
+    callers, not an extension point."""
+    name: str
+    # The step builder takes the model under the dp preset alone and
+    # without error-feedback compression (parallel/engine.py, which
+    # also picks the kind's forward + loss by ``name``).
+    dp_only: bool
+    # (batch, cfg): ``fit()``'s check of its first batch, a ValueError.
+    check_first_batch: Callable
+    # sample_batch -> what ``model.init`` takes after its rng.
+    init_inputs: Callable
+    # (cfg, batch_size) -> a host batch of zeros at ``cfg.data``'s
+    # sizes, for the tools and tests that lower a step and run nothing.
+    zero_batch: Callable
+
+
+def _check_image_batch(batch, cfg):
+    from ..utils.checks import validate_batch
+
+    validate_batch(batch, cfg.data.image_size, use_depth=cfg.data.use_depth)
+
+
+def _image_init_inputs(sample_batch):
+    depth = sample_batch.get("depth")
+    return (jnp.asarray(sample_batch["image"]),
+            None if depth is None else jnp.asarray(depth))
+
+
+def _zero_image_batch(cfg, batch_size):
+    h, w = cfg.data.image_size
+    batch = {"image": np.zeros((batch_size, h, w, 3), np.float32),
+             "mask": np.zeros((batch_size, h, w, 1), np.float32)}
+    if cfg.data.use_depth:
+        batch["depth"] = np.zeros((batch_size, h, w, 1), np.float32)
+    return batch
+
+
+def _check_token_batch(batch, cfg):
+    from ..utils.checks import validate_token_batch
+
+    validate_token_batch(batch, cfg.data.seq_len, cfg.model.lm.vocab)
+
+
+def _token_init_inputs(sample_batch):
+    # Parameter shapes do not depend on the sequence length: a short
+    # stretch of one sequence keeps the init program small.
+    return (jnp.asarray(sample_batch["tokens"])[:1, :128],)
+
+
+def _zero_token_batch(cfg, batch_size):
+    return {k: np.zeros((batch_size, cfg.data.seq_len), np.int32)
+            for k in ("tokens", "targets")}
+
+
+_KINDS = {k.name: k for k in (
+    Kind("image", False, _check_image_batch, _image_init_inputs,
+         _zero_image_batch),
+    Kind("tokens", True, _check_token_batch, _token_init_inputs,
+         _zero_token_batch))}
+
+
+def kind_of(model) -> Kind:
+    """The :class:`Kind` of a built model: its class says ``kind =
+    "tokens"`` or, an image model, nothing."""
+    return _KINDS[getattr(model, "kind", "image")]
 
 
 def register_model(name: str):

@@ -57,6 +57,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..losses import deep_supervision_loss
+from ..models import kind_of
 from ..models.layers import resample_routes
 from ..train.state import TrainState
 from ..train.step import (_loss_kwargs, apply_update, chunk_batch_spec,
@@ -170,6 +171,12 @@ def make_unified_train_step(
     bucket_bytes = int(comm_bucket_mb * 2 ** 20)
     hierarchy = hier_data_groups(mesh, data_hosts)
     ef = grad_compression == "int8_ef"
+    kind = kind_of(model)
+    if kind.dp_only and (preset != "dp" or ef):
+        raise ValueError(
+            "the token model trains under the dp preset without "
+            f"error-feedback compression, got preset={preset!r} "
+            f"grad_compression={grad_compression!r}")
     # ZeRO-2: the gradient tree is pinned to the buffer layout so the
     # partitioner reduce-scatters instead of materializing the full
     # replicated tree between reduce and update.
@@ -326,34 +333,34 @@ def make_unified_train_step(
         new_state, metrics = _finish(state, grads, comps, new_stats)
         return (new_state, new_res[None]), metrics
 
-    # The token model (models/lfm2.py; ``kind`` is a class attribute) is
-    # the third forward+loss path beside ``sp`` and the image branch of
-    # ``_forward_loss``, chosen by the model at trace time: the batch is
-    # tokens/targets, the forward returns the final hidden states and
-    # the expert layers' counters, the loss is the chunked cross-entropy
-    # over the tied embedding; everything after the gradients is the
-    # shared tail.  It is defined HERE, below every closure an image
-    # model's trace passes through, and not as an ``if`` inside
-    # ``_forward_loss``: the Pallas kernels' serialized bodies carry the
-    # line numbers of the frames above them, those bytes are part of the
-    # compile-cache key, and a line inserted above would make every
-    # image config's step a cache miss (and, on the chip, a different
-    # compile: PERF.md section 6, PRs 27-28).
-    tokens = getattr(model, "kind", "image") == "tokens"
-    if tokens and (preset != "dp" or ef):
-        raise ValueError(
-            "the token model trains under the dp preset without "
-            f"error-feedback compression, got preset={preset!r} "
-            f"grad_compression={grad_compression!r}")
+    # A token model's forward + loss, the third beside ``sp`` and the
+    # image branch of ``_forward_loss``: the batch is tokens/targets,
+    # the forward returns the final hidden states and the expert
+    # layers' counters, the loss is the chunked cross-entropy over the
+    # tied embedding; everything after the gradients is the shared tail.
+
+    def _token_loss(outputs, counters, params, targets):
+        """-> (total, counters).  A model that names its own loss
+        (``token_loss``: models/ouro.py, four heads' cross-entropies
+        under a learned exit distribution) is given its outputs, the
+        parameters and the targets; every other token model hands back
+        ONE hidden state, whose loss is the chunked cross-entropy over
+        the head's matrix: the embedding, unless the model names its
+        own (``head``)."""
+        from ..losses.token_ce import tied_cross_entropy
+
+        own = getattr(model, "token_loss", None)
+        if own is not None:
+            total, more = own(outputs, params, targets)
+            return total, dict(counters, **more)
+        module, leaf = getattr(model, "head", ("embed", "embedding"))
+        return tied_cross_entropy(outputs, params[module][leaf],
+                                  targets), counters
 
     def _forward_loss_tokens(state, batch):
         # The buffers come back from the model, as BatchNorm's do from
         # an image model: a router balanced by rule moves its selection
-        # bias every step (models/lfm2.py::ExpertLayer).  The loss is
-        # ``_token_loss`` below.  This function keeps the line numbers
-        # it had before that seam: the frames above a model's kernels
-        # (``model.apply``, ``jax.grad``, the caller of this function)
-        # are part of the older token steps' compile-cache keys.
+        # bias every step (models/lfm2.py::ExpertLayer).
 
         def loss_fn(params):
             (hidden, counters), mut = model.apply(
@@ -375,25 +382,7 @@ def make_unified_train_step(
         grads, comps, _ = _reduce(grads, comps)
         return _finish(state, grads, comps, new_stats)
 
-    def _token_loss(outputs, counters, params, targets):
-        """-> (total, counters).  A model that names its own loss
-        (``token_loss``: models/ouro.py, four heads' cross-entropies
-        under a learned exit distribution) is given its outputs, the
-        parameters and the targets; every other token model hands back
-        ONE hidden state, whose loss is the chunked cross-entropy over
-        the head's matrix: the embedding, unless the model names its
-        own (``head``)."""
-        from ..losses.token_ce import tied_cross_entropy
-
-        own = getattr(model, "token_loss", None)
-        if own is not None:
-            total, more = own(outputs, params, targets)
-            return total, dict(counters, **more)
-        module, leaf = getattr(model, "head", ("embed", "embedding"))
-        return tied_cross_entropy(outputs, params[module][leaf],
-                                  targets), counters
-
-    inner_fn = (step_fn_tokens if tokens
+    inner_fn = (step_fn_tokens if kind.name == "tokens"
                 else step_fn_ef if ef else step_fn)
     body = chunked_step_fn(inner_fn, steps_per_dispatch,
                            always_scan=_always_scan)

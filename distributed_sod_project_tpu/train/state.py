@@ -51,23 +51,15 @@ def create_train_state(rng, model, tx, sample_batch,
     and wrap them with the optimizer's initial state.  ``pretrained``
     merges a ported ImageNet backbone (.npz) over the fresh init.
     ``ema=True`` seeds the EMA tree as a copy of the initial params."""
-    if getattr(model, "kind", "image") == "tokens":
-        # Parameter shapes do not depend on the sequence length: a short
-        # stretch of one sequence keeps the init program small.
-        tokens = jnp.asarray(sample_batch["tokens"])[:1, :128]
-        variables = jax.jit(
-            lambda r, t: model.init(r, t, train=False))(rng, tokens)
-        return _wrap_state(variables, tx, ema)
-    image = jnp.asarray(sample_batch["image"])
-    depth = sample_batch.get("depth")
-    if depth is not None:
-        depth = jnp.asarray(depth)
+    from ..models import kind_of
+
     # ONE compiled program, not an eager init: un-jitted, flax's init
     # dispatches (and on the chip compiles) every primitive on its own —
     # 165 s before the trainer's first log line for minet_r50_dp on a
     # v5e, ~110 s of a server's start (chip_smoke.py, PR 23).
     variables = jax.jit(
-        lambda r, i, d: model.init(r, i, d, train=False))(rng, image, depth)
+        lambda r, *inputs: model.init(r, *inputs, train=False))(
+            rng, *kind_of(model).init_inputs(sample_batch))
     if pretrained:
         from ..models.pretrained import load_pretrained
 

@@ -96,11 +96,9 @@ def validate_first_batch(batch: Dict, cfg, model) -> None:
     """``fit()``'s one check of its first batch: a token model's batch
     against ``data.seq_len`` and the vocabulary rows the model holds, an
     image model's against ``data.image_size`` (and depth)."""
-    if getattr(model, "kind", "image") == "tokens":
-        validate_token_batch(batch, cfg.data.seq_len, cfg.model.lm.vocab)
-    else:
-        validate_batch(batch, cfg.data.image_size,
-                       use_depth=cfg.data.use_depth)
+    from ..models import kind_of
+
+    kind_of(model).check_first_batch(batch, cfg)
 
 
 def validate_token_batch(batch: Dict, seq_len: int, vocab: int) -> None:

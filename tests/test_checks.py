@@ -3,7 +3,10 @@
 import numpy as np
 import pytest
 
-from distributed_sod_project_tpu.utils.checks import validate_batch
+from distributed_sod_project_tpu.configs import apply_overrides, get_config
+from distributed_sod_project_tpu.models import build_model
+from distributed_sod_project_tpu.utils.checks import (validate_batch,
+                                                      validate_first_batch)
 
 
 def _good(b=2, hw=16, depth=False):
@@ -18,9 +21,15 @@ def _good(b=2, hw=16, depth=False):
     return out
 
 
+def _first(batch, config):
+    """``fit()``'s check of its first batch, through the model's kind."""
+    cfg = apply_overrides(get_config(config), ["data.image_size=16,16"])
+    validate_first_batch(batch, cfg, build_model(cfg.model))
+
+
 def test_good_batch_passes():
-    validate_batch(_good(), (16, 16))
-    validate_batch(_good(depth=True), (16, 16), use_depth=True)
+    _first(_good(), "minet_vgg16_ref")
+    _first(_good(depth=True), "hdfnet_rgbd")
 
 
 @pytest.mark.parametrize("breaker,match", [
@@ -46,4 +55,6 @@ def test_all_zero_mask_warns():
 
 def test_missing_depth_fails():
     with pytest.raises(ValueError, match="missing 'depth'"):
-        validate_batch(_good(), (16, 16), use_depth=True)
+        _first(_good(), "hdfnet_rgbd")
+    with pytest.raises(ValueError, match="missing 'image'"):
+        _first({"tokens": np.zeros((2, 16), np.int32)}, "minet_vgg16_ref")

@@ -19,11 +19,14 @@ worker) such a child could hold the library's lock at the moment this
 file's fixture asks for it, turning every compile test into a skip.
 """
 
+import base64
 import collections
 import glob
 import importlib
+import importlib.util
 import json
 import os
+import re
 import subprocess
 import sys
 from functools import partial
@@ -32,6 +35,10 @@ import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(_REPO, "tools"))
+import step_cache_key  # noqa: E402 — imports no JAX until it is called
 
 _P = "distributed_sod_project_tpu.pallas."
 fc, fr, dfm, fl, fs, fa, vb, gm, mu, ssd, cc, rot = (
@@ -344,39 +351,92 @@ def test_kernel_compiles_for_v5e(chip, name):
     assert compiled.as_text().count("tpu_custom_call") >= n_calls
 
 
-def _lowered_token_step(name, topo, monkeypatch):
-    """The registered token config's whole train step at the cell's own
-    size, lowered for the described chip on abstract state."""
-    import numpy as np
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
+def _lowered_step(name, topo, monkeypatch):
+    """The registered config's whole train step at the cell's own size,
+    lowered for the described chip on abstract state, as
+    ``tools/step_cache_key.py`` lowers it."""
     from distributed_sod_project_tpu.configs import get_config
-    from distributed_sod_project_tpu.models import build_model
-    from distributed_sod_project_tpu.parallel.engine import \
-        make_unified_train_step
-    from distributed_sod_project_tpu.train import (build_optimizer,
-                                                   create_train_state)
 
     # jax.default_backend() still says cpu here: steer the flash kernels
     # (and whatever else asks) to Mosaic for the length of this test.
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg = get_config(name)
-    n = cfg.data.seq_len
-    mesh = Mesh(np.array([topo.devices[0]]).reshape(1, 1, 1),
-                ("data", "model", "seq"))
-    model = build_model(cfg.model)
-    tx, sched = build_optimizer(cfg.optim, 20000)
-    batch = {k: np.zeros((1, n), np.int32) for k in ("tokens", "targets")}
-    state = jax.eval_shape(lambda: create_train_state(
-        jax.random.key(0), model, tx, batch))
-    on = lambda spec: lambda x: jax.ShapeDtypeStruct(  # noqa: E731
-        x.shape, x.dtype, sharding=NamedSharding(mesh, spec))
-    step = make_unified_train_step(
-        model, cfg.loss, tx, mesh, preset="dp", schedule=sched,
-        donate_batch=True, remat=cfg.model.remat,
-        remat_policy=cfg.model.remat_policy)
-    return step.lower(jax.tree_util.tree_map(on(P()), state),
-                      jax.tree_util.tree_map(on(P("data")), batch))
+    return step_cache_key.lower_step(get_config(name), topo.devices[0])
+
+
+_KEYED = """\
+from distributed_sod_project_tpu.pallas import fused_loss
+
+
+def program(a, b):
+    return fused_loss.pixel_region_sums(a, b, interpret=False)
+"""
+
+
+def test_the_cache_key_does_not_know_where_the_code_stands(chip, tmp_path):
+    """The canonical IR (what JAX's persistent cache hashes) of one
+    kernel-bearing program, lowered from two source files at two paths
+    that differ by an inserted line: the same bytes, because the package
+    keeps Python frames out of the kernels' serialized bodies
+    (``pallas/__init__.py``) — and other bytes with JAX's default of ten
+    frames put back, which is what every tree before PR 46 compiled
+    under."""
+    for where, pad in (("here", ""), ("elsewhere", "# a line more\n")):
+        (tmp_path / where).mkdir()
+        (tmp_path / where / "keyed.py").write_text(pad + _KEYED)
+    args = [jax.ShapeDtypeStruct(_IMG.shape, _IMG.dtype, sharding=chip)] * 2
+
+    def key(where):  # a fresh module a call: nothing traced is reused
+        spec = importlib.util.spec_from_file_location(
+            "keyed", tmp_path / where / "keyed.py")
+        module = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(module)
+        lowered = jax.jit(module.program).lower(*args)
+        assert "tpu_custom_call" in lowered.as_text()
+        return step_cache_key.canonical_ir(lowered)
+
+    assert jax.config.jax_traceback_in_locations_limit == 0
+    assert key("here") == key("elsewhere")
+    jax.config.update("jax_traceback_in_locations_limit", 10)
+    try:
+        assert key("here") != key("elsewhere")
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", 0)
+
+
+@pytest.mark.parametrize("name", ["basnet_ds", "lfm2_8b_a1b_ep4"])
+def test_no_kernel_body_of_a_step_names_the_checkout(chip, monkeypatch,
+                                                     capsys, name):
+    """``tools/step_cache_key.py`` from where it stands, on an image and
+    on a token configuration at tiny widths (the fused loss and SSIM
+    kernels under BASNet's eight outputs; the first token model's causal
+    flash, grouped-product and un-permute kernels): one line with the
+    hash and the kernel count, and no serialized ``tpu_custom_call`` body of the
+    whole lowered step carries the checkout's path (nor any Python
+    file's) — the compile cache's key is the same from any checkout.
+    (In this process because only one may hold the TPU library; the
+    names the tool steers are put back after it.)"""
+    monkeypatch.setattr(jax, "default_backend", jax.default_backend)
+    monkeypatch.setattr(vb, "_device_kind", vb._device_kind)
+    seen = []
+    real = step_cache_key.canonical_ir
+    monkeypatch.setattr(step_cache_key, "canonical_ir",
+                        lambda lowered: seen.append(lowered) or real(lowered))
+    from test_profiler_names import _TOKEN_MODELS  # the tiny widths
+
+    sets = [a for o in _TOKEN_MODELS.get(name, []) for a in ("--set", o)]
+    assert step_cache_key.main(
+        ["--config", name, "--batch", "2", "--image-size", "64",
+         "--seq-len", "256"] + sets) == 0
+    line = capsys.readouterr().out.strip().splitlines()[-1]
+    found = re.fullmatch(name + r" canonical step IR sha256 [0-9a-f]{64} "
+                         r"\(\d+ bytes, (\d+) kernels\)", line)
+    assert found and int(found.group(1)) >= 8, line
+    (lowered,) = seen
+    bodies = [base64.b64decode(b) for b in re.findall(
+        r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', lowered.as_text())]
+    assert len(bodies) == int(found.group(1))
+    assert all(b.startswith(b"ML\xefR") for b in bodies)  # MLIR bytecode
+    assert not [b for b in bodies if _REPO.encode() in b or b".py" in b]
 
 
 def _state_gib_and_fits(compiled):
@@ -395,7 +455,7 @@ def test_the_state_space_step_compiles_for_v5e_and_fits(chip, topo,
     temporaries inside the chip's 15.75 GiB.  (The compiler's own books,
     which decide whether it rematerialises, are read from its log:
     .claude/skills/verify.)"""
-    lowered = _lowered_token_step("granite_4_0_h_micro_pp4", topo,
+    lowered = _lowered_step("granite_4_0_h_micro_pp4", topo,
                                   monkeypatch)
     assert lowered.as_text().count("tpu_custom_call") == 56
     # 772 M parameters x 12 bytes
@@ -413,7 +473,7 @@ def test_the_looped_step_compiles_for_v5e_and_fits(chip, topo, monkeypatch):
     192 of them a step, 64 MiB each, around the rotation).
     (The compiler's own books read 12.51 of 14.54 GiB: PERF.md section 4;
     they are read from its log, .claude/skills/verify.)"""
-    lowered = _lowered_token_step("ouro_2_6b_pp6", topo, monkeypatch)
+    lowered = _lowered_step("ouro_2_6b_pp6", topo, monkeypatch)
     assert lowered.as_text().count("tpu_custom_call") == 160
     compiled = lowered.compile()
     assert ".remat" not in compiled.as_text()
@@ -439,7 +499,7 @@ def test_the_hybrid_step_compiles_for_v5e_and_fits(chip, topo, monkeypatch):
     rematerialised by the compiler, state + temporaries inside the chip's
     15.75 GiB.  (The compiler's own books read 11.37 of 14.65 GiB: PERF.md
     section 4.)"""
-    lowered = _lowered_token_step("nemotron_3_super_tp8_ep64", topo,
+    lowered = _lowered_step("nemotron_3_super_tp8_ep64", topo,
                                   monkeypatch)
     assert lowered.as_text().count("tpu_custom_call") == 162
     compiled = lowered.compile()
@@ -469,7 +529,6 @@ def test_availability_rules_match_the_compiler():
     assert not dfm.fused_dynamic_filter_available((B, 160, 160, 64), 3, 4)
 
 
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _NO_CHIP_CLIS = {
     "train.py": ["train.py", "--config", "minet_r50_dp", "--device", "tpu",
                  "--max-steps", "1"],

@@ -21,19 +21,13 @@ seeded weights (benchmark/harness/weights_ssm.py):
   layer by layer;
 - every new ``dsod.*`` scope in the lowered step, inside the encoder
   stage;
-- the first two token models' steps are the programs they were
-  (StableHLO sha256);
 - three steps of ``fit()`` with the two counters on the stream.
 """
 
 import collections
 import dataclasses
-import hashlib
 import logging
-import os
 import re
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -464,36 +458,6 @@ def test_no_product_of_the_step_is_outside_a_stage(lowered_text):
     assert len(dots) > 100
     assert [ln[-160:] for ln in dots if not _STAGE.search(locs.get(
         re.search(r"loc\((#loc\d+)\)\s*$", ln).group(1), ""))] == []
-
-
-@pytest.mark.parametrize("config,sha", [
-    ("lfm2_8b_a1b_ep4",
-     "64c67471bb7894da77c5f2c61f88423e2433ea9fd0840880f6f7353582353757"),
-    ("kimi_vl_a3b_ep8",
-     "f2f7822374b39c5b885103c7cd5c9afc83db4f2979aa2101c980ed2360d5c0a5")])
-def test_the_older_token_models_steps_are_the_programs_they_were(
-        tmp_path, config, sha):
-    """``tools/dump_hlo.py`` on both older token configs, as its command
-    line runs it (a process of its own: this suite's conftest sets a
-    matmul precision, which is part of a program): the StableHLO of the
-    commit before this model (PR 36's tree, 74d629d; the first one's
-    with PR 40's one-kernel causal backward; both with PR 44's grouped
-    product, whose weight block moves only where the expert or the
-    column block does, and with PR 45's causal flash grids, which hold
-    only the tile pairs on or under the diagonal), to the byte.  A PR
-    that means to change one of those steps changes its hash with it and
-    says so in PERF.md."""
-    tools = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
-                         "tools")
-    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
-    subprocess.run(
-        [sys.executable, "-c",
-         "import sys, dump_hlo; dump_hlo.dump(sys.argv[2], "
-         "sys.argv[1], compile_cost=False)", str(tmp_path), config],
-        check=True, env=dict(env, PYTHONPATH=tools, JAX_PLATFORMS="cpu"),
-        capture_output=True, timeout=600)
-    with open(tmp_path / f"{config}.stablehlo.txt", "rb") as f:
-        assert hashlib.sha256(f.read()).hexdigest() == sha
 
 
 # -- the loop -----------------------------------------------------------------

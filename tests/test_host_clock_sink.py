@@ -15,8 +15,6 @@
   ``fit()`` left in the sink.
 """
 
-import ast
-import inspect
 import logging
 import threading
 
@@ -354,20 +352,6 @@ def test_a_planted_slow_interval_logs_one_stall_line_a_steady_run_none(
     spent = dict(zip(*[iter(line.split(" | ")[1].split())] * 2))
     assert float(spent["dsod.train.log"]) == pytest.approx(2.2, abs=0.05)
     assert "cpu process" in line and "gc " in line and "compiles none" in line
-
-
-def test_the_steps_line_and_column_in_fit_are_the_image_cells_cache_key():
-    """On the chip a Pallas kernel's serialized body keeps line AND
-    column of the frames above it, ``fit()``'s among them
-    (.claude/skills/verify): moving either call of the step changes the
-    compile-cache key of every image config's step though its StableHLO
-    stands.  A PR that has to move them says so and pays that compile."""
-    calls = sorted(
-        (n.lineno, n.col_offset)
-        for n in ast.walk(ast.parse(inspect.getsource(loop)))
-        if isinstance(n, ast.Assign) and ast.unparse(n.value)
-        == "train_step(state, batch)")
-    assert calls == [(886, 32), (889, 28)]
 
 
 # -- the benchmark's readers ----------------------------------------------

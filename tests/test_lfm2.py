@@ -930,7 +930,11 @@ def test_image_models_step_names_none_of_them():
 
 def test_packed_tokens_are_deterministic_zipf_documents():
     from distributed_sod_project_tpu.data.tokens import EOD, PackedTokens
-    from distributed_sod_project_tpu.utils.checks import validate_token_batch
+    from distributed_sod_project_tpu.utils.checks import validate_first_batch
+
+    def check(batch, vocab=1024):  # fit()'s, through the model's kind
+        cfg = _cfg("data.seq_len=4096", f"model.lm.vocab={vocab}")
+        validate_first_batch(batch, cfg, build_model(cfg.model))
 
     ds = PackedTokens(size=8, seq_len=4096, vocab=1024)
     a, b = ds[5], ds[5]
@@ -945,11 +949,13 @@ def test_packed_tokens_are_deterministic_zipf_documents():
     counts = np.bincount(ids, minlength=1024)[1:]
     assert counts[:8].sum() > counts[512:].sum()  # Zipf: few ids, most mass
     batch = {k: np.stack([ds[i][k] for i in range(2)]) for k in a}
-    validate_token_batch(batch, 4096, 1024)
+    check(batch)
     with pytest.raises(ValueError, match="outside"):
-        validate_token_batch(batch, 4096, 512)
+        check(batch, vocab=512)
     with pytest.raises(ValueError, match="shifted"):
-        validate_token_batch(dict(batch, targets=batch["tokens"]), 4096, 1024)
+        check(dict(batch, targets=batch["tokens"]))
+    with pytest.raises(ValueError, match="missing 'tokens'"):
+        check({"image": batch["tokens"]})
 
 
 def test_three_steps_of_fit_at_tiny_size(tmp_path):

@@ -1,11 +1,14 @@
 """Model zoo tests: forward shapes + finite loss/grad smoke (SURVEY.md §4)."""
 
+import warnings
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from distributed_sod_project_tpu.configs import get_config
+from distributed_sod_project_tpu.configs import (apply_overrides, get_config,
+                                                 list_configs)
 from distributed_sod_project_tpu.models import build_model
 from distributed_sod_project_tpu.models.backbones import ResNet34, ResNet50, VGG16
 
@@ -274,6 +277,42 @@ def test_registry_builds_all_zoo_models():
 
     assert {"minet", "u2net", "basnet", "hdfnet",
             "gatenet"} <= set(list_models())
+
+
+@pytest.mark.parametrize("config_name", list_configs())
+def test_every_registered_config_resolves_to_a_kind(config_name):
+    """The seam the step builder, the state, the first-batch check and
+    the lowering tools ask (``models/registry.py::kind_of``): every
+    registered configuration's model is an image or a token model, and
+    the kind's zero batch is one its own check and its init accept."""
+    from distributed_sod_project_tpu.models import kind_of
+
+    cfg = apply_overrides(get_config(config_name), [
+        "data.image_size=32,32", "data.seq_len=192"])
+    kind = kind_of(build_model(cfg.model))
+    tokens = cfg.model.name in ("lfm2", "kimi", "granite", "ouro",
+                                "nemotron_h")
+    assert kind.name == ("tokens" if tokens else "image")
+    assert kind.dp_only == tokens
+    batch = kind.zero_batch(cfg, 2)
+    with warnings.catch_warnings():  # zeros: "every mask pixel is 0"
+        warnings.simplefilter("ignore", UserWarning)
+        kind.check_first_batch(batch, cfg)
+    shapes = [None if a is None else a.shape
+              for a in kind.init_inputs(batch)]
+    if tokens:
+        assert sorted(batch) == ["targets", "tokens"]
+        assert shapes == [(1, 128)]  # a stretch of ONE sequence
+        with pytest.raises(ValueError, match="not integers"):
+            kind.check_first_batch(kind.zero_batch(apply_overrides(
+                cfg, ["data.seq_len=64"]), 2), cfg)
+    else:
+        depth = (2, 32, 32, 1) if cfg.data.use_depth else None
+        assert ("depth" in batch) == cfg.data.use_depth
+        assert shapes == [(2, 32, 32, 3), depth]
+        with pytest.raises(ValueError, match="image shape"):
+            kind.check_first_batch(kind.zero_batch(apply_overrides(
+                cfg, ["data.image_size=16,16"]), 2), cfg)
 
 
 @pytest.mark.slow

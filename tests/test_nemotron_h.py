@@ -20,17 +20,11 @@ seeded weights (benchmark/harness/weights_hybrid.py):
 - what a rematerialised layer keeps by name, and the step's log line;
 - every new ``dsod.*`` scope in the lowered step, inside the encoder
   stage;
-- the fourth token model's step is the program it was, and this one's
-  is the program this file pins (StableHLO sha256);
 - three steps of ``fit()`` with both families of counters on the stream.
 """
 
-import hashlib
 import logging
-import os
 import re
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -584,34 +578,6 @@ def test_no_product_of_the_step_is_outside_a_stage(lowered_text):
     assert len(dots) > 30
     assert [ln[-160:] for ln in dots if not _STAGE.search(locs.get(
         re.search(r"loc\((#loc\d+)\)\s*$", ln).group(1), ""))] == []
-
-
-# -- the same-program rule ---------------------------------------------------
-
-@pytest.mark.parametrize("config,sha", [
-    ("ouro_2_6b_pp6",
-     "ec4cabce12c073955b2077f9e80d15c2fff424f93c8b5ca5e2a34ee1cfe85181"),
-    ("nemotron_3_super_tp8_ep64",
-     "929d5936b27b67aaffd2b1385ff8685402719f7bd0a5a358b39256fc2e4b91f9")])
-def test_the_step_is_the_program_this_file_pins(tmp_path, config, sha):
-    """``tools/dump_hlo.py`` as its command line runs it (a process of
-    its own), to the byte: the fourth token model's step as the commit
-    before this model had it (PR 42's tree, ce641e3; tests/test_granite.py
-    and tests/test_ouro.py pin the first three), and this model's own
-    step as the PR that added it left it but for PR 44's grouped
-    product and its counter.  A PR that means to change
-    either changes its hash with it and says so in PERF.md."""
-    tools = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
-                         "tools")
-    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
-    subprocess.run(
-        [sys.executable, "-c",
-         "import sys, dump_hlo; dump_hlo.dump(sys.argv[2], "
-         "sys.argv[1], compile_cost=False)", str(tmp_path), config],
-        check=True, env=dict(env, PYTHONPATH=tools, JAX_PLATFORMS="cpu"),
-        capture_output=True, timeout=600)
-    with open(tmp_path / f"{config}.stablehlo.txt", "rb") as f:
-        assert hashlib.sha256(f.read()).hexdigest() == sha
 
 
 # -- the loop -----------------------------------------------------------------

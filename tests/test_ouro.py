@@ -15,19 +15,12 @@ tiny widths, float32, seeded weights (benchmark/harness/weights_loop.py):
 - what a rematerialised visit keeps, counted per visit, and the log line;
 - every new ``dsod.*`` scope in the lowered step inside its stage, no
   product outside a stage, the counters on the stream;
-- the three older token models' steps are the programs they were
-  (StableHLO sha256 of the state-space step; the other two are pinned in
-  tests/test_granite.py);
 - three steps of ``fit()``.
 """
 
 import dataclasses
-import hashlib
 import logging
-import os
 import re
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -447,30 +440,6 @@ def test_no_product_of_the_step_is_outside_a_stage(lowered_text):
     heads = [ln for ln in dots if "dsod.heads" in locs.get(
         re.search(r"loc\((#loc\d+)\)\s*$", ln).group(1), "")]
     assert heads  # the head products, forward and backward
-
-
-def test_the_state_space_step_is_the_program_it_was(tmp_path):
-    """``tools/dump_hlo.py`` on the third token config, as its command
-    line runs it: the StableHLO of the commit before this model (PR 40's
-    tree, 345bcb8), to the byte; tests/test_granite.py pins the first
-    two.  The loss seam in ``parallel/engine.py`` leaves all three the
-    programs they were."""
-    tools = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
-                         "tools")
-    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
-    subprocess.run(
-        [sys.executable, "-c",
-         "import sys, dump_hlo; dump_hlo.dump(sys.argv[2], "
-         "sys.argv[1], compile_cost=False)", str(tmp_path),
-         "granite_4_0_h_micro_pp4"],
-        check=True, env=dict(env, PYTHONPATH=tools, JAX_PLATFORMS="cpu"),
-        capture_output=True, timeout=600)
-    with open(tmp_path / "granite_4_0_h_micro_pp4.stablehlo.txt", "rb") as f:
-        assert hashlib.sha256(f.read()).hexdigest() == GRANITE_SHA
-
-
-GRANITE_SHA = (
-    "086f1f0c1c391aaf596551052ca1ffa7836d0226ef53bc26aa73a5cc73d01793")
 
 
 # -- the loop ----------------------------------------------------------------

@@ -20,7 +20,6 @@ import re
 import threading
 
 import jax
-import jax.numpy as jnp
 import pytest
 
 from distributed_sod_project_tpu.configs import (DataConfig, MeshConfig,
@@ -150,7 +149,7 @@ _TOKEN_MODELS = {
 def _lowered_step_text(name: str, size: int = 64) -> str:
     """``lower().as_text(debug_info=True)`` of the config's train step
     on abstract state at a tiny size: traced, never compiled."""
-    from distributed_sod_project_tpu.models import build_model
+    from distributed_sod_project_tpu.models import build_model, kind_of
     from distributed_sod_project_tpu.parallel import make_mesh
     from distributed_sod_project_tpu.parallel.engine import \
         make_unified_train_step
@@ -159,18 +158,12 @@ def _lowered_step_text(name: str, size: int = 64) -> str:
 
     cfg = apply_overrides(get_config(name), [
         "global_batch_size=2", f"data.image_size={size},{size}",
-        "mesh.data=1", "mesh.model=1", "mesh.seq=1"]
+        "data.seq_len=256", "mesh.data=1", "mesh.model=1", "mesh.seq=1"]
         + _TOKEN_MODELS.get(name, []))
     mesh = make_mesh(cfg.mesh, jax.devices()[:1])
     model = build_model(cfg.model)
     tx, sched = build_optimizer(cfg.optim, 100)
-    batch = {"image": jnp.zeros((2, size, size, 3)),
-             "mask": jnp.zeros((2, size, size, 1))}
-    if cfg.data.use_depth:
-        batch["depth"] = jnp.zeros((2, size, size, 1))
-    if getattr(model, "kind", "image") == "tokens":
-        batch = {k: jnp.zeros((2, 256), jnp.int32)
-                 for k in ("tokens", "targets")}
+    batch = kind_of(model).zero_batch(cfg, 2)
     state = jax.eval_shape(
         lambda: create_train_state(jax.random.key(0), model, tx, batch))
     step = make_unified_train_step(model, cfg.loss, tx, mesh, preset="dp",

@@ -76,11 +76,9 @@ def dump(config_name: str, out_dir: str, n_devices: int = 8,
         jax.config.update("jax_platforms", "cpu")
     except Exception:  # noqa: BLE001 — backend already initialized
         pass
-    import numpy as np
-
     from distributed_sod_project_tpu.configs import (apply_overrides,
                                                      get_config)
-    from distributed_sod_project_tpu.models import build_model
+    from distributed_sod_project_tpu.models import build_model, kind_of
     from distributed_sod_project_tpu.parallel.mesh import (
         batch_sharding, make_mesh)
     from distributed_sod_project_tpu.train import (
@@ -100,18 +98,10 @@ def dump(config_name: str, out_dir: str, n_devices: int = 8,
     model = build_model(cfg.model)
     tx, sched = build_optimizer(cfg.optim, 100)
 
-    rng = np.random.RandomState(0)
-    b, hw = cfg.global_batch_size, image_size
-    batch = {
-        "image": rng.randn(b, hw, hw, 3).astype(np.float32),
-        "mask": (rng.rand(b, hw, hw, 1) > 0.5).astype(np.float32),
-    }
-    if cfg.data.use_depth:
-        batch["depth"] = rng.randn(b, hw, hw, 1).astype(np.float32)
-    if getattr(model, "kind", "image") == "tokens":
-        # The token model: --image-size is the sequence length here.
-        batch = {k: rng.randint(0, cfg.model.lm.vocab, (b, hw)).astype(
-            np.int32) for k in ("tokens", "targets")}
+    # Zeros: nothing runs, and neither the init nor the step program
+    # reads the values (a token model's --image-size is its sequence
+    # length, set above).
+    batch = kind_of(model).zero_batch(cfg, cfg.global_batch_size)
     state = create_train_state(jax.random.key(0), model, tx, batch)
     dbatch = jax.device_put(batch, batch_sharding(mesh))
 
