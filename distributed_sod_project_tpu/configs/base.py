@@ -89,8 +89,9 @@ class DataConfig:
 @dataclasses.dataclass(frozen=True)
 class LMConfig:
     """Shape of a token model (model.name=lfm2 | kimi | granite | ouro |
-    nemotron_h, models/lfm2.py, models/kimi.py, models/granite.py,
-    models/ouro.py, models/nemotron_h.py).
+    nemotron_h | phi4flash, models/lfm2.py, models/kimi.py,
+    models/granite.py, models/ouro.py, models/nemotron_h.py,
+    models/phi4flash.py).
     The defaults
     are LFM2-8B-A1B's published widths (LiquidAI, config.json) and the
     share one chip holds in `lfm2_8b_a1b_ep4`: the layers kept,
@@ -103,7 +104,11 @@ class LMConfig:
     The state-space sizes and the four multipliers are read by `granite`
     alone, whose attention layer is position-free (it reads no
     `rope_theta`); the passes and the exit term by `ouro` alone, which
-    reads `layer_types` for its length only (every layer is attention)."""
+    reads `layer_types` for its length only (every layer is attention).
+    `phi4_mini_flash_pp5` (`phi4flash`) reads `layer_types` as mamba |
+    window | full | gmu | cross, `ssm_heads` as the Mamba-1 channels (a
+    decay of its own each: `ssm_head_dim` 1), and the three fields at
+    the end."""
 
     vocab: int = 16384
     hidden: int = 2048
@@ -169,6 +174,14 @@ class LMConfig:
     # reads the hidden state itself.
     latent_width: int = 0
     shared_width: int = 0
+    # The decoder-hybrid-decoder stage (phi4flash): a window layer's
+    # query sees its last `window` keys alone, its own among them;
+    # ssm_dt_rank is the width of the Mamba-1 step size's low-rank
+    # projection; first_layer is the PUBLISHED index of the first layer
+    # kept (differential attention's starting lambda reads it).
+    window: int = 0
+    ssm_dt_rank: int = 0
+    first_layer: int = 0
 
 
 @dataclasses.dataclass(frozen=True)

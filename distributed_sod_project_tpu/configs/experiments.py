@@ -371,3 +371,45 @@ def nemotron_3_super_tp8_ep64() -> ExperimentConfig:
         num_epochs=100,
         mesh=MeshConfig(data=1, model=1, seq=1),
     )
+
+
+@register_config("phi4_mini_flash_pp5")
+def phi4_mini_flash_pp5() -> ExperimentConfig:
+    """The sixth token model: Phi-4-mini-flash-reasoning (microsoft,
+    ``phi4flash``) at its published widths, the THIRD of 5 pipeline
+    stages — published layers 14-19 of the 32, the stretch on which the
+    self-decoder hands over to the cross-decoder, so that it holds each
+    of the five layer kinds: Mamba-1, 512-key sliding-window
+    differential attention, Mamba-1 (its scan's output kept), full
+    differential attention (its keys and values kept), a gated memory
+    unit that reads the kept output, cross-attention that reads the kept
+    keys and values.  No layer divided — with rows 0-25,087 of the
+    200,064-row tied embedding (the rows are divided over 8 chips; the
+    tied head and the loss stay on this chip so that a step is a whole
+    step).  Trains on packed synthetic documents, 1 sequence of 16,384
+    tokens a step, AdamW, per-layer remat.  ``model.lm.*`` /
+    ``data.seq_len`` shrink it for a CPU drive
+    (tests/test_phi4flash.py)."""
+    return ExperimentConfig(
+        name="phi4_mini_flash_pp5",
+        data=DataConfig(dataset="packed_tokens", hflip=False,
+                        synthetic_size=4096, seq_len=16384, vocab=25088),
+        model=ModelConfig(
+            name="phi4flash", backbone="none", sync_bn=False, remat=True,
+            lm=LMConfig(
+                vocab=25088, hidden=2560,
+                layer_types=("mamba", "window", "mamba", "full", "gmu",
+                             "cross"),
+                ffn_types=("dense",) * 6, heads=40, kv_heads=20,
+                head_dim=64, dense_width=10240, norm_eps=1e-5,
+                ssm_heads=5120, ssm_head_dim=1, ssm_state=16, ssm_conv=4,
+                ssm_chunk=128, ssm_dt_rank=160, window=512,
+                first_layer=14)),
+        loss=LossConfig(),
+        # AdamW and the warm-up of the five other token configs.
+        optim=OptimConfig(optimizer="adamw", lr=3e-4, weight_decay=0.1,
+                          schedule="poly", warmup_steps=2000),
+        global_batch_size=1,
+        num_epochs=100,
+        mesh=MeshConfig(data=1, model=1, seq=1),
+    )

@@ -50,8 +50,9 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
-from ..pallas.flash_attention import (CAUSAL_RESIDUAL_NAMES, causal_blocks,
-                                      causal_pairs, flash_attention_causal)
+from ..pallas.flash_attention import (CAUSAL_RESIDUAL_NAMES, band_back,
+                                      causal_blocks, causal_pairs,
+                                      flash_attention_causal)
 from ..pallas.grouped_matmul import (TILE_M, grid_order, grouped_matmul,
                                      weight_block_fetches)
 from ..pallas.moe_unpermute import moe_unpermute, unpermute_steps
@@ -655,17 +656,24 @@ def log_saves(model: str, layers: int, saved, names,
             saved["bytes"] / 2 ** 20)
 
 
-def log_flash_grid(saved, seq_len: int) -> None:
+def log_flash_grid(saved, seq_len: int, window: int | None = None) -> None:
     """Beside ``log_saves``, under its rule: how many grid steps a head
     the causal flash kernels take over ``seq_len`` tokens (the tile pairs
     on or under the diagonal, from the function that builds the kernels'
-    tables) of the rectangle's."""
+    tables) of the rectangle's; with ``window``, the pairs of a layer
+    whose queries see their last ``window`` keys alone."""
     if saved:
         from ..utils.logging import get_logger
 
-        nb = causal_blocks(seq_len)[1]
-        get_logger().info("flash grid: steps=%d of %d a head",
-                          causal_pairs(nb)[0][0].size, nb * nb)
+        blk, nb = causal_blocks(seq_len)
+        if window is None or window >= seq_len:
+            get_logger().info("flash grid: steps=%d of %d a head",
+                              causal_pairs(nb)[0][0].size, nb * nb)
+        else:
+            get_logger().info(
+                "flash grid (window %d): steps=%d of %d a head", window,
+                causal_pairs(nb, 1, band_back(window, blk))[0][0].size,
+                nb * nb)
 
 
 def moe_counters(per_layer, pairs_total: int):

@@ -306,3 +306,14 @@ def _build_nemotron_h(cfg, *, dtype, param_dtype, axis_name):
 
     return NemotronH(cfg=cfg.lm, remat=cfg.remat, dtype=dtype,
                      param_dtype=param_dtype)
+
+
+@register_model("phi4flash")
+def _build_phi4flash(cfg, *, dtype, param_dtype, axis_name):
+    """The sixth token model (Mamba-1, windowed and full differential
+    attention, a gated memory unit and cross-attention that read what
+    earlier layers kept): its shape is ``cfg.lm``, as for ``lfm2``."""
+    from .phi4flash import Phi4Flash
+
+    return Phi4Flash(cfg=cfg.lm, remat=cfg.remat, dtype=dtype,
+                     param_dtype=param_dtype)

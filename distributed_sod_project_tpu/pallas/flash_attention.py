@@ -438,7 +438,7 @@ def causal_blocks(n: int, block: int | None = None) -> tuple[int, int]:
     return block, -(-n // block)
 
 
-def causal_pairs(nb: int, group: int = 1):
+def causal_pairs(nb: int, group: int = 1, back: int | None = None):
     """The tile pairs on or under the diagonal of ``nb`` blocks a side,
     in the two orders the causal kernels' last grid axis walks them
     (int32 numpy tables, read through scalar prefetch).
@@ -448,46 +448,82 @@ def causal_pairs(nb: int, group: int = 1):
     j == 0 and ends on its diagonal.  ``backward = (kv block, head in
     group, q block)``, ``group`` entries a pair: kv block i outer, under
     it each of the group's heads in turn, its q blocks j = i .. nb - 1,
-    so every head starts a kv block on the diagonal."""
+    so every head starts a kv block on the diagonal.
+
+    ``back`` (a sliding window, :func:`band_back`): only the pairs at
+    most ``back`` blocks under the diagonal, in the same two orders — a
+    row then starts at ``j == max(i - back, 0)`` and a kv block ends at
+    q block ``min(i + back, nb - 1)``."""
     rows = np.arange(nb, dtype=np.int32)
-    ahead = nb - rows                        # pairs kv block i visits a head
-    fwd = (np.repeat(rows, rows + 1),
-           np.concatenate([rows[:i + 1] for i in rows]))
+    back = nb - 1 if back is None else min(back, nb - 1)
+    low = np.maximum(rows - back, 0)         # a q block's first kv block
+    ahead = np.minimum(nb - rows, back + 1)  # pairs kv block i visits a head
+    fwd = (np.repeat(rows, rows - low + 1),
+           np.concatenate([rows[low[i]:i + 1] for i in rows]))
     bwd = (np.repeat(rows, group * ahead),
            np.concatenate([np.repeat(np.arange(group, dtype=np.int32), a)
                            for a in ahead]),
-           np.concatenate([np.tile(rows[i:], group) for i in rows]))
+           np.concatenate([np.tile(rows[i:i + ahead[i]], group)
+                           for i in rows]))
     return fwd, bwd
 
 
-def _causal_p(q_ref, k_ref, lse_or_none, *, scale, masked):
+def band_back(window: int, block: int) -> int:
+    """Blocks under the diagonal a window of ``window`` keys (the query's
+    own among them) reaches: tile pair (i, j) holds a visible entry iff
+    ``i - back <= j <= i``."""
+    if window < 1:
+        raise ValueError(f"a window of {window} keys sees nothing")
+    return (window + block - 2) // block
+
+
+def _causal_p(q_ref, k_ref, lse_or_none, *, scale, masked, band=None):
     """One tile pair: the masked, scaled scores (forward: no lse yet),
-    or ``p = exp(scores - lse)`` once the row's lse is known."""
+    or ``p = exp(scores - lse)`` once the row's lse is known.  ``band``
+    = (blocks the pair lies under the diagonal, window): the mask of a
+    windowed pair, both of its edges (``0 <= row - col < window`` in
+    whole-sequence positions)."""
     s = lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
                         preferred_element_type=jnp.float32) * scale
     if masked:
         row = lax.broadcasted_iota(jnp.int32, s.shape, 0)
         col = lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(col <= row, s, _MASK_VALUE)
+        if band is None:
+            s = jnp.where(col <= row, s, _MASK_VALUE)
+        else:
+            under, window = band
+            gap = row - col + under * s.shape[0]
+            s = jnp.where((gap >= 0) & (gap < window), s, _MASK_VALUE)
     if lse_or_none is None:
         return s
     return jnp.exp(s - _widen(lse_or_none, s.shape[1]))
 
 
+def _band_edges(i, j, window, blk):
+    """A windowed pair (q block i, kv block j): (the mask's ``band``,
+    whether the pair needs it: the diagonal, and the pairs so far under
+    it that the window's far edge cuts them)."""
+    return (i - j, window), (j == i) | (i - j >= window // blk)
+
+
 def _c_fwd_kernel(qb_ref, kb_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                  m_s, l_s, acc_s, *, scale: float):
+                  m_s, l_s, acc_s, *, scale: float, window=None):
     pair = pl.program_id(1)
     i, j = qb_ref[pair], kb_ref[pair]           # q block; kv block <= i
     d = acc_s.shape[1]
+    blk = q_ref.shape[1]
+    first = 0 if window is None else jnp.maximum(
+        i - band_back(window, blk), 0)
 
-    @pl.when(j == 0)
+    @pl.when(j == first)
     def _():
         m_s[...] = jnp.full(m_s.shape, _MASK_VALUE, jnp.float32)
         l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
         acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
 
-    def visit(masked):
-        s = _causal_p(q_ref, k_ref, None, scale=scale, masked=masked)
+    def visit(masked, band=None):
+        s = _causal_p(q_ref, k_ref, None, scale=scale, masked=masked,
+                      band=band)
         m_prev = m_s[...]
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])
         p = jnp.exp(s - _widen(m_next, s.shape[1]))
@@ -499,11 +535,19 @@ def _c_fwd_kernel(qb_ref, kb_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                              preferred_element_type=jnp.float32)
         acc_s[...] = acc_s[...] * _widen(corr, d) + pv
 
-    pl.when(j < i)(lambda: visit(False))
+    if window is None:
+        pl.when(j < i)(lambda: visit(False))
+    else:
+        # A row the far edge hides all of a pair from leaves m at the
+        # mask's value there; the diagonal, which every row sees, then
+        # scales that pair's share to exp(mask - m) = 0.
+        band, cut = _band_edges(i, j, window, blk)
+        pl.when(~cut)(lambda: visit(False))
+        pl.when(cut & (j < i))(lambda: visit(True, band))
 
     @pl.when(j == i)   # the diagonal: the row's last pair
     def _():
-        visit(True)
+        visit(True, None if window is None else (0, window))
         o_ref[0] = (acc_s[...] / _widen(l_s[...], d)).astype(o_ref.dtype)
         lse_ref[0] = m_s[...] + jnp.log(l_s[...])
 
@@ -520,7 +564,7 @@ def _causal_ds(p, q_side, v_ref, *, scale):
 
 def _c_bwd_kernel(kb_ref, head_ref, qb_ref, k_ref, v_ref, q_ref, do_ref,
                   out_ref, lse_ref, dq_ref, dk_ref, dv_ref, dq_s, dk_s, dv_s,
-                  *, scale: float, nb: int, group: int):
+                  *, scale: float, nb: int, group: int, window=None):
     pair = pl.program_id(1)
     # kv block; head in group; q block >= i
     i, g, j = kb_ref[pair], head_ref[pair], qb_ref[pair]
@@ -535,8 +579,9 @@ def _c_bwd_kernel(kb_ref, head_ref, qb_ref, k_ref, v_ref, q_ref, do_ref,
         dk_s[...] = jnp.zeros(dk_s.shape, jnp.float32)
         dv_s[...] = jnp.zeros(dv_s.shape, jnp.float32)
 
-    def visit(masked):
-        p = _causal_p(q_ref, k_ref, lse_ref[0], scale=scale, masked=masked)
+    def visit(masked, band=None):
+        p = _causal_p(q_ref, k_ref, lse_ref[0], scale=scale, masked=masked,
+                      band=band)
         over_q = (((0,), (0,)), ((), ()))   # contract the pair's q rows
         dv_s[...] += lax.dot_general(p.astype(do_ref.dtype), do_ref[0],
                                      over_q,
@@ -548,59 +593,86 @@ def _c_bwd_kernel(kb_ref, head_ref, qb_ref, k_ref, v_ref, q_ref, do_ref,
         dq_s[t] += lax.dot_general(ds, k_ref[0], (((1,), (0,)), ((), ())),
                                    preferred_element_type=jnp.float32)
 
-    pl.when(j > i)(lambda: visit(False))
+    last = nb - 1
+    if window is None:
+        pl.when(j > i)(lambda: visit(False))
+    else:
+        blk = q_ref.shape[1]
+        band, cut = _band_edges(j, i, window, blk)
+        pl.when(~cut)(lambda: visit(False))
+        pl.when(cut & (j > i))(lambda: visit(True, band))
+        last = jnp.minimum(i + band_back(window, blk), last)
 
     @pl.when(j == i)
     def _():
-        visit(True)
+        visit(True, None if window is None else (0, window))
         # Every kv block <= i has added to this head's q block i by now.
         dq_ref[0] = dq_s[t].astype(dq_ref.dtype)
 
-    @pl.when((g == group - 1) & (j == nb - 1))   # ... and its last
+    @pl.when((g == group - 1) & (j == last))   # ... and its last
     def _():
         dk_ref[0] = dk_s[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
 
 
+def _causal_cfg(cfg, np_):
+    """(block, group, interpret, window or None, blocks under the
+    diagonal the tables hold or None)."""
+    blk, group, interpret, *rest = cfg
+    window = rest[0] if rest else None
+    return (blk, group, interpret, window,
+            None if window is None else band_back(window, blk))
+
+
+def _band_share(pairs, nb):
+    """Share of the triangle's tile pairs a forward table holds."""
+    return pairs[0].size / (nb * (nb + 1) // 2)
+
+
 @jax.named_scope("dsod.kernel.flash_attention_causal")
 def _c_fwd_call(q, k, v, cfg):
-    blk, group, interpret = cfg
     bh, np_, d = q.shape
-    pairs, _ = causal_pairs(np_ // blk)
+    dv = v.shape[2]                              # a value's own width
+    blk, group, interpret, window, back = _causal_cfg(cfg, np_)
+    pairs, _ = causal_pairs(np_ // blk, 1, back)
+    share = _band_share(pairs, np_ // blk)
     q_ix = lambda b, p, qb, kb: (b, qb[p], 0)  # noqa: E731
+    kv_ix = lambda b, p, qb, kb: (b // group, kb[p], 0)  # noqa: E731
     qs = pl.BlockSpec((1, blk, d), q_ix)
-    kvs = pl.BlockSpec((1, blk, d),
-                       lambda b, p, qb, kb: (b // group, kb[p], 0))
     row = pl.BlockSpec((1, blk, _LANES), q_ix)
     return pl.pallas_call(
-        partial(_c_fwd_kernel, scale=1.0 / d**0.5),
+        partial(_c_fwd_kernel, scale=1.0 / d**0.5, window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(bh, pairs[0].size),
-            in_specs=[qs, kvs, kvs],
-            out_specs=[qs, row],
+            in_specs=[qs, pl.BlockSpec((1, blk, d), kv_ix),
+                      pl.BlockSpec((1, blk, dv), kv_ix)],
+            out_specs=[pl.BlockSpec((1, blk, dv), q_ix), row],
             scratch_shapes=[pltpu.VMEM((blk, _LANES), jnp.float32),
                             pltpu.VMEM((blk, _LANES), jnp.float32),
-                            pltpu.VMEM((blk, d), jnp.float32)]),
-        out_shape=[jax.ShapeDtypeStruct((bh, np_, d), q.dtype),
+                            pltpu.VMEM((blk, dv), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((bh, np_, dv), q.dtype),
                    jax.ShapeDtypeStruct((bh, np_, _LANES), jnp.float32)],
         cost_estimate=pl.CostEstimate(
-            flops=2 * bh * np_ * np_ * d,
-            transcendentals=bh * np_ * np_ // 2,
-            bytes_accessed=2 * (q.size + k.size) * q.dtype.itemsize),
+            flops=int(share * bh * np_ * np_ * (d + dv)),
+            transcendentals=int(share * (bh * np_ * np_ // 2)),
+            bytes_accessed=(q.size + bh * np_ * dv + k.size + v.size)
+            * q.dtype.itemsize),
         interpret=interpret,
     )(*pairs, q, k, v)
 
 
-def _causal_bwd_vmem_bytes(group, np_, blk, d, itemsize):
+def _causal_bwd_vmem_bytes(group, np_, blk, d, itemsize, dv=None):
     """What the fused backward holds in VMEM: the float32 dq accumulator
     of the group's G heads over the whole row range; each operand and
-    result tile twice (the pipeline's two buffers); the kv block's two
+    result tile twice (the pipeline's two buffers: k, q, dq, dk of the
+    key's width, v, do, out, dv of the value's); the kv block's two
     accumulators; and room for the float32 score-sized temporaries of one
     tile pair (s, p, dp, ds, their casts and transposes)."""
     d = -(-d // _LANES) * _LANES
-    tiles = 2 * blk * (itemsize * 8 * d + 4 * _LANES)
-    return (4 * group * np_ * d + tiles + 4 * blk * 2 * d
+    dv = d if dv is None else -(-dv // _LANES) * _LANES
+    tiles = 2 * blk * (itemsize * 4 * (d + dv) + 4 * _LANES)
+    return (4 * group * np_ * d + tiles + 4 * blk * (d + dv)
             + 12 * 4 * blk * blk)
 
 
@@ -612,15 +684,20 @@ def _c_bwd_kernel_call(q, k, v, out, lse, do, cfg):
     (``causal_pairs``' backward order)."""
     from .vmem_budget import fitted_vmem_params
 
-    blk, group, interpret = cfg
     bh, np_, d = q.shape
+    dv = v.shape[2]
+    blk, group, interpret, window, back = _causal_cfg(cfg, np_)
     nb = np_ // blk
-    _, pairs = causal_pairs(nb, group)
+    fwd_pairs, pairs = causal_pairs(nb, group, back)
+    share = _band_share(fwd_pairs, nb)
     q_ix = lambda b, p, kb, head, qb: (  # noqa: E731
         b * group + head[p], qb[p], 0)
-    qs = pl.BlockSpec((1, blk, d), q_ix)
+    kv_ix = lambda b, p, kb, head, qb: (b, kb[p], 0)  # noqa: E731
+    qs, outs = pl.BlockSpec((1, blk, d), q_ix), pl.BlockSpec((1, blk, dv),
+                                                             q_ix)
     row = pl.BlockSpec((1, blk, _LANES), q_ix)
-    kvs = pl.BlockSpec((1, blk, d), lambda b, p, kb, head, qb: (b, kb[p], 0))
+    ks, vs = pl.BlockSpec((1, blk, d), kv_ix), pl.BlockSpec((1, blk, dv),
+                                                            kv_ix)
     # dq's finished block (head, block i: written at the diagonal, the
     # head's first pair under kv block i) stays put while that head's q
     # blocks pass and is flushed after them.
@@ -628,26 +705,27 @@ def _c_bwd_kernel_call(q, k, v, out, lse, do, cfg):
         b * group + head[p], kb[p], 0))
     acc = lambda *shape: pltpu.VMEM(shape, jnp.float32)  # noqa: E731
     return pl.pallas_call(
-        partial(_c_bwd_kernel, scale=1.0 / d**0.5, nb=nb, group=group),
+        partial(_c_bwd_kernel, scale=1.0 / d**0.5, nb=nb, group=group,
+                window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(k.shape[0], pairs[0].size),
-            in_specs=[kvs, kvs, qs, qs, qs, row],
-            out_specs=[dqs, kvs, kvs],
+            in_specs=[ks, vs, qs, outs, outs, row],
+            out_specs=[dqs, ks, vs],
             scratch_shapes=[acc(group * nb, blk, d), acc(blk, d),
-                            acc(blk, d)]),
+                            acc(blk, dv)]),
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         compiler_params=fitted_vmem_params(
-            _causal_bwd_vmem_bytes(group, np_, blk, d, q.dtype.itemsize),
+            _causal_bwd_vmem_bytes(group, np_, blk, d, q.dtype.itemsize, dv),
             f"flash_attention_causal's backward over {np_} rows of "
             f"{group} heads a kv head"),
         cost_estimate=pl.CostEstimate(
-            flops=5 * bh * np_ * np_ * d,
-            transcendentals=bh * np_ * np_ // 2,
-            bytes_accessed=(4 * q.size + 2 * k.size + 2 * v.size)
-            * q.dtype.itemsize + 4 * lse.size),
+            flops=int(share * bh * np_ * np_ * (3 * d + 2 * dv)),
+            transcendentals=int(share * (bh * np_ * np_ // 2)),
+            bytes_accessed=(2 * q.size + 2 * do.size + 2 * k.size
+                            + 2 * v.size) * q.dtype.itemsize + 4 * lse.size),
         interpret=interpret,
     )(*pairs, k, v, q, do, out, lse)
 
@@ -693,36 +771,44 @@ def _flash_causal_bwd(cfg, res, g):
 _flash_causal.defvjp(_flash_causal_fwd, _flash_causal_bwd)
 
 
-def flash_attention_causal(q, k, v, *, block: int | None = None,
+def flash_attention_causal(q, k, v, *, window: int | None = None,
+                           block: int | None = None,
                            interpret: bool | None = None) -> jnp.ndarray:
     """Causal attention with grouped KV heads.
 
-    q: [B, Hq, N, D]; k, v: [B, Hkv, N, D] with Hkv dividing Hq (query
-    head h reads kv head h // (Hq / Hkv)); any N (zero-padded to the
-    block), D <= 128 or a multiple of 128.  The grid holds only the tile
-    pairs on or under the diagonal (``causal_pairs``): one above it is no
-    grid step.  Differentiable: the backward is one Pallas kernel that
+    q: [B, Hq, N, D]; k: [B, Hkv, N, D]; v: [B, Hkv, N, Dv] with Hkv
+    dividing Hq (query head h reads kv head h // (Hq / Hkv)); any N
+    (zero-padded to the block), D and Dv each <= 128 or a multiple of
+    128; the result is Dv wide.  The grid holds only the tile pairs on
+    or under the diagonal (``causal_pairs``): one above it is no grid
+    step.  ``window``: query i sees keys ``i - window < j <= i`` alone
+    (its own among them); the grid then holds only the pairs that band
+    touches, and the pairs an edge of it cuts are masked.
+    Differentiable: the backward is one Pallas kernel that
     visits each tile pair once for dq, dk and dv, its float32 dq
     accumulators held in VMEM for the whole sequence of a kv head's
     group — a sequence too long for the chip's VMEM raises.
     """
-    if k.shape != v.shape or q.ndim != 4 or k.ndim != 4:
+    if k.shape[:3] != v.shape[:3] or q.ndim != 4 or k.ndim != 4 \
+            or v.ndim != 4:
         raise ValueError(f"bad q/k/v shapes {q.shape} {k.shape} {v.shape}")
     b, hq, n, d = q.shape
-    hkv = k.shape[1]
+    hkv, dv = k.shape[1], v.shape[3]
     if (b, n, d) != (k.shape[0], k.shape[2], k.shape[3]) or hq % hkv:
         raise ValueError(f"q {q.shape} and k/v {k.shape} do not pair into "
                          "groups of query heads over shared kv heads")
-    if d > _LANES and d % _LANES:
-        raise ValueError(f"head dim {d} unsupported")
+    if any(w > _LANES and w % _LANES for w in (d, dv)):
+        raise ValueError(f"head dim {d} / {dv} unsupported")
     block, nb = causal_blocks(n, block)
     np_ = nb * block
     interpret = (jax.default_backend() == "cpu" if interpret is None
                  else interpret)
-    fold = lambda t: _pad_n(t.reshape(-1, n, d), np_)  # noqa: E731
-    out = _flash_causal(fold(q), fold(k), fold(v),
-                        (block, hq // hkv, interpret))
-    return out[:, :n].reshape(b, hq, n, d)
+    fold = lambda t: _pad_n(t.reshape(-1, n, t.shape[3]), np_)  # noqa: E731
+    cfg = (block, hq // hkv, interpret)
+    if window is not None and window < n:   # (a longer one hides nothing)
+        cfg += (int(window),)
+    out = _flash_causal(fold(q), fold(k), fold(v), cfg)
+    return out[:, :n].reshape(b, hq, n, dv)
 
 
 # ---------------------------------------------------------------------------

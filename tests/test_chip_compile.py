@@ -41,12 +41,12 @@ sys.path.insert(0, os.path.join(_REPO, "tools"))
 import step_cache_key  # noqa: E402 — imports no JAX until it is called
 
 _P = "distributed_sod_project_tpu.pallas."
-fc, fr, dfm, fl, fs, fa, vb, gm, mu, ssd, cc, rot = (
+fc, fr, dfm, fl, fs, fa, vb, gm, mu, ssd, cc, rot, sel = (
     importlib.import_module(_P + m)
     for m in ("fused_conv", "fused_resample", "dynamic_filter",
               "fused_loss", "fused_ssim", "flash_attention",
               "vmem_budget", "grouped_matmul", "moe_unpermute", "ssd_scan",
-              "causal_conv", "rotary"))
+              "causal_conv", "rotary", "selective_scan"))
 
 _S = collections.namedtuple("_S", "shape dtype")  # an argument's spec
 B = 2  # the kernels grid over images; the tile is what the compiler prices
@@ -175,6 +175,19 @@ _ssd = partial(ssd.ssd_scan, chunk=256, interpret=False)
 _SSD_ARGS = (_S((1, 16384, 64, 64), BF), _S((1, 16384, 64), F32),
              _S((64,), F32), _S((1, 16384, 128), BF),
              _S((1, 16384, 128), BF))
+
+
+# phi4_mini_flash_pp5: one 16,384-token sequence of 5,120 Mamba-1
+# channels of 16 states (x, delta, A, B, C, D), and one differential
+# layer's two softmax maps in one call: 40 query heads of 64 over 20 key
+# heads of 64 and values of 128.
+_sel = partial(sel.selective_scan, interpret=False)
+_SEL_ARGS = (_S((1, 16384, 5120), BF), _S((1, 16384, 5120), F32),
+             _S((5120, 16), F32), _S((1, 16384, 16), BF),
+             _S((1, 16384, 16), BF), _S((5120,), F32))
+_DIFF_QKV = (_S((1, 40, 16384, 64), BF), _S((1, 20, 16384, 64), BF),
+             _S((1, 20, 16384, 128), BF))
+_window = partial(_causal, window=512)
 
 
 # ... and its mixer's conv + bias + SiLU over x | B | C: 4,352 columns of
@@ -338,6 +351,20 @@ CASES = {
         _rotary, _ROTARY_ARGS, *_ROTARY_ARGS) + (1,),
     "moe_unpermute@53248": _unpermute(53248),
     "moe_unpermute@135168": _unpermute(135168),
+    # phi4_mini_flash_pp5's selective scan: forward (y and the states the
+    # chunks started from), and the gradient (the forward again + ONE
+    # backward kernel that gives all six cotangents).
+    "selective_scan.fwd@16384x5120": (_sel, _SEL_ARGS, 1),
+    "selective_scan.bwd@16384x5120": (
+        jax.grad(lambda *a: _sel(*a).astype(F32).sum(),
+                 argnums=(0, 1, 2, 3, 4, 5)), _SEL_ARGS, 2),
+    # ... and the grouped causal pair at a value twice the key's width,
+    # over the triangle and over the 512-key band.
+    "flash_attention_causal.bwd@16384x40x64v128": (
+        _causal_grad, _DIFF_QKV, 2),
+    "flash_attention_causal.bwd@16384x40x64v128,window512": (
+        jax.grad(lambda q, k, v: _window(q, k, v).astype(F32).sum(),
+                 argnums=(0, 1, 2)), _DIFF_QKV, 2),
 }
 
 
@@ -506,6 +533,23 @@ def test_the_hybrid_step_compiles_for_v5e_and_fits(chip, topo, monkeypatch):
     assert ".remat" not in compiled.as_text()
     # 700.9 M parameters x 12 bytes
     assert 7.8 < _state_gib_and_fits(compiled) < 7.9
+
+
+def test_the_decoder_hybrid_decoder_step_compiles_for_v5e_and_fits(
+        chip, topo, monkeypatch):
+    """``phi4_mini_flash_pp5``'s whole train step at the cell's size
+    (published widths, 6 layers, 16,384 tokens) compiled for a described
+    v5e: 16 kernels (two Mamba-1 layers: the scan forward ONCE and
+    backward, the conv forward twice and backward; three attention
+    layers: one forward and one backward causal flash call each, both
+    softmax maps in one), no op rematerialised by the compiler, state +
+    temporaries inside the chip's 15.75 GiB."""
+    lowered = _lowered_step("phi4_mini_flash_pp5", topo, monkeypatch)
+    assert lowered.as_text().count("tpu_custom_call") == 16
+    compiled = lowered.compile()
+    assert ".remat" not in compiled.as_text()
+    # 697.2 M parameters x 12 bytes
+    assert 7.7 < _state_gib_and_fits(compiled) < 7.9
 
 
 def test_availability_rules_match_the_compiler():

@@ -291,7 +291,7 @@ def test_every_registered_config_resolves_to_a_kind(config_name):
         "data.image_size=32,32", "data.seq_len=192"])
     kind = kind_of(build_model(cfg.model))
     tokens = cfg.model.name in ("lfm2", "kimi", "granite", "ouro",
-                                "nemotron_h")
+                                "nemotron_h", "phi4flash")
     assert kind.name == ("tokens" if tokens else "image")
     assert kind.dp_only == tokens
     batch = kind.zero_batch(cfg, 2)

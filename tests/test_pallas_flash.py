@@ -417,3 +417,113 @@ def test_flash_mla_pair_grid_matches_plain_attention(n, h, dtype):
         np.testing.assert_allclose(
             np.asarray(a, np.float32), np.asarray(b), err_msg=name,
             atol=5 * tol * float(jnp.max(jnp.abs(b))))
+
+
+# -- the grouped causal kernel: a sliding window, a value wider than a key ---
+
+def _dense_causal(q, k, v, window=None):
+    """Masked softmax in plain XLA: grouped kv heads, causal, a query
+    seeing its last ``window`` keys alone (its own among them)."""
+    b, hq, n, d = q.shape
+    g = hq // k.shape[1]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, jnp.repeat(k, g, 1)) / d ** 0.5
+    i, j = jnp.arange(n)[:, None], jnp.arange(n)[None, :]
+    seen = j <= i if window is None else (j <= i) & (i - j < window)
+    return jnp.einsum("bhqk,bhkd->bhqd",
+                      jax.nn.softmax(jnp.where(seen, s, -jnp.inf), -1),
+                      jnp.repeat(v, g, 1))
+
+
+def _causal_inputs(n, d=32, dv=32, seed=0):
+    ks = jax.random.split(jax.random.key(seed), 4)
+    return (jax.random.normal(ks[0], (2, 4, n, d)),
+            jax.random.normal(ks[1], (2, 2, n, d)),
+            jax.random.normal(ks[2], (2, 2, n, dv)),
+            jax.random.normal(ks[3], (2, 4, n, dv)))
+
+
+# blocks of 128 over 640 (5 blocks) or 600 rows (padding): a window
+# smaller than a block (the diagonal pair cut twice), equal to one (the
+# published 512 over blocks of 512, in small), between one and two,
+# larger than two, of one key; and a value twice the key's width
+@pytest.mark.parametrize("n,window,dv", [
+    (640, 40, 32), (640, 128, 32), (600, 200, 32), (640, 300, 32),
+    (640, 1, 32), (640, None, 64), (600, 128, 64)])
+def test_causal_window_and_wide_value_match_dense_masked_softmax(
+        n, window, dv):
+    from distributed_sod_project_tpu.pallas.flash_attention import \
+        flash_attention_causal
+
+    q, k, v, cot = _causal_inputs(n, dv=dv)
+    kernel = lambda *a: flash_attention_causal(  # noqa: E731
+        *a, window=window, block=128)
+    out = kernel(q, k, v)
+    assert out.shape == cot.shape
+    np.testing.assert_allclose(np.asarray(out), np.asarray(
+        _dense_causal(q, k, v, window)), atol=3e-6)
+    got = jax.grad(lambda *a: jnp.sum(kernel(*a) * cot), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(_dense_causal(*a, window) * cot),
+                    (0, 1, 2))(q, k, v)
+    for name, a, b in zip("qkv", got, want):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), err_msg=f"d{name}",
+            atol=5e-6 * max(float(jnp.max(jnp.abs(b))), 1.0))
+
+
+def test_a_window_longer_than_the_sequence_is_plain_causal():
+    """The same tables, the same kernels: the same program."""
+    from distributed_sod_project_tpu.pallas.flash_attention import \
+        flash_attention_causal
+
+    q, k, v, _ = _causal_inputs(256)
+    plain = jax.make_jaxpr(lambda *a: flash_attention_causal(
+        *a, block=128))(q, k, v)
+    for window in (256, 1000):
+        assert str(jax.make_jaxpr(lambda *a: flash_attention_causal(
+            *a, window=window, block=128))(q, k, v)) == str(plain)
+    assert str(jax.make_jaxpr(lambda *a: flash_attention_causal(
+        *a, window=255, block=128))(q, k, v)) != str(plain)
+
+
+@pytest.mark.parametrize("group", [1, 4])
+@pytest.mark.parametrize("nb", [1, 2, 5, 16, 32])
+def test_without_a_window_the_tables_are_the_triangles(nb, group):
+    """``window=None`` builds what ``causal_pairs`` built before it took
+    a band (the comprehension is PR 45's function, kept here), and a band
+    that reaches every block is the triangle."""
+    from distributed_sod_project_tpu.pallas.flash_attention import \
+        causal_pairs
+
+    rows = np.arange(nb, dtype=np.int32)
+    ahead = nb - rows
+    fwd = (np.repeat(rows, rows + 1),
+           np.concatenate([rows[:i + 1] for i in rows]))
+    bwd = (np.repeat(rows, group * ahead),
+           np.concatenate([np.repeat(np.arange(group, dtype=np.int32), a)
+                           for a in ahead]),
+           np.concatenate([np.tile(rows[i:], group) for i in rows]))
+    for back in (None, nb - 1, nb + 3):
+        got = causal_pairs(nb, group, back)
+        for a, b in zip(got[0] + got[1], fwd + bwd):
+            assert a.dtype == b.dtype == np.int32
+            np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("window,block,back", [
+    (1, 128, 0), (2, 128, 1), (128, 128, 1), (129, 128, 1), (130, 128, 2),
+    (512, 512, 1), (513, 512, 1), (514, 512, 2), (1024, 512, 2)])
+def test_the_band_holds_the_pairs_a_window_touches_and_no_other(
+        window, block, back):
+    from distributed_sod_project_tpu.pallas.flash_attention import (
+        band_back, causal_pairs)
+
+    assert band_back(window, block) == back
+    nb, group = 6, 2
+    (q_of, k_of), (kv_of, head_of, qb_of) = causal_pairs(nb, group, back)
+    # pair (i, j) holds an entry with 0 <= row - col < window
+    touched = [(i, j) for i in range(nb) for j in range(i + 1)
+               if (i - j - 1) * block + 1 < window]
+    assert list(zip(q_of.tolist(), k_of.tolist())) == touched
+    assert list(zip(kv_of.tolist(), head_of.tolist(), qb_of.tolist())) == [
+        (j, g, i) for j in range(nb) for g in range(group)
+        for i in range(j, nb) if (i, j) in touched]

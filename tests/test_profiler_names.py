@@ -143,7 +143,12 @@ _TOKEN_MODELS = {
         "model.lm.latent_width=32", "model.lm.shared_width=96",
         "model.lm.experts=16", "model.lm.experts_held=4", "model.lm.top_k=3",
         "model.lm.ssm_heads=8", "model.lm.ssm_head_dim=16",
-        "model.lm.ssm_state=16", "model.lm.ssm_chunk=64"]}
+        "model.lm.ssm_state=16", "model.lm.ssm_chunk=64"],
+    "phi4_mini_flash_pp5": _TINY_LM + [
+        "model.lm.kv_heads=2", "model.lm.head_dim=16",
+        "model.lm.ssm_heads=128", "model.lm.ssm_state=16",
+        "model.lm.ssm_chunk=64", "model.lm.ssm_dt_rank=4",
+        "model.lm.window=24"]}
 
 
 def _lowered_step_text(name: str, size: int = 64) -> str:

@@ -9,7 +9,8 @@ the commit before each later model left them, with PR 40's one-kernel
 causal backward, PR 44's grouped product (a weight block moves only
 where the expert or the column block does) and PR 45's causal flash
 grids (only the tile pairs on or under the diagonal); the image step as
-PR 46 found it.  On the chip the kernels are Mosaic calls this lowering
+PR 46 found it; the sixth token step as PR 47 brought it (the windowed
+band and the value's own width moved none of the five before it).  On the chip the kernels are Mosaic calls this lowering
 cannot see: ``tools/step_cache_key.py`` hashes those.
 """
 
@@ -33,6 +34,8 @@ PINS = {
         "ec4cabce12c073955b2077f9e80d15c2fff424f93c8b5ca5e2a34ee1cfe85181",
     "nemotron_3_super_tp8_ep64":
         "929d5936b27b67aaffd2b1385ff8685402719f7bd0a5a358b39256fc2e4b91f9",
+    "phi4_mini_flash_pp5":
+        "cfa5951d4fe55935753c7aa8717ac211328842aa997aef10c7bbe7b7135b6abc",
 }
 
 

@@ -43,7 +43,11 @@ _TINY_LM = {  # model.name -> the token model's shrink
                            "model.lm.shared_width=96", "model.lm.experts=16",
                            "model.lm.experts_held=4", "model.lm.top_k=3",
                            "model.lm.ssm_heads=8", "model.lm.ssm_head_dim=16",
-                           "model.lm.ssm_state=16", "model.lm.ssm_chunk=32"]}
+                           "model.lm.ssm_state=16", "model.lm.ssm_chunk=32"],
+    "phi4flash": _TINY + ["model.lm.kv_heads=2", "model.lm.head_dim=16",
+                          "model.lm.ssm_heads=128", "model.lm.ssm_state=16",
+                          "model.lm.ssm_chunk=32", "model.lm.ssm_dt_rank=4",
+                          "model.lm.window=24"]}
 
 
 def dump(config_name: str, out_dir: str, n_devices: int = 8,
